@@ -1,7 +1,9 @@
 """Benchmark: PGPE + fully-vectorized neuroevolution rollout throughput.
 
-The driver runs this on real TPU hardware and records the single JSON line
-printed to stdout. Metric: environment steps per second through the flagship
+Runs on the TPU (an accelerator is required unless ``JAX_PLATFORMS=cpu``
+asks for the CPU, where the numbers check correctness and counts, not speed)
+and prints a single JSON line to stdout, whose ``backend`` field is the
+device as jax reports it. Metric: environment steps per second through the flagship
 path — ``run_vectorized_rollout`` (one jitted program containing the whole
 population x env x time loop) driven by PGPE, popsize 10k, MLP policy on the
 pure-JAX Humanoid locomotion env (17 actuated DOF, 109-dim obs, contact
@@ -49,8 +51,8 @@ contract and hoisted top-level for the primary one: ``compile_seconds``
 (cost-model FLOPs per counted env-step), ``peak_hbm_bytes`` (analyzed peak
 footprint — donation-aware, a dropped ``donate_argnums`` inflates it) and
 ``model_efficiency`` (MFU-style: achieved MODEL FLOP rate —
-2 x param_count useful FLOPs per counted env-step — vs the nominal
-per-backend peak; ``EVOTORCH_PEAK_FLOPS`` overrides; see
+2 x param_count useful FLOPs per counted env-step — vs the device's
+published peak, observability.report.DEVICE_PEAKS; null on the CPU; see
 bench_common.ledger_columns for why the cost-model FLOPs are NOT the
 numerator). ``BENCH_LEDGER=0`` skips the capture
 (one extra untimed trace+compile per contract) and keeps the line
@@ -90,11 +92,12 @@ asserted bit-identical to the standalone leg during warmup. Adds
 ``slo --check-bench --max-queue-wait-p99`` reads). Off by default; line
 byte-compatible.
 
-``BENCH_COMPILE_CACHE=1`` enables the persistent XLA compilation cache
-(observability/compilecache.py; dir override ``EVOTORCH_COMPILE_CACHE_DIR``)
-and appends a ``compile_cache`` block — hit/miss counters and cold/warm
-provenance, so a recorded ``compile_seconds`` can be attributed to a real
-compile vs a cache deserialize. Default off; line byte-compatible.
+The persistent XLA compilation cache is always on
+(observability/compilecache.py: ``JAX_COMPILATION_CACHE_DIR`` when set, else
+``<checkout>/compile_cache``); the line's ``compile_cache`` block carries
+hit/miss counters and cold/warm provenance, so a recorded
+``compile_seconds`` can be attributed to a real compile vs a cache
+deserialize.
 
 ``BENCH_BACKEND=mujoco`` additionally measures the REAL-MuJoCo host path
 (``MjVecEnv`` over ``mujoco.rollout``): the PR-2 synchronous fixed-chunk loop
@@ -117,6 +120,7 @@ from bench_common import (
     bench_config,
     bench_hidden,
     build_policy,
+    device_record,
     fresh_pgpe_state,
     ledger_columns,
     measure_mujoco,
@@ -131,8 +135,6 @@ def main():
     use_cpu = setup_backend()
     import jax
     import jax.numpy as jnp
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
     from evotorch_tpu.algorithms.functional import (
         pgpe_ask,
@@ -149,19 +151,20 @@ def main():
         run_vectorized_rollout,
         run_vectorized_rollout_compacting,
     )
-    from evotorch_tpu.observability import GroupTelemetry, MetricsHub
+    from evotorch_tpu.observability import (
+        GroupTelemetry,
+        MetricsHub,
+        cache_stats,
+        enable_persistent_cache,
+    )
     from evotorch_tpu.observability import ledger as program_ledger
     from evotorch_tpu.observability.inventory import capture_compact_chunk
     from evotorch_tpu.observability.programs import abstract_like
 
     cfg = bench_config(use_cpu)
-    if cfg["compile_cache"]:
-        # BENCH_COMPILE_CACHE=1: persistent XLA compile cache — the second
-        # process deserializes instead of recompiling; the line's
-        # `compile_cache` block says which happened (cold/warm provenance)
-        from evotorch_tpu.observability import enable_persistent_cache
-
-        enable_persistent_cache()
+    # persistent XLA compile cache: a second process deserializes instead of
+    # recompiling; the line's `compile_cache` block says which happened
+    enable_persistent_cache()
     popsize = cfg["popsize"]
     episode_length = cfg["episode_length"]
     generations = cfg["generations"]
@@ -797,7 +800,7 @@ def main():
         "eval_mode": eval_mode,
         "lowrank": lowrank,
         "compute_dtype": str(compute_dtype.__name__ if compute_dtype else "float32"),
-        "backend": "cpu-fallback" if use_cpu else "tpu",
+        "backend": device_record(),
     }
     primary_groups = group_telemetry_by_mode.get(eval_mode)
     if primary_groups is not None and primary_groups.has_health:
@@ -870,26 +873,22 @@ def main():
         line["policy_form"] = (
             "trunk_delta" if trunk_delta else "lowrank" if lowrank else "dense"
         )
-    if cfg["compile_cache"]:
-        # hit/miss counters from the persistent compile cache plus the
-        # derived provenance: "warm" = every program this process compiled
-        # was deserialized from the cache (a prior process paid the
-        # compiles), "cold" = at least one real compile, "mixed" otherwise
-        from evotorch_tpu.observability import cache_stats
-
-        stats_cc = cache_stats()
-        hits, misses = stats_cc["hits"], stats_cc["misses"]
-        provenance = (
+    # hit/miss counters from the persistent compile cache plus the derived
+    # provenance: "warm" = every program this process compiled was
+    # deserialized from the cache (a prior process paid the compiles),
+    # "cold" = at least one real compile, "mixed" otherwise
+    stats_cc = cache_stats()
+    hits, misses = stats_cc["hits"], stats_cc["misses"]
+    line["compile_cache"] = {
+        "provenance": (
             "warm" if misses == 0 and hits > 0
             else "cold" if hits == 0
             else "mixed"
-        )
-        line["compile_cache"] = {
-            "provenance": provenance,
-            "hits": hits,
-            "misses": misses,
-            "dir": stats_cc["dir"],
-        }
+        ),
+        "hits": hits,
+        "misses": misses,
+        "dir": stats_cc["dir"],
+    }
     if cfg["mj_backend"]:
         # BENCH_BACKEND=mujoco: append the real-MuJoCo host-path columns
         # (sync chunked loop vs pipelined refill scheduler over MjVecEnv);

@@ -18,11 +18,9 @@ supported (``BENCH_SPMD``, docs/sharding.md):
   run-to-run; ``BENCH_AB_REPEATS`` samples each, default 3, medians
   reported) with ``spmd_speedup`` = gspmd / shard_map median steps/s.
 
-Runs unchanged on real multi-chip hardware (e.g. v5e-8): with a healthy
-multi-device backend the mesh spans the real chips. On this rig it is
-exercised on the 8-virtual-device CPU mesh
-(``JAX_PLATFORMS=cpu python bench_multichip.py``) and on the single real TPU
-chip (mesh of 1).
+With an accelerator the mesh spans the real chips (four on a v5e 2x2
+host); ``JAX_PLATFORMS=cpu python bench_multichip.py`` exercises the same
+program on the 8-virtual-device CPU mesh (correctness and counts, not speed).
 
 Knobs: the same BENCH_* env vars as bench.py, plus ``BENCH_MESH`` (``"8"``
 = 1-D pop mesh of 8, ``"4x2"`` / ``"pop=4,model=2"`` = 2-D; default all
@@ -51,6 +49,7 @@ from bench_common import (
     bench_config,
     build_policy,
     compact_kwargs,
+    device_record,
     fresh_pgpe_state,
     ledger_columns,
     refill_kwargs,
@@ -67,8 +66,6 @@ def main():
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
     from evotorch_tpu.algorithms.functional import (
         pgpe_ask,
         pgpe_ask_trunk_delta,
@@ -83,14 +80,12 @@ def main():
         run_vectorized_rollout,
         run_vectorized_rollout_compacting_sharded,
     )
+    from evotorch_tpu.observability import enable_persistent_cache
     from evotorch_tpu.parallel import make_generation_step, make_mesh, parse_mesh_shape
     from evotorch_tpu.parallel import mesh_label as mesh_label_of
 
     cfg = bench_config(use_cpu, cpu_episode_length=50)
-    if cfg["compile_cache"]:
-        from evotorch_tpu.observability import enable_persistent_cache
-
-        enable_persistent_cache()
+    enable_persistent_cache()
     popsize = cfg["popsize"]
     episode_length = cfg["episode_length"]
     generations = cfg["generations"]
@@ -559,7 +554,7 @@ def main():
         "episode_length": episode_length,
         "eval_mode": eval_mode,
         "compute_dtype": str(compute_dtype.__name__ if compute_dtype else "float32"),
-        "backend": "cpu-mesh" if use_cpu else "tpu",
+        "backend": device_record(),
     }
     if cfg["tuned"] and eval_mode == "episodes_refill" and refill_src is not None:
         line["tuned_config_source"] = refill_src

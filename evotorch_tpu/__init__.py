@@ -10,27 +10,6 @@ Package entry parity: reference ``src/evotorch/__init__.py:29-38`` re-exports
 ``Problem, Solution, SolutionBatch, ProblemBoundEvaluator`` and subpackages.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # this codebase targets the stable `jax.shard_map(..., check_vma=...)`
-    # API; on older jax (<= 0.4.x) the same functionality lives at
-    # `jax.experimental.shard_map.shard_map(..., check_rep=...)` — install a
-    # signature-adapting alias so every call site works on both
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def _shard_map_compat(
-        f, mesh=None, *, in_specs, out_specs, check_vma=True, **kwargs
-    ):
-        # mesh stays positional-or-keyword: the stable jax.shard_map accepts
-        # `jax.shard_map(f, mesh, in_specs=..., out_specs=...)`
-        kwargs.setdefault("check_rep", check_vma)
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-
-    _jax.shard_map = _shard_map_compat
-
 from . import algorithms, checkpoint, decorators, distributions, envs, logging, models, neuroevolution, operators, ops, optimizers, parallel, testing, tools, utils
 from .core import Problem, ProblemBoundEvaluator, Solution, SolutionBatch, SolutionBatchPieces
 from .decorators import expects_ndim, on_aux_device, on_cuda, on_device, pass_info, rowwise, vectorized

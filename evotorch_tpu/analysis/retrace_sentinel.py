@@ -55,7 +55,9 @@ __all__ = [
 ]
 
 # the logger that emits exactly one "Compiling <name> with global shapes"
-# record per trace+lower (jax 0.4.x: jax/_src/interpreters/pxla.py)
+# record per trace+lower (jax/_src/interpreters/pxla.py; tests/
+# test_retrace_sentinel.py and chip_smoke.py each first show the sentinel
+# counting a compile they know happened, in case the line ever moves)
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 _COMPILE_RE = re.compile(r"^Compiling (\S+) with global shapes")
 # siblings jax.log_compiles turns chatty when a CALLER enabled it; quiet=True

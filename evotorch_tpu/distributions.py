@@ -396,8 +396,9 @@ def _use_fused_sampling() -> bool:
     different random stream than XLA's threefry, so enabling it changes
     sampled values (not just speed); set ``EVOTORCH_TPU_FUSED_SAMPLING=1``
     after micro-benching (``bench_ops.py``) shows a win on your shapes.
-    TPU only — the on-chip PRNG primitives have no lowering elsewhere, so on
-    other backends the flag warns once and the XLA path runs.
+    TPU only — the on-chip PRNG primitives have no lowering elsewhere, so
+    with the flag set off the chip, sampling fails to compile (an error, not
+    a quiet switch to the XLA sampler).
 
     Read at trace time, like ``EVOTORCH_TPU_FUSED_RANK``: the OO samplers key
     their jit cache on the flag's value, so toggling the env var takes effect
@@ -405,19 +406,7 @@ def _use_fused_sampling() -> bool:
     at their own first trace."""
     import os
 
-    if os.environ.get("EVOTORCH_TPU_FUSED_SAMPLING", "0") != "1":
-        return False
-    if jax.default_backend() == "tpu":
-        return True
-    import warnings
-
-    warnings.warn(
-        "EVOTORCH_TPU_FUSED_SAMPLING=1 ignored: the fused sampling kernel's "
-        f"on-chip PRNG only lowers on TPU (current backend: "
-        f"{jax.default_backend()}); using the XLA sampler",
-        stacklevel=3,
-    )
-    return False
+    return os.environ.get("EVOTORCH_TPU_FUSED_SAMPLING", "0") == "1"
 
 
 class SymmetricSeparableGaussian(SeparableGaussian):

@@ -8,7 +8,7 @@ threaded batched stepper — instead of N sequential ``env.step`` calls. One
 are then recomputed *from the physics state* by a per-family table
 (:class:`_V5Family`), which is what makes the per-term reward decomposition
 (forward velocity / control cost / healthy bonus) available on every step —
-the fidelity harness (``fidelity.py``) and BENCH_NOTES both consume it.
+the fidelity harness (``fidelity.py``) consumes it.
 
 Faithfulness: ``FULLPHYSICS``-state round-tripping through ``rollout`` with
 ``nstep = frame_skip`` reproduces gymnasium's own ``do_simulation`` stepping

@@ -273,8 +273,8 @@ def _bquat_to_mat(q: jnp.ndarray) -> jnp.ndarray:
     angular velocities, torques, contact offsets, the body-frame angular
     update): building the matrix once (~20 flops) and applying it at 15
     flops/vector halves the rotation arithmetic vs the 30-flop quat-rotate
-    formula — the substep is VPU-flop/fusion bound (BENCH_NOTES.md
-    utilization analysis), so this is a direct attack on the dominant cost."""
+    formula — the substep is VPU-flop/fusion bound (the r2b
+    arithmetic, ROADMAP S2), so this is a direct attack on the dominant cost."""
     w, x, y, z = q[..., 0, :], q[..., 1, :], q[..., 2, :], q[..., 3, :]
     xx, yy, zz = x * x, y * y, z * z
     xy, xz, yz = x * y, x * z, y * z

@@ -3,7 +3,7 @@
 The defining cost of ES evaluation is that every population lane carries its
 OWN parameter vector, so the policy forward is a batch of N tiny per-lane
 matvecs — the MXU cannot amortize weight loads across lanes, and throughput
-collapses as the policy grows (measured in BENCH_NOTES.md: 8x params ->
+collapses as the policy grows (r2 chip run, ROADMAP S4: 8x params ->
 3.4x slower). The classic low-rank answer (the LM-MA-ES / random-subspace ES
 family) restructures the perturbation instead of the hardware:
 
@@ -427,7 +427,8 @@ def _apply_trunk_delta_blocked(module, cp, fx, z, obs, states, block: int):
     """The same forward with the LANE axis chunked into static blocks of
     ``block`` via ``lax.map`` — bounds the per-GEMM activation working set
     (the autotuner's trunk-blocking knob). Per-lane results are independent,
-    so blocking changes scheduling, not values."""
+    so blocking changes scheduling, and values only by the summation order
+    of a GEMM over ``block`` lanes instead of all of them (float32 rounding)."""
     n = obs.shape[0]
     nb = n // block
 

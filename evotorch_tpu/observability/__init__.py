@@ -51,7 +51,7 @@ Three cooperating pieces (docs/observability.md has the full catalog):
 - :mod:`~evotorch_tpu.observability.slo` — declarative SLO watchdog
   (per-group occupancy floor, starvation ceiling off the top queue-wait
   bucket, steady_compiles == 0, min progress) surfaced as searcher status
-  keys (``VecNEProblem(slo=...)``) and the tpu_window.sh battery verdict
+  keys (``VecNEProblem(slo=...)``) and a bench-line verdict CLI
   (``python -m evotorch_tpu.observability.slo --check-bench``).
 """
 
@@ -104,8 +104,8 @@ from .programs import (  # noqa: F401
     ProgramRecord,
     compare_to_baseline,
     default_ledger_baseline_path,
-    guarded_cost_analysis,
-    guarded_memory_analysis,
+    cost_analysis,
+    memory_analysis,
     ledger,
     load_ledger_baseline,
     save_ledger_baseline,
@@ -176,8 +176,8 @@ __all__ = [
     "ProgramRecord",
     "compare_to_baseline",
     "default_ledger_baseline_path",
-    "guarded_cost_analysis",
-    "guarded_memory_analysis",
+    "cost_analysis",
+    "memory_analysis",
     "ledger",
     "load_ledger_baseline",
     "save_ledger_baseline",

@@ -1,22 +1,26 @@
-"""Persistent XLA compilation cache wiring.
+"""Persistent XLA compilation cache wiring — one rule for where it lives.
 
 jax can serialize compiled executables to disk and reload them in later
-processes (``jax_compilation_cache_dir``).  For this repo's programs the
-win is large: the flagship rollout program takes ~10 s to compile cold on
-this box and ~2 s to deserialize warm, so every bench / battery / curve
-process after the first skips most of its startup tax.
+processes. The flagship generation takes about 25 s to compile for a TPU v5e
+per eval contract, so every process after the first skips most of its
+start-up tax when it finds the cache.
 
-:func:`enable_persistent_cache` turns the cache on with thresholds
-lowered to "cache everything" (the defaults skip entries that compiled in
-under a second, which covers most of our CPU-mesh test programs), and
-registers monitoring listeners so callers can report hit/miss provenance
-(:func:`cache_stats`) — bench.py uses this for its ``compile_cache``
-JSON keys, and the warm-start acceptance test asserts hits > 0 in the
-second process.
+**The rule.** Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is
+the cache and the code sets no other; where it is not, the cache is
+``<checkout>/compile_cache`` (the test suite: ``compile_cache/tests`` under
+it). Nothing else places the cache — no argument, no second variable — so
+whoever runs the program (a driver that mounts a cache, a developer who
+wants none of their entries in the checkout) decides from outside, and
+``chip_smoke.py``, the bench scripts, the CLIs, the examples and
+``conftest.py`` all agree.
 
-The default cache directory lives next to the bench output dirs and is
-gitignored: serialized executables are machine- and jax-version-specific
-artifacts, not source.
+:func:`enable_persistent_cache` applies the rule with thresholds lowered to
+"cache everything" (the defaults skip entries that compiled in under a
+second) and registers monitoring listeners so callers can report hit/miss
+provenance (:func:`cache_stats`).
+
+The default directory is gitignored: serialized executables are machine- and
+jax-version-specific artifacts, not source.
 """
 
 from __future__ import annotations
@@ -25,6 +29,7 @@ import os
 from typing import Dict, Optional
 
 import jax
+from jax._src import monitoring
 
 # Sibling of bench_curves/ at the repo root; gitignored (machine-local).
 DEFAULT_CACHE_DIR = os.path.join(
@@ -44,10 +49,6 @@ def _install_listener() -> None:
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
-    try:
-        from jax._src import monitoring
-    except ImportError:  # pragma: no cover - jax internals moved
-        return
 
     def _on_event(event: str, **kwargs) -> None:
         if event == _HIT_EVENT:
@@ -59,21 +60,20 @@ def _install_listener() -> None:
     _LISTENER_INSTALLED = True
 
 
-def enable_persistent_cache(
-    cache_dir: Optional[str] = None, *, xla_caches: bool = True
-) -> str:
-    """Enable jax's persistent compilation cache rooted at ``cache_dir``.
+def enable_persistent_cache(subdir: str = "") -> str:
+    """Enable jax's persistent compilation cache at the directory the rule
+    names (module docstring): ``JAX_COMPILATION_CACHE_DIR`` when set, else
+    ``<checkout>/compile_cache/<subdir>``. Returns the directory in use.
 
     Thresholds are dropped to zero so even fast-compiling programs are
-    cached — on a 1-core box the *second* process's wall clock is what we
-    are buying, and deserialization is cheap at every size.  Returns the
-    directory in use.  Idempotent; re-enabling with a different directory
-    re-points the cache.
+    cached. Idempotent.
     """
     global _ENABLED_DIR
     from ..resilience.retry import retry_call
 
-    path = os.path.abspath(cache_dir or os.environ.get("EVOTORCH_COMPILE_CACHE_DIR") or DEFAULT_CACHE_DIR)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.normpath(
+        os.path.join(DEFAULT_CACHE_DIR, subdir)
+    )
     # the cache dir often lives on shared/network storage: creating it
     # retries with bounded backoff (and is fault-injectable at site
     # "compilecache.io"); jax itself degrades to uncached compiles when
@@ -82,16 +82,13 @@ def enable_persistent_cache(
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    if xla_caches:
-        # ``xla_caches=False`` opts out (the test suite does): "all" embeds
-        # extra machine-local cache paths into the hashed compile options, so
-        # entries re-key whenever the directory moves, and the XLA-internal
-        # autotuning caches buy nothing on the CPU backend anyway.
-        try:
-            # Also cache XLA-internal autotuning artifacts where supported.
-            jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-        except Exception:  # graftlint: allow(swallow): older jax without the XLA-caches option; the main cache is already on
-            pass
+    # jax's own default for this option, like "all", writes a path under the
+    # cache directory into the compile options, and the options are hashed
+    # into every entry's key: a cache copied or mounted at another path then
+    # never hits (shown on the CPU and on the v5e, CHANGES.md PR 21). The
+    # XLA-internal caches the option names are GPU autotuning artifacts;
+    # nothing here uses them.
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
     _install_listener()
     _ENABLED_DIR = path
     return path
@@ -105,8 +102,3 @@ def cache_stats() -> Dict[str, object]:
         "hits": _COUNTS["hits"],
         "misses": _COUNTS["misses"],
     }
-
-
-def reset_stats() -> None:
-    _COUNTS["hits"] = 0
-    _COUNTS["misses"] = 0

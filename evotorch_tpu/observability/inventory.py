@@ -14,9 +14,8 @@ Everything here builds programs at a configurable *gate shape*
 compile on the CPU mesh, not minutes). FLOPs and per-lane memory scale
 ~linearly in ``popsize``/``episode_length`` for fixed program structure,
 so a structural regression at the gate shape is a flagship regression too
-— the gate catches it in tier-1 instead of months later in a rare healthy
-TPU window (the flagship-shape snapshot on the real chip is a
-``scripts/tpu_window.sh`` battery step).
+— the gate catches it in tier-1 (``report --flagship`` captures the same
+inventory at the flagship shape on the chip).
 
 Heavy imports stay inside the builders: ``observability`` is imported by
 ``algorithms`` at class-definition time, so importing envs/algorithms at

@@ -12,12 +12,12 @@ safe); steady-state execution is never observed or perturbed.
 
 Pieces:
 
-- :func:`guarded_cost_analysis` / :func:`guarded_memory_analysis` — the
-  backend-robust accessors. ``lowered.cost_analysis()`` and
-  ``compiled.memory_analysis()`` availability varies by backend and jax
-  path (a backend can return ``None``, raise, or list-wrap the dict); these
-  normalize to plain dicts and degrade to ``None`` instead of crashing, so
-  ledger fields are nullable rather than fatal.
+- :func:`cost_analysis` / :func:`memory_analysis` — ``compiled.
+  cost_analysis()`` and ``compiled.memory_analysis()`` normalized to plain
+  dicts. Both read the COMPILED stage, which the CPU and the TPU both
+  provide (``Lowered.cost_analysis()`` is ``None`` through a PJRT C-API
+  plug-in such as libtpu); an analysis that fails is an error, not a null
+  column.
 - donation verification — two independent signals for "XLA actually
   aliased the buffers ``donate_argnums`` promised":
   (a) **static**: the compiled module's ENTRY ``input_output_alias`` table
@@ -68,8 +68,8 @@ __all__ = [
     "compare_to_baseline",
     "default_ledger_baseline_path",
     "donated_param_indices",
-    "guarded_cost_analysis",
-    "guarded_memory_analysis",
+    "cost_analysis",
+    "memory_analysis",
     "ledger",
     "load_ledger_baseline",
     "parse_alias_sources",
@@ -93,7 +93,7 @@ def abstract_like(tree):
 
 
 # ---------------------------------------------------------------------------
-# backend-robust introspection
+# introspection of the compiled stage
 # ---------------------------------------------------------------------------
 
 #: normalized cost fields (XLA's HloCostAnalysis names, spaces and all)
@@ -114,58 +114,31 @@ _MEMORY_FIELDS = (
 )
 
 
-def guarded_cost_analysis(lowered) -> Optional[Dict[str, float]]:
-    """``lowered.cost_analysis()`` normalized to
-    ``{"flops", "transcendentals", "bytes_accessed"}`` floats, or ``None``
-    when the backend path provides no analysis (CPU fallbacks and older
-    plugin paths can return ``None``, raise, or wrap the dict in a
-    per-partition list — all of those degrade to nullable fields instead
-    of crashing the caller)."""
-    try:
-        cost = lowered.cost_analysis()
-    except Exception:  # graftlint: allow(swallow): guarded probe: analysis availability varies by backend, None degrades the column
-        return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    if not isinstance(cost, dict):
-        return None
-    out: Dict[str, float] = {}
-    for name, xla_key in _COST_FIELDS:
-        value = cost.get(xla_key)
-        if isinstance(value, (int, float)) and value >= 0:
-            out[name] = float(value)
-    return out or None
+def cost_analysis(compiled) -> Dict[str, float]:
+    """``compiled.cost_analysis()`` normalized to
+    ``{"flops", "transcendentals", "bytes_accessed"}`` floats (the fields of
+    XLA's cost model that are stable; the per-operand utilization entries
+    are dropped)."""
+    cost = compiled.cost_analysis()
+    return {
+        name: float(cost[xla_key]) for name, xla_key in _COST_FIELDS if xla_key in cost
+    }
 
 
-def guarded_memory_analysis(compiled) -> Optional[Dict[str, int]]:
+def memory_analysis(compiled) -> Dict[str, int]:
     """``compiled.memory_analysis()`` normalized to plain int byte fields
-    plus the derived ``peak_bytes``, or ``None`` when unavailable.
+    plus the derived ``peak_bytes``.
 
     ``peak_bytes = argument + output - alias + temp`` — the live-at-once
     footprint of one execution. Donation-aware by construction: an aliased
     (donated) output reuses its argument's buffer, so a DROPPED donation
     shows up as an inflated ``peak_bytes`` — exactly the regression the
     gate exists to catch."""
-    try:
-        mem = compiled.memory_analysis()
-    except Exception:  # graftlint: allow(swallow): guarded probe: analysis availability varies by backend, None degrades the column
-        return None
-    if mem is None:
-        return None
-    out: Dict[str, int] = {}
-    for name, attr in _MEMORY_FIELDS:
-        value = getattr(mem, attr, None)
-        if isinstance(value, int) and value >= 0:
-            out[name] = value
-    if not out:
-        return None
-    if all(k in out for k in ("argument_bytes", "output_bytes", "temp_bytes")):
-        out["peak_bytes"] = (
-            out["argument_bytes"]
-            + out["output_bytes"]
-            - out.get("alias_bytes", 0)
-            + out["temp_bytes"]
-        )
+    mem = compiled.memory_analysis()
+    out = {name: int(getattr(mem, attr)) for name, attr in _MEMORY_FIELDS}
+    out["peak_bytes"] = (
+        out["argument_bytes"] + out["output_bytes"] - out["alias_bytes"] + out["temp_bytes"]
+    )
     return out
 
 
@@ -174,25 +147,18 @@ def guarded_memory_analysis(compiled) -> Optional[Dict[str, int]]:
 # ---------------------------------------------------------------------------
 
 
-def donated_param_indices(lowered) -> Optional[List[int]]:
+def donated_param_indices(lowered) -> List[int]:
     """Flat ENTRY-parameter indices the lowering marked donated, from
-    ``lowered.args_info`` (leaves flatten in parameter order). ``None``
-    when the stage doesn't expose the info.
+    ``lowered.args_info`` (leaves flatten in parameter order).
 
     Caveat: with ``keep_unused=False`` (the jit default) an entirely
     UNUSED argument is pruned from the executable and shifts parameter
     numbering; donated state args are by construction used, so the mapping
     is exact for every program this repo registers."""
-    try:
-        import jax
+    import jax
 
-        leaves = jax.tree_util.tree_leaves(lowered.args_info)
-    except Exception:  # graftlint: allow(swallow): guarded probe: analysis availability varies by backend, None degrades the column
-        return None
-    flags = [getattr(leaf, "donated", None) for leaf in leaves]
-    if any(flag is None for flag in flags):
-        return None
-    return [i for i, flag in enumerate(flags) if flag]
+    leaves = jax.tree_util.tree_leaves(lowered.args_info)
+    return [i for i, leaf in enumerate(leaves) if leaf.donated]
 
 
 def parse_alias_sources(hlo_text: str) -> Optional[List[int]]:
@@ -251,16 +217,9 @@ class DonationReport:
         }
 
 
-def _donation_report(lowered, compiled) -> Optional[DonationReport]:
+def _donation_report(lowered, compiled) -> DonationReport:
     donated = donated_param_indices(lowered)
-    if donated is None:
-        return None
-    try:
-        text = compiled.as_text()
-    except Exception:  # graftlint: allow(swallow): guarded probe: analysis availability varies by backend, None degrades the column
-        return None
-    aliased = parse_alias_sources(text)
-    aliased = [] if aliased is None else aliased
+    aliased = parse_alias_sources(compiled.as_text()) or []
     missing = [p for p in donated if p not in aliased]
     return DonationReport(
         donated=tuple(donated), aliased=tuple(aliased), missing=tuple(missing)
@@ -299,9 +258,8 @@ def verify_runtime_donation(fn, args: Sequence[Any], donate_argnums: Sequence[in
 
 @dataclass
 class ProgramRecord:
-    """Everything the ledger knows about one (program, shape) pair. Nullable
-    fields mean "this backend/jax path did not provide the analysis" (the
-    guarded accessors above), never "zero"."""
+    """Everything the ledger knows about one (program, shape) pair;
+    :meth:`ProgramLedger.capture` fills every field."""
 
     name: str
     shape: Dict[str, Any] = field(default_factory=dict)
@@ -383,15 +341,14 @@ class ProgramLedger:
         t2 = time.perf_counter()
         # analyses run OUTSIDE the timed windows: compile_seconds is the
         # compile, not the cost-analysis pass over the (possibly huge) module
-        cost = guarded_cost_analysis(lowered)
         record = ProgramRecord(
             name=name,
             shape=shape,
             platform=jax.devices()[0].platform,
             lower_seconds=t1 - t0,
             compile_seconds=t2 - t1,
-            cost=cost,
-            memory=guarded_memory_analysis(compiled),
+            cost=cost_analysis(compiled),
+            memory=memory_analysis(compiled),
             donation=_donation_report(lowered, compiled),
         )
         with self._lock:

@@ -140,16 +140,12 @@ def ensure_compile_timer() -> None:
     trace/lower/backend-compile durations accumulate into
     ``counters['compile_seconds']`` via jax's monitoring events.
 
-    Idempotent; a jax build without the monitoring API degrades to a no-op
-    (the counter just stays 0.0)."""
+    Idempotent."""
     global _timer_installed
     with _compile_lock:
         if _timer_installed:
             return
-        try:
-            from jax import monitoring
+        from jax import monitoring
 
-            monitoring.register_event_duration_secs_listener(_on_duration_event)
-        except Exception:  # graftlint: allow(swallow): older jax without the monitoring hook; timing column degrades to absent
-            pass
+        monitoring.register_event_duration_secs_listener(_on_duration_event)
         _timer_installed = True

@@ -61,15 +61,15 @@ checkpoint the window state):
 
 The watchdog surfaces as searcher status keys (``slo_ok`` /
 ``slo_violations`` / ``slo_detail``) via ``VecNEProblem(slo=...)``, and as
-a battery verdict via the CLI::
+a bench-line verdict via the CLI::
 
     python -m evotorch_tpu.observability.slo --check-bench bench.log \
         --verdict-out slo_verdict.txt
 
 which reads the LAST JSON line of a bench log (the bench.py output
-contract), applies the battery default rules (steady_compiles == 0 plus a
-global occupancy floor), writes a one-word ``pass``/``fail`` verdict file
-for tpu_watch.sh, prints a JSON verdict line, and exits 0/1 — or 2
+contract), applies the default rules (steady_compiles == 0 plus a
+global occupancy floor), writes a one-word ``pass``/``fail`` verdict
+file, prints a JSON verdict line, and exits 0/1 — or 2
 ("insufficient") when the log has no decodable JSON line or the line
 carries none of the checked keys (a BENCH_TELEMETRY=0 line): missing data
 is distinguishable from failing data. A partial trailing line (crashed
@@ -353,7 +353,7 @@ DEFAULT_RULES: Tuple[Rule, ...] = (
     Rule("min_progress", threshold=1),
 )
 
-#: battery-verdict defaults for ``--check-bench``: the flagship bench line
+#: verdict defaults for ``--check-bench``: the flagship bench line
 #: must be retrace-free and show a sane primary-mode occupancy
 DEFAULT_BENCH_RULES: Tuple[Rule, ...] = (
     Rule("no_steady_compiles"),
@@ -377,7 +377,7 @@ def check_bench_line(
     min_score_snr: Optional[float] = None,
     max_queue_wait_p99: Optional[float] = None,
 ) -> SLOReport:
-    """Apply the battery rules to one decoded bench.py JSON line.
+    """Apply the verdict rules to one decoded bench.py JSON line.
 
     The bench line carries scalars, not a (G, K) matrix, so this reads the
     top-level ``occupancy`` / ``steady_compiles`` keys (plus per-mode
@@ -567,7 +567,7 @@ def _main(argv=None) -> int:
         "--verdict-out",
         metavar="PATH",
         default=None,
-        help="write a one-word pass/fail verdict file (read by tpu_watch.sh)",
+        help="write a one-word pass/fail verdict file",
     )
     args = parser.parse_args(argv)
 

@@ -10,10 +10,13 @@ VMEM scheduling wins:
 - ``fused_centered_rank``: rank -> centered-utility transform fused over a
   fitness vector.
 
-Every kernel has an XLA fallback (the default path), distributionally
-equivalent but not bit-identical (different PRNG streams). CPU tests exercise
-the fused math in Pallas interpret mode; the on-chip-PRNG production kernel
-is covered by a TPU-gated test (tests/test_ops.py::test_pallas_sampling_on_tpu).
+Every kernel has an XLA form (the default path); the sampling kernel's is
+distributionally equivalent but not bit-identical (different PRNG streams).
+Both kernels are compiled for a TPU v5e in tests/test_ops.py (the installed
+libtpu compiles for a chip the host lacks) and run compiled on the chip,
+against their XLA forms, by chip_smoke.py. Asked for off the chip they are an
+error; the ranking kernel alone has an interpret mode, for the CPU tests, and
+only when the caller passes ``interpret=True``.
 """
 
 from .sampling import sample_symmetric_gaussian

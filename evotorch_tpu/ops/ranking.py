@@ -21,7 +21,7 @@ __all__ = ["fused_centered_rank"]
 
 
 def _pallas_kernel(fit_ref, out_ref):
-    fit = fit_ref[:]  # one fitness vector (batch dims handled by vmap)
+    fit = fit_ref[0]  # one fitness vector, held as a (1, n) row
     n = fit.shape[-1]
     # rank of each element = number of strictly-smaller elements plus the
     # number of equal elements appearing earlier (stable tie-break), computed
@@ -40,7 +40,7 @@ def _pallas_kernel(fit_ref, out_ref):
     equal = (row == col) | (row_nan & col_nan)  # NaN == NaN for the tie-break
     smaller = value_smaller | (equal & (jdx < idx))
     ranks = jnp.sum(smaller.astype(jnp.float32), axis=-1)
-    out_ref[:] = ranks / (n - 1) - 0.5
+    out_ref[0] = ranks / (n - 1) - 0.5
 
 
 @functools.partial(jax.jit, static_argnames=("higher_is_better", "use_pallas", "interpret"))
@@ -51,7 +51,11 @@ def fused_centered_rank(
     use_pallas: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Centered ranks in ``[-0.5, 0.5]`` along the last axis."""
+    """Centered ranks in ``[-0.5, 0.5]`` along the last axis.
+
+    ``use_pallas=True`` compiles the kernel for the TPU and is an error
+    anywhere else, unless ``interpret=True`` (the tests' CPU form) is passed
+    explicitly — the backend never picks the mode."""
     x = jnp.asarray(fitnesses)
     if not use_pallas or x.dtype not in (
         jnp.float32,
@@ -70,23 +74,20 @@ def fused_centered_rank(
 
     from jax.experimental import pallas as pl
 
-    # no Mosaic lowering off-TPU: interpret there (tests; the tools/ranking
-    # dispatcher only auto-selects this path on TPU anyway)
-    interpret = interpret or jax.default_backend() != "tpu"
-
     if x.shape[-1] == 1:
         # degenerate population: match the XLA fallback (zeros, no 0/0)
         return jnp.zeros_like(x)
 
     signed = (x if higher_is_better else -x).astype(jnp.float32)
     batch_shape = signed.shape[:-1]
-    flat = signed.reshape((-1, signed.shape[-1]))
-
+    n = signed.shape[-1]
+    # (B, 1, n): vmap squeezes the batch axis out of the block, and a block
+    # whose last two dimensions are the whole (1, n) row is one Mosaic accepts
+    # (a squeezed 1-D (n,) block of a (B, n) array is not)
     call = pl.pallas_call(
         _pallas_kernel,
-        out_shape=jax.ShapeDtypeStruct((signed.shape[-1],), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
     )
-    out = jax.vmap(call)(flat)
-    out = out.reshape(batch_shape + (signed.shape[-1],)) if batch_shape else out[0]
+    out = jax.vmap(call)(signed.reshape((-1, 1, n))).reshape(batch_shape + (n,))
     return out.astype(x.dtype) if jnp.issubdtype(x.dtype, jnp.floating) else out

@@ -46,8 +46,7 @@ def init_distributed(
     cluster environment variables are present (e.g. on Cloud TPU pods, GKE
     with the JAX plugin, or SLURM); single-host runs return False untouched.
     """
-    already = getattr(jax.distributed, "is_initialized", None)
-    if callable(already) and jax.distributed.is_initialized():
+    if jax.distributed.is_initialized():
         return True
     # Multi-process SPMD on the CPU backend needs a cross-process
     # collectives implementation; the default ("none") makes EVERY
@@ -58,10 +57,7 @@ def init_distributed(
     # and it must be set before the first backend use, which is why it
     # lives here and not in callers. Inert on TPU.
     def _enable_cpu_collectives():
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # graftlint: allow(swallow): a jax without the option: CPU multi-process unsupported anyway
-            pass  # a jax without the option: CPU multi-process unsupported
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
     # the handshake retries with bounded backoff (resilience.retry): the
     # usual first-boot race — this process dials before the coordinator

@@ -46,15 +46,11 @@ def _main(argv=None) -> int:
     )
     args = parser.parse_args(argv)
 
-    if args.cpu:
-        import os
+    # the server is the one process that holds the chip; its clients speak
+    # JSONL over the pipe and never import a backend
+    from ..resilience import setup_backend
 
-        os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
-    import evotorch_tpu  # noqa: F401  (shard_map alias install)
+    setup_backend(args.cpu)
 
     from ..envs import make_env
     from .server import EvalServer

@@ -41,12 +41,12 @@ def _float_dtype_like(x: jnp.ndarray):
 def _use_fused_centered(n: int) -> bool:
     """Dispatch ``centered`` to the fused Pallas kernel (``ops/ranking.py``)?
     Default: **off** — the kernel ships opt-in until an on-chip micro-bench
-    (``bench_ops.py``, captured by ``scripts/tpu_window.sh``) records a win
-    over ``centered_xla`` at representative population sizes; an unmeasured
-    default in every TPU PGPE generation is risk with no evidence. Opt in
-    with ``EVOTORCH_TPU_FUSED_RANK=1`` (any backend, any n that fits VMEM);
-    ``=0`` pins it off. Read at trace time: jitted callers bake the decision
-    into their compiled executable."""
+    (``bench_ops.py``) records a win over ``centered_xla`` at representative
+    population sizes; an unmeasured default in every TPU PGPE generation is
+    risk with no evidence. Opt in with ``EVOTORCH_TPU_FUSED_RANK=1`` (on the
+    TPU, any n that fits VMEM; off the chip the kernel is an error); ``=0``
+    pins it off. Read at trace time: jitted callers bake the decision into
+    their compiled executable."""
     flag = os.environ.get("EVOTORCH_TPU_FUSED_RANK", "auto")
     if flag != "1":
         return False

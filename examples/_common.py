@@ -13,9 +13,11 @@ def setup_platform():
     parser.add_argument("--cpu", action="store_true", help="force the CPU backend")
     parser.add_argument("--generations", type=int, default=None)
     args, _ = parser.parse_known_args()
-    if args.cpu:
-        os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-        import jax
+    from evotorch_tpu.observability import enable_persistent_cache
+    from evotorch_tpu.resilience import setup_backend
 
-        jax.config.update("jax_platforms", "cpu")
+    # --cpu (or JAX_PLATFORMS=cpu) asks for the 8-virtual-device CPU;
+    # anything else requires an accelerator and fails without one
+    setup_backend(args.cpu)
+    enable_persistent_cache()
     return args

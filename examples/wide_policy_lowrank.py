@@ -2,7 +2,7 @@
 
 The MXU cannot amortize weights across ES lanes when every lane carries its
 own parameters — growing the policy 64x64 -> 256x256 costs ~3.4x throughput
-on a v5e (BENCH_NOTES.md). ``PGPE(..., lowrank_rank=k)`` restructures the
+on a v5e (r2 chip run, ROADMAP S4). ``PGPE(..., lowrank_rank=k)`` restructures the
 perturbation instead of the hardware: the population is
 ``theta_i = center + B z_i`` with a shared per-generation basis, evaluated
 with (k+1) large shared-weight matmuls, and the dense ``(N, L)`` population

@@ -441,13 +441,14 @@ def test_graftlint_collects_mesh_axes_declaration():
 # ---------------------------------------------------------------------------
 
 _CACHE_WORKER = """
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import json
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 from evotorch_tpu.observability import cache_stats, enable_persistent_cache
-enable_persistent_cache(sys.argv[1])
+from evotorch_tpu.resilience import setup_backend
+
+setup_backend(force_cpu=True)
+enable_persistent_cache()  # the parent placed it: JAX_COMPILATION_CACHE_DIR
 
 from evotorch_tpu.envs import CartPole
 from evotorch_tpu.neuroevolution.net import FlatParamsPolicy, Linear, Tanh
@@ -489,13 +490,14 @@ def test_persistent_compile_cache_warm_process(tmp_path):
 
     worker = tmp_path / "cache_worker.py"
     worker.write_text(_CACHE_WORKER)
-    cache_dir = tmp_path / "compile_cache"
     env = dict(os.environ)
     env["PYTHONPATH"] = os.getcwd() + os.pathsep + env.get("PYTHONPATH", "")
+    # a private cache, placed the one way the rule allows: from outside
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "compile_cache")
 
     def run():
         out = subprocess.run(
-            [sys.executable, str(worker), str(cache_dir)],
+            [sys.executable, str(worker)],
             env=env, capture_output=True, text=True, timeout=240,
         )
         assert out.returncode == 0, f"worker failed:\n{out.stdout}\n{out.stderr}"

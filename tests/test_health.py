@@ -13,7 +13,7 @@ The contracts under test:
   stream never stalls; a flat one does) and serialize round-trip;
 - the plateau / stdev_collapse / score_snr_floor rules trip on injected
   degeneracy with named violations while a healthy run stays ``slo_ok``;
-- the bench-CLI health flags follow the 0/1/2 exit taxonomy;
+- the bench-CLI health flags follow the 0/1/2 exit codes;
 - the ``telemetry-schema`` graftlint checker flags hard-coded column
   literals outside devicemetrics.py.
 """
@@ -523,7 +523,7 @@ def test_check_bench_line_score_collapse_and_snr():
     assert check_bench_line(_bench_line(), min_score_snr=1.0).ok
 
 
-def test_check_bench_cli_exit_taxonomy(tmp_path, capsys):
+def test_check_bench_cli_exit_codes(tmp_path, capsys):
     from evotorch_tpu.observability.slo import _main
 
     log = tmp_path / "bench.log"

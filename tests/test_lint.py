@@ -498,7 +498,7 @@ def test_timing_accepts_block_until_ready_in_region():
 
 
 def test_timing_accepts_blocking_local_helper():
-    # the `once()` pattern (scripts/tune_compact.py): the dispatch + block
+    # the `once()` pattern: the dispatch + block
     # live inside a locally-defined helper the timed loop calls
     findings = _lint(
         """

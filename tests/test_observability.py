@@ -399,7 +399,7 @@ def test_slo_bench_line_verdict(tmp_path):
     bad = {"occupancy": 0.02, "steady_compiles": 1}
     report = check_bench_line(bad)
     assert not report.ok and len(report.violations) == 2
-    # the CLI form tpu_window.sh's slo_check step runs: last JSON line of
+    # the CLI form: last JSON line of
     # the log, one-word verdict file, exit status as the step verdict
     log = tmp_path / "bench.log"
     log.write_text("noise\n" + json.dumps(good) + "\n" + json.dumps(bad) + "\n")

@@ -36,8 +36,8 @@ from evotorch_tpu.observability import (
     compare_to_baseline,
     counters,
     default_ledger_baseline_path,
-    guarded_cost_analysis,
-    guarded_memory_analysis,
+    cost_analysis,
+    memory_analysis,
     load_ledger_baseline,
     save_ledger_baseline,
     verify_runtime_donation,
@@ -81,60 +81,21 @@ def gate_capture():
 
 
 # ---------------------------------------------------------------------------
-# guarded introspection (backend-robust accessors)
+# introspection of the compiled stage
 # ---------------------------------------------------------------------------
 
 
-class _RaisingStage:
-    def cost_analysis(self):
-        raise RuntimeError("analysis unavailable on this backend path")
-
-    def memory_analysis(self):
-        raise RuntimeError("analysis unavailable on this backend path")
-
-
-class _NoneStage:
-    def cost_analysis(self):
-        return None
-
-    def memory_analysis(self):
-        return None
-
-
-class _ListWrappedCost:
-    """Some jax paths return a per-partition LIST of cost dicts."""
-
-    def cost_analysis(self):
-        return [{"flops": 3.0, "bytes accessed": 5.0, "utilization0{}": 9.0}]
-
-
-class _EmptyListCost:
-    def cost_analysis(self):
-        return []
-
-
-def test_guarded_accessors_degrade_to_none_instead_of_raising():
-    assert guarded_cost_analysis(_RaisingStage()) is None
-    assert guarded_memory_analysis(_RaisingStage()) is None
-    assert guarded_cost_analysis(_NoneStage()) is None
-    assert guarded_memory_analysis(_NoneStage()) is None
-    assert guarded_cost_analysis(_EmptyListCost()) is None
-    # list-wrapped dicts normalize; only the stable fields survive
-    assert guarded_cost_analysis(_ListWrappedCost()) == {
-        "flops": 3.0,
-        "bytes_accessed": 5.0,
+def test_compiled_stage_analyses():
+    """Normalized dicts with the documented fields, including the
+    donation-aware peak_bytes derivation — read from the COMPILED stage,
+    the one both the CPU and the TPU provide."""
+    compiled = jax.jit(lambda x: x * 2.0 + 1.0).lower(jnp.zeros((64, 64))).compile()
+    cost = cost_analysis(compiled)
+    assert {"flops", "bytes_accessed"} <= set(cost) <= {
+        "flops", "transcendentals", "bytes_accessed"
     }
-
-
-def test_guarded_accessors_on_the_cpu_mesh():
-    """The real path on this backend: normalized dicts with the documented
-    fields, including the donation-aware peak_bytes derivation."""
-    fn = jax.jit(lambda x: x * 2.0 + 1.0)
-    lowered = fn.lower(jnp.zeros((64, 64)))
-    cost = guarded_cost_analysis(lowered)
-    assert cost is not None and cost["flops"] > 0
-    memory = guarded_memory_analysis(lowered.compile())
-    assert memory is not None
+    assert cost["flops"] > 0
+    memory = memory_analysis(compiled)
     for field in ("argument_bytes", "output_bytes", "temp_bytes", "peak_bytes"):
         assert field in memory and memory[field] >= 0
     assert memory["peak_bytes"] == (

@@ -72,20 +72,21 @@ def test_ties_get_distinct_ranks():
     assert len(set(np.asarray(u).tolist())) == 3
 
 
-def test_centered_dispatches_to_fused_kernel(monkeypatch):
-    # EVOTORCH_TPU_FUSED_RANK=1 forces the fused path on any backend
-    # (interpret-mode off-TPU); results must be identical to the XLA form,
-    # through the public rank() entry the algorithms actually call
+def test_centered_fused_flag_is_an_error_off_the_chip(monkeypatch):
+    # EVOTORCH_TPU_FUSED_RANK=1 asks for the compiled kernel through the
+    # public rank() entry; off the chip that is an error, never a quiet
+    # interpret-mode run (chip_smoke.py checks the kernel against
+    # centered_xla where it compiles)
     import numpy as np
 
     from evotorch_tpu.tools.ranking import centered_xla, rank
 
     fit = jnp.asarray(np.random.default_rng(0).normal(size=257), jnp.float32)
     monkeypatch.setenv("EVOTORCH_TPU_FUSED_RANK", "1")
-    got = rank(fit, "centered", higher_is_better=True)
+    with pytest.raises(ValueError, match="interpret mode"):
+        rank(fit, "centered", higher_is_better=True)
     monkeypatch.setenv("EVOTORCH_TPU_FUSED_RANK", "0")
     want = rank(fit, "centered", higher_is_better=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
     np.testing.assert_allclose(
         np.asarray(want), np.asarray(centered_xla(fit, higher_is_better=True)), atol=0
     )
@@ -129,19 +130,21 @@ def test_fused_rank_nan_semantics_match_xla():
 
 def test_fused_sampling_optin_dispatch(monkeypatch):
     # EVOTORCH_TPU_FUSED_SAMPLING is opt-in; the dispatcher must be OFF by
-    # default (the kernel changes the random stream, not just the speed)
+    # default (the kernel changes the random stream, not just the speed).
+    # Set off the chip it is an error — the on-chip PRNG only lowers on the
+    # TPU — never a warning and the XLA sampler
     import jax
-    import pytest
 
-    from evotorch_tpu.distributions import _use_fused_sampling
+    from evotorch_tpu.distributions import (
+        SymmetricSeparableGaussian,
+        _use_fused_sampling,
+    )
 
+    dist = SymmetricSeparableGaussian({"mu": jnp.zeros(4), "sigma": jnp.ones(4)})
     monkeypatch.delenv("EVOTORCH_TPU_FUSED_SAMPLING", raising=False)
     assert not _use_fused_sampling()
+    assert dist.sample(6, key=jax.random.key(0)).shape == (6, 4)
     monkeypatch.setenv("EVOTORCH_TPU_FUSED_SAMPLING", "1")
-    if jax.default_backend() == "tpu":
-        assert _use_fused_sampling()
-    else:
-        # the on-chip PRNG only lowers on TPU: elsewhere the flag must warn
-        # and fall back to the XLA sampler instead of crashing the first ask
-        with pytest.warns(UserWarning, match="only lowers on TPU"):
-            assert not _use_fused_sampling()
+    assert _use_fused_sampling()
+    with pytest.raises(ValueError, match="interpret mode"):
+        dist.sample(6, key=jax.random.key(0))

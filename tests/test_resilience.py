@@ -1,6 +1,6 @@
 """Fault tolerance (ISSUE 17; docs/resilience.md): durable run bundles,
-non-finite score quarantine in every eval contract, the retry/backoff +
-watchdog edges, and the deterministic ``EVOTORCH_FAULTS`` harness.
+non-finite score quarantine in every eval contract, retry/backoff at the
+host edges, and the deterministic ``EVOTORCH_FAULTS`` harness.
 
 The contract under test is three-legged: a SIGKILL at any instant costs at
 most one checkpoint interval (and the resumed trajectory is BIT-IDENTICAL
@@ -36,13 +36,11 @@ from evotorch_tpu.observability.registry import counters
 from evotorch_tpu.resilience import (
     BUNDLE_SCHEMA_VERSION,
     CorruptBundleError,
-    DeviceProbeTimeout,
     InjectedFault,
     RunCheckpointer,
     configure,
     fault_point,
     parse_spec,
-    probe_devices,
     retry_call,
 )
 
@@ -620,23 +618,6 @@ def test_retry_does_not_catch_unlisted_exceptions():
         retry_call(
             lambda: {}["missing"], site="u", retries=3, base_delay=0.001
         )
-
-
-# ---------------------------------------------------------------------------
-# first-device-use watchdog
-# ---------------------------------------------------------------------------
-
-
-def test_probe_devices_returns_devices():
-    devices = probe_devices(timeout=60)
-    assert len(devices) >= 1
-
-
-def test_probe_devices_flags_silent_cpu_fallback():
-    # under pytest the backend IS cpu, which is exactly the plugin's silent-
-    # fallback signature: expect_accelerator must turn it into an error
-    with pytest.raises(DeviceProbeTimeout, match="accelerator"):
-        probe_devices(timeout=60, expect_accelerator=True)
 
 
 # ---------------------------------------------------------------------------

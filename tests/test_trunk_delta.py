@@ -118,9 +118,12 @@ def test_trunk_forward_matches_dense_rnn():
     )
 
 
-def test_trunk_forward_blocked_bit_identical():
+def test_trunk_forward_blocked_matches_single_block():
     # the blocked forward (static lane blocks through lax.map) runs the SAME
-    # per-lane ops, so it must be bit-identical to the single-block form
+    # per-lane ops; the shared-trunk GEMM over a 4-lane block may accumulate
+    # in another order than the one over all 12 lanes, though (XLA's CPU
+    # GEMM under jax 0.9.0 does: 1 ulp), so the two agree to float32
+    # rounding, not bit for bit
     policy = _mlp_policy()
     params = _trunk_batch(policy, n=12, k=4, seed=5)
     obs = jnp.asarray(np.random.default_rng(6).normal(size=(12, 9)), jnp.float32)
@@ -130,7 +133,9 @@ def test_trunk_forward_blocked_bit_identical():
     blocked, _ = trunk_delta_forward(
         policy, params, prepare_trunk_delta(policy, params, trunk_block=4), obs, None
     )
-    np.testing.assert_array_equal(np.asarray(one), np.asarray(blocked))
+    np.testing.assert_allclose(
+        np.asarray(one), np.asarray(blocked), rtol=1e-6, atol=1e-6
+    )
 
 
 @pytest.mark.parametrize("mode", ["budget", "episodes", "episodes_refill"])
