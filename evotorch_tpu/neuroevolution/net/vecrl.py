@@ -47,6 +47,7 @@ from ...observability.devicemetrics import (
     pack_group_telemetry,
     queue_wait_bucket_index,
 )
+from ...observability.scopes import scope
 from ...tools.lowrank import is_factored
 from ..net.functional import FlatParamsPolicy
 from ..net.lowrank import (
@@ -77,6 +78,23 @@ __all__ = [
 ]
 
 
+def _in_scope(name: str):
+    """Decorator form of ``scope(name)`` with a fresh context manager per
+    call (jax's own decorator form saves the previous name stack on the one
+    shared object, so it is neither re-entrant nor safe across threads that
+    trace at the same time)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
+
 # ------------------- population-parameter representations -------------------
 # The engine accepts a population as a dense (N, L) matrix, a
 # LowRankParamsBatch (center + shared basis + per-lane coefficients — the
@@ -105,6 +123,7 @@ def _params_take(params_batch, idx):
     return params_batch[idx]
 
 
+@_in_scope("rollout_edges")
 def _forward_ctx(policy, params_batch, trunk_block: int = 0):
     """Precompute the loop-invariant forward context (per-layer center/basis
     or trunk/factor trees for the factored paths); call inside jit, OUTSIDE
@@ -127,6 +146,19 @@ def _batched_forward(policy, params_batch, ctx, obs, states):
         out, _ = jax.vmap(lambda p, o: policy(p, o))(params_batch, obs)
         return out, None
     return jax.vmap(policy)(params_batch, obs, states)
+
+
+def _forward_in_compute_dtype(forward, policy_in, states, compute_dtype):
+    """``forward(policy_in, states)`` with its input cast to the compute dtype
+    and its raw output cast back to float32: the whole of a control step's
+    policy forward, in every engine."""
+    with scope("policy_forward"):
+        if compute_dtype is not None:
+            policy_in = policy_in.astype(compute_dtype)
+        raw, new_states = forward(policy_in, states)
+        if compute_dtype is not None:
+            raw = raw.astype(jnp.float32)
+    return raw, new_states
 
 
 def reset_tensors(tree: Any, mask: jnp.ndarray) -> Any:
@@ -404,12 +436,31 @@ def _policy_to_action(raw, action_space, noise, clip: bool):
     return act
 
 
+@_in_scope("env_step")
+def _env_step(env, env_states, raw, noise_keys, action_noise_stdev):
+    """One substep of every lane's env on the policy's raw output: action
+    noise from each lane's own chain (the draw is independent of the working
+    width / batch composition), clipping, then the env's own step. Returns
+    ``(env_states, obs, rewards, dones)``."""
+    noise = None
+    if action_noise_stdev is not None:
+        noise = action_noise_stdev * jax.vmap(
+            lambda k: jax.random.normal(k, raw.shape[1:])
+        )(noise_keys)
+    actions = _policy_to_action(raw, env.action_space, noise, clip=True)
+    if getattr(env, "batched_native", False):
+        return env.batch_step(env_states, actions)
+    return jax.vmap(env.step)(env_states, actions)
+
+
+@_in_scope("env_reset")
 def _env_reset(env, keys):
     if getattr(env, "batched_native", False):
         return env.batch_reset(keys)
     return jax.vmap(env.reset)(keys)
 
 
+@_in_scope("env_reset")
 def _env_state_select(env, mask, a, b):
     """Per-lane env-state select: lane i takes ``a`` where ``mask[i]``."""
     if getattr(env, "batched_native", False):
@@ -458,6 +509,7 @@ def _env_state_take(env, states, idx):
     return jax.tree_util.tree_map(lambda x: x[idx], states)
 
 
+@_in_scope("obs_norm")
 def _stats_psum_merge(old: CollectedStats, new: CollectedStats, axis_name: str):
     """Every shard absorbs every shard's stat delta: the per-step form of the
     end-of-rollout delta merge (the accumulators are linear, so delta-psum
@@ -468,6 +520,7 @@ def _stats_psum_merge(old: CollectedStats, new: CollectedStats, axis_name: str):
     )
 
 
+@_in_scope("rollout_edges")
 def _rollout_init(
     env,
     policy: FlatParamsPolicy,
@@ -622,62 +675,49 @@ def _make_step(
 
     def step(params_batch, ctx, c: RolloutCarry) -> RolloutCarry:
         n = c.active.shape[0]
-        # advance each lane's own PRNG chain (only when this config consumes
-        # randomness — otherwise the chains stay untouched and XLA drops the
-        # splits entirely)
-        if auto_reset or action_noise_stdev is not None:
-            triple = jax.vmap(lambda k: jax.random.split(k, 3))(c.key)
-            lane_keys, noise_keys, reset_keys = triple[:, 0], triple[:, 1], triple[:, 2]
-        else:
-            lane_keys, noise_keys, reset_keys = c.key, None, None
+        with scope("contract"):
+            # advance each lane's own PRNG chain (only when this config
+            # consumes randomness — otherwise the chains stay untouched and
+            # XLA drops the splits entirely)
+            if auto_reset or action_noise_stdev is not None:
+                triple = jax.vmap(lambda k: jax.random.split(k, 3))(c.key)
+                lane_keys, noise_keys, reset_keys = triple[:, 0], triple[:, 1], triple[:, 2]
+            else:
+                lane_keys, noise_keys, reset_keys = c.key, None, None
 
-        policy_in = (
-            stats_normalize(c.stats, c.obs) if observation_normalization else c.obs
+        policy_in = c.obs
+        if observation_normalization:
+            with scope("obs_norm"):
+                policy_in = stats_normalize(c.stats, c.obs)
+        raw, new_policy_states = _forward_in_compute_dtype(
+            lambda obs, states: _batched_forward(policy, params_batch, ctx, obs, states),
+            policy_in,
+            c.policy_states,
+            compute_dtype,
         )
-        if compute_dtype is not None:
-            policy_in = policy_in.astype(compute_dtype)
-        raw, new_policy_states = _batched_forward(
-            policy, params_batch, ctx, policy_in, c.policy_states
+        new_env_states, new_obs, rewards, dones = _env_step(
+            env, c.env_states, raw, noise_keys, action_noise_stdev
         )
-        if compute_dtype is not None:
-            raw = raw.astype(jnp.float32)
 
-        noise = None
-        if action_noise_stdev is not None:
-            # per-lane noise from each lane's own chain: the draw is
-            # independent of the working width / batch composition
-            noise = action_noise_stdev * jax.vmap(
-                lambda k: jax.random.normal(k, raw.shape[1:])
-            )(noise_keys)
-        actions = _policy_to_action(raw, env.action_space, noise, clip=True)
+        with scope("contract"):
+            steps_in_episode = c.steps_in_episode + 1
+            # guaranteed truncation at max_t (gym TimeLimit semantics): even an
+            # env that never emits done internally ends its episode here, so
+            # per-episode score averaging stays well-defined
+            dones = dones | (steps_in_episode >= max_t)
 
-        if getattr(env, "batched_native", False):
-            new_env_states, new_obs, rewards, dones = env.batch_step(
-                c.env_states, actions
-            )
-        else:
-            new_env_states, new_obs, rewards, dones = jax.vmap(env.step)(
-                c.env_states, actions
-            )
+            if decrease_rewards_by is not None:
+                rewards = rewards - decrease_rewards_by
+            if alive_bonus_schedule is not None:
+                rewards = rewards + alive_bonus_for_step(
+                    steps_in_episode, alive_bonus_schedule
+                ) * (~dones)
 
-        steps_in_episode = c.steps_in_episode + 1
-        # guaranteed truncation at max_t (gym TimeLimit semantics): even an
-        # env that never emits done internally ends its episode here, so
-        # per-episode score averaging stays well-defined
-        dones = dones | (steps_in_episode >= max_t)
+            active_f = c.active
+            scores = c.scores + jnp.where(active_f, rewards, 0.0)
 
-        if decrease_rewards_by is not None:
-            rewards = rewards - decrease_rewards_by
-        if alive_bonus_schedule is not None:
-            rewards = rewards + alive_bonus_for_step(
-                steps_in_episode, alive_bonus_schedule
-            ) * (~dones)
-
-        active_f = c.active
-        scores = c.scores + jnp.where(active_f, rewards, 0.0)
-
-        finished = dones & active_f
-        episodes_done = c.episodes_done + finished.astype(jnp.int32)
+            finished = dones & active_f
+            episodes_done = c.episodes_done + finished.astype(jnp.int32)
 
         if auto_reset:
             # auto-reset the envs that finished an episode (reset keys come
@@ -686,77 +726,82 @@ def _make_step(
             env_states_next = _env_state_select(
                 env, finished, fresh_states, new_env_states
             )
-            obs_next = _lane_select(finished, fresh_obs, new_obs)
-            steps_in_episode = jnp.where(finished, 0, steps_in_episode)
-            if new_policy_states is not None:
-                new_policy_states = reset_tensors(new_policy_states, finished)
-            if budget_mode:
-                active = active_f  # every lane runs its full budget
-            else:
-                active = episodes_done < num_episodes
+            with scope("env_reset"):
+                obs_next = _lane_select(finished, fresh_obs, new_obs)
+            with scope("contract"):
+                steps_in_episode = jnp.where(finished, 0, steps_in_episode)
+                if new_policy_states is not None:
+                    new_policy_states = reset_tensors(new_policy_states, finished)
+                if budget_mode:
+                    active = active_f  # every lane runs its full budget
+                else:
+                    active = episodes_done < num_episodes
         else:
             # freeze finished lanes at their last pre-terminal state: they
             # never run another episode, so no fresh reset is ever needed
-            active = episodes_done < num_episodes
+            with scope("contract"):
+                active = episodes_done < num_episodes
+                steps_in_episode = jnp.where(active, steps_in_episode, 0)
             env_states_next = _env_state_select(
                 env, active, new_env_states, c.env_states
             )
-            obs_next = _lane_select(active, new_obs, c.obs)
-            steps_in_episode = jnp.where(active, steps_in_episode, 0)
+            with scope("env_reset"):
+                obs_next = _lane_select(active, new_obs, c.obs)
 
-        if budget_mode and not masked_width:
-            total_steps = c.total_steps + n
-        else:
-            # episodes modes, and budget under padding (``masked_width``:
-            # some lanes are permanently-inactive pad rows whose slots must
-            # not count as genuine interactions)
-            total_steps = c.total_steps + jnp.sum(active_f.astype(jnp.int32))
+        with scope("contract"):
+            if budget_mode and not masked_width:
+                total_steps = c.total_steps + n
+            else:
+                # episodes modes, and budget under padding (``masked_width``:
+                # some lanes are permanently-inactive pad rows whose slots must
+                # not count as genuine interactions)
+                total_steps = c.total_steps + jnp.sum(active_f.astype(jnp.int32))
         # normalization statistics come from the observations the policy will
         # actually consume next step: post-reset-selection obs, masked by the
         # envs still running (ADVICE r1: not the pre-reset terminal obs)
-        new_stats = (
-            stats_update(c.stats, obs_next, mask=active)
-            if observation_normalization
-            else c.stats
-        )
-        if observation_normalization and stats_sync_axis is not None:
-            new_stats = _stats_psum_merge(c.stats, new_stats, stats_sync_axis)
+        new_stats = c.stats
+        if observation_normalization:
+            with scope("obs_norm"):
+                new_stats = stats_update(c.stats, obs_next, mask=active)
+                if stats_sync_axis is not None:
+                    new_stats = _stats_psum_merge(c.stats, new_stats, stats_sync_axis)
 
-        if collect_telemetry and num_groups > 1:
-            # per-group accounting: lane i charges its env-step (if active)
-            # and episode completion (if it fired this step) to PER-LANE
-            # accumulators — two fused elementwise adds; the segment_sum into
-            # group_counts happens once at the loop boundary
-            # (_fold_lane_counts), so the per-step cost is G-independent.
-            # Padding lanes never activate or fire, so their only charge is
-            # capacity (t_global at fold time) — the same semantics as the
-            # v1 global scalars.
-            lane_steps = c.lane_steps + active_f.astype(jnp.int32)
-            lane_episodes = c.lane_episodes + finished.astype(jnp.int32)
-        else:
-            lane_steps = c.lane_steps
-            lane_episodes = c.lane_episodes
+        with scope("contract"):
+            if collect_telemetry and num_groups > 1:
+                # per-group accounting: lane i charges its env-step (if active)
+                # and episode completion (if it fired this step) to PER-LANE
+                # accumulators — two fused elementwise adds; the segment_sum
+                # into group_counts happens once at the loop boundary
+                # (_fold_lane_counts), so the per-step cost is G-independent.
+                # Padding lanes never activate or fire, so their only charge is
+                # capacity (t_global at fold time) — the same semantics as the
+                # v1 global scalars.
+                lane_steps = c.lane_steps + active_f.astype(jnp.int32)
+                lane_episodes = c.lane_episodes + finished.astype(jnp.int32)
+            else:
+                lane_steps = c.lane_steps
+                lane_episodes = c.lane_episodes
 
-        return RolloutCarry(
-            env_states=env_states_next,
-            obs=obs_next,
-            policy_states=new_policy_states,
-            scores=scores,
-            episodes_done=episodes_done,
-            steps_in_episode=steps_in_episode,
-            active=active,
-            stats=new_stats,
-            key=lane_keys,
-            total_steps=total_steps,
-            t_global=c.t_global + 1,
-            # telemetry: every iteration executes `n` lane-step slots,
-            # whether the lanes are live or idling masked
-            capacity=(c.capacity + n) if collect_telemetry else c.capacity,
-            lane_groups=c.lane_groups,
-            group_counts=c.group_counts,
-            lane_steps=lane_steps,
-            lane_episodes=lane_episodes,
-        )
+            return RolloutCarry(
+                env_states=env_states_next,
+                obs=obs_next,
+                policy_states=new_policy_states,
+                scores=scores,
+                episodes_done=episodes_done,
+                steps_in_episode=steps_in_episode,
+                active=active,
+                stats=new_stats,
+                key=lane_keys,
+                total_steps=total_steps,
+                t_global=c.t_global + 1,
+                # telemetry: every iteration executes `n` lane-step slots,
+                # whether the lanes are live or idling masked
+                capacity=(c.capacity + n) if collect_telemetry else c.capacity,
+                lane_groups=c.lane_groups,
+                group_counts=c.group_counts,
+                lane_steps=lane_steps,
+                lane_episodes=lane_episodes,
+            )
 
     return step
 
@@ -1056,19 +1101,15 @@ def run_vectorized_rollout(
 
     ctx = _forward_ctx(policy, params_batch, trunk_block=int(trunk_block))
     if budget_mode:
-        budget = max_t * int(num_episodes)
         final = jax.lax.fori_loop(
-            0, budget, lambda _, c: step(params_batch, ctx, c), carry
+            0,
+            max_t * int(num_episodes),
+            lambda _, c: step(params_batch, ctx, c),
+            carry,
         )
-        # average episodic return over the budget: completed episodes plus
-        # the fractional trailing one (exactly the episodic mean whenever the
-        # budget lands on an episode boundary)
-        episodes_frac = (
-            final.episodes_done + final.steps_in_episode.astype(jnp.float32) / max_t
-        )
-        mean_scores = final.scores / jnp.maximum(episodes_frac, 1.0 / max_t)
     else:
 
+        @_in_scope("contract")
         def cond(c: RolloutCarry):
             any_active = jnp.any(c.active)
             if stats_sync_axis is not None:
@@ -1081,74 +1122,85 @@ def run_vectorized_rollout(
             return any_active & (c.t_global < hard_cap)
 
         final = jax.lax.while_loop(cond, lambda c: step(params_batch, ctx, c), carry)
-        mean_scores = final.scores / jnp.maximum(final.episodes_done, 1)
-    nf_bad = None
-    if nonfinite_quarantine:
-        mean_scores, nf_bad = _quarantine_nonfinite(
-            mean_scores,
-            valid_mask=(
-                None
-                if num_valid is None
-                else jnp.arange(n_total, dtype=jnp.int32) < num_valid
-            ),
-            penalty=nonfinite_penalty,
-            sync_axis=nonfinite_sync_axis,
-        )
-    total_episodes = jnp.sum(final.episodes_done)
-    if num_valid is not None and not budget_mode:
-        # padding lanes were initialized as already-finished; subtract their
-        # synthetic episodes_done so counters/telemetry report genuine work
-        total_episodes = total_episodes - jnp.int32(
-            (n_total - num_valid) * int(num_episodes)
-        )
-    if not telemetry:
-        eval_telemetry = None
-    elif collect_groups:
-        # the per-group counter block IS the telemetry (no histograms in the
-        # non-refill engines: nothing queues, nothing waits); the per-lane
-        # accumulators fold here, once, after the loop
-        group_counts = _fold_lane_counts(
-            final.group_counts,
-            final.lane_steps,
-            final.lane_episodes,
-            final.lane_groups,
-            final.t_global,
-            num_groups,
-        )
-        if nf_bad is not None:
-            # lanes == solutions in these engines, so the per-lane group ids
-            # charge the quarantine counts to the right rows
-            group_counts = _nonfinite_group_counts(
-                group_counts, nf_bad, final.lane_groups, num_groups
+    # everything below runs once per program, after the loop
+    with scope("rollout_edges"):
+        if budget_mode:
+            # average episodic return over the budget: completed episodes plus
+            # the fractional trailing one (exactly the episodic mean whenever
+            # the budget lands on an episode boundary)
+            episodes_frac = (
+                final.episodes_done + final.steps_in_episode.astype(jnp.float32) / max_t
             )
-        eval_telemetry = pack_group_telemetry(group_counts)
-    else:
-        eval_telemetry = pack_group_telemetry(
-            pack_eval_telemetry(
-                env_steps=final.total_steps,
-                episodes=total_episodes,
-                capacity=final.capacity,
-                lane_width=final.active.shape[0],
-                nonfinite=(
-                    0 if nf_bad is None else jnp.sum(nf_bad.astype(jnp.int32))
+            mean_scores = final.scores / jnp.maximum(episodes_frac, 1.0 / max_t)
+        else:
+            mean_scores = final.scores / jnp.maximum(final.episodes_done, 1)
+        nf_bad = None
+        if nonfinite_quarantine:
+            mean_scores, nf_bad = _quarantine_nonfinite(
+                mean_scores,
+                valid_mask=(
+                    None
+                    if num_valid is None
+                    else jnp.arange(n_total, dtype=jnp.int32) < num_valid
                 ),
-            )[None]
+                penalty=nonfinite_penalty,
+                sync_axis=nonfinite_sync_axis,
+            )
+        total_episodes = jnp.sum(final.episodes_done)
+        if num_valid is not None and not budget_mode:
+            # padding lanes were initialized as already-finished; subtract their
+            # synthetic episodes_done so counters/telemetry report genuine work
+            total_episodes = total_episodes - jnp.int32(
+                (n_total - num_valid) * int(num_episodes)
+            )
+        if not telemetry:
+            eval_telemetry = None
+        elif collect_groups:
+            # the per-group counter block IS the telemetry (no histograms in the
+            # non-refill engines: nothing queues, nothing waits); the per-lane
+            # accumulators fold here, once, after the loop
+            group_counts = _fold_lane_counts(
+                final.group_counts,
+                final.lane_steps,
+                final.lane_episodes,
+                final.lane_groups,
+                final.t_global,
+                num_groups,
+            )
+            if nf_bad is not None:
+                # lanes == solutions in these engines, so the per-lane group ids
+                # charge the quarantine counts to the right rows
+                group_counts = _nonfinite_group_counts(
+                    group_counts, nf_bad, final.lane_groups, num_groups
+                )
+            eval_telemetry = pack_group_telemetry(group_counts)
+        else:
+            eval_telemetry = pack_group_telemetry(
+                pack_eval_telemetry(
+                    env_steps=final.total_steps,
+                    episodes=total_episodes,
+                    capacity=final.capacity,
+                    lane_width=final.active.shape[0],
+                    nonfinite=(
+                        0 if nf_bad is None else jnp.sum(nf_bad.astype(jnp.int32))
+                    ),
+                )[None]
+            )
+        if eval_telemetry is not None and health:
+            eval_telemetry = _health_telemetry(
+                eval_telemetry,
+                mean_scores,
+                final.lane_groups if collect_groups else None,
+                num_groups,
+                num_valid,
+            )
+        return RolloutResult(
+            scores=mean_scores,
+            stats=final.stats,
+            total_steps=final.total_steps,
+            total_episodes=total_episodes,
+            telemetry=eval_telemetry,
         )
-    if eval_telemetry is not None and health:
-        eval_telemetry = _health_telemetry(
-            eval_telemetry,
-            mean_scores,
-            final.lane_groups if collect_groups else None,
-            num_groups,
-            num_valid,
-        )
-    return RolloutResult(
-        scores=mean_scores,
-        stats=final.stats,
-        total_steps=final.total_steps,
-        total_episodes=total_episodes,
-        telemetry=eval_telemetry,
-    )
 
 
 # --------------------------- lane-compacting runner ---------------------------
@@ -1377,176 +1429,170 @@ def _run_refill(
     period = max(1, int(refill_period))
     stride = int(seed_stride) if seed_stride is not None else nv
 
-    params_batch = _params_cast(params_batch, compute_dtype)
-    if lane_ids is None:
-        lane_ids = jnp.arange(n, dtype=jnp.int32)
-    store, forward = _refill_forward_setup(
-        policy, params_batch, trunk_block=int(trunk_block)
-    )
+    # what runs once, before the loop: casts, the forward's loop-invariant
+    # context, the first width-many queue items with their resets and statistics
+    with scope("rollout_edges"):
+        params_batch = _params_cast(params_batch, compute_dtype)
+        if lane_ids is None:
+            lane_ids = jnp.arange(n, dtype=jnp.int32)
+        store, forward = _refill_forward_setup(
+            policy, params_batch, trunk_block=int(trunk_block)
+        )
 
-    collect_groups = bool(telemetry) and int(num_groups) > 1 and groups is not None
-    groups_arr = (
-        jnp.asarray(groups, dtype=jnp.int32) if collect_groups else None
-    )
+        collect_groups = bool(telemetry) and int(num_groups) > 1 and groups is not None
+        groups_arr = (
+            jnp.asarray(groups, dtype=jnp.int32) if collect_groups else None
+        )
 
-    # stacked (per-group) observation-normalization slots: detected by the
-    # count's rank so the traced signature is the discriminator (an aval
-    # rank change is a different program anyway — no new static argument)
-    stacked_stats = stats is not None and getattr(stats.count, "ndim", 0) == 1
-    if stacked_stats:
-        if not collect_groups:
-            raise ValueError(
-                "stacked (per-group) stats require telemetry plus a groups"
-                " array with num_groups > 1 — each slot needs lane->group"
-                " bindings to credit"
-            )
-        if stats.count.shape[0] != int(num_groups):
-            raise ValueError(
-                f"stacked stats carry {stats.count.shape[0]} slots but"
-                f" num_groups={num_groups}"
-            )
-
-    def item_keys(items):
-        """(chain, reset) PRNG keys + solution index of queue items. Episode
-        ``e`` of solution ``s`` is seeded ``fold_in(key, lane_ids[s] +
-        e * seed_stride)`` — at e=0 exactly the monolithic runner's per-lane
-        seeding, so matched-seed refill reproduces plain ``episodes``
-        bit-for-bit at ``num_episodes=1`` (observation normalization off —
-        see the ``run_vectorized_rollout`` docstring), for ANY width,
-        sharded or not (``seed_stride`` must be the GLOBAL popsize on a
-        sharded caller). With ``solution_keys``, each item folds its seed
-        into ITS solution's base key instead of the shared ``key`` — the
-        per-tenant isolation form (see ``run_vectorized_rollout``)."""
-        sol = items % nv
-        ep = items // nv
-        seeds = lane_ids[sol] + ep * jnp.int32(stride)
-        if solution_keys is not None:
-            ik = jax.vmap(jax.random.fold_in)(solution_keys[sol], seeds)
-        else:
-            ik = jax.vmap(lambda s: jax.random.fold_in(key, s))(seeds)
-        pair = jax.vmap(lambda k: jax.random.split(k, 2))(ik)
-        return pair[:, 0], pair[:, 1], sol
-
-    items0 = jnp.arange(width, dtype=jnp.int32)
-    chain0, reset0, sol0 = item_keys(items0)
-    env_states0, obs0 = _env_reset(env, reset0)
-    if observation_normalization:
+        # stacked (per-group) observation-normalization slots: detected by the
+        # count's rank so the traced signature is the discriminator (an aval
+        # rank change is a different program anyway — no new static argument)
+        stacked_stats = stats is not None and getattr(stats.count, "ndim", 0) == 1
         if stacked_stats:
-            new_stats = group_stats_update(
-                stats, obs0, groups_arr[sol0], None, int(num_groups)
-            )
+            if not collect_groups:
+                raise ValueError(
+                    "stacked (per-group) stats require telemetry plus a groups"
+                    " array with num_groups > 1 — each slot needs lane->group"
+                    " bindings to credit"
+                )
+            if stats.count.shape[0] != int(num_groups):
+                raise ValueError(
+                    f"stacked stats carry {stats.count.shape[0]} slots but"
+                    f" num_groups={num_groups}"
+                )
+
+        def item_keys(items):
+            """(chain, reset) PRNG keys + solution index of queue items. Episode
+            ``e`` of solution ``s`` is seeded ``fold_in(key, lane_ids[s] +
+            e * seed_stride)`` — at e=0 exactly the monolithic runner's per-lane
+            seeding, so matched-seed refill reproduces plain ``episodes``
+            bit-for-bit at ``num_episodes=1`` (observation normalization off —
+            see the ``run_vectorized_rollout`` docstring), for ANY width,
+            sharded or not (``seed_stride`` must be the GLOBAL popsize on a
+            sharded caller). With ``solution_keys``, each item folds its seed
+            into ITS solution's base key instead of the shared ``key`` — the
+            per-tenant isolation form (see ``run_vectorized_rollout``)."""
+            sol = items % nv
+            ep = items // nv
+            seeds = lane_ids[sol] + ep * jnp.int32(stride)
+            if solution_keys is not None:
+                ik = jax.vmap(jax.random.fold_in)(solution_keys[sol], seeds)
+            else:
+                ik = jax.vmap(lambda s: jax.random.fold_in(key, s))(seeds)
+            pair = jax.vmap(lambda k: jax.random.split(k, 2))(ik)
+            return pair[:, 0], pair[:, 1], sol
+
+        items0 = jnp.arange(width, dtype=jnp.int32)
+        chain0, reset0, sol0 = item_keys(items0)
+        env_states0, obs0 = _env_reset(env, reset0)
+        if observation_normalization:
+            if stacked_stats:
+                new_stats = group_stats_update(
+                    stats, obs0, groups_arr[sol0], None, int(num_groups)
+                )
+            else:
+                new_stats = stats_update(
+                    stats, obs0, mask=jnp.ones(width, dtype=bool)
+                )
+            if stats_sync_axis is not None:
+                new_stats = _stats_psum_merge(stats, new_stats, stats_sync_axis)
+            stats = new_stats
+
+        policy_states0 = _initial_policy_states(policy, width, compute_dtype)
+
+        if telemetry:
+            # the histogram is carried even at G=1 (one row): tail queue wait is
+            # a property of the refill schedule, not of multi-tenancy
+            hist_groups = int(num_groups) if collect_groups else 1
+            hist0 = jnp.zeros((hist_groups, QUEUE_WAIT_BUCKETS), dtype=jnp.int32)
+            idle_since0 = jnp.zeros(width, dtype=jnp.int32)
         else:
-            new_stats = stats_update(
-                stats, obs0, mask=jnp.ones(width, dtype=bool)
-            )
-        if stats_sync_axis is not None:
-            new_stats = _stats_psum_merge(stats, new_stats, stats_sync_axis)
-        stats = new_stats
+            hist0 = jnp.zeros((0, QUEUE_WAIT_BUCKETS), dtype=jnp.int32)
+            idle_since0 = jnp.zeros((0,), dtype=jnp.int32)
+        if collect_groups:
+            lane_groups0 = groups_arr[sol0]
+            group_counts0 = _init_group_counts(lane_groups0, int(num_groups))
+        else:
+            lane_groups0 = _empty_lane_groups()
+            group_counts0 = _empty_group_counts()
 
-    policy_states0 = _initial_policy_states(policy, width, compute_dtype)
-
-    if telemetry:
-        # the histogram is carried even at G=1 (one row): tail queue wait is
-        # a property of the refill schedule, not of multi-tenancy
-        hist_groups = int(num_groups) if collect_groups else 1
-        hist0 = jnp.zeros((hist_groups, QUEUE_WAIT_BUCKETS), dtype=jnp.int32)
-        idle_since0 = jnp.zeros(width, dtype=jnp.int32)
-    else:
-        hist0 = jnp.zeros((0, QUEUE_WAIT_BUCKETS), dtype=jnp.int32)
-        idle_since0 = jnp.zeros((0,), dtype=jnp.int32)
-    if collect_groups:
-        lane_groups0 = groups_arr[sol0]
-        group_counts0 = _init_group_counts(lane_groups0, int(num_groups))
-    else:
-        lane_groups0 = _empty_lane_groups()
-        group_counts0 = _empty_group_counts()
-
-    carry = RefillCarry(
-        env_states=env_states0,
-        obs=obs0,
-        policy_states=policy_states0,
-        lane_params=store[sol0],
-        lane_sol=sol0,
-        lane_score=jnp.zeros(width),
-        steps_in_episode=jnp.zeros(width, dtype=jnp.int32),
-        active=jnp.ones(width, dtype=bool),
-        scores_buf=jnp.zeros(n, dtype=jnp.float32),
-        eps_buf=jnp.zeros(n, dtype=jnp.int32),
-        next_item=jnp.asarray(width, dtype=jnp.int32),
-        stats=stats,
-        key=chain0,
-        total_steps=jnp.zeros((), dtype=jnp.int32),
-        t_global=jnp.zeros((), dtype=jnp.int32),
-        capacity=jnp.zeros((), dtype=jnp.int32),
-        wait_sum=jnp.zeros((), dtype=jnp.int32),
-        idle_since=idle_since0,
-        hist=hist0,
-        lane_groups=lane_groups0,
-        group_counts=group_counts0,
-    )
+        carry = RefillCarry(
+            env_states=env_states0,
+            obs=obs0,
+            policy_states=policy_states0,
+            lane_params=store[sol0],
+            lane_sol=sol0,
+            lane_score=jnp.zeros(width),
+            steps_in_episode=jnp.zeros(width, dtype=jnp.int32),
+            active=jnp.ones(width, dtype=bool),
+            scores_buf=jnp.zeros(n, dtype=jnp.float32),
+            eps_buf=jnp.zeros(n, dtype=jnp.int32),
+            next_item=jnp.asarray(width, dtype=jnp.int32),
+            stats=stats,
+            key=chain0,
+            total_steps=jnp.zeros((), dtype=jnp.int32),
+            t_global=jnp.zeros((), dtype=jnp.int32),
+            capacity=jnp.zeros((), dtype=jnp.int32),
+            wait_sum=jnp.zeros((), dtype=jnp.int32),
+            idle_since=idle_since0,
+            hist=hist0,
+            lane_groups=lane_groups0,
+            group_counts=group_counts0,
+        )
 
     def step(c: RefillCarry) -> RefillCarry:
         # the per-lane chains advance ONLY when this config draws action
         # noise (refill resets use the item's own key, not the lane chain) —
         # the same 3-way split discipline as the monolithic engine, so the
         # realized noise matches it draw-for-draw
-        if action_noise_stdev is not None:
-            triple = jax.vmap(lambda k: jax.random.split(k, 3))(c.key)
-            lane_keys, noise_keys = triple[:, 0], triple[:, 1]
-        else:
-            lane_keys, noise_keys = c.key, None
+        with scope("contract"):
+            if action_noise_stdev is not None:
+                triple = jax.vmap(lambda k: jax.random.split(k, 3))(c.key)
+                lane_keys, noise_keys = triple[:, 0], triple[:, 1]
+            else:
+                lane_keys, noise_keys = c.key, None
 
-        if not observation_normalization:
-            policy_in = c.obs
-        elif stacked_stats:
-            # per-group slots: each lane is normalized by ITS group's
-            # running statistics (tenant isolation)
-            policy_in = group_stats_normalize(c.stats, c.obs, c.lane_groups)
-        else:
-            policy_in = stats_normalize(c.stats, c.obs)
-        if compute_dtype is not None:
-            policy_in = policy_in.astype(compute_dtype)
-        raw, new_policy_states = forward(c.lane_params, policy_in, c.policy_states)
-        if compute_dtype is not None:
-            raw = raw.astype(jnp.float32)
-
-        noise = None
-        if action_noise_stdev is not None:
-            noise = action_noise_stdev * jax.vmap(
-                lambda k: jax.random.normal(k, raw.shape[1:])
-            )(noise_keys)
-        actions = _policy_to_action(raw, env.action_space, noise, clip=True)
-
-        if getattr(env, "batched_native", False):
-            new_env_states, new_obs, rewards, dones = env.batch_step(
-                c.env_states, actions
-            )
-        else:
-            new_env_states, new_obs, rewards, dones = jax.vmap(env.step)(
-                c.env_states, actions
-            )
-
-        steps_in_episode = c.steps_in_episode + 1
-        dones = dones | (steps_in_episode >= max_t)
-        if decrease_rewards_by is not None:
-            rewards = rewards - decrease_rewards_by
-        if alive_bonus_schedule is not None:
-            rewards = rewards + alive_bonus_for_step(
-                steps_in_episode, alive_bonus_schedule
-            ) * (~dones)
-
-        active_f = c.active
-        lane_score = c.lane_score + jnp.where(active_f, rewards, 0.0)
-        finished = dones & active_f
-        # segment reduction: credit finished episodes to their solutions
-        # (idle lanes contribute an exact 0.0 to whatever row they last ran)
-        scores_buf = c.scores_buf.at[c.lane_sol].add(
-            jnp.where(finished, lane_score, 0.0)
+        with scope("obs_norm"):
+            if not observation_normalization:
+                policy_in = c.obs
+            elif stacked_stats:
+                # per-group slots: each lane is normalized by ITS group's
+                # running statistics (tenant isolation)
+                policy_in = group_stats_normalize(c.stats, c.obs, c.lane_groups)
+            else:
+                policy_in = stats_normalize(c.stats, c.obs)
+        raw, new_policy_states = _forward_in_compute_dtype(
+            lambda obs, states: forward(c.lane_params, obs, states),
+            policy_in,
+            c.policy_states,
+            compute_dtype,
         )
-        eps_buf = c.eps_buf.at[c.lane_sol].add(finished.astype(jnp.int32))
-        total_steps = c.total_steps + jnp.sum(active_f.astype(jnp.int32))
+        new_env_states, new_obs, rewards, dones = _env_step(
+            env, c.env_states, raw, noise_keys, action_noise_stdev
+        )
 
-        running = active_f & ~finished
+        with scope("contract"):
+            steps_in_episode = c.steps_in_episode + 1
+            dones = dones | (steps_in_episode >= max_t)
+            if decrease_rewards_by is not None:
+                rewards = rewards - decrease_rewards_by
+            if alive_bonus_schedule is not None:
+                rewards = rewards + alive_bonus_for_step(
+                    steps_in_episode, alive_bonus_schedule
+                ) * (~dones)
+
+            active_f = c.active
+            lane_score = c.lane_score + jnp.where(active_f, rewards, 0.0)
+            finished = dones & active_f
+            # segment reduction: credit finished episodes to their solutions
+            # (idle lanes contribute an exact 0.0 to whatever row they last ran)
+            scores_buf = c.scores_buf.at[c.lane_sol].add(
+                jnp.where(finished, lane_score, 0.0)
+            )
+            eps_buf = c.eps_buf.at[c.lane_sol].add(finished.astype(jnp.int32))
+            total_steps = c.total_steps + jnp.sum(active_f.astype(jnp.int32))
+
+            running = active_f & ~finished
         # freeze non-running lanes at their pre-step state (the monolithic
         # engine's no-reset trick: bounded states, no NaN leakage) and reset
         # their per-episode bookkeeping so a later refill starts clean.
@@ -1555,39 +1601,44 @@ def _run_refill(
         # bit-identity contract must hold for stateful policies whose
         # initial_state() is nonzero, not just the built-in RNN/LSTM zeros)
         env_states_base = _env_state_select(env, running, new_env_states, c.env_states)
-        obs_base = _lane_select(running, new_obs, c.obs)
-        steps_base = jnp.where(running, steps_in_episode, 0)
-        lane_score = jnp.where(running, lane_score, 0.0)
-        policy_states_base = (
-            None
-            if new_policy_states is None
-            else jax.tree_util.tree_map(
-                lambda s, init: _lane_select(running, s, init),
-                new_policy_states,
-                policy_states0,
+        with scope("env_reset"):
+            obs_base = _lane_select(running, new_obs, c.obs)
+        with scope("contract"):
+            steps_base = jnp.where(running, steps_in_episode, 0)
+            lane_score = jnp.where(running, lane_score, 0.0)
+            policy_states_base = (
+                None
+                if new_policy_states is None
+                else jax.tree_util.tree_map(
+                    lambda s, init: _lane_select(running, s, init),
+                    new_policy_states,
+                    policy_states0,
+                )
             )
-        )
 
-        idle = ~running
-        gate = jnp.any(idle) & (c.next_item < total_items)
-        if period > 1:
-            gate = gate & (((c.t_global + 1) % period) == 0)
-        # ranks among idle lanes -> candidate queue items; lanes beyond the
-        # queue end stay idle (drained). Computed outside the cond so both
-        # branches agree on `take`'s provenance.
-        offs = jnp.cumsum(idle.astype(jnp.int32)) - 1
-        cand = c.next_item + offs
-        take = idle & (cand < total_items) & gate
+            idle = ~running
+            gate = jnp.any(idle) & (c.next_item < total_items)
+            if period > 1:
+                gate = gate & (((c.t_global + 1) % period) == 0)
+            # ranks among idle lanes -> candidate queue items; lanes beyond the
+            # queue end stay idle (drained). Computed outside the cond so both
+            # branches agree on `take`'s provenance.
+            offs = jnp.cumsum(idle.astype(jnp.int32)) - 1
+            cand = c.next_item + offs
+            take = idle & (cand < total_items) & gate
 
         def do_refill(op):
             env_states, obs_cur, lane_params, lane_sol, keys = op
-            chain, reset_k, sol = item_keys(jnp.where(take, cand, 0))
+            with scope("contract"):  # the queue pop
+                chain, reset_k, sol = item_keys(jnp.where(take, cand, 0))
             fresh_states, fresh_obs = _env_reset(env, reset_k)
             env_states = _env_state_select(env, take, fresh_states, env_states)
-            obs_cur = _lane_select(take, fresh_obs, obs_cur)
-            lane_sol = jnp.where(take, sol, lane_sol)
-            lane_params = _lane_select(take, store[sol], lane_params)
-            keys = jnp.where(take, chain, keys)
+            with scope("env_reset"):
+                obs_cur = _lane_select(take, fresh_obs, obs_cur)
+            with scope("contract"):
+                lane_sol = jnp.where(take, sol, lane_sol)
+                lane_params = _lane_select(take, store[sol], lane_params)
+                keys = jnp.where(take, chain, keys)
             return env_states, obs_cur, lane_params, lane_sol, keys
 
         def skip_refill(op):
@@ -1601,111 +1652,114 @@ def _run_refill(
                 (env_states_base, obs_base, c.lane_params, c.lane_sol, lane_keys),
             )
         )
-        active = running | take
-        next_item = c.next_item + jnp.sum(take.astype(jnp.int32))
+        with scope("contract"):
+            active = running | take
+            next_item = c.next_item + jnp.sum(take.astype(jnp.int32))
 
-        if telemetry:
-            # telemetry: each iteration executes W lane-step slots; lanes
-            # idle AFTER this step's refill while the queue still holds work
-            # are waiting on the refill gate / drain order (the
-            # starvation-accounting numerator)
-            capacity = c.capacity + jnp.int32(width)
-            wait_sum = c.wait_sum + jnp.where(
-                next_item < total_items,
-                jnp.sum((~active).astype(jnp.int32)),
-                0,
-            )
-            # queue-wait histogram: a lane's wait is refill step minus the
-            # step its previous episode finished (same-step refill = 0 →
-            # bucket 0). `take` is all-False when the cond gate is closed,
-            # so updating outside the cond adds zeros — no divergence.
-            # Lanes drained at queue end never refill → never counted.
-            tcur = c.t_global + 1
-            idle_since = jnp.where(finished, tcur, c.idle_since)
-            waits = jnp.where(take, tcur - idle_since, 0)
-            buckets = queue_wait_bucket_index(waits)
-            take_i = take.astype(jnp.int32)
-            if collect_groups:
-                sol_in = jnp.where(take, cand, 0) % nv
-                g_in = groups_arr[sol_in]
-                hist = c.hist.at[g_in, buckets].add(take_i)
-                lane_groups = jnp.where(take, g_in, c.lane_groups)
-                per_lane = jnp.stack(
-                    [
-                        active_f.astype(jnp.int32),
-                        finished.astype(jnp.int32),
-                        jnp.ones(width, dtype=jnp.int32),
-                    ],
-                    axis=1,
+            if telemetry:
+                # telemetry: each iteration executes W lane-step slots; lanes
+                # idle AFTER this step's refill while the queue still holds work
+                # are waiting on the refill gate / drain order (the
+                # starvation-accounting numerator)
+                capacity = c.capacity + jnp.int32(width)
+                wait_sum = c.wait_sum + jnp.where(
+                    next_item < total_items,
+                    jnp.sum((~active).astype(jnp.int32)),
+                    0,
                 )
-                group_counts = c.group_counts.at[:, : _COL_LANE_WIDTH].add(
-                    jax.ops.segment_sum(
-                        per_lane, c.lane_groups, num_segments=num_groups
+                # queue-wait histogram: a lane's wait is refill step minus the
+                # step its previous episode finished (same-step refill = 0 →
+                # bucket 0). `take` is all-False when the cond gate is closed,
+                # so updating outside the cond adds zeros — no divergence.
+                # Lanes drained at queue end never refill → never counted.
+                tcur = c.t_global + 1
+                idle_since = jnp.where(finished, tcur, c.idle_since)
+                waits = jnp.where(take, tcur - idle_since, 0)
+                buckets = queue_wait_bucket_index(waits)
+                take_i = take.astype(jnp.int32)
+                if collect_groups:
+                    sol_in = jnp.where(take, cand, 0) % nv
+                    g_in = groups_arr[sol_in]
+                    hist = c.hist.at[g_in, buckets].add(take_i)
+                    lane_groups = jnp.where(take, g_in, c.lane_groups)
+                    per_lane = jnp.stack(
+                        [
+                            active_f.astype(jnp.int32),
+                            finished.astype(jnp.int32),
+                            jnp.ones(width, dtype=jnp.int32),
+                        ],
+                        axis=1,
                     )
-                )
-                group_counts = group_counts.at[:, _COL_REFILL].add(
-                    jax.ops.segment_sum(
-                        take_i, g_in, num_segments=num_groups
+                    group_counts = c.group_counts.at[:, : _COL_LANE_WIDTH].add(
+                        jax.ops.segment_sum(
+                            per_lane, c.lane_groups, num_segments=num_groups
+                        )
                     )
-                )
-                # per-step gating matches the scalar wait_sum above (the
-                # UPDATED next_item), so the column sum equals it exactly
-                wait_lane = jnp.where(
-                    next_item < total_items, (~active).astype(jnp.int32), 0
-                )
-                group_counts = group_counts.at[:, _COL_WAIT].add(
-                    jax.ops.segment_sum(
-                        wait_lane, lane_groups, num_segments=num_groups
+                    group_counts = group_counts.at[:, _COL_REFILL].add(
+                        jax.ops.segment_sum(
+                            take_i, g_in, num_segments=num_groups
+                        )
                     )
-                )
+                    # per-step gating matches the scalar wait_sum above (the
+                    # UPDATED next_item), so the column sum equals it exactly
+                    wait_lane = jnp.where(
+                        next_item < total_items, (~active).astype(jnp.int32), 0
+                    )
+                    group_counts = group_counts.at[:, _COL_WAIT].add(
+                        jax.ops.segment_sum(
+                            wait_lane, lane_groups, num_segments=num_groups
+                        )
+                    )
+                else:
+                    hist = c.hist.at[0, buckets].add(take_i)
+                    lane_groups = c.lane_groups
+                    group_counts = c.group_counts
             else:
-                hist = c.hist.at[0, buckets].add(take_i)
-                lane_groups = c.lane_groups
-                group_counts = c.group_counts
-        else:
-            capacity, wait_sum = c.capacity, c.wait_sum
-            idle_since, hist = c.idle_since, c.hist
-            lane_groups, group_counts = c.lane_groups, c.group_counts
+                capacity, wait_sum = c.capacity, c.wait_sum
+                idle_since, hist = c.idle_since, c.hist
+                lane_groups, group_counts = c.lane_groups, c.group_counts
 
         # obs-norm statistics count ONLY live-lane observations: the
         # post-refill obs each still-active lane will consume next step
         # (idle/drained lanes are masked out entirely). Stacked slots
         # credit the POST-refill lane groups: a fresh reset observation
         # belongs to the incoming item's group, not the departed one's.
-        if not observation_normalization:
-            new_stats = c.stats
-        elif stacked_stats:
-            new_stats = group_stats_update(
-                c.stats, obs_next, lane_groups, active, num_groups
-            )
-        else:
-            new_stats = stats_update(c.stats, obs_next, mask=active)
-        if observation_normalization and stats_sync_axis is not None:
-            new_stats = _stats_psum_merge(c.stats, new_stats, stats_sync_axis)
+        with scope("obs_norm"):
+            if not observation_normalization:
+                new_stats = c.stats
+            elif stacked_stats:
+                new_stats = group_stats_update(
+                    c.stats, obs_next, lane_groups, active, num_groups
+                )
+            else:
+                new_stats = stats_update(c.stats, obs_next, mask=active)
+            if observation_normalization and stats_sync_axis is not None:
+                new_stats = _stats_psum_merge(c.stats, new_stats, stats_sync_axis)
 
-        return RefillCarry(
-            env_states=env_states_next,
-            obs=obs_next,
-            policy_states=policy_states_base,
-            lane_params=lane_params_next,
-            lane_sol=lane_sol_next,
-            lane_score=lane_score,
-            steps_in_episode=steps_base,
-            active=active,
-            scores_buf=scores_buf,
-            eps_buf=eps_buf,
-            next_item=next_item,
-            stats=new_stats,
-            key=keys_next,
-            total_steps=total_steps,
-            t_global=c.t_global + 1,
-            capacity=capacity,
-            wait_sum=wait_sum,
-            idle_since=idle_since,
-            hist=hist,
-            lane_groups=lane_groups,
-            group_counts=group_counts,
-        )
+        with scope("contract"):
+            return RefillCarry(
+                env_states=env_states_next,
+                obs=obs_next,
+                policy_states=policy_states_base,
+                lane_params=lane_params_next,
+                lane_sol=lane_sol_next,
+                lane_score=lane_score,
+                steps_in_episode=steps_base,
+                active=active,
+                scores_buf=scores_buf,
+                eps_buf=eps_buf,
+                next_item=next_item,
+                stats=new_stats,
+                key=keys_next,
+                total_steps=total_steps,
+                t_global=c.t_global + 1,
+                capacity=capacity,
+                wait_sum=wait_sum,
+                idle_since=idle_since,
+                hist=hist,
+                lane_groups=lane_groups,
+                group_counts=group_counts,
+            )
 
     # greedy-scheduling makespan bound (total work / W + longest item) plus
     # the refill-period waiting slack — a safety net, not the exit condition
@@ -1716,6 +1770,7 @@ def _run_refill(
         + 2
     )
 
+    @_in_scope("contract")
     def cond(c: RefillCarry):
         # pending queue items keep the loop alive even when every lane is
         # momentarily idle (all lanes can finish on a step whose refill gate
@@ -1730,63 +1785,64 @@ def _run_refill(
         return any_work & (c.t_global < hard_cap)
 
     final = jax.lax.while_loop(cond, step, carry)
-    mean_scores = final.scores_buf / jnp.maximum(final.eps_buf, 1).astype(jnp.float32)
-    nf_bad = None
-    if nonfinite_quarantine:
-        mean_scores, nf_bad = _quarantine_nonfinite(
-            mean_scores,
-            valid_mask=(
-                None
-                if num_valid is None
-                else jnp.arange(n, dtype=jnp.int32) < nv
-            ),
-            penalty=nonfinite_penalty,
-            sync_axis=nonfinite_sync_axis,
-        )
-    total_episodes = jnp.sum(final.eps_buf)
-    if not telemetry:
-        eval_telemetry = None
-    elif collect_groups:
-        group_counts = final.group_counts
-        if nf_bad is not None:
-            # scores_buf is per SOLUTION here: charge each quarantined
-            # solution's group directly off the per-solution id array
-            group_counts = _nonfinite_group_counts(
-                group_counts, nf_bad, groups_arr, num_groups
-            )
-        eval_telemetry = pack_group_telemetry(group_counts, final.hist)
-    else:
-        eval_telemetry = pack_group_telemetry(
-            pack_eval_telemetry(
-                env_steps=final.total_steps,
-                episodes=total_episodes,
-                capacity=final.capacity,
-                lane_width=width,
-                # items 0..width-1 seeded the lanes; everything past
-                # that entered through the refill gather
-                refill_events=final.next_item - jnp.int32(width),
-                queue_wait=final.wait_sum,
-                nonfinite=(
-                    0 if nf_bad is None else jnp.sum(nf_bad.astype(jnp.int32))
+    with scope("rollout_edges"):  # once per program, after the loop
+        mean_scores = final.scores_buf / jnp.maximum(final.eps_buf, 1).astype(jnp.float32)
+        nf_bad = None
+        if nonfinite_quarantine:
+            mean_scores, nf_bad = _quarantine_nonfinite(
+                mean_scores,
+                valid_mask=(
+                    None
+                    if num_valid is None
+                    else jnp.arange(n, dtype=jnp.int32) < nv
                 ),
-            )[None],
-            final.hist,
+                penalty=nonfinite_penalty,
+                sync_axis=nonfinite_sync_axis,
+            )
+        total_episodes = jnp.sum(final.eps_buf)
+        if not telemetry:
+            eval_telemetry = None
+        elif collect_groups:
+            group_counts = final.group_counts
+            if nf_bad is not None:
+                # scores_buf is per SOLUTION here: charge each quarantined
+                # solution's group directly off the per-solution id array
+                group_counts = _nonfinite_group_counts(
+                    group_counts, nf_bad, groups_arr, num_groups
+                )
+            eval_telemetry = pack_group_telemetry(group_counts, final.hist)
+        else:
+            eval_telemetry = pack_group_telemetry(
+                pack_eval_telemetry(
+                    env_steps=final.total_steps,
+                    episodes=total_episodes,
+                    capacity=final.capacity,
+                    lane_width=width,
+                    # items 0..width-1 seeded the lanes; everything past
+                    # that entered through the refill gather
+                    refill_events=final.next_item - jnp.int32(width),
+                    queue_wait=final.wait_sum,
+                    nonfinite=(
+                        0 if nf_bad is None else jnp.sum(nf_bad.astype(jnp.int32))
+                    ),
+                )[None],
+                final.hist,
+            )
+        if eval_telemetry is not None and health:
+            eval_telemetry = _health_telemetry(
+                eval_telemetry,
+                mean_scores,
+                groups_arr if collect_groups else None,
+                num_groups,
+                num_valid,
+            )
+        return RolloutResult(
+            scores=mean_scores,
+            stats=final.stats,
+            total_steps=final.total_steps,
+            total_episodes=total_episodes,
+            telemetry=eval_telemetry,
         )
-    if eval_telemetry is not None and health:
-        eval_telemetry = _health_telemetry(
-            eval_telemetry,
-            mean_scores,
-            groups_arr if collect_groups else None,
-            num_groups,
-            num_valid,
-        )
-    return RolloutResult(
-        scores=mean_scores,
-        stats=final.stats,
-        total_steps=final.total_steps,
-        total_episodes=total_episodes,
-        telemetry=eval_telemetry,
-    )
 
 
 @functools.lru_cache(maxsize=_ENGINE_CACHE_SIZE)
@@ -1851,6 +1907,7 @@ def _compacting_fns(
     def chunk_fn(params_batch, carry, num_steps: int):
         ctx = _forward_ctx(policy, params_batch)  # loop-invariant, per chunk
 
+        @_in_scope("contract")
         def cond(s):
             i, c = s
             any_active = jnp.any(c.active)
@@ -1867,7 +1924,8 @@ def _compacting_fns(
             return i + 1, step(params_batch, ctx, c)
 
         _, out = jax.lax.while_loop(cond, body, (jnp.zeros((), jnp.int32), carry))
-        return out, jnp.sum(out.active.astype(jnp.int32))
+        with scope("rollout_edges"):
+            return out, jnp.sum(out.active.astype(jnp.int32))
 
     @partial(jax.jit, static_argnames=("new_width",))
     def compact_fn(carry, params_batch, lane_ids, scores_buf, eps_buf, new_width: int):

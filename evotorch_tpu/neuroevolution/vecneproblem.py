@@ -198,6 +198,9 @@ class VecNE(NEProblem):
         self._tuned_cacheable = not (isinstance(env, str) and env_config)
         self._tuned_resolution: dict = {}
         self._tuned_config_source: Optional[str] = None
+        # layout the last dense population sent to a mesh arrived in, where it
+        # was committed to one (lower_evaluation lowers for the same layout)
+        self._population_layout = None
         if obs_norm_sync not in ("cohort", "step"):
             raise ValueError(
                 f"obs_norm_sync must be 'cohort' or 'step', got {obs_norm_sync!r}"
@@ -463,10 +466,12 @@ class VecNE(NEProblem):
         return status
 
     # ------------------------------------------------------------ evaluation
-    def _rollout_batch(self, values: jnp.ndarray, key, groups=None) -> tuple:
-        if self._eval_backend is not None:
-            return self._eval_backend.evaluate(self, values, key, groups=groups)
-        kwargs = dict(
+    def _contract_kwargs(self) -> dict:
+        """The eval contract's static configuration, as every rollout entry
+        point takes it (the single-device engines, the sharded evaluator, the
+        fused training span): the ONE place it is assembled, so the programs
+        they build, and the one ``lower_evaluation`` lowers, cannot drift."""
+        return dict(
             num_episodes=self._num_episodes,
             episode_length=self._episode_length,
             observation_normalization=self._observation_normalization,
@@ -478,27 +483,88 @@ class VecNE(NEProblem):
             nonfinite_penalty=self._nonfinite_penalty,
             health=self._health_telemetry,
         )
+
+    def _rollout_kwargs(self, popsize: int, groups=None) -> dict:
+        """Keyword arguments of the single-device rollout of ``popsize``
+        solutions: ``run_vectorized_rollout``'s, or under ``episodes_compact``
+        ``run_vectorized_rollout_compacting``'s."""
+        kwargs = self._contract_kwargs()
         if groups is not None:
             # num_groups stays the problem-GLOBAL count: sub-batch matrices
             # share the row space, so they stay addable
             kwargs["groups"] = groups
             kwargs["num_groups"] = self._num_groups
         if self._eval_mode == "episodes_compact":
+            kwargs.update(self._compact_kwargs(popsize))
+            return kwargs
+        kwargs["eval_mode"] = self._eval_mode
+        if self._eval_mode == "episodes_refill":
+            kwargs.update(self._refill_kwargs(popsize))
+        return kwargs
+
+    def _rollout_batch(self, values: jnp.ndarray, key, groups=None) -> tuple:
+        if self._eval_backend is not None:
+            return self._eval_backend.evaluate(self, values, key, groups=groups)
+        popsize = _params_popsize(values)
+        kwargs = self._rollout_kwargs(popsize, groups)
+        if self._eval_mode == "episodes_compact":
             return run_vectorized_rollout_compacting(
                 self._env, self._policy, values, key, self._obs_norm.stats,
-                prewarm=self._take_prewarm(_params_popsize(values)),
-                **self._compact_kwargs(_params_popsize(values)), **kwargs,
+                prewarm=self._take_prewarm(popsize), **kwargs,
             )
-        if self._eval_mode == "episodes_refill":
-            kwargs.update(self._refill_kwargs(_params_popsize(values)))
         return run_vectorized_rollout(
+            self._env, self._policy, values, key, self._obs_norm.stats, **kwargs
+        )
+
+    def lower_evaluation(self, popsize: int):
+        """The device program ``evaluate`` dispatches for a dense population
+        of ``popsize`` solutions, lowered on ``ShapeDtypeStruct``s
+        (``jax.stages.Lowered``): ``run_vectorized_rollout`` with this
+        problem's contract on one device, or the memoized sharded evaluator's
+        program where ``num_actors`` gives a mesh. Nothing runs and no PRNG
+        key is drawn; through the persistent compile cache ``.compile()`` of
+        it is a load. ``compile().as_text()`` names every instruction with
+        its scope (``observability.scopes.instruction_scopes``), which a
+        device trace without the HLO proto does not.
+
+        ``episodes_compact`` (host-orchestrated chunks) and an
+        ``eval_backend`` have no single program and raise. Under
+        ``max_num_envs`` the program is that of one full sub-batch."""
+        if self._eval_backend is not None or self._eval_mode == "episodes_compact":
+            raise ValueError(
+                "lower_evaluation needs the one compiled rollout program of"
+                " eval_mode 'budget', 'episodes' or 'episodes_refill' without an"
+                f" eval_backend; got eval_mode={self._eval_mode!r}"
+            )
+
+        def abstract(x):
+            # an array that came out of a mesh program is committed to its
+            # layout, and jit specialises on that; anything else is free
+            sharding = x.sharding if getattr(x, "committed", False) else None
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+        popsize = int(popsize)
+        key = abstract(self._rng_key)
+        stats = jax.tree_util.tree_map(abstract, self._obs_norm.stats)
+        mesh = self._num_actors_mesh(popsize)
+        if mesh is None and self._max_num_envs is not None:
+            popsize = min(popsize, self._max_num_envs)
+        shape = (popsize, self.solution_length)
+        if mesh is not None:
+            # a population that came out of a mesh program arrives committed
+            # to a layout (the OO searchers' does from the second generation
+            # on), and the evaluator's jit specialises on it
+            values = jax.ShapeDtypeStruct(shape, self.dtype, sharding=self._population_layout)
+            evaluator = self._sharded_rollout_evaluator(mesh, "pop")
+            return evaluator.program_builder("dense", popsize).lower(values, key, stats)
+        values = jax.ShapeDtypeStruct(shape, self.dtype)
+        return run_vectorized_rollout.lower(
             self._env,
             self._policy,
             values,
             key,
-            self._obs_norm.stats,
-            eval_mode=self._eval_mode,
-            **kwargs,
+            stats,
+            **self._rollout_kwargs(popsize, self._check_solution_groups(popsize)),
         )
 
     def _resolve_num_actors_request(self):
@@ -684,19 +750,7 @@ class VecNE(NEProblem):
         memo = self.__dict__.setdefault("_sharded_evaluator_memo", {})
         evaluator = memo.get(mesh)
         if evaluator is None:
-            kwargs = dict(
-                num_episodes=self._num_episodes,
-                episode_length=self._episode_length,
-                observation_normalization=self._observation_normalization,
-                alive_bonus_schedule=self._alive_bonus_schedule,
-                decrease_rewards_by=self._decrease_rewards_by,
-                action_noise_stdev=self._action_noise_stdev,
-                compute_dtype=self._compute_dtype,
-                eval_mode=self._eval_mode,
-                nonfinite_quarantine=self._nonfinite_quarantine,
-                nonfinite_penalty=self._nonfinite_penalty,
-                health=self._health_telemetry,
-            )
+            kwargs = dict(self._contract_kwargs(), eval_mode=self._eval_mode)
             if self._eval_mode == "episodes_refill":
                 # explicit knobs pass through GLOBAL (the helper's
                 # convention); with none, the helper consults the
@@ -741,6 +795,7 @@ class VecNE(NEProblem):
         is_lowrank = is_factored(values)
         if not is_lowrank:
             values = jnp.asarray(values)
+            self._population_layout = values.sharding if values.committed else None
         n = len(batch)
 
         stats = self._obs_norm.stats
@@ -764,16 +819,7 @@ class VecNE(NEProblem):
                 stats,
                 mesh=mesh,
                 axis_name=axis_name,
-                num_episodes=self._num_episodes,
-                episode_length=self._episode_length,
-                observation_normalization=obsnorm,
-                alive_bonus_schedule=self._alive_bonus_schedule,
-                decrease_rewards_by=self._decrease_rewards_by,
-                action_noise_stdev=self._action_noise_stdev,
-                compute_dtype=self._compute_dtype,
-                nonfinite_quarantine=self._nonfinite_quarantine,
-                nonfinite_penalty=self._nonfinite_penalty,
-                health=self._health_telemetry,
+                **self._contract_kwargs(),
                 prewarm=self._take_prewarm(n),
                 stats_sync=(obsnorm and self._obs_norm_sync == "step"),
                 groups=groups,
@@ -831,19 +877,7 @@ class VecNE(NEProblem):
         from ..parallel.evaluate import make_training_span as _make_span
 
         popsize = int(popsize)
-        kwargs = dict(
-            num_episodes=self._num_episodes,
-            episode_length=self._episode_length,
-            observation_normalization=self._observation_normalization,
-            alive_bonus_schedule=self._alive_bonus_schedule,
-            decrease_rewards_by=self._decrease_rewards_by,
-            action_noise_stdev=self._action_noise_stdev,
-            compute_dtype=self._compute_dtype,
-            nonfinite_quarantine=self._nonfinite_quarantine,
-            nonfinite_penalty=self._nonfinite_penalty,
-            health=self._health_telemetry,
-            eval_mode=self._eval_mode,
-        )
+        kwargs = dict(self._contract_kwargs(), eval_mode=self._eval_mode)
         if self._eval_mode == "episodes_refill":
             kwargs.update(self._refill_kwargs(popsize))
         groups = self._check_solution_groups(popsize)

@@ -25,6 +25,7 @@ jax-version-specific artifacts, not source.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from typing import Dict, Optional
 
@@ -92,6 +93,31 @@ def enable_persistent_cache(subdir: str = "") -> str:
     _install_listener()
     _ENABLED_DIR = path
     return path
+
+
+@contextlib.contextmanager
+def past_persistent_cache():
+    """Compile inside as if there were no persistent cache, and put it back
+    afterwards. For what must see the program and not the cache's entry: an
+    executable deserialized from the cache reports another peak memory than a
+    fresh one, and it carries the metadata it was stored with: the cache key
+    ignores ``jax.named_scope`` names, so after a scope was added or moved
+    (or under a cache written by an older commit) the cached executable names
+    its instructions' scopes as they were. The directory option alone is not
+    enough: the cache singleton initialises once and keeps what it saw first,
+    so the bypass flips the enable flag and resets the singleton. jax's
+    in-process caches are the caller's to clear (``jax.clear_caches()``) where
+    the same program was already compiled in this process."""
+    from jax._src import compilation_cache
+
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
 
 
 def cache_stats() -> Dict[str, object]:

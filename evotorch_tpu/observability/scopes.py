@@ -1,0 +1,167 @@
+"""Names for the work inside the compiled rollout.
+
+The evaluation is one device program (``run_vectorized_rollout`` and its
+siblings in ``neuroevolution/net/vecrl.py``), and a device trace times its
+ops without saying which layer an op belongs to. ``scope(name)`` is
+``jax.named_scope("evotorch_tpu." + name)``: it writes the name into the
+``op_name`` metadata of every HLO instruction traced inside it and changes
+nothing else. The optimized program is the same program (the persistent
+cache's key does not even see the names: jax strips debug info from the
+module it hashes, so an executable cached before a scope was added or moved
+is handed back WITHOUT it; clear the cache after touching scopes).
+
+``ROLLOUT_SCOPES`` is the one place the names are declared:
+
+- ``policy_forward``: the population-wide forward (dense ``vmap(policy)``,
+  low-rank, trunk-delta), with the casts into and out of the compute dtype;
+- ``env_step``: the env substep and the mapping of the policy's output to
+  an action;
+- ``env_reset``: the fresh reset inside the loop and the per-lane select
+  between fresh and stepped state;
+- ``obs_norm``: normalising observations and updating (and, across shards,
+  merging) their running statistics;
+- ``contract``: the rest of a control step: PRNG chains, done flags, reward
+  adjustments, scores, episode and step counters, activity masks, the
+  refill queue;
+- ``rollout_edges``: what runs once per program, outside the loop: the first
+  reset and statistics, parameter casts, forward contexts, score averaging,
+  quarantine, telemetry packing.
+
+A v5e trace names an op by its instruction (``%fusion.12 = ...``) and, unless
+the HLO proto is recorded with it, carries no metadata; the compiled
+program's text carries both. ``instruction_scopes`` reads the text, so
+instruction name is the join key: scope from the text, seconds from the
+trace (``benchmark/harness/scopes.py``). With the HLO proto recorded, xprof
+shows the same names on its own.
+"""
+
+from __future__ import annotations
+
+import re
+from collections import Counter
+from typing import Dict, Optional
+
+import jax
+
+__all__ = ["ROLLOUT_SCOPES", "SCOPE_PREFIX", "scope", "instruction_scopes"]
+
+ROLLOUT_SCOPES = (
+    "policy_forward",
+    "env_step",
+    "env_reset",
+    "obs_norm",
+    "contract",
+    "rollout_edges",
+)
+SCOPE_PREFIX = "evotorch_tpu."
+
+
+def scope(name: str):
+    """``jax.named_scope("evotorch_tpu.<name>")`` for a declared name."""
+    if name not in ROLLOUT_SCOPES:
+        raise ValueError(f"{name!r} is not one of ROLLOUT_SCOPES {ROLLOUT_SCOPES}")
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+# `  ROOT %fusion.3 = f32[8]{0} fusion(...), ..., metadata={op_name="..." ...}`
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+) = (.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[^\s(]+)\s*\(.*\{\s*$")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z\-]*)\(")  # after the shape, whose `T(8,128)` is upper case
+_CALLS = re.compile(r"\bcalls=(%[\w.\-]+)")
+_NAME = re.compile(r"%[\w.\-]+")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# a path component, also where a transformation wraps it: `vmap(evotorch_tpu.x/...)`
+_COMPONENT = re.compile(r"(?:^|[/(])" + re.escape(SCOPE_PREFIX) + r"(\w+)(?=[/)]|$)")
+# control flow and tuple plumbing compute nothing of their own: they neither
+# take a neighbour's scope nor hand one on (a `while` is not its operands' work)
+_PLUMBING = frozenset(
+    ("while", "conditional", "call", "tuple", "get-tuple-element", "parameter", "constant")
+)
+
+
+def _named_scope(line: str) -> Optional[str]:
+    op_name = _OP_NAME.search(line)
+    if op_name is not None:
+        for component in _COMPONENT.finditer(op_name.group(1)):
+            if component.group(1) in ROLLOUT_SCOPES:
+                return component.group(1)
+    return None
+
+
+def _most_named(scopes, names) -> Optional[str]:
+    named = Counter(scopes[name] for name in names if scopes[name] is not None)
+    return named.most_common(1)[0][0] if named else None
+
+
+def instruction_scopes(hlo_text: str, *, inherit: bool = True) -> Dict[str, Optional[str]]:
+    """``{instruction name: scope or None}`` for every ``%name = ...`` line of
+    every computation in ``compiled.as_text()``. The scope is the OUTERMOST
+    component of the instruction's ``op_name`` path that is
+    ``evotorch_tpu.<member of ROLLOUT_SCOPES>``; an instruction without
+    metadata, or with no such component, has none of its own.
+
+    ``inherit`` (default): the compiler makes instructions of its own, without
+    metadata: the root of a fusion (a convert, a copy, a bitcast), the async
+    copies that prefetch an operand into fast memory, relayout copies, the
+    ``dynamic-update-slice`` fusions it rewrites a concatenate into. On a v5e
+    they are a seventh of the flagship rollout's device time. Such an
+    instruction works for its neighbours, so it takes, in this order, the
+    scope that most of the instructions it ``calls`` (fuses) name, else most
+    of its users, else most of its operands, inside its computation and
+    repeated until nothing changes; control flow and tuple plumbing stay out
+    of it. ``inherit=False`` reads the metadata alone."""
+    scopes: Dict[str, Optional[str]] = {}
+    computations: Dict[str, dict] = {}  # computation -> {instruction: (opcode, names in its line)}
+    members = None
+    for line in hlo_text.splitlines():
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None:
+            header = _COMPUTATION.match(line)
+            if header is not None:
+                members = computations.setdefault(header.group(1), {})
+            continue
+        name, rest = instruction.groups()
+        scopes[name] = _named_scope(rest)
+        if members is not None:
+            opcode = _OPCODE.search(rest)
+            members[name] = (opcode.group(1) if opcode else None, rest)
+    if inherit:
+        for members in computations.values():
+            _inherit(scopes, members, computations)
+    return scopes
+
+
+def _inherit(scopes, members, computations) -> None:
+    """Give the scopeless instructions of one computation their neighbours'
+    scope (see ``instruction_scopes``)."""
+    pending = [n for n, (opcode, _) in members.items() if scopes[n] is None and opcode not in _PLUMBING]
+    if not pending:
+        return
+    for name in pending:  # a fusion, an async pair: from what it wraps
+        called = _CALLS.search(members[name][1])
+        if called is not None and called.group(1) in computations:
+            scopes[name] = _most_named(scopes, computations[called.group(1)])
+    operands = {
+        name: [
+            other
+            for other in _NAME.findall(rest)
+            if other != name and other in members and members[other][0] not in _PLUMBING
+        ]
+        for name, (opcode, rest) in members.items()
+        if opcode not in _PLUMBING
+    }
+    users: Dict[str, list] = {name: [] for name in operands}
+    for name, reads in operands.items():
+        for other in reads:
+            users[other].append(name)
+    pending = [name for name in pending if scopes[name] is None]
+    while pending:
+        found = {}
+        for name in pending:
+            scope = _most_named(scopes, users[name]) or _most_named(scopes, operands[name])
+            if scope is not None:
+                found[name] = scope
+        if not found:
+            break
+        scopes.update(found)
+        pending = [name for name in pending if name not in found]

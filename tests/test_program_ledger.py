@@ -60,22 +60,13 @@ def gate_capture():
     suite-wide): an executable DESERIALIZED from the cache reports a
     constant +1408 bytes of peak memory on this backend, which would skew
     the fingerprints the gate bands against ledger_baseline.json — the
-    instrument must measure the program, not the cache's framing. The dir
-    knob alone is NOT enough: the cache singleton initializes once and
-    keeps the directory it saw first, so the bypass must flip the enable
-    flag and reset the singleton (restored afterwards, so the rest of the
-    suite keeps its warm cache)."""
-    from jax._src import compilation_cache as _compilation_cache
+    instrument must measure the program, not the cache's framing (restored
+    afterwards, so the rest of the suite keeps its warm cache)."""
+    from evotorch_tpu.observability.compilecache import past_persistent_cache
 
-    enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    _compilation_cache.reset_cache()
-    try:
+    with past_persistent_cache():
         led = ProgramLedger()
         records, errors = capture_inventory(GateConfig(), led, strict=True)
-    finally:
-        jax.config.update("jax_enable_compilation_cache", enabled)
-        _compilation_cache.reset_cache()
     assert errors == {}
     return records
 
