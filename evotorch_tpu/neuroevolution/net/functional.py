@@ -62,12 +62,20 @@ class FlatParamsPolicy:
         return flat
 
     def unravel(self, flat_params: jnp.ndarray) -> Any:
+        """The module's parameter tree of one flat vector. The population
+        engines (``net/vecrl.py``) ``vmap`` this over a dense ``(N, L)``
+        population ONCE per program, at the rollout's edge, and step
+        ``module.apply`` on the resulting tree: cutting the flat matrix into
+        per-layer blocks is a physical copy on the TPU, not a view."""
         return self._unravel(flat_params)
 
     def initial_state(self):
         return self.module.initial_state()
 
     def __call__(self, flat_params, x, state=None) -> Tuple[jnp.ndarray, Any]:
+        """The single-solution form: unravel, then apply. Fine where it runs
+        once per call (``Policy``, ``to_policy``, the host path); inside a
+        stepping loop use ``unravel`` outside it and ``module.apply`` inside."""
         params = self._unravel(flat_params)
         return self.module.apply(params, x, state)
 

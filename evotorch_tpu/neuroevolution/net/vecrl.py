@@ -118,34 +118,52 @@ def _params_cast(params_batch, dtype):
 
 
 def _params_take(params_batch, idx):
+    """Rows ``idx`` of a population: of a factored batch, of the dense matrix,
+    or of every leaf of its unravelled tree."""
     if is_factored(params_batch):
         return params_batch.take(idx)
-    return params_batch[idx]
+    return jax.tree_util.tree_map(lambda x: x[idx], params_batch)
 
 
 @_in_scope("rollout_edges")
 def _forward_ctx(policy, params_batch, trunk_block: int = 0):
-    """Precompute the loop-invariant forward context (per-layer center/basis
-    or trunk/factor trees for the factored paths); call inside jit, OUTSIDE
-    stepping loops. ``trunk_block`` is the static lane-block size of the
-    trunk-delta forward (0 = single block; ignored by the other forms)."""
+    """The loop-invariant forward context of a population: what a control
+    step's forward reads. Call inside jit, OUTSIDE stepping loops.
+
+    - dense ``(N, L)`` matrix: the unravelled population, a per-layer tree
+      with a leading lane axis (leaves ``(N, out, in)``, ``(N, out)``, ...).
+      Cutting the flat matrix into per-layer blocks is a physical copy on
+      the TPU (the tiled minor dimensions change), so it happens here, once
+      per program, and no loop body touches the flat matrix;
+    - low-rank / trunk-delta: the per-layer center/basis or trunk/factor
+      trees. ``trunk_block`` is the static lane-block size of the trunk-delta
+      forward (0 = single block; ignored by the other forms)."""
     if isinstance(params_batch, TrunkDeltaParamsBatch):
         return prepare_trunk_delta(policy, params_batch, trunk_block=trunk_block)
     if isinstance(params_batch, LowRankParamsBatch):
         return prepare_lowrank(policy, params_batch)
-    return None
+    return jax.vmap(policy.unravel)(params_batch)
 
 
 def _batched_forward(policy, params_batch, ctx, obs, states):
-    """Whole-population policy forward for any representation."""
+    """Whole-population policy forward for any representation: reads
+    ``ctx`` (``_forward_ctx`` of ``params_batch``); the dense form reads
+    nothing else, so the flat matrix is dead once its context is built."""
     if isinstance(params_batch, TrunkDeltaParamsBatch):
         return trunk_delta_forward(policy, params_batch, ctx, obs, states)
     if isinstance(params_batch, LowRankParamsBatch):
         return lowrank_forward(policy, params_batch, ctx, obs, states)
+    return _dense_tree_forward(policy, ctx, obs, states)
+
+
+def _dense_tree_forward(policy, tree, obs, states):
+    """The module applied lane by lane to an unravelled population (or to
+    the refill engine's width-``W`` slice of one)."""
+    apply = policy.module.apply
     if states is None:
-        out, _ = jax.vmap(lambda p, o: policy(p, o))(params_batch, obs)
+        out, _ = jax.vmap(lambda p, o: apply(p, o, None))(tree, obs)
         return out, None
-    return jax.vmap(policy)(params_batch, obs, states)
+    return jax.vmap(apply)(tree, obs, states)
 
 
 def _forward_in_compute_dtype(forward, policy_in, states, compute_dtype):
@@ -1233,7 +1251,11 @@ class RefillCarry(NamedTuple):
     env_states: Any
     obs: jnp.ndarray
     policy_states: Any
-    lane_params: Any  # (W, L) dense rows or (W, k) low-rank coefficients
+    # what the forward reads per lane: the width-W rows of the unravelled
+    # population (a per-layer tree, leaves (W, out, in), (W, out), ...) for a
+    # dense population, or (W, k) coefficients for the factored forms. Never
+    # flat (W, L) rows: those would be cut into layers again in every step
+    lane_params: Any
     lane_sol: jnp.ndarray  # (W,) local solution index each lane is running
     lane_score: jnp.ndarray  # (W,) return of the lane's CURRENT episode
     steps_in_episode: jnp.ndarray
@@ -1273,12 +1295,18 @@ def _default_refill_width(total_items: int) -> int:
 def _refill_forward_setup(policy, params_batch, trunk_block: int = 0):
     """Per-lane parameter storage + forward for the refill engine.
 
-    The loop carries only the PER-LANE slice of the population (dense rows,
-    or factored coefficients — the shared center/basis/factors stay
-    loop-invariant closures), so a refill gathers O(W x row), never the
-    whole population. Returns ``(store, forward)``: ``store`` is the
-    (N, row) gather source and ``forward(lane_params, obs, states)`` runs
-    the policy at width W."""
+    The loop carries only the PER-LANE slice of the population, so a refill
+    gathers O(W x row), never the whole population. Returns ``(store,
+    forward)``: ``store`` is the gather source, a pytree whose leaves have a
+    leading axis N, and ``forward(lane_params, obs, states)`` runs the
+    policy at width W on ``lane_params``, the same pytree at width W
+    (``_params_take(store, sol)``).
+
+    - dense population: ``store`` is ``_forward_ctx``'s unravelled tree
+      (leaves ``(N, out, in)``, ``(N, out)``, ...), built once; the loop
+      gathers and carries per-layer blocks and never sees a flat row;
+    - factored forms: ``store`` is the ``(N, k)`` coefficients; the shared
+      center/basis/factors stay loop-invariant closures."""
     if isinstance(params_batch, TrunkDeltaParamsBatch):
         from .lowrank import (
             _apply_trunk_delta,
@@ -1326,7 +1354,9 @@ def _refill_forward_setup(policy, params_batch, trunk_block: int = 0):
 
             def forward(lane_coeffs, obs, states):
                 dense = params_batch.materialize_rows(lane_coeffs)
-                return _batched_forward(policy, dense, None, obs, states)
+                return _dense_tree_forward(
+                    policy, jax.vmap(policy.unravel)(dense), obs, states
+                )
 
         return params_batch.coeffs, forward
     if isinstance(params_batch, LowRankParamsBatch):
@@ -1362,14 +1392,13 @@ def _refill_forward_setup(policy, params_batch, trunk_block: int = 0):
 
             def forward(lane_coeffs, obs, states):
                 dense = params_batch.materialize_rows(lane_coeffs)
-                return _batched_forward(policy, dense, None, obs, states)
+                return _dense_tree_forward(
+                    policy, jax.vmap(policy.unravel)(dense), obs, states
+                )
 
         return params_batch.coeffs, forward
 
-    def forward(lane_params, obs, states):
-        return _batched_forward(policy, lane_params, None, obs, states)
-
-    return params_batch, forward
+    return _forward_ctx(policy, params_batch), partial(_dense_tree_forward, policy)
 
 
 def _run_refill(
@@ -1520,7 +1549,7 @@ def _run_refill(
             env_states=env_states0,
             obs=obs0,
             policy_states=policy_states0,
-            lane_params=store[sol0],
+            lane_params=_params_take(store, sol0),
             lane_sol=sol0,
             lane_score=jnp.zeros(width),
             steps_in_episode=jnp.zeros(width, dtype=jnp.int32),
@@ -1637,7 +1666,9 @@ def _run_refill(
                 obs_cur = _lane_select(take, fresh_obs, obs_cur)
             with scope("contract"):
                 lane_sol = jnp.where(take, sol, lane_sol)
-                lane_params = _lane_select(take, store[sol], lane_params)
+                lane_params = jax.tree_util.tree_map(
+                    partial(_lane_select, take), _params_take(store, sol), lane_params
+                )
                 keys = jnp.where(take, chain, keys)
             return env_states, obs_cur, lane_params, lane_sol, keys
 

@@ -12,8 +12,9 @@ is handed back WITHOUT it; clear the cache after touching scopes).
 
 ``ROLLOUT_SCOPES`` is the one place the names are declared:
 
-- ``policy_forward``: the population-wide forward (dense ``vmap(policy)``,
-  low-rank, trunk-delta), with the casts into and out of the compute dtype;
+- ``policy_forward``: the population-wide forward (dense
+  ``vmap(module.apply)`` over the unravelled population, low-rank,
+  trunk-delta), with the casts into and out of the compute dtype;
 - ``env_step``: the env substep and the mapping of the policy's output to
   an action;
 - ``env_reset``: the fresh reset inside the loop and the per-lane select
@@ -24,7 +25,8 @@ is handed back WITHOUT it; clear the cache after touching scopes).
   adjustments, scores, episode and step counters, activity masks, the
   refill queue;
 - ``rollout_edges``: what runs once per program, outside the loop: the first
-  reset and statistics, parameter casts, forward contexts, score averaging,
+  reset and statistics, parameter casts, forward contexts (the unravel of a
+  dense population into per-layer blocks among them), score averaging,
   quarantine, telemetry packing.
 
 A v5e trace names an op by its instruction (``%fusion.12 = ...``) and, unless

@@ -1,3 +1,5 @@
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1021,3 +1023,106 @@ def test_sharded_compacting_obs_norm_step_sync():
     # exact: every shard absorbed the global cohort every step
     assert float(r_sync.stats.count) == float(r_ref.stats.count)
     assert int(r_sync.total_episodes) == 32
+
+
+# -- the dense population is unravelled at the rollout's edge ----------------
+
+
+def _loop_reads(jaxpr, shapes, in_loop=False):
+    """Equations inside a ``while``/``scan`` body (at any nesting depth) that
+    read an array whose shape is in ``shapes``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if in_loop and any(getattr(v.aval, "shape", None) in shapes for v in eqn.invars):
+            found.append(eqn)
+        inner = in_loop or eqn.primitive.name in ("while", "scan")
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
+                if hasattr(sub, "eqns"):
+                    found.extend(_loop_reads(sub, shapes, inner))
+    return found
+
+
+def _per_step_flat_forms(monkeypatch):
+    """The plain reference, patched into the engines: the loop carries the
+    flat rows and every control step runs ``jax.vmap(policy)(flat, obs,
+    state)``."""
+    from evotorch_tpu.neuroevolution.net import vecrl
+
+    def per_step(policy, flat, obs, states):
+        if states is None:
+            out, _ = jax.vmap(lambda p, o: policy(p, o))(flat, obs)
+            return out, None
+        return jax.vmap(policy)(flat, obs, states)
+
+    def batched_forward(policy, flat, ctx, obs, states):
+        return per_step(policy, flat, obs, states)
+
+    def refill_forward_setup(policy, flat, trunk_block=0):
+        return flat, partial(per_step, policy)
+
+    monkeypatch.setattr(vecrl, "_forward_ctx", lambda policy, flat, trunk_block=0: None)
+    monkeypatch.setattr(vecrl, "_batched_forward", batched_forward)
+    monkeypatch.setattr(vecrl, "_refill_forward_setup", refill_forward_setup)
+
+
+@pytest.mark.parametrize("net_kind", ["tanh_mlp", "lstm"])
+@pytest.mark.parametrize("eval_mode", ["budget", "episodes", "episodes_refill"])
+def test_dense_population_is_unravelled_outside_the_loop(eval_mode, net_kind, monkeypatch):
+    env = CartPole(continuous_actions=True)
+    if net_kind == "tanh_mlp":
+        net = Linear(env.observation_size, 8) >> Tanh() >> Linear(8, env.action_size)
+    else:
+        net = LSTM(env.observation_size, 8) >> Linear(8, env.action_size)
+    policy = FlatParamsPolicy(net)
+    n, width = 10, 4
+    params = 0.5 * jax.vmap(policy.init_parameters)(jax.random.split(jax.random.key(0), n))
+    stats = RunningNorm(env.observation_size).stats
+    kwargs = dict(
+        num_episodes=2, episode_length=12, eval_mode=eval_mode, observation_normalization=True
+    )
+    if eval_mode == "episodes_refill":
+        kwargs["refill_width"] = width
+    flat_shapes = {(n, policy.parameter_count), (width, policy.parameter_count)}
+
+    def make_rollout():  # not jitted (the loops hand back concrete carries), traced afresh
+        return lambda params, key, stats: run_vectorized_rollout.__wrapped__(
+            env, policy, params, key, stats, **kwargs
+        )
+
+    finals = []
+    for name in ("while_loop", "fori_loop"):
+
+        def spy(*args, _loop=getattr(jax.lax, name)):
+            finals.append(_loop(*args))
+            return finals[-1]
+
+        monkeypatch.setattr(jax.lax, name, spy)
+
+    key = jax.random.key(7)
+    jaxpr = jax.make_jaxpr(make_rollout())(params, key, stats).jaxpr
+    assert any(e.primitive.name in ("while", "scan") for e in jaxpr.eqns)
+    assert _loop_reads(jaxpr, flat_shapes) == []
+    finals.clear()
+    got = make_rollout()(params, key, stats)
+    (got_final,) = finals
+
+    _per_step_flat_forms(monkeypatch)
+    assert _loop_reads(jax.make_jaxpr(make_rollout())(params, key, stats).jaxpr, flat_shapes)
+    finals.clear()
+    want = make_rollout()(params, key, stats)
+    (want_final,) = finals
+
+    assert int(got.total_steps) > 0 and np.isfinite(np.asarray(got.scores)).all()
+    for name in ("scores", "stats", "total_steps", "total_episodes", "telemetry"):
+        for a, b in zip(
+            jax.tree_util.tree_leaves(getattr(got, name)),
+            jax.tree_util.tree_leaves(getattr(want, name)),
+            strict=True,
+        ):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    states = jax.tree_util.tree_leaves(got_final.policy_states)
+    assert bool(states) == (net_kind == "lstm")
+    for a, b in zip(states, jax.tree_util.tree_leaves(want_final.policy_states), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
