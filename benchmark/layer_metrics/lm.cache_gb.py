@@ -1,0 +1,16 @@
+"""Bytes of the attention cache the population carries as policy state (keys
+and values of every held layer and lane, at the compute dtype)."""
+
+LAYER = "lm cache"
+UNIT = "GB"
+BETTER = "lower"
+SOURCE = "program_counter"
+MOVES = "env_steps_per_s"
+
+
+def applies(workload):
+    return LAYER in workload["layers"]
+
+
+def measure(run):
+    return run.session.cache_bytes / 1e9

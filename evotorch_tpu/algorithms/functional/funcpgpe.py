@@ -170,8 +170,8 @@ def pgpe_ask_trunk_delta(key, state: PGPEState, *, popsize: int, rank: int, poli
     sampler needs the policy's parameter tree. Returns a
     ``TrunkDeltaParamsBatch`` the vectorized rollout engine evaluates with
     ONE shared-trunk GEMM per layer; the PGPE update is
-    :func:`pgpe_tell_trunk_delta` (same factored gradients as low-rank mode,
-    through the materialized effective basis)."""
+    :func:`pgpe_tell_trunk_delta` (the factored gradients of low-rank mode,
+    leaf by leaf from the factors: the batch holds no basis)."""
     import jax
 
     if not state.symmetric:
@@ -185,16 +185,9 @@ def pgpe_ask_trunk_delta(key, state: PGPEState, *, popsize: int, rank: int, poli
     _, opt_ask, _ = get_functional_optimizer(state.optimizer)
     center = opt_ask(state.optimizer_state)
     key_factors, key_coeffs = jax.random.split(key)
-    factors, basis = sample_trunk_delta_factors(
-        key_factors, policy, state.stdev, int(rank)
-    )
+    factors = sample_trunk_delta_factors(key_factors, policy, state.stdev, int(rank))
     return SymmetricSeparableGaussian._sample_trunk_delta(
-        key_coeffs,
-        {"mu": center, "sigma": state.stdev},
-        int(popsize),
-        int(rank),
-        factors,
-        basis,
+        key_coeffs, {"mu": center, "sigma": state.stdev}, int(popsize), int(rank), factors
     )
 
 
@@ -223,10 +216,10 @@ def pgpe_ask_lowrank(key, state: PGPEState, *, popsize: int, rank: int):
 
 
 def pgpe_tell_lowrank(state: PGPEState, params, evals) -> PGPEState:
-    """The PGPE update from a factored-evaluated population (low-rank OR
-    trunk-delta — the gradients read only the shared effective basis and the
-    per-lane coefficients): identical math to ``pgpe_tell`` on the
-    materialized population, computed in O(L * rank) without building it."""
+    """The PGPE update from a low-rank-evaluated population (the gradients
+    read only the shared effective basis and the per-lane coefficients):
+    identical math to ``pgpe_tell`` on the materialized population, computed
+    in O(L * rank) without building it."""
     from ...tools.ranking import rank as rank_fn
 
     if not state.symmetric:
@@ -257,6 +250,46 @@ def pgpe_tell_lowrank(state: PGPEState, params, evals) -> PGPEState:
     return replace(state, optimizer_state=new_optimizer_state, stdev=new_stdev)
 
 
-#: the trunk-delta batch carries its materialized effective basis, so the
-#: factored update applies verbatim
-pgpe_tell_trunk_delta = pgpe_tell_lowrank
+def pgpe_tell_trunk_delta(state: PGPEState, params, evals) -> PGPEState:
+    """The PGPE update from a trunk-delta-evaluated population: the same
+    math, leaf by leaf from the batch's factors (it holds no basis). The
+    center's part runs to its end before the stdev's starts and the stdev is
+    rewritten leaf by leaf, so under ``jax.jit(..., donate_argnums=0)`` the
+    update needs one vector of the parameters' length beside the state (the
+    OO ``PGPE(lowrank_rank=("trunk_delta", k))`` runs the same two gradient
+    functions in the same order)."""
+    import jax
+
+    from ...tools.ranking import rank as rank_fn
+
+    if not state.symmetric:
+        raise ValueError("pgpe_tell_trunk_delta requires symmetric=True (the PGPE default)")
+    _, opt_ask, opt_tell = get_functional_optimizer(state.optimizer)
+    weights = rank_fn(
+        jnp.asarray(evals), state.ranking_method, higher_is_better=state.maximize
+    )
+    dist = SymmetricSeparableGaussian
+    parameters = {
+        "mu": opt_ask(state.optimizer_state),
+        "sigma": state.stdev,
+        **_grad_divisors(True),
+    }
+    mu_grad = dist._trunk_delta_mu_gradient(parameters, params, weights, state.ranking_method)
+    new_optimizer_state = opt_tell(state.optimizer_state, follow_grad=mu_grad)
+    new_optimizer_state, stdev = jax.lax.optimization_barrier((new_optimizer_state, state.stdev))
+    rate = state.stdev_learning_rate[..., None]
+    target_stdev = dist._trunk_delta_sigma_gradient(
+        {**parameters, "sigma": stdev},
+        params,
+        weights,
+        state.ranking_method,
+        into=lambda leaf, grad_leaf: leaf + rate * grad_leaf,
+    )
+    new_stdev = modify_vector(
+        stdev,
+        target_stdev,
+        lb=state.stdev_min,
+        ub=state.stdev_max,
+        max_change=state.stdev_max_change,
+    )
+    return replace(state, optimizer_state=new_optimizer_state, stdev=new_stdev)

@@ -30,6 +30,7 @@ import numpy as np
 from ..core import Problem, SolutionBatch
 from ..observability.tracer import span
 from ..distributions import (
+    _split_params,
     Distribution,
     ExpGaussian,
     ExpSeparableGaussian,
@@ -71,7 +72,7 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         distributed: bool = False,
         popsize_weighted_grad_avg: Optional[bool] = None,
         ensure_even_popsize: bool = False,
-        lowrank_rank: Optional[int] = None,
+        lowrank_rank=None,
     ):
         problem.ensure_numeric()
         problem.ensure_unbounded()
@@ -98,6 +99,13 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
                 center_init, allow_scalar=False, about="center_init"
             )
 
+        # one argument names the factored population's form and its rank
+        lowrank_rank, trunk_delta_rank = _factored_form(lowrank_rank)
+        if trunk_delta_rank is not None:
+            # the trunk-delta update donates the center's buffer: the searcher
+            # owns a copy, never the caller's array
+            mu = jnp.array(mu, copy=True)
+
         stdev_init = to_stdev_init(
             solution_length=problem.solution_length, stdev_init=stdev_init, radius_init=radius_init
         )
@@ -110,10 +118,8 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
 
         # factored (low-rank) population mode: the MXU path for wide policies
         # (tools/lowrank.py; sampling + gradients on the distribution class)
-        self._lowrank_rank = None if lowrank_rank is None else int(lowrank_rank)
+        self._lowrank_rank = lowrank_rank
         if self._lowrank_rank is not None:
-            if self._lowrank_rank < 1:
-                raise ValueError(f"lowrank_rank must be >= 1, got {lowrank_rank}")
             if not hasattr(dist_cls, "_sample_lowrank"):
                 raise ValueError(
                     f"{dist_cls.__name__} has no factored sampler; "
@@ -146,6 +152,24 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
                 }
             )
 
+        # the shared-trunk form of the factored population (docs/policies.md):
+        # the factors are structured by the problem's policy and the batch
+        # holds no basis, so the update is one donated device program that
+        # writes center, stdev and optimizer state leaf by leaf
+        self._trunk_delta_rank = trunk_delta_rank
+        self._trunk_delta_tell = None
+        if self._trunk_delta_rank is not None:
+            if not hasattr(dist_cls, "_sample_trunk_delta") or distributed:
+                raise ValueError(
+                    "lowrank_rank=('trunk_delta', k) requires symmetric, non-distributed"
+                    " PGPE (SymmetricSeparableGaussian)"
+                )
+            if not hasattr(problem, "policy"):
+                raise ValueError(
+                    "lowrank_rank=('trunk_delta', k) needs a problem with a policy (the"
+                    " factors follow its parameter tree): a neuroevolution problem"
+                )
+
         self._popsize = int(popsize)
         self._popsize_max = None if popsize_max is None else int(popsize_max)
         self._num_interactions = None if num_interactions is None else int(num_interactions)
@@ -170,7 +194,16 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
             }
         )
 
-        ensure = problem.ensure_tensor_length_and_dtype
+        def ensure(value, about):
+            # the trunk-delta form clamps leaf by leaf with scalars: at the
+            # sizes it exists for, one more vector of the solution's length is
+            # memory the update lacks
+            if self._trunk_delta_rank is not None:
+                if np.ndim(value) != 0:
+                    raise ValueError(f"with lowrank_rank=('trunk_delta', k), {about} is a scalar")
+                return jnp.asarray(value, dtype=problem.dtype)
+            return problem.ensure_tensor_length_and_dtype(value, about=about)
+
         self._stdev_min = None if stdev_min is None else ensure(stdev_min, about="stdev_min")
         self._stdev_max = None if stdev_max is None else ensure(stdev_max, about="stdev_max")
         self._stdev_max_change = (
@@ -271,6 +304,17 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
 
     # -------------------------------------------------------- non-distributed
     def _sample_population(self, popsize: int, *, basis=None) -> SolutionBatch:
+        """``basis``: what later rounds of one generation share with its
+        first (the low-rank basis; the trunk-delta factors)."""
+        if self._trunk_delta_rank is not None:
+            samples = self._distribution.sample_trunk_delta(
+                popsize,
+                self._trunk_delta_rank,
+                self._problem.policy,
+                key=self._problem.next_rng_key(),
+                factors=basis,
+            )
+            return SolutionBatch(self._problem, values=samples)
         if self._lowrank_rank is not None:
             samples = self._distribution.sample_lowrank(
                 popsize,
@@ -304,8 +348,11 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         while True:
             with span("ask", "algo"):
                 batch = self._sample_population(self._popsize, basis=gen_basis)
-            if self._lowrank_rank is not None and gen_basis is None:
-                gen_basis = batch.values.basis
+            if gen_basis is None:
+                if self._lowrank_rank is not None:
+                    gen_basis = batch.values.basis
+                elif self._trunk_delta_rank is not None:
+                    gen_basis = batch.values.factors
             with span("eval", "algo", popsize=len(batch)):
                 problem.evaluate(batch)
             batches.append(batch)
@@ -396,6 +443,13 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         samples = pop.values
         fitnesses = pop.evals[:, self._obj_index]
         obj_sense = self._problem.senses[self._obj_index]
+        if self._trunk_delta_rank is not None:
+            with span("tell", "algo"), jax.profiler.TraceAnnotation("evotorch_tpu.update"):
+                self._update_trunk_delta(samples, fitnesses, obj_sense)
+            with jax.profiler.TraceAnnotation("evotorch_tpu.ask"):
+                self._fill_and_eval_pop()
+            self._mean_eval = jnp.nanmean(self._population.evals[:, self._obj_index])
+            return
         with span("tell", "algo"):
             with jax.profiler.TraceAnnotation("evotorch_tpu.grad"):
                 grads = self._distribution.compute_gradients(
@@ -449,6 +503,49 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
             self._update_distribution(avg)
 
     # --------------------------------------------------------------- updates
+    def _update_trunk_delta(self, samples, fitnesses, obj_sense: str):
+        """Gradient, optimizer step and stdev update of the trunk-delta form
+        as ONE device program with center, stdev and optimizer state donated
+        (``_make_trunk_delta_tell``): at a policy of hundreds of millions of
+        parameters those three are most of the device's memory and a second
+        copy of any does not fit. The evaluated population shares its
+        ``center`` with the distribution, so it is dropped first."""
+        if self._trunk_delta_tell is None:
+            if self._optimizer is not None and not hasattr(self._optimizer, "pure_ascent"):
+                raise TypeError(
+                    "the trunk-delta form needs an optimizer with a pure step;"
+                    f" {type(self._optimizer).__name__} has none"
+                )
+            self._trunk_delta_tell = _make_trunk_delta_tell(
+                type(self._distribution),
+                _split_params(self._distribution.parameters)[1],
+                self._optimizer,
+                center_learning_rate=self._center_learning_rate,
+                stdev_learning_rate=self._stdev_learning_rate,
+                ranking_method=self._ranking_method if self._ranking_method is not None else "raw",
+                higher_is_better=(obj_sense == "max"),
+                clamped=tuple(
+                    x is not None
+                    for x in (self._stdev_min, self._stdev_max, self._stdev_max_change)
+                ),
+            )
+        self._population = None
+        parameters = self._distribution.parameters
+        state = () if self._optimizer is None else self._optimizer.state()
+        mu, sigma, state, update_norm = self._trunk_delta_tell(
+            parameters["mu"],
+            parameters["sigma"],
+            state,
+            samples.coeffs,
+            samples.factors,
+            fitnesses,
+            (self._stdev_min, self._stdev_max, self._stdev_max_change),
+        )
+        if self._optimizer is not None:
+            self._optimizer.load_state(state)
+        self._center_update_norm_dev = update_norm
+        self._distribution = self._distribution.modified_copy(mu=mu, sigma=sigma)
+
     def _update_distribution(self, gradients: dict):
         """Distribution update + controlled sigma clamping
         (reference ``gaussian.py:369-419``)."""
@@ -480,9 +577,90 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         self._distribution = new_dist
 
 
+def _make_trunk_delta_tell(
+    dist_cls,
+    static_items,
+    optimizer,
+    *,
+    center_learning_rate: float,
+    stdev_learning_rate: float,
+    ranking_method: str,
+    higher_is_better: bool,
+    clamped: tuple,
+):
+    """The trunk-delta update as one jitted program that donates center,
+    stdev and optimizer state: ``tell(mu, sigma, optimizer_state, coeffs,
+    factors, fitnesses, clamps) -> (mu, sigma, optimizer_state, norm of the
+    center's step)``. The center's part runs to its end before the stdev's
+    starts (an optimization barrier), so the two gradients of the parameters'
+    length never live together; the stdev is rewritten leaf by leaf in
+    place. The functional ``pgpe_tell_trunk_delta`` orders its work the same
+    way."""
+    from ..tools.lowrank import TrunkDeltaParamsBatch
+    from ..tools.ranking import rank
+
+    def tell(mu, sigma, optimizer_state, coeffs, factors, fitnesses, clamps):
+        parameters = {"mu": mu, "sigma": sigma, **dict(static_items)}
+        samples = TrunkDeltaParamsBatch(center=mu, coeffs=coeffs, factors=factors)
+        weights = rank(fitnesses, ranking_method, higher_is_better=higher_is_better)
+        grad = dist_cls._trunk_delta_mu_gradient(parameters, samples, weights, ranking_method)
+        if optimizer is None:
+            step = jnp.asarray(center_learning_rate, grad.dtype) * grad
+        else:
+            step, optimizer_state = optimizer.pure_ascent(optimizer_state, grad)
+        update_norm = jnp.linalg.norm(step)
+        mu = mu + step
+        mu, optimizer_state, update_norm, sigma = jax.lax.optimization_barrier(
+            (mu, optimizer_state, update_norm, sigma)
+        )
+        rate = jnp.asarray(stdev_learning_rate, sigma.dtype)
+        lb, ub, max_change = clamps  # scalars: applied leaf by leaf, in place
+
+        def follow(leaf, grad_leaf):
+            target = leaf + rate * grad_leaf
+            if any(clamped):
+                target = modify_tensor(leaf, target, lb=lb, ub=ub, max_change=max_change)
+            return target
+
+        new_sigma = dist_cls._trunk_delta_sigma_gradient(
+            {**parameters, "sigma": sigma}, samples, weights, ranking_method, into=follow
+        )
+        return mu, new_sigma, optimizer_state, update_norm
+
+    return jax.jit(tell, donate_argnums=(0, 1, 2))
+
+
+def _factored_form(lowrank_rank):
+    """``lowrank_rank`` as ``(basis rank, trunk-delta rank)``, one of them
+    None at least: ``k`` is the factored population with a dense ``(L, k)``
+    basis (``tools/lowrank.py``), ``("trunk_delta", k)`` the shared-trunk form
+    whose factors follow the policy's parameter tree and which builds no
+    array of the solution's length times ``k``."""
+    if lowrank_rank is None:
+        return None, None
+    trunk_delta = isinstance(lowrank_rank, (tuple, list))
+    if trunk_delta:
+        form, rank = lowrank_rank
+        if form != "trunk_delta":
+            raise ValueError(f"lowrank_rank is a rank or ('trunk_delta', rank), got {lowrank_rank!r}")
+    else:
+        rank = lowrank_rank
+    if int(rank) < 1:
+        raise ValueError(f"lowrank_rank must be >= 1, got {rank}")
+    return (None, int(rank)) if trunk_delta else (int(rank), None)
+
+
 class PGPE(GaussianSearchAlgorithm):
     """PGPE with 0-centered ranking and ClipUp, the configuration of
-    Toklu et al. (2020) (reference ``gaussian.py:503-743``)."""
+    Toklu et al. (2020) (reference ``gaussian.py:503-743``).
+
+    ``lowrank_rank`` asks for a factored population and names its form:
+    ``k`` samples a dense ``(L, k)`` basis;
+    ``("trunk_delta", k)`` samples rank-``k`` factors that follow the
+    parameter tree of the problem's policy, builds nothing of ``L x k``, and
+    updates center, stdev and optimizer state in one donated program (what a
+    policy of hundreds of millions of parameters needs; stdev clamps are
+    scalars there)."""
 
     DISTRIBUTION_TYPE = NotImplemented  # set per instance (symmetric or not)
     DISTRIBUTION_PARAMS = NotImplemented
@@ -509,7 +687,7 @@ class PGPE(GaussianSearchAlgorithm):
         obj_index: Optional[int] = None,
         distributed: bool = False,
         popsize_weighted_grad_avg: Optional[bool] = None,
-        lowrank_rank: Optional[int] = None,
+        lowrank_rank=None,
     ):
         if lowrank_rank is not None and not symmetric:
             raise ValueError("lowrank_rank requires symmetric=True (the PGPE default)")

@@ -1157,16 +1157,25 @@ class SolutionBatch(Serializable, RecursivePrintable):
                     )
 
                 fv = first._values
+
+                def _shared(values):
+                    # what every lane of a factored batch shares besides the
+                    # center: the low-rank basis, or the trunk-delta factors
+                    return jax.tree_util.tree_leaves(
+                        values.basis if hasattr(values, "basis") else values.factors
+                    )
+
                 if not all(
                     _same_array(b._values.center, fv.center)
-                    and _same_array(b._values.basis, fv.basis)
+                    and all(map(_same_array, _shared(b._values), _shared(fv)))
                     for b in batches[1:]
                 ):
                     raise TypeError(
                         "Factored (low-rank) batches concatenate only when "
                         "they share one generation's center and basis (sample "
                         "the later rounds with sample_lowrank(..., "
-                        "basis=first_batch.values.basis)); batches drawn "
+                        "basis=first_batch.values.basis), or sample_trunk_delta("
+                        "..., factors=first_batch.values.factors)); batches drawn "
                         "against different bases have no shared factored "
                         "form — materialize first (batch.values.materialize())"
                     )

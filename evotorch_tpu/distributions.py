@@ -26,13 +26,14 @@ batched pure-functional API.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Type
 
 import jax
 import jax.numpy as jnp
 
 from .tools.cloning import Serializable
-from .tools.lowrank import LowRankParamsBatch, TrunkDeltaParamsBatch, is_factored
+from .tools.lowrank import LowRankParamsBatch, TrunkDeltaParamsBatch, is_factored, write_leaves
 from .tools.misc import to_jax_dtype
 from .tools.ranking import rank
 from .tools.recursiveprintable import RecursivePrintable
@@ -102,6 +103,25 @@ def _jitted_sample_lowrank_for(cls):
         fn = jax.jit(sample, static_argnames=("static_items", "num_solutions", "rank"))
         _JITTED_SAMPLE_LOWRANK_CACHE[cls] = fn
     return fn
+
+
+@functools.lru_cache(maxsize=64)
+def _jitted_sample_trunk_delta(cls, policy, num_solutions, rank, draw_factors):
+    # lazy import: distributions (L1) must not import neuroevolution (L3) at
+    # module scope
+    from .neuroevolution.net.lowrank import sample_trunk_delta_factors
+
+    @jax.jit
+    def sample(key, sigma, factors):
+        key_factors, key_coeffs = jax.random.split(key)
+        if draw_factors:
+            factors = sample_trunk_delta_factors(key_factors, policy, sigma, rank)
+        batch = cls._sample_trunk_delta(
+            key_coeffs, {"mu": sigma, "sigma": sigma}, num_solutions, rank, factors
+        )
+        return batch.coeffs, batch.factors
+
+    return sample
 
 
 def _jitted_grads_for(cls):
@@ -441,10 +461,10 @@ class SymmetricSeparableGaussian(SeparableGaussian):
 
     @classmethod
     def _compute_gradients(cls, parameters, samples, weights, ranking_used) -> dict:
+        if isinstance(samples, TrunkDeltaParamsBatch):
+            # the same algebra leaf by leaf from the factors: no (L, k) basis
+            return cls._compute_gradients_trunk_delta(parameters, samples, weights, ranking_used)
         if is_factored(samples):
-            # both factored forms expose the same center/basis/coeffs algebra
-            # (tools.lowrank.FACTORED_BATCH_TYPES); the gradient math below
-            # reads only .basis/.coeffs, so it covers trunk-delta batches too
             return cls._compute_gradients_lowrank(parameters, samples, weights, ranking_used)
         if "parenthood_ratio" in parameters:
             return cls._compute_gradients_via_parenthood_ratio(parameters, samples, weights)
@@ -565,24 +585,92 @@ class SymmetricSeparableGaussian(SeparableGaussian):
         )
         return {"mu": mu_grad, "sigma": sigma_grad}
 
+    # ------------------- the shared-trunk (trunk-delta) form -----------------
+    # theta_i = mu + basis z_i as above, with the basis STRUCTURED per
+    # parameter leaf (tools.lowrank.DeltaFactor: rank-1 blocks b_m a_m^T) and
+    # never built: at the sizes this form exists for (a 700M-parameter
+    # decoder) an (L, k) basis is several times the device's memory. The
+    # gradients are the factored formulas above, leaf by leaf:
+    #   mu_grad[leaf]    = B diag(c) A^T,           c = ((f+ - f-)/2) @ Z
+    #   rowquad[leaf]    = sum_mn M_mn (b_m*b_n)(a_m*a_n)^T,  M = Z^T diag((f+ + f-)/2) Z
+    # each leaf written into place (tools.lowrank.write_leaves), so one
+    # leaf's temporaries live at a time. The factor structure is the
+    # policy's (neuroevolution/net/lowrank.py:sample_trunk_delta_factors).
+
     @classmethod
-    def _sample_trunk_delta(
-        cls, key, parameters, num_solutions, rank, factors, basis
+    def _sample_trunk_delta(cls, key, parameters, num_solutions, rank, factors) -> TrunkDeltaParamsBatch:
+        """Draw a ``TrunkDeltaParamsBatch`` against one generation's
+        ``factors``: antithetic coefficient pairs in :meth:`_sample_lowrank`'s
+        layout ``[+z0, -z0, +z1, -z1, ...]``. Nothing of the parameters'
+        length is drawn or copied: ``center`` IS ``mu``."""
+        if num_solutions % 2 != 0:
+            raise ValueError(
+                f"Number of solutions sampled from {cls.__name__} must be even,"
+                f" got {num_solutions}"
+            )
+        mu = parameters["mu"]
+        z = jax.random.normal(key, (num_solutions // 2, int(rank)), dtype=mu.dtype)
+        coeffs = jnp.stack([z, -z], axis=1).reshape(num_solutions, int(rank))
+        return TrunkDeltaParamsBatch(center=mu, coeffs=coeffs, factors=factors)
+
+    def sample_trunk_delta(
+        self, num_solutions: int, rank: int, policy, *, key=None, factors=None
     ) -> TrunkDeltaParamsBatch:
-        """Draw a ``TrunkDeltaParamsBatch`` against an externally-structured
-        (factors, effective-basis) pair — the shared-trunk policy form
-        (``neuroevolution/net/lowrank.py``'s ``sample_trunk_delta_factors``
-        draws the pair; the structure is policy-shaped, so it cannot be
-        drawn here). The antithetic coefficient layout is exactly
-        :meth:`_sample_lowrank`'s, so gradients, concatenation and the
-        guardrail see an ordinary factored batch."""
-        lr = cls._sample_lowrank(key, parameters, num_solutions, rank, basis=basis)
-        return TrunkDeltaParamsBatch(
-            center=lr.center, basis=lr.basis, coeffs=lr.coeffs, factors=factors
+        """Stateful-API counterpart: draws the generation's factors from
+        ``policy``'s parameter structure (or reuses ``factors``: later rounds
+        of one generation stay concatenable) and fresh coefficients."""
+        if key is None:
+            key = self.next_rng_key()
+        coeffs, factors = _jitted_sample_trunk_delta(
+            type(self), policy, int(num_solutions), int(rank), factors is None
+        )(key, self._parameters["sigma"], factors)
+        # ``center`` is the mu object itself (see sample_lowrank), and never
+        # leaves the jitted sampler as an output: that would be a copy of it
+        return TrunkDeltaParamsBatch(center=self._parameters["mu"], coeffs=coeffs, factors=factors)
+
+    @staticmethod
+    def _trunk_delta_weights(samples: TrunkDeltaParamsBatch, weights, ranking_used):
+        """``c`` ``(k,)``, ``M`` ``(k, k)`` and ``sum((f+ + f-)/2)`` of the
+        formulas above."""
+        weights = _zero_center_weights(weights, ranking_used)
+        z = samples.coeffs[0::2]
+        fdplus, fdminus = weights[0::2], weights[1::2]
+        w_s = (fdplus + fdminus) / 2
+        return ((fdplus - fdminus) / 2) @ z, z.T @ (w_s[:, None] * z), jnp.sum(w_s), weights
+
+    @classmethod
+    def _trunk_delta_mu_gradient(cls, parameters, samples, weights, ranking_used):
+        c, _, _, weights = cls._trunk_delta_weights(samples, weights, ranking_used)
+        grad = write_leaves(
+            jnp.zeros_like(parameters["sigma"]),
+            samples.factors,
+            lambda factor, _: factor.delta(c[None])[0],
         )
+        return _divide_grad(parameters, "mu", grad, weights)
 
+    @classmethod
+    def _trunk_delta_sigma_gradient(cls, parameters, samples, weights, ranking_used, into=None):
+        """The sigma gradient; with ``into`` (a function ``(sigma_leaf,
+        grad_leaf) -> new leaf``) the result of applying it leaf by leaf to
+        ``sigma`` instead, written in place: the update without a second
+        vector of sigma's length."""
+        _, m, total, weights = cls._trunk_delta_weights(samples, weights, ranking_used)
+        scale = _divide_grad(parameters, "sigma", jnp.ones((), m.dtype), weights)
 
+        def leaf(factor, sigma_leaf):
+            grad = scale * (factor.quadratic(m) - total * sigma_leaf**2) / sigma_leaf
+            return grad if into is None else into(sigma_leaf, grad)
 
+        return write_leaves(parameters["sigma"], samples.factors, leaf)
+
+    @classmethod
+    def _compute_gradients_trunk_delta(cls, parameters, samples, weights, ranking_used) -> dict:
+        """Equal to ``_compute_gradients_lowrank`` on the materialised basis
+        (tests/test_trunk_delta.py keeps that algebra as a helper)."""
+        return {
+            "mu": cls._trunk_delta_mu_gradient(parameters, samples, weights, ranking_used),
+            "sigma": cls._trunk_delta_sigma_gradient(parameters, samples, weights, ranking_used),
+        }
 
 
 class ExpSeparableGaussian(SeparableGaussian):

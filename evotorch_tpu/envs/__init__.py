@@ -27,6 +27,7 @@ from .ant import Ant
 from .humanoid import Humanoid
 from .walker2d import Walker2D
 from .halfcheetah import HalfCheetah
+from .tokens import TokenCopyEnv
 from .registry import make_env, register_env
 
 __all__ = [
@@ -43,6 +44,7 @@ __all__ = [
     "Ant",
     "Walker2D",
     "HalfCheetah",
+    "TokenCopyEnv",
     "make_env",
     "register_env",
 ]

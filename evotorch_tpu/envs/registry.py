@@ -104,5 +104,9 @@ def _register_defaults():
     register_env("halfcheetah", HalfCheetah)
     register_env("half_cheetah", HalfCheetah)
 
+    from .tokens import TokenCopyEnv
+
+    register_env("token_copy", TokenCopyEnv)
+
 
 _register_defaults()

@@ -1,0 +1,699 @@
+"""A sparse-expert decoder as an evolvable policy, decoded stepwise.
+
+The modules of the ``afmoe`` family (Trinity): token embedding, RMSNorm,
+gated grouped-query attention with a per-lane cache as the policy's
+recurrent state, SwiGLU, and a sigmoid-routed expert layer that is told which
+experts it holds. They follow the ``Module`` protocol of ``layers.py``
+(``init`` / ``initial_state`` / ``apply(params, x, state)``), so a decoder is
+a policy like any other: the observation is one token id, the output the
+logits over the held vocabulary, the state the attention cache.
+
+Two forwards per module, one set of equations (``_forward`` of each class
+takes the two accessors that differ):
+
+- ``apply(params, x, state)``: one lane with its own weights: the dense form
+  (``vmap`` over a population), for tests and for the comparison with the
+  plain reference (``benchmark/reference/afmoe_decoder.py``);
+- ``trunk_delta_apply(center, factors, z, x, state)``: every lane at once in
+  the shared-trunk form (``net/lowrank.py``): each projection is ``x @ W_c^T
+  + ((x @ A) * z) @ B^T`` over all lanes, the held experts are ONE grouped
+  product over the (lane, expert) pairs that hit them, attention reads the
+  lanes' caches. ``net/lowrank.py:_apply_trunk_delta`` dispatches to it.
+
+**Shares of a deployment.** ``experts_held`` (a range of expert ids) and
+``vocab_held`` (rows of embedding and head) say which part of the published
+model this process holds. The expert layer routes over ALL ``num_experts``,
+normalises over the selected ``num_experts_per_tok``, and adds only its held
+experts' terms and the shared expert: what absent experts would add is left
+out, and that partial result goes on. Nothing stands in for absent chips.
+
+**The cache.** ``sliding_attention`` layers hold a ring of
+``min(sliding_window, max_positions)`` slots, ``full_attention`` layers
+``max_positions`` slots, laid out ``(kv_heads, slots, head_dim)`` per lane in
+the compute dtype. A lane's state holds its position ``t`` (reset with the
+lane) and a write pointer ``step`` that counts the steps since the state was
+made and is NOT reset: every lane of a population steps once per control
+step, so ``step`` is one number for all of them and the population-wide
+forward writes the new key and value of every lane with one
+``dynamic_update_slice`` at slot ``step mod slots`` (a per-lane slot would be
+a 512-row scatter per layer per step). A slot's age is ``(step - slot) mod
+slots`` and a lane reads the slots no older than its own ``t``: its own
+episode, at most ``slots`` back, whatever the other lanes did. RoPE is
+applied to a key with its lane's position when it is written.
+``reset_state`` zeroes the cache rows and ``t`` of the lanes that ended an
+episode, lane by lane, and leaves ``step``.
+
+**What a lane consumed.** The decoder's state also keeps, per lane, the token
+id and the lane's position ``t`` of each of the last ``max_positions`` steps
+(``seen``; written like the cache, never reset): the generated text of an
+evaluation is its observations, so ``state_report`` hands back what every
+lane read and wrote, and ``stepwise_logits`` replays such a record.
+
+No reference counterpart: the reference evaluates MLP and small recurrent
+policies only.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ...observability.scopes import scope
+from .layers import Module
+
+__all__ = [
+    "Embedding",
+    "RMSNorm",
+    "SwiGLU",
+    "GatedAttention",
+    "SparseExperts",
+    "DecoderLayer",
+    "AfmoeDecoder",
+    "stepwise_logits",
+]
+
+F32 = jnp.float32
+INIT_STD = 0.02  # the family's ``initializer_range``
+
+
+def _normal(key, shape, std=INIT_STD):
+    return std * jax.random.normal(key, shape, F32)
+
+
+def rms_norm(x, weight, eps):
+    """``weight * x / sqrt(mean(x^2) + eps)`` over the last axis, computed in
+    float32 and returned in ``x``'s dtype."""
+    xf = x.astype(F32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * weight.astype(F32)).astype(x.dtype)
+
+
+def rope(x, positions, theta):
+    """Rotary embedding over all of the last axis (the ``rotate_half``
+    convention: the two halves of a head are the pairs). ``positions``
+    broadcasts against ``x``'s leading axes."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=F32) / half)
+    angle = jnp.asarray(positions, F32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(F32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+# -- the two accessors ---------------------------------------------------------
+# A module's equations read its parameters through ``mm(name, x)`` (x times
+# the transposed ``(out, in)`` weight) and ``vec(name)`` (a 1-D parameter,
+# broadcast against the lanes). Dense: one lane, its own weights, a lane axis
+# of one. Trunk-delta: all lanes, shared trunk plus per-lane rank-k delta.
+
+
+class _Dense:
+    def __init__(self, params):
+        self.p = params
+        self.z = None
+
+    def sub(self, name):
+        return _Dense(self.p[name])
+
+    def mm(self, name, x, precision=None):
+        return jnp.matmul(x, self.p[name].T.astype(x.dtype), precision=precision)
+
+    def vec(self, name):
+        return self.p[name][None]
+
+    def rows(self, name, ids):
+        return self.p[name][ids]
+
+
+class _Trunk:
+    def __init__(self, center, factors, z):
+        self.p, self.f, self.z = center, factors, z
+
+    def sub(self, name):
+        return _Trunk(self.p[name], self.f[name], self.z)
+
+    def mm(self, name, x, precision=None):
+        w, f = self.p[name], self.f[name]
+        z = self.z.astype(x.dtype)
+        a, b = f.a.astype(x.dtype), f.b.astype(x.dtype)
+        trunk = jnp.matmul(x, w.T.astype(x.dtype), precision=precision)
+        thin = jnp.matmul(x, a, precision=precision) * z
+        return trunk + jnp.matmul(thin, b.T, precision=precision)
+
+    def vec(self, name):
+        return self.p[name] + self.z @ self.f[name].b.T
+
+    def rows(self, name, ids):
+        f = self.f[name]
+        return self.p[name][ids] + (f.b[ids] * self.z) @ f.a.T
+
+
+def _lane_form(apply_lanes, params, x, state):
+    """The one-lane ``apply`` of a module from its all-lanes equations."""
+    batched = None if state is None else jax.tree_util.tree_map(lambda s: s[None], state)
+    y, new_state = apply_lanes(_Dense(params), x[None], batched)
+    if new_state is not None:
+        new_state = jax.tree_util.tree_map(lambda s: s[0], new_state)
+    return y[0], new_state
+
+
+class _LaneModule(Module):
+    """A module written once, over a leading lane axis, against an accessor."""
+
+    def _forward(self, acc, x, state):
+        raise NotImplementedError
+
+    def apply(self, params, x, state=None):
+        if state is None:
+            state = self.initial_state()
+        return _lane_form(self._forward, params, x, state)
+
+    def trunk_delta_apply(self, center, factors, z, x, state):
+        """All lanes at once in the shared-trunk form: ``center`` the trunk's
+        parameter tree, ``factors`` the matching tree of ``_Factor`` nodes,
+        ``z`` the ``(n, k)`` coefficients, ``x`` and ``state`` with a leading
+        lane axis."""
+        if state is None and self.is_stateful:
+            state = _fresh_lanes(self.initial_state(), x.shape[0])
+        return self._forward(_Trunk(center, factors, z), x, state)
+
+
+def _fresh_lanes(proto, n):
+    return jax.tree_util.tree_map(lambda s: jnp.broadcast_to(s, (n,) + s.shape), proto)
+
+
+class Embedding(_LaneModule):
+    """Rows of a ``(rows, dim)`` table, times ``scale``. The input is a token
+    id, as a scalar or as the ``(1,)`` observation of a token environment."""
+
+    def __init__(self, rows: int, dim: int, *, scale: float = 1.0):
+        self.rows, self.dim, self.scale = int(rows), int(dim), float(scale)
+
+    def init(self, key):
+        return {"weight": _normal(key, (self.rows, self.dim))}
+
+    def _forward(self, acc, x, state):
+        ids = x.astype(jnp.int32).reshape(x.shape[0])
+        return acc.rows("weight", ids) * self.scale, state
+
+
+class RMSNorm(_LaneModule):
+    def __init__(self, dim: int, *, eps: float = 1e-5):
+        self.dim, self.eps = int(dim), float(eps)
+
+    def init(self, key):
+        return {"weight": jnp.ones((self.dim,), F32)}
+
+    def _forward(self, acc, x, state):
+        return rms_norm(x, acc.vec("weight"), self.eps), state
+
+
+class SwiGLU(_LaneModule):
+    """``W_down(silu(W_gate x) * W_up x)``."""
+
+    def __init__(self, dim: int, width: int):
+        self.dim, self.width = int(dim), int(width)
+
+    def init(self, key):
+        kg, ku, kd = jax.random.split(key, 3)
+        return {
+            "gate": _normal(kg, (self.width, self.dim)),
+            "up": _normal(ku, (self.width, self.dim)),
+            "down": _normal(kd, (self.dim, self.width)),
+        }
+
+    def _forward(self, acc, x, state):
+        h = jax.nn.silu(acc.mm("gate", x)) * acc.mm("up", x)
+        return acc.mm("down", h), state
+
+
+class GatedAttention(_LaneModule):
+    """``x + RMSNorm(W_o((softmax(q.k / sqrt(d)) . v) * sigmoid(W_g xn)))``
+    with ``xn = RMSNorm(x)``, per-head RMSNorm of ``q`` and ``k``, RoPE where
+    ``rope_theta`` is given (the sliding layers), grouped-query heads, and the
+    cache of the module docstring as its state."""
+
+    def __init__(
+        self,
+        dim: int,
+        num_heads: int,
+        num_kv_heads: int,
+        head_dim: int,
+        *,
+        slots: int,
+        rope_theta: Optional[float],
+        eps: float = 1e-5,
+    ):
+        self.dim, self.heads, self.kv_heads = int(dim), int(num_heads), int(num_kv_heads)
+        self.head_dim, self.slots, self.eps = int(head_dim), int(slots), float(eps)
+        self.rope_theta = None if rope_theta is None else float(rope_theta)
+        if self.heads % self.kv_heads:
+            raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
+
+    def init(self, key):
+        kq, kk, kv, kg, ko = jax.random.split(key, 5)
+        wide, narrow = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        return {
+            "in_norm": jnp.ones((self.dim,), F32),
+            "q": _normal(kq, (wide, self.dim)),
+            "k": _normal(kk, (narrow, self.dim)),
+            "v": _normal(kv, (narrow, self.dim)),
+            "g": _normal(kg, (wide, self.dim)),
+            "q_norm": jnp.ones((self.head_dim,), F32),
+            "k_norm": jnp.ones((self.head_dim,), F32),
+            "o": _normal(ko, (self.dim, wide)),
+            "post_norm": jnp.ones((self.dim,), F32),
+        }
+
+    def initial_state(self):
+        cache = jnp.zeros((self.kv_heads, self.slots, self.head_dim), F32)
+        zero = jnp.zeros((), jnp.int32)
+        return {"k": cache, "v": cache, "t": zero, "step": zero}
+
+    def reset_state(self, state, mask):
+        """Zero ``t`` and the cache rows of the lanes in ``mask``, one lane
+        at a time (a select over the whole cache would read and write all of
+        it in every control step; an episode's end is rare). ``step`` stays."""
+        n = mask.shape[0]
+        ended = jnp.nonzero(mask, size=n, fill_value=0)[0]
+        blank = jnp.zeros((1,) + state["k"].shape[1:], state["k"].dtype)
+
+        def zero_lane(i, caches):
+            at = (ended[i], 0, 0, 0)
+            return tuple(jax.lax.dynamic_update_slice(c, blank, at) for c in caches)
+
+        k, v = jax.lax.fori_loop(
+            0, jnp.sum(mask.astype(jnp.int32)), zero_lane, (state["k"], state["v"])
+        )
+        return {"k": k, "v": v, "t": jnp.where(mask, 0, state["t"]), "step": state["step"]}
+
+    def _forward(self, acc, x, state):
+        n, kv, hd, slots = x.shape[0], self.kv_heads, self.head_dim, self.slots
+        group = self.heads // kv
+        with scope("fwd_attention"):
+            xn = rms_norm(x, acc.vec("in_norm"), self.eps)
+            q = acc.mm("q", xn).reshape(n, kv, group, hd)
+            k = acc.mm("k", xn).reshape(n, kv, hd)
+            v = acc.mm("v", xn).reshape(n, kv, hd)
+            gate = acc.mm("g", xn)
+            q = rms_norm(q, acc.vec("q_norm")[:, None, None, :], self.eps)
+            k = rms_norm(k, acc.vec("k_norm")[:, None, :], self.eps)
+            t = state["t"]
+            if self.rope_theta is not None:
+                q = rope(q, t[:, None, None], self.rope_theta)
+                k = rope(k, t[:, None], self.rope_theta)
+            cache_dtype = state["k"].dtype
+            slot = jnp.mod(state["step"][0], slots)
+            at = (0, 0, slot, 0)
+            kc = jax.lax.dynamic_update_slice(state["k"], k[:, :, None, :].astype(cache_dtype), at)
+            vc = jax.lax.dynamic_update_slice(state["v"], v[:, :, None, :].astype(cache_dtype), at)
+            age = jnp.mod(slot - jnp.arange(slots, dtype=jnp.int32), slots)
+            readable = age[None, :] <= t[:, None]  # (n, slots)
+            scores = jnp.einsum(
+                "nkgd,nksd->nkgs", q.astype(cache_dtype), kc, preferred_element_type=F32
+            ) / math.sqrt(hd)
+            scores = jnp.where(readable[:, None, None, :], scores, -jnp.inf)
+            weights = jax.nn.softmax(scores, axis=-1).astype(cache_dtype)
+            mixed = jnp.einsum("nkgs,nksd->nkgd", weights, vc, preferred_element_type=F32)
+            mixed = mixed.reshape(n, self.heads * hd) * jax.nn.sigmoid(gate.astype(F32))
+            out = acc.mm("o", mixed.astype(x.dtype))
+            y = x + rms_norm(out, acc.vec("post_norm"), self.eps)
+        return y, {"k": kc, "v": vc, "t": t + 1, "step": state["step"] + 1}
+
+
+class SparseExperts(_LaneModule):
+    """``RMSNorm``-wrapped sigmoid-routed experts with a shared expert:
+    ``x + RMSNorm(sum_e w_e E_e(y) + S(y))``, ``y = RMSNorm(x)``; router
+    logits, sigmoid and top-k in float32; the ``expert_bias`` selects and
+    does not weigh; the weights are the selected scores, normalised over the
+    selected (``route_norm``) and times ``route_scale``. ``experts_held``:
+    the range of expert ids whose weights this module holds; the others'
+    terms are left out. Held experts are stacked ``(held, in, out)``: the
+    grouped product's right-hand side."""
+
+    def __init__(
+        self,
+        dim: int,
+        width: int,
+        num_experts: int,
+        experts_per_token: int,
+        *,
+        experts_held: Optional[range] = None,
+        num_shared_experts: int = 1,
+        route_scale: float = 1.0,
+        route_norm: bool = True,
+        score_func: str = "sigmoid",
+        eps: float = 1e-5,
+    ):
+        if score_func != "sigmoid":
+            raise ValueError(f"score_func {score_func!r}: only the sigmoid router is implemented")
+        self.dim, self.width = int(dim), int(width)
+        self.num_experts, self.top_k = int(num_experts), int(experts_per_token)
+        held = range(self.num_experts) if experts_held is None else experts_held
+        if held.step != 1 or not 0 <= held.start < held.stop <= self.num_experts:
+            raise ValueError(f"experts_held {held!r} is not a range of ids within {self.num_experts}")
+        self.held = held
+        self.shared = SwiGLU(dim, int(num_shared_experts) * width) if num_shared_experts else None
+        self.route_scale, self.route_norm, self.eps = float(route_scale), bool(route_norm), float(eps)
+
+    def init(self, key):
+        kr, kg, ku, kd, ks = jax.random.split(key, 5)
+        e = len(self.held)
+        params = {
+            "in_norm": jnp.ones((self.dim,), F32),
+            "router": _normal(kr, (self.num_experts, self.dim)),
+            "expert_bias": jnp.zeros((self.num_experts,), F32),
+            "experts": {
+                "gate": _normal(kg, (e, self.dim, self.width)),
+                "up": _normal(ku, (e, self.dim, self.width)),
+                "down": _normal(kd, (e, self.width, self.dim)),
+            },
+            "post_norm": jnp.ones((self.dim,), F32),
+        }
+        if self.shared is not None:
+            params["shared"] = self.shared.init(ks)
+        return params
+
+    def initial_state(self):
+        """What the last step chose, and two counters that never reset: the
+        lane's (lane, expert) pairs that hit a held expert, and the pairs on
+        the fullest held expert of each step (one number for all lanes of a
+        population)."""
+        zero = jnp.zeros((), jnp.int32)
+        return {"chosen": jnp.zeros((self.top_k,), jnp.int32), "hits": zero, "fullest": zero}
+
+    def reset_state(self, state, mask):
+        return state  # nothing of an episode lives here
+
+    def route(self, acc, y):
+        """Expert ids ``(n, top_k)`` and their float32 weights."""
+        logits = acc.mm("router", y.astype(F32), precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + acc.vec("expert_bias").astype(F32), self.top_k)
+        weights = jnp.take_along_axis(scores, chosen, axis=-1)
+        if self.route_norm:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return chosen, weights * self.route_scale
+
+    def _experts_dense(self, params, y, chosen, weights):
+        """One lane's held experts, all computed and weighed by the router's
+        weight (zero where not chosen): the dense form's plain sum."""
+        ids = jnp.arange(self.held.start, self.held.stop)
+        per_expert = jnp.sum(
+            jnp.where(chosen[0][None, :] == ids[:, None], weights[0][None, :], 0.0), axis=-1
+        )
+        x = y[0]
+        gate = jnp.einsum("i,eiw->ew", x, params["gate"].astype(x.dtype))
+        up = jnp.einsum("i,eiw->ew", x, params["up"].astype(x.dtype))
+        out = jnp.einsum("ew,ewo->eo", jax.nn.silu(gate) * up, params["down"].astype(x.dtype))
+        mixed = jnp.sum(out.astype(F32) * per_expert[:, None], axis=0)[None].astype(y.dtype)
+        return mixed, (per_expert > 0).astype(jnp.int32)
+
+    def _experts_grouped(self, center, factors, z, y, chosen, weights):
+        """All lanes' held experts as one grouped product: the (lane, expert)
+        pairs that hit a held expert, sorted by expert, against the stacked
+        weights (``jax.lax.ragged_dot``). No pair is dropped and there is no
+        capacity factor: the product has room for every lane hitting
+        ``min(top_k, held)`` experts (on the v5e its time does not depend on
+        that room: PERF.md, PR 28). Rows are gathered and summed back by
+        one-hot matmuls. Returns the lanes' sums and the pairs on each held
+        expert."""
+        n, held = y.shape[0], len(self.held)
+        rows = n * min(self.top_k, held)
+        local = chosen - self.held.start
+        key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True)[:rows]  # the held pairs come first, by expert
+        key = key[order]
+        lane = order // self.top_k
+        hit = key < held
+        sizes = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32)
+        place = ((lane[:, None] == jnp.arange(n)[None, :]) & hit[:, None]).astype(y.dtype)
+        x_rows = place @ y
+        z_rows = place @ z.astype(y.dtype)
+
+        def grouped(name, x):
+            w, f = center[name].astype(x.dtype), factors[name]
+            thin = jax.lax.ragged_dot(x, f.a.astype(x.dtype), sizes) * z_rows
+            return jax.lax.ragged_dot(x, w, sizes) + jax.lax.ragged_dot(
+                thin, jnp.swapaxes(f.b, 1, 2).astype(x.dtype), sizes
+            )
+
+        hidden = jax.nn.silu(grouped("gate", x_rows)) * grouped("up", x_rows)
+        out = grouped("down", hidden).astype(F32) * weights.reshape(-1)[order][:, None]
+        out = jnp.where(hit[:, None], out, 0.0)  # rows past the last group hold no pair
+        return place.T @ out.astype(y.dtype), sizes
+
+    def _forward(self, acc, x, state):
+        with scope("fwd_router"):
+            y = rms_norm(x, acc.vec("in_norm"), self.eps)
+            chosen, weights = self.route(acc, y)
+        with scope("fwd_experts"):
+            if acc.z is None:
+                mixed, load = self._experts_dense(acc.p["experts"], y, chosen, weights)
+            else:
+                mixed, load = self._experts_grouped(
+                    acc.p["experts"], acc.f["experts"], acc.z, y, chosen, weights
+                )
+            if self.shared is not None:
+                mixed = mixed + self.shared._forward(acc.sub("shared"), y, None)[0]
+            out = x + rms_norm(mixed, acc.vec("post_norm"), self.eps)
+            local = chosen - self.held.start
+            hits = jnp.sum((local >= 0) & (local < len(self.held)), axis=-1, dtype=jnp.int32)
+            state = {
+                "chosen": chosen.astype(jnp.int32),
+                "hits": state["hits"] + hits,
+                "fullest": state["fullest"] + jnp.max(load).astype(jnp.int32),
+            }
+        return out, state
+
+
+class _DenseMLP(_LaneModule):
+    """``x + RMSNorm(SwiGLU(RMSNorm(x)))``: the MLP of the first
+    ``num_dense_layers`` layers."""
+
+    def __init__(self, dim: int, width: int, *, eps: float = 1e-5):
+        self.inner, self.dim, self.eps = SwiGLU(dim, width), int(dim), float(eps)
+
+    def init(self, key):
+        return {
+            "in_norm": jnp.ones((self.dim,), F32),
+            "mlp": self.inner.init(key),
+            "post_norm": jnp.ones((self.dim,), F32),
+        }
+
+    def _forward(self, acc, x, state):
+        with scope("fwd_dense_mlp"):
+            y = rms_norm(x, acc.vec("in_norm"), self.eps)
+            out, _ = self.inner._forward(acc.sub("mlp"), y, None)
+            return x + rms_norm(out, acc.vec("post_norm"), self.eps), state
+
+
+class DecoderLayer(_LaneModule):
+    """Attention, then the layer's MLP (dense or sparse)."""
+
+    def __init__(self, attention: GatedAttention, mlp: _LaneModule):
+        self.attention, self.mlp = attention, mlp
+
+    def init(self, key):
+        ka, km = jax.random.split(key)
+        return {"attn": self.attention.init(ka), "mlp": self.mlp.init(km)}
+
+    def initial_state(self):
+        return {"attn": self.attention.initial_state(), "mlp": self.mlp.initial_state()}
+
+    def reset_state(self, state, mask):
+        mlp = state["mlp"]
+        return {
+            "attn": self.attention.reset_state(state["attn"], mask),
+            "mlp": None if mlp is None else self.mlp.reset_state(mlp, mask),
+        }
+
+    def _forward(self, acc, x, state):
+        x, attn = self.attention._forward(acc.sub("attn"), x, state["attn"])
+        x, mlp = self.mlp._forward(acc.sub("mlp"), x, state["mlp"])
+        return x, {"attn": attn, "mlp": mlp}
+
+
+class AfmoeDecoder(_LaneModule):
+    """The decoder under the published configuration's own keys, plus the
+    share this process holds: ``layers_held`` (ids into the published stack;
+    a layer's kind follows from its id), ``experts_held`` (a range of expert
+    ids, every sparse layer's), ``vocab_held`` (rows of embedding and head),
+    and ``max_positions``, the longest episode the cache must hold. Input:
+    one token id; output: float logits over the held vocabulary."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size: int,
+        num_attention_heads: int,
+        num_key_value_heads: int,
+        head_dim: int,
+        intermediate_size: int,
+        moe_intermediate_size: int,
+        num_experts: int,
+        num_experts_per_tok: int,
+        num_shared_experts: int,
+        num_dense_layers: int,
+        layer_types: Sequence[str],
+        sliding_window: int,
+        rope_theta: float,
+        route_scale: float,
+        route_norm: bool,
+        score_func: str,
+        rms_norm_eps: float,
+        vocab_size: int,
+        mup_enabled: bool,
+        max_positions: int,
+        layers_held: Optional[Sequence[int]] = None,
+        experts_held: Optional[range] = None,
+        vocab_held: Optional[int] = None,
+    ):
+        self.hidden_size, self.eps = int(hidden_size), float(rms_norm_eps)
+        self.vocab_held = int(vocab_size if vocab_held is None else vocab_held)
+        if not 0 < self.vocab_held <= int(vocab_size):
+            raise ValueError("vocab_held must lie in (0, vocab_size]")
+        self.layers_held = tuple(range(len(layer_types)) if layers_held is None else layers_held)
+        self.max_positions = int(max_positions)
+        layers = []
+        for index in self.layers_held:
+            kind = layer_types[index]
+            if kind not in ("sliding_attention", "full_attention"):
+                raise ValueError(f"layer_types[{index}] = {kind!r}")
+            sliding = kind == "sliding_attention"
+            attention = GatedAttention(
+                hidden_size,
+                num_attention_heads,
+                num_key_value_heads,
+                head_dim,
+                slots=min(int(sliding_window), self.max_positions) if sliding else self.max_positions,
+                rope_theta=rope_theta if sliding else None,  # no positions in full layers
+                eps=self.eps,
+            )
+            if index < int(num_dense_layers):
+                mlp = _DenseMLP(hidden_size, intermediate_size, eps=self.eps)
+            else:
+                mlp = SparseExperts(
+                    hidden_size,
+                    moe_intermediate_size,
+                    num_experts,
+                    num_experts_per_tok,
+                    experts_held=experts_held,
+                    num_shared_experts=num_shared_experts,
+                    route_scale=route_scale,
+                    route_norm=route_norm,
+                    score_func=score_func,
+                    eps=self.eps,
+                )
+            layers.append(DecoderLayer(attention, mlp))
+        self.layers = tuple(layers)
+        scale = math.sqrt(hidden_size) if mup_enabled else 1.0
+        self.embedding = Embedding(self.vocab_held, hidden_size, scale=scale)
+
+    def init(self, key):
+        ke, kh, *kl = jax.random.split(key, 2 + len(self.layers))
+        return {
+            "embed": self.embedding.init(ke)["weight"],
+            "layers": tuple(layer.init(k) for layer, k in zip(self.layers, kl)),
+            "final_norm": jnp.ones((self.hidden_size,), F32),
+            "head": _normal(kh, (self.vocab_held, self.hidden_size)),
+        }
+
+    def initial_state(self):
+        slots = jnp.zeros((self.max_positions,), jnp.int32)
+        return {
+            "layers": tuple(layer.initial_state() for layer in self.layers),
+            "seen": {"ids": slots, "positions": slots, "step": jnp.zeros((), jnp.int32)},
+        }
+
+    def reset_state(self, state, mask):
+        layers = tuple(layer.reset_state(s, mask) for layer, s in zip(self.layers, state["layers"]))
+        return {"layers": layers, "seen": state["seen"]}  # the record outlives an episode
+
+    def state_report(self, state) -> dict:
+        """What the lane-batched ``state`` says of the steps since it was
+        made (the rollout engine returns it beside its telemetry).
+        Whole-population counters: (lane, expert) pairs that hit held
+        experts, the pairs on the fullest held expert summed over steps and
+        layers, the expert-layer steps they are sums over, and the cache
+        slots written. Per lane, in step order (the last ``max_positions``
+        steps): the id each step consumed and the lane's position in its
+        episode there, ``(n, steps)``; the model's token for position ``t``
+        of an episode is the id consumed at ``t + 1``."""
+        layers = state["layers"]
+        sparse = [s["mlp"] for s in layers if s["mlp"] is not None]
+        zero = jnp.zeros((), jnp.int32)
+        seen = state["seen"]
+        # the ring's oldest step comes first
+        first = jnp.where(seen["step"][0] > self.max_positions, seen["step"][0] % self.max_positions, 0)
+        return {
+            "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),
+            "expert_pairs_fullest": sum((m["fullest"][0] for m in sparse), zero),
+            "expert_layer_steps": sum((s["attn"]["step"][0] for s in layers if s["mlp"] is not None), zero),
+            "cache_slots_written": sum((jnp.sum(s["attn"]["step"]) for s in layers), zero),
+            "ids_seen": jnp.roll(seen["ids"], -first, axis=1),
+            "positions_seen": jnp.roll(seen["positions"], -first, axis=1),
+        }
+
+    def _forward(self, acc, x, state):
+        with scope("fwd_head"):
+            ids = x.astype(jnp.int32).reshape(x.shape[0])
+            h = acc.rows("embed", ids) * self.embedding.scale
+            if acc.z is not None:
+                h = h.astype(acc.z.dtype)  # the lanes' compute dtype (ids carry none)
+            seen = state["seen"]
+            at = (0, jnp.mod(seen["step"][0], self.max_positions))
+            positions = state["layers"][0]["attn"]["t"]
+            seen = {
+                "ids": jax.lax.dynamic_update_slice(seen["ids"], ids[:, None], at),
+                "positions": jax.lax.dynamic_update_slice(seen["positions"], positions[:, None], at),
+                "step": seen["step"] + 1,
+            }
+        new_state = []
+        layers = acc.sub("layers")
+        for i, layer in enumerate(self.layers):
+            h, s = layer._forward(layers.sub(i), h, state["layers"][i])
+            new_state.append(s)
+        with scope("fwd_head"):
+            logits = acc.mm("head", rms_norm(h, acc.vec("final_norm"), self.eps))
+        return logits, {"layers": tuple(new_state), "seen": seen}
+
+
+def stepwise_logits(policy, params_batch, ids, *, positions=None, lanes=None, compute_dtype=None):
+    """Decode the id sequences ``ids`` ``(n, T)`` one token a step,
+    teacher-forced, through the population-wide forward the rollout engine
+    steps (the same ``_batched_forward`` on the same forward context, the
+    cache as carried state): float32 logits ``(n, T, vocab)`` and the experts
+    chosen ``(T, sparse layers, n, top_k)``. Scoring a population on given
+    text, and replaying what an evaluation consumed (``state_report``'s
+    ``ids_seen`` and ``positions_seen``): with ``positions`` ``(n, T)``, a
+    lane whose position reads 0 after the first step starts an episode there
+    and its state is reset first, as the engine resets it at an episode's
+    end. ``lanes``: indices of the lanes whose logits and experts are
+    returned (all of them step; default all). Call under ``jit``. ``policy``
+    wraps an :class:`AfmoeDecoder`."""
+    from .vecrl import _batched_forward, _forward_ctx, _initial_policy_states, _params_cast
+
+    params_batch = _params_cast(params_batch, compute_dtype)
+    ctx = _forward_ctx(policy, params_batch)
+    states = _initial_policy_states(policy, ids.shape[0], compute_dtype)
+    lanes = jnp.arange(ids.shape[0]) if lanes is None else jnp.asarray(lanes)
+    if positions is None:
+        restarts = jnp.zeros(ids.shape, bool)
+    else:
+        restarts = (jnp.asarray(positions) == 0).at[:, 0].set(False)
+
+    def body(states, column):
+        column, restart = column
+        states = policy.module.reset_state(states, restart)
+        logits, states = _batched_forward(policy, params_batch, ctx, column[:, None], states)
+        chosen = [s["mlp"]["chosen"][lanes] for s in states["layers"] if s["mlp"] is not None]
+        routes = jnp.stack(chosen) if chosen else jnp.zeros((0,), jnp.int32)
+        return states, (logits[lanes].astype(F32), routes)
+
+    _, (logits, routes) = jax.lax.scan(body, states, (ids.T, restarts.T))
+    return jnp.swapaxes(logits, 0, 1), routes

@@ -46,11 +46,16 @@ class FlatParamsPolicy:
     def __init__(self, module: Module, *, key=None):
         self.module = module
         template_key = key if key is not None else jax.random.key(0)
-        template = module.init(template_key)
-        flat, unravel = ravel_pytree(template)
-        self._template_flat = flat
-        self._unravel = unravel
-        self.parameter_count = int(flat.shape[0])
+        made = {}
+
+        def template(k):
+            # traced for its shapes alone: a policy of hundreds of millions
+            # of parameters is not initialised twice over to be measured
+            flat, made["unravel"] = ravel_pytree(module.init(k))
+            return flat
+
+        self.parameter_count = int(jax.eval_shape(template, template_key).shape[0])
+        self._unravel = made["unravel"]
 
     @property
     def num_parameters(self) -> int:

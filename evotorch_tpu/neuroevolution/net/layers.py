@@ -62,6 +62,13 @@ class Module:
     def apply(self, params, x, state=None) -> Tuple[jnp.ndarray, Any]:
         raise NotImplementedError
 
+    def reset_state(self, state: Any, mask: jnp.ndarray) -> Any:
+        """The lane-batched ``state`` with the lanes in ``mask`` back at
+        their start (an episode ended there). The default zeroes those lanes'
+        rows of every leaf; a module whose state is large, or not all
+        per-lane, brings its own."""
+        return zero_rows(state, mask)
+
     @property
     def is_stateful(self) -> bool:
         return self.initial_state() is not None
@@ -73,6 +80,16 @@ class Module:
 
     def __call__(self, params, x, state=None):
         return self.apply(params, x, state)
+
+
+def zero_rows(tree: Any, mask: jnp.ndarray) -> Any:
+    """Zero the rows of every leaf where ``mask`` is True."""
+
+    def zero(leaf):
+        m = mask.reshape(mask.shape + (1,) * (leaf.ndim - mask.ndim))
+        return jnp.where(m, jnp.zeros_like(leaf), leaf)
+
+    return jax.tree_util.tree_map(zero, tree)
 
 
 class Sequential(Module):
@@ -103,6 +120,14 @@ class Sequential(Module):
         if all(s is None for s in out_state):
             out_state = None
         return x, out_state
+
+    def reset_state(self, state, mask):
+        if state is None:
+            return None
+        return tuple(
+            None if s is None else m.reset_state(s, mask)
+            for m, s in zip(self.modules, state)
+        )
 
     def __repr__(self):
         return " >> ".join(repr(m) for m in self.modules)

@@ -44,8 +44,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from jax.flatten_util import ravel_pytree
-
+from ...tools.lowrank import DeltaFactor as _Factor
 from ...tools.lowrank import LowRankParamsBatch, TrunkDeltaParamsBatch
 from .layers import LSTM, RNN, Bias, Linear, Module, Sequential
 
@@ -246,80 +245,66 @@ def lowrank_forward(
 # ---------------------------------------------------------------------------
 
 
-class _Factor(NamedTuple):
-    """Per-parameter-leaf delta factors. For a 2-D weight leaf ``a`` is
-    (in, k) and ``b`` is (out, k) with sigma's block scale folded into
-    ``b``; for a 1-D leaf ``a`` is an empty (0, k) placeholder and ``b``
-    holds the sigma-folded dense direction matrix (size, k) — exactly a
-    low-rank bias basis."""
-
-    a: jnp.ndarray
-    b: jnp.ndarray
-
-
 def trunk_delta_supported(module: Module) -> bool:
-    """The trunk-delta path covers the same structured stacks as the
-    augmented-matmul path: Sequential pipelines of Linear / Bias / RNN /
-    LSTM / parameterless layers."""
-    return lowrank_supported(module)
+    """The trunk-delta path covers the structured stacks of the
+    augmented-matmul path (Sequential pipelines of Linear / Bias / RNN / LSTM
+    / parameterless layers) and every module that brings its own
+    ``trunk_delta_apply(center, factors, z, x, state)`` (the decoder modules
+    of ``net/decoder.py``)."""
+    if isinstance(module, Sequential):
+        return all(trunk_delta_supported(m) for m in module.modules)
+    return hasattr(module, "trunk_delta_apply") or lowrank_supported(module)
 
 
 def sample_trunk_delta_factors(key, policy, sigma: jnp.ndarray, rank: int):
-    """Draw one generation's delta factors and materialize their effective
-    basis.
+    """Draw one generation's delta factors: a pytree mirroring the policy's
+    parameter tree with a ``tools.lowrank.DeltaFactor`` at every leaf. The
+    population is ``theta_i = center + basis @ z_i`` with the column ``m`` of
+    the basis the concatenation of the leaves' rank-1 blocks; the basis is
+    implied, never built (gradients, guardrail and ``materialize_rows`` read
+    the factors leaf by leaf).
 
-    Returns ``(factors, basis)``: ``factors`` is a pytree mirroring the
-    policy's parameter tree with a :class:`_Factor` at every leaf, and
-    ``basis`` is the flat (L, k) effective basis whose column ``m`` is the
-    concatenation of ``vec(b_m a_m^T)`` (2-D leaves) and the 1-D direction
-    columns — the SAME ``theta_i = center + basis @ z_i`` algebra as
-    :class:`LowRankParamsBatch`, so gradients and the exhaustion guardrail
-    apply unchanged.
-
-    Sigma folding: 1-D leaves fold the per-parameter sigma exactly; 2-D
-    leaves fold the block's RMS sigma (a per-parameter scale would break
-    the rank-1 structure the fast forward depends on). Per-entry delta
-    variance is ``sigma^2`` (blockwise for matrices), matching the default
-    low-rank basis scaling at equal rank.
+    Sigma folding: 1-D leaves fold the per-parameter sigma exactly; matrix
+    leaves (2-D ``(out, in)``; 3-D ``(group, in, out)`` stacks, one block per
+    member) fold the block's RMS sigma (a per-parameter scale would break the
+    rank-1 structure the fast forward depends on). Per-entry delta variance
+    is ``sigma^2`` (blockwise for matrices), matching the default low-rank
+    basis scaling at equal rank.
     """
     sigma_tree = policy.unravel(sigma)
     leaves, treedef = jax.tree_util.tree_flatten(sigma_tree)
     factor_nodes = []
-    basis_leaves = []
     inv_sqrt_k = 1.0 / jnp.sqrt(jnp.asarray(float(rank), sigma.dtype))
     for i, sigma_leaf in enumerate(leaves):
         k_a = jax.random.fold_in(key, 2 * i)
         k_b = jax.random.fold_in(key, 2 * i + 1)
+        dtype = sigma_leaf.dtype
         if sigma_leaf.ndim == 2:
             out_f, in_f = sigma_leaf.shape
-            a = jax.random.normal(k_a, (in_f, rank), sigma_leaf.dtype)
+            a = jax.random.normal(k_a, (in_f, rank), dtype)
             block_rms = jnp.sqrt(jnp.mean(sigma_leaf * sigma_leaf))
-            b = jax.random.normal(k_b, (out_f, rank), sigma_leaf.dtype) * (
-                block_rms * inv_sqrt_k
+            b = jax.random.normal(k_b, (out_f, rank), dtype) * (block_rms * inv_sqrt_k)
+        elif sigma_leaf.ndim == 3:
+            group, in_f, out_f = sigma_leaf.shape
+            a = jax.random.normal(k_a, (group, in_f, rank), dtype)
+            block_rms = jnp.sqrt(jnp.mean(sigma_leaf * sigma_leaf, axis=(1, 2)))
+            b = jax.random.normal(k_b, (group, out_f, rank), dtype) * (
+                block_rms[:, None, None] * inv_sqrt_k
             )
-            factor_nodes.append(_Factor(a=a, b=b))
-            basis_leaves.append(jnp.einsum("om,im->oim", b, a))
         elif sigma_leaf.ndim == 1:
-            dirs = (
-                jax.random.normal(k_b, sigma_leaf.shape + (rank,), sigma_leaf.dtype)
+            a = jnp.zeros((0, rank), dtype)
+            b = (
+                jax.random.normal(k_b, sigma_leaf.shape + (rank,), dtype)
                 * inv_sqrt_k
                 * sigma_leaf[:, None]
             )
-            factor_nodes.append(
-                _Factor(a=jnp.zeros((0, rank), sigma_leaf.dtype), b=dirs)
-            )
-            basis_leaves.append(dirs)
         else:
             raise ValueError(
-                "trunk-delta factors need 1-D or 2-D parameter leaves; got "
-                f"shape {sigma_leaf.shape} (leaf {i})"
+                "trunk-delta factors need 1-D, 2-D or stacked 3-D parameter leaves;"
+                f" got shape {sigma_leaf.shape} (leaf {i})"
             )
-    factors = jax.tree_util.tree_unflatten(treedef, factor_nodes)
-    basis_tree = jax.tree_util.tree_unflatten(treedef, basis_leaves)
-    basis = jax.vmap(lambda t: ravel_pytree(t)[0], in_axes=-1, out_axes=-1)(
-        basis_tree
-    )
-    return factors, basis
+        factor_nodes.append(_Factor(a=a, b=b))
+    return jax.tree_util.tree_unflatten(treedef, factor_nodes)
 
 
 class _TrunkPrepared(NamedTuple):
@@ -419,6 +404,8 @@ def _apply_trunk_delta(module: Module, cp, fx, z, x, state):
         return _rnn_trunk(module, cp, fx, z, x, state)
     if isinstance(module, LSTM):
         return _lstm_trunk(module, cp, fx, z, x, state)
+    if hasattr(module, "trunk_delta_apply"):  # a module with a form of its own
+        return module.trunk_delta_apply(cp, fx, z, x, state)
     # parameterless layer: batched apply is the plain apply
     return module.apply(cp, x, state)
 

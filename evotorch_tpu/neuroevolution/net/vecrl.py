@@ -50,6 +50,7 @@ from ...observability.devicemetrics import (
 from ...observability.scopes import scope
 from ...tools.lowrank import is_factored
 from ..net.functional import FlatParamsPolicy
+from ..net.layers import zero_rows
 from ..net.lowrank import (
     LowRankParamsBatch,
     TrunkDeltaParamsBatch,
@@ -171,7 +172,8 @@ def _forward_in_compute_dtype(forward, policy_in, states, compute_dtype):
     and its raw output cast back to float32: the whole of a control step's
     policy forward, in every engine."""
     with scope("policy_forward"):
-        if compute_dtype is not None:
+        # token ids stay integers: bfloat16 holds no id above 256 exactly
+        if compute_dtype is not None and jnp.issubdtype(policy_in.dtype, jnp.floating):
             policy_in = policy_in.astype(compute_dtype)
         raw, new_states = forward(policy_in, states)
         if compute_dtype is not None:
@@ -181,13 +183,10 @@ def _forward_in_compute_dtype(forward, policy_in, states, compute_dtype):
 
 def reset_tensors(tree: Any, mask: jnp.ndarray) -> Any:
     """Zero the rows of every leaf where ``mask`` is True (the reference's
-    nested-state resetter, ``vecrl.py:866-1016``), as a pure function."""
-
-    def zero_rows(leaf):
-        m = mask.reshape(mask.shape + (1,) * (leaf.ndim - mask.ndim))
-        return jnp.where(m, jnp.zeros_like(leaf), leaf)
-
-    return jax.tree_util.tree_map(zero_rows, tree)
+    nested-state resetter, ``vecrl.py:866-1016``), as a pure function. The
+    engines reset a policy's state through ``Module.reset_state``, whose
+    default is this."""
+    return zero_rows(tree, mask)
 
 
 class Policy:
@@ -405,6 +404,11 @@ class RolloutResult(NamedTuple):
     # it is part of the same transfer, never a new dispatch. None when the
     # engine ran with telemetry=False.
     telemetry: Any = None
+    # what a stateful policy's final state says of the evaluation
+    # (``Module.state_report``: a dict of device arrays; the decoder's
+    # expert-load and cache counters and the ids every lane consumed), from
+    # the monolithic engine; None for a policy that reports nothing
+    policy_report: Any = None
 
 
 class RolloutCarry(NamedTuple):
@@ -505,13 +509,13 @@ def _initial_policy_states(policy: FlatParamsPolicy, n: int, compute_dtype):
     proto = policy.initial_state()
     if proto is None:
         return None
-    return jax.tree_util.tree_map(
-        lambda leaf: jnp.broadcast_to(
-            leaf if compute_dtype is None else leaf.astype(compute_dtype),
-            (n,) + leaf.shape,
-        ),
-        proto,
-    )
+    def lanes(leaf):
+        # counters and positions stay integers
+        if compute_dtype is not None and jnp.issubdtype(leaf.dtype, jnp.floating):
+            leaf = leaf.astype(compute_dtype)
+        return jnp.broadcast_to(leaf, (n,) + leaf.shape)
+
+    return jax.tree_util.tree_map(lanes, proto)
 
 
 def _env_state_take(env, states, idx):
@@ -749,7 +753,9 @@ def _make_step(
             with scope("contract"):
                 steps_in_episode = jnp.where(finished, 0, steps_in_episode)
                 if new_policy_states is not None:
-                    new_policy_states = reset_tensors(new_policy_states, finished)
+                    new_policy_states = policy.module.reset_state(
+                        new_policy_states, finished
+                    )
                 if budget_mode:
                     active = active_f  # every lane runs its full budget
                 else:
@@ -1212,12 +1218,16 @@ def run_vectorized_rollout(
                 num_groups,
                 num_valid,
             )
+        report = getattr(policy.module, "state_report", None)
         return RolloutResult(
             scores=mean_scores,
             stats=final.stats,
             total_steps=final.total_steps,
             total_episodes=total_episodes,
             telemetry=eval_telemetry,
+            policy_report=(
+                report(final.policy_states) if telemetry and report is not None else None
+            ),
         )
 
 
@@ -2355,11 +2365,9 @@ def _params_shard_spec(params_kind: str, axis_name: str):
         # coefficients shard; the shared center/basis replicate
         return LowRankParamsBatch(center=P(), basis=P(), coeffs=P(axis_name))
     if params_kind == "trunk_delta":
-        # coefficients shard; trunk, effective basis and the factor tree
-        # replicate (factors=P() is a pytree-prefix spec over the subtree)
-        return TrunkDeltaParamsBatch(
-            center=P(), basis=P(), coeffs=P(axis_name), factors=P()
-        )
+        # coefficients shard; trunk and factor tree replicate (factors=P()
+        # is a pytree-prefix spec over the subtree)
+        return TrunkDeltaParamsBatch(center=P(), coeffs=P(axis_name), factors=P())
     return P(axis_name)
 
 

@@ -261,6 +261,7 @@ class VecNE(NEProblem):
         # the status dict (the same lag-by-one device-scalar discipline as
         # basis_capture: the decode is a ~24-byte transfer, never a stall)
         self._pending_telemetry = None
+        self._last_policy_report = None
         self._last_telemetry = None
         self._last_group_telemetry = None
 
@@ -516,9 +517,12 @@ class VecNE(NEProblem):
             self._env, self._policy, values, key, self._obs_norm.stats, **kwargs
         )
 
-    def lower_evaluation(self, popsize: int):
+    def lower_evaluation(self, popsize: int, *, like=None):
         """The device program ``evaluate`` dispatches for a dense population
-        of ``popsize`` solutions, lowered on ``ShapeDtypeStruct``s
+        of ``popsize`` solutions (or, with ``like``, for a factored
+        population of that batch's form: a ``LowRankParamsBatch`` or
+        ``TrunkDeltaParamsBatch``, concrete or abstract, on one device),
+        lowered on ``ShapeDtypeStruct``s
         (``jax.stages.Lowered``): ``run_vectorized_rollout`` with this
         problem's contract on one device, or the memoized sharded evaluator's
         program where ``num_actors`` gives a mesh. Nothing runs and no PRNG
@@ -558,6 +562,11 @@ class VecNE(NEProblem):
             evaluator = self._sharded_rollout_evaluator(mesh, "pop")
             return evaluator.program_builder("dense", popsize).lower(values, key, stats)
         values = jax.ShapeDtypeStruct(shape, self.dtype)
+        if like is not None:
+            if mesh is not None or not is_factored(like):
+                raise ValueError("like= takes a factored batch, on one device")
+            values = jax.tree_util.tree_map(abstract, like)
+            popsize = _params_popsize(like)
         return run_vectorized_rollout.lower(
             self._env,
             self._policy,
@@ -687,6 +696,15 @@ class VecNE(NEProblem):
             self._obs_norm.stats = result.stats
         self._bump_counters(result.total_steps, result.total_episodes)
         self._consume_telemetry(result.telemetry)
+        self._last_policy_report = getattr(result, "policy_report", None)
+
+    @property
+    def last_policy_report(self):
+        """The last evaluation's ``RolloutResult.policy_report``: what a
+        stateful policy's final state says of it (the decoder's expert load
+        and cache writes, and the ids every lane consumed), as device arrays;
+        None for a policy that reports nothing."""
+        return self._last_policy_report
 
     # ------------------------------------------------------- policy exports
     def to_policy(self, solution) -> Module:

@@ -29,6 +29,17 @@ is handed back WITHOUT it; clear the cache after touching scopes).
   dense population into per-layer blocks among them), score averaging,
   quarantine, telemetry packing.
 
+``FORWARD_SCOPES`` name the parts of a forward that has parts worth telling
+apart (the decoder of ``net/decoder.py``), INSIDE ``policy_forward``:
+``fwd_attention`` (projections, norms, RoPE, cache write, scores over the
+cache), ``fwd_router`` (the expert layer's norm, router, top-k),
+``fwd_experts`` (the sort of the pairs, the grouped product over the held
+experts, the shared expert), ``fwd_dense_mlp``, ``fwd_head`` (embedding
+gather, final norm, head). ``instruction_scopes`` keeps reading the OUTERMOST
+rollout scope, so what read ``policy_forward`` before still does;
+``instruction_scopes(..., names=FORWARD_SCOPES)`` reads the INNERMOST
+component among the forward's names.
+
 A v5e trace names an op by its instruction (``%fusion.12 = ...``) and, unless
 the HLO proto is recorded with it, carries no metadata; the compiled
 program's text carries both. ``instruction_scopes`` reads the text, so
@@ -45,7 +56,7 @@ from typing import Dict, Optional
 
 import jax
 
-__all__ = ["ROLLOUT_SCOPES", "SCOPE_PREFIX", "scope", "instruction_scopes"]
+__all__ = ["ROLLOUT_SCOPES", "FORWARD_SCOPES", "SCOPE_PREFIX", "scope", "instruction_scopes"]
 
 ROLLOUT_SCOPES = (
     "policy_forward",
@@ -55,13 +66,22 @@ ROLLOUT_SCOPES = (
     "contract",
     "rollout_edges",
 )
+FORWARD_SCOPES = (
+    "fwd_attention",
+    "fwd_router",
+    "fwd_experts",
+    "fwd_dense_mlp",
+    "fwd_head",
+)
 SCOPE_PREFIX = "evotorch_tpu."
 
 
 def scope(name: str):
     """``jax.named_scope("evotorch_tpu.<name>")`` for a declared name."""
-    if name not in ROLLOUT_SCOPES:
-        raise ValueError(f"{name!r} is not one of ROLLOUT_SCOPES {ROLLOUT_SCOPES}")
+    if name not in ROLLOUT_SCOPES and name not in FORWARD_SCOPES:
+        raise ValueError(
+            f"{name!r} is not one of ROLLOUT_SCOPES {ROLLOUT_SCOPES} or FORWARD_SCOPES {FORWARD_SCOPES}"
+        )
     return jax.named_scope(SCOPE_PREFIX + name)
 
 
@@ -81,13 +101,17 @@ _PLUMBING = frozenset(
 )
 
 
-def _named_scope(line: str) -> Optional[str]:
+def _named_scope(line: str, names=ROLLOUT_SCOPES) -> Optional[str]:
+    """The outermost component of the line's ``op_name`` that is one of the
+    rollout's scopes; for any other ``names``, the innermost that is one of
+    them."""
     op_name = _OP_NAME.search(line)
-    if op_name is not None:
-        for component in _COMPONENT.finditer(op_name.group(1)):
-            if component.group(1) in ROLLOUT_SCOPES:
-                return component.group(1)
-    return None
+    if op_name is None:
+        return None
+    found = [c.group(1) for c in _COMPONENT.finditer(op_name.group(1)) if c.group(1) in names]
+    if not found:
+        return None
+    return found[0] if names is ROLLOUT_SCOPES else found[-1]
 
 
 def _most_named(scopes, names) -> Optional[str]:
@@ -95,12 +119,16 @@ def _most_named(scopes, names) -> Optional[str]:
     return named.most_common(1)[0][0] if named else None
 
 
-def instruction_scopes(hlo_text: str, *, inherit: bool = True) -> Dict[str, Optional[str]]:
+def instruction_scopes(
+    hlo_text: str, *, inherit: bool = True, names=ROLLOUT_SCOPES
+) -> Dict[str, Optional[str]]:
     """``{instruction name: scope or None}`` for every ``%name = ...`` line of
     every computation in ``compiled.as_text()``. The scope is the OUTERMOST
     component of the instruction's ``op_name`` path that is
     ``evotorch_tpu.<member of ROLLOUT_SCOPES>``; an instruction without
-    metadata, or with no such component, has none of its own.
+    metadata, or with no such component, has none of its own. With
+    ``names=FORWARD_SCOPES`` it is the INNERMOST component among those names
+    (the parts of a forward, which sit inside ``policy_forward``).
 
     ``inherit`` (default): the compiler makes instructions of its own, without
     metadata: the root of a fusion (a convert, a copy, a bitcast), the async
@@ -123,7 +151,7 @@ def instruction_scopes(hlo_text: str, *, inherit: bool = True) -> Dict[str, Opti
                 members = computations.setdefault(header.group(1), {})
             continue
         name, rest = instruction.groups()
-        scopes[name] = _named_scope(rest)
+        scopes[name] = _named_scope(rest, names)
         if members is not None:
             opcode = _OPCODE.search(rest)
             members[name] = (opcode.group(1) if opcode else None, rest)

@@ -37,6 +37,28 @@ class _FunctionalWrapper:
             grad, self._length, self._dtype, about=f"{type(self).__name__}.ascent"
         )
 
+    #: the attributes that hold the optimizer's arrays
+    _STATE: tuple = ()
+
+    def state(self) -> tuple:
+        return tuple(getattr(self, name) for name in self._STATE)
+
+    def load_state(self, state) -> None:
+        for name, value in zip(self._STATE, state):
+            setattr(self, name, value)
+
+    def pure_ascent(self, state, grad):
+        """``ascent`` as a pure function: ``(state, grad) -> (step, new
+        state)``, the object left as it was. For a caller that runs the step
+        inside a compiled program of its own, with the state's buffers
+        donated, and stores the result with ``load_state``."""
+        saved = self.state()
+        self.load_state(state)
+        try:
+            return self.ascent(grad), self.state()
+        finally:
+            self.load_state(saved)
+
 
 class ClipUp(_FunctionalWrapper):
     """The ClipUp optimizer (Toklu et al. 2020; reference
@@ -47,6 +69,7 @@ class ClipUp(_FunctionalWrapper):
     _param_group_items = {"lr": "_stepsize", "max_speed": "_max_speed", "momentum": "_momentum"}
     _param_group_item_lb = {"lr": 0.0, "max_speed": 0.0, "momentum": 0.0}
     _param_group_item_ub = {"momentum": 1.0}
+    _STATE = ("_velocity",)
 
     def __init__(
         self,
@@ -131,6 +154,8 @@ class ClipUpParameterGroup(Mapping):
 class Adam(_FunctionalWrapper):
     """Adam with ``ascent`` semantics (reference ``optimizers.py:101-170``)."""
 
+    _STATE = ("_m", "_v", "_t")
+
     def __init__(
         self,
         *,
@@ -179,6 +204,8 @@ class Adam(_FunctionalWrapper):
 class SGD(_FunctionalWrapper):
     """SGD (optionally with momentum) with ``ascent`` semantics
     (reference ``optimizers.py:173-229``)."""
+
+    _STATE = ("_velocity",)
 
     def __init__(
         self,

@@ -112,7 +112,7 @@ def _constrain_population(values, mesh: Mesh):
     inside a jitted program. Low-rank batches shard their per-lane
     coefficients and replicate the shared center/basis (the factored analog
     of ``vecrl._params_shard_spec``). Trunk-delta batches additionally pin
-    their L-sized trunk arrays (flat center + materialized effective basis)
+    their L-sized trunk array (the flat center)
     to the ``model`` axis when the mesh has one — STORAGE sharding (ZeRO
     style): XLA all-gathers the trunk at its use sites, which is
     value-exact, so scores stay bit-identical to the unsharded program
@@ -130,7 +130,6 @@ def _constrain_population(values, mesh: Mesh):
         )
         return TrunkDeltaParamsBatch(
             center=jax.lax.with_sharding_constraint(values.center, trunk),
-            basis=jax.lax.with_sharding_constraint(values.basis, trunk),
             coeffs=jax.lax.with_sharding_constraint(
                 values.coeffs, NamedSharding(mesh, spec)
             ),
