@@ -30,12 +30,15 @@ Conventions
 
 from __future__ import annotations
 
+import functools
+from types import SimpleNamespace
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = [
     "BodyState",
@@ -195,20 +198,599 @@ class System(NamedTuple):
 
 
 # ---------------------------------------------------------------------------
-# Dynamics — population-minor ("batch-trailing") formulation
+# Dynamics — one implementation, on per-body per-component rows
 # ---------------------------------------------------------------------------
 #
-# TPU vector registers are (8 sublanes x 128 lanes) tiles over the two
-# minor-most axes. Arrays shaped (popsize, nb, 3) — what `vmap` over a
-# single-env step produces — put 3 elements in the 128-lane axis: ~2% lane
-# utilization, and the rollout loop carry materializes that padding every
-# substep. The engine therefore computes natively on *batch-trailing* arrays
-# (nb, 3, B): the population axis fills the lanes, the component axis sits in
-# sublanes, and all body gathers/scatters become static row selections /
-# one-hot einsum contractions (dense matmuls). Measured on a v5e, this layout
-# is >10x faster than the vmap layout for the same loop-carried arithmetic.
-# The single-instance API (`physics_step` etc.) is the B=1 special case, so
-# there is exactly one implementation of the dynamics.
+# The state of a population is batch-trailing, ``(nb, comp, B)``: the
+# population fills the 128 lanes of a TPU vector register. The arithmetic of
+# a substep is written ONCE (``_substep_rows``) as plain elementwise
+# operations on *rows*: one array per body and component, picked with static
+# Python indices. Joint endpoints pick rows, the scatter of a joint's wrench
+# onto its two bodies is the two additions it stands for, and every model
+# constant is a Python float, so a structural zero (an anchor on an axis, a
+# locked DOF, identity joint axes) costs nothing. Such a function does not
+# care what shape a row has, and two callers hand it rows:
+#
+# - the plain form (``_plain_step``): rows are ``(B,)`` slices under ``jit``.
+#   The CPU, small populations and the single-instance API (its ``B = 1``
+#   case) run it, and so does the benchmark's plain reference;
+# - the fused form (``_fused_step``): one Pallas kernel over blocks of 1,024
+#   lanes, in which a row is one full ``(8, 128)`` register. A block's state
+#   and actions are read from HBM once, all ``substeps`` substeps run on it
+#   with every intermediate in registers or VMEM, and the new state is
+#   written once. XLA cut the same control step into ~3,500 small fusions
+#   with a pass through memory between them (PERF.md, PR 29).
+#
+# ``physics_step_batched`` chooses by what it can observe: the fused form
+# where the program is lowered for a TPU and there is at least one block of
+# lanes, the plain form everywhere else. No flag selects either.
+
+_LANES = 128  # a vector register holds (8 sublanes, 128 lanes) of float32
+_BLOCK = 8 * _LANES  # lanes a kernel block steps: every row one full register
+FUSED_KERNEL_NAME = "rigidbody_fused_step"
+
+
+class _Row:
+    """A row under arithmetic. Operators and the few functions below bind
+    ``lax`` primitives directly: ``jnp``'s operators trace a jitted ufunc per
+    call (0.2-0.7 ms each), and at ~4,500 operations a substep, traced once
+    per form and program, that was tens of seconds of a run's set-up on the
+    chip's host (PERF.md, PR 29). The other operand is a row or a Python
+    number, and a model constant that is exactly 0 or +-1 costs no operation:
+    ``row * 0.0`` is the Python ``0.0``, which the next operator drops in
+    turn. Comparisons give rows of booleans for ``_where``."""
+
+    __slots__ = ("x",)
+
+    def __init__(self, x):
+        self.x = x
+
+    def __add__(self, other):
+        return self if _is_zero(other) else _Row(lax.add(self.x, _raw(other)))
+
+    def __sub__(self, other):
+        return self if _is_zero(other) else _Row(lax.sub(self.x, _raw(other)))
+
+    def __rsub__(self, other):
+        return -self if _is_zero(other) else _Row(lax.sub(other, self.x))
+
+    def __mul__(self, other):
+        if _is_zero(other):
+            return 0.0
+        if isinstance(other, float) and abs(other) == 1.0:
+            return self if other > 0 else -self
+        return _Row(lax.mul(self.x, _raw(other)))
+
+    def __truediv__(self, other):
+        return _Row(lax.div(self.x, _raw(other)))
+
+    def __rtruediv__(self, other):
+        return _Row(lax.div(other, self.x))
+
+    def __neg__(self):
+        return _Row(lax.neg(self.x))
+
+    def __lt__(self, other):
+        return _Row(lax.lt(self.x, _raw(other)))
+
+    def __gt__(self, other):
+        return _Row(lax.gt(self.x, _raw(other)))
+
+    def __ge__(self, other):
+        return _Row(lax.ge(self.x, _raw(other)))
+
+    __radd__, __rmul__ = __add__, __mul__
+
+
+def _is_zero(value) -> bool:
+    return isinstance(value, (int, float)) and value == 0
+
+
+def _raw(value):
+    return value.x if isinstance(value, _Row) else value
+
+
+def _where(mask: _Row, a, b) -> _Row:
+    """``a`` where ``mask`` else ``b``; either may be a Python float."""
+    a, b = _raw(a), _raw(b)
+    if isinstance(a, float):
+        a = lax.full_like(b, a)
+    elif isinstance(b, float):
+        b = lax.full_like(a, b)
+    return _Row(lax.select(mask.x, a, b))
+
+
+def _maximum(a: _Row, b) -> _Row:
+    return _Row(lax.max(a.x, _raw(b)))
+
+
+def _minimum(a: _Row, b) -> _Row:
+    return _Row(lax.min(a.x, _raw(b)))
+
+
+def _clip(a: _Row, lo: float, hi: float) -> _Row:
+    return _Row(lax.clamp(lo, a.x, hi))
+
+
+def _sqrt(a: _Row) -> _Row:
+    return _Row(lax.sqrt(a.x))
+
+
+def _abs(a: _Row) -> _Row:
+    return _Row(lax.abs(a.x))
+
+
+def _atan2(s: _Row, w: _Row) -> _Row:
+    return _Row(lax.atan2(s.x, w.x))
+
+
+def _dot(a, b):
+    return sum(x * y for x, y in zip(a, b))
+
+
+def _cross(a, b):
+    return [
+        a[1] * b[2] - a[2] * b[1],
+        a[2] * b[0] - a[0] * b[2],
+        a[0] * b[1] - a[1] * b[0],
+    ]
+
+
+def _rotate(R, v):
+    return [_dot(R[i], v) for i in range(3)]
+
+
+def _rotate_inv(R, v):
+    return [_dot((R[0][i], R[1][i], R[2][i]), v) for i in range(3)]
+
+
+def _rotation_rows(q):
+    """The nine rows of a body's rotation matrix from its quaternion rows.
+    Built once per substep: ~8 vectors are rotated per body (joint anchors,
+    relative angular velocities, torques, contact offsets, the body-frame
+    angular update) at 15 operations each instead of 30."""
+    w, x, y, z = q
+    xx, yy, zz = x * x, y * y, z * z
+    xy, xz, yz = x * y, x * z, y * z
+    wx, wy, wz = w * x, w * y, w * z
+    return [
+        [1.0 - 2.0 * (yy + zz), 2.0 * (xy - wz), 2.0 * (xz + wy)],
+        [2.0 * (xy + wz), 1.0 - 2.0 * (xx + zz), 2.0 * (yz - wx)],
+        [2.0 * (xz - wy), 2.0 * (yz + wx), 1.0 - 2.0 * (xx + yy)],
+    ]
+
+
+def _atan2_first_quadrant(s, w):
+    """float32 ``atan2(s, w)`` for ``s >= 0`` and ``w >= 0``, from operations
+    a Pallas TPU kernel can lower (``jnp.arctan2`` is not one): the range
+    reduction and the odd polynomial of Cephes' ``atanf``, which libm's and
+    XLA's own expansions use too. ``atan(lo / hi)`` on ``[0, 1]``, moved by
+    ``pi / 4`` above ``tan(pi / 8)`` with one division for both cases, and
+    mirrored about ``pi / 4`` where ``s > w``. Within a few ulp of
+    ``jnp.arctan2`` on the whole quadrant, edges included
+    (``tests/test_rigidbody_fused.py``)."""
+    hi = _maximum(s, w)
+    lo = _minimum(s, w)
+    shifted = lo > hi * 0.41421356237309503
+    num = _where(shifted, lo - hi, lo)
+    den = _where(shifted, lo + hi, hi)
+    t = num / _maximum(den, 1e-37)  # atan2(0, 0) = 0
+    z = t * t
+    poly = (
+        ((z * 8.05374449538e-2 - 1.38776856032e-1) * z + 1.99777106478e-1) * z
+        - 3.33329491539e-1
+    ) * z * t + t
+    angle = _where(shifted, poly + 0.7853981633974483, poly)
+    return _where(s > w, 1.5707963267948966 - angle, angle)
+
+
+class _Model(SimpleNamespace):
+    """A ``System`` as the row form reads it: every array a nested list of
+    Python numbers, so model constants are literals of the traced program
+    and of the kernel (a float32 is held exactly by a Python float). Hashes
+    by content: two instances of one env (a problem and its frozen twin)
+    share the programs cached below."""
+
+    def __init__(self, sys: System):
+        values = {}
+        for name, value in jax.device_get(sys._asdict()).items():  # one transfer
+            if isinstance(value, np.ndarray):
+                if value.dtype.kind == "f":
+                    value = value.astype(np.float64)
+                value = value.tolist()
+            values[name] = value
+        super().__init__(**values)
+        self._content = repr(sorted(values.items()))
+
+    def __hash__(self):
+        return hash(self._content)
+
+    def __eq__(self, other):
+        return self._content == other._content
+
+
+@functools.lru_cache(maxsize=64)
+def _substep_program(m: _Model, h: float, in_kernel: bool):
+    """One substep on rows, jitted: a caller's loop body is then ONE traced
+    call, and the ~4,500 operations behind it are traced once per model and
+    row shape in the process. In the kernel a row is always ``(8, 128)``, so
+    every program after the first (another lane count, the frozen twin of a
+    problem) reuses the trace; tracing them per program inside a Pallas
+    kernel cost 3 s each on the chip's host (PERF.md, PR 29)."""
+    atan2 = _atan2_first_quadrant if in_kernel else _atan2
+    return jax.jit(lambda rows, actions: _substep_rows(m, rows, actions, h, atan2))
+
+
+def _joint_wrenches(m, j, pos, quat, vel, ang, R, actions, atan2, force, torque):
+    """Joint ``j``'s constraint, limit and actuation wrench, added onto the
+    ``force`` / ``torque`` rows of its two bodies."""
+    p, c = m.joint_parent[j], m.joint_child[j]
+    Rp = R[p]
+
+    # positional constraint: pull the two anchor points together
+    ra = _rotate(Rp, m.anchor_p[j])  # world lever arms
+    rb = _rotate(R[c], m.anchor_c[j])
+    va = _cross(ang[p], ra)
+    vb = _cross(ang[c], rb)
+    fj = []
+    for k in range(3):
+        err = (pos[c][k] + rb[k]) - (pos[p][k] + ra[k])
+        verr = (vel[c][k] + vb[k]) - (vel[p][k] + va[k])
+        fj.append(-m.pos_k[j] * err - m.pos_c[j] * verr)
+    tb = _cross(rb, fj)
+    ta = _cross(ra, fj)
+
+    # angular: relative rotation conj(parent) * child, as a rotation vector
+    # along the shortest arc, in the parent's frame
+    aw, ax, ay, az = quat[p]
+    bw, bx, by, bz = quat[c]
+    w = aw * bw + ax * bx + ay * by + az * bz
+    x = aw * bx - ax * bw - ay * bz + az * by
+    y = aw * by + ax * bz - ay * bw - az * bx
+    z = aw * bz - ax * by + ay * bx - az * bw
+    s = _sqrt(x * x + y * y + z * z)
+    angle = 2.0 * atan2(s, _abs(w))
+    # angle/s -> 2/w as s -> 0; keep the division finite everywhere
+    scale = _where(s < 1e-7, 2.0, angle / _maximum(s, 1e-12))
+    scale = _where(w < 0.0, -scale, scale)  # q and -q are one rotation
+    phi = [x * scale, y * scale, z * scale]
+    w_rel = _rotate_inv(Rp, [ang[c][k] - ang[p][k] for k in range(3)])
+
+    # components along the (orthonormal) joint axes; since the axes form a
+    # complete basis, the whole angular response is expressed per component,
+    # which lets every axis carry its own gain (a thigh's inertia about its
+    # long axis is ~6x smaller than across it — shared gains would put the
+    # twist axis past the explicit-integration stability bound)
+    comp_torque = []
+    for a in range(3):
+        phi_a = _dot(m.axes[j][a], phi)
+        w_a = _dot(m.axes[j][a], w_rel)
+        free, gear = m.free[j][a], m.gear[j][a]
+        lo, hi = m.limit_lo[j][a], m.limit_hi[j][a]
+        torque_a = 0.0
+        if free != 1.0:  # a locked axis: spring and damper toward the reference
+            locked_t = -m.ang_k[j][a] * phi_a - m.ang_c[j][a] * w_a
+            torque_a = (1.0 - free) * locked_t
+        if free != 0.0:  # a free axis: limits, passive tone, damping, actuation
+            over = _maximum(phi_a - hi, 0.0)
+            under = _maximum(lo - phi_a, 0.0)
+            free_t = (
+                m.limit_k[j][a] * (under - over)
+                - m.tone_k[j][a] * phi_a
+                - m.joint_damping[j][a] * w_a
+            )
+            index = m.act_index[j][a]
+            drive = actions[index] if index < m.num_act else 0.0
+            if m.act_mode != "position":
+                free_t = free_t + gear * drive
+            elif gear > 0.0:
+                # action in [-1, 1] maps to a target angle: 0 is the reference
+                # pose, +/-1 the joint limits; a torque-clipped PD servo tracks it
+                target = 0.0
+                if not _is_zero(drive):
+                    target = _where(drive >= 0.0, drive * hi, -drive * lo)
+                pd = m.act_kp[j][a] * (target - phi_a) - m.act_kd[j][a] * w_a
+                free_t = free_t + _clip(pd, -gear, gear)
+            torque_a = torque_a + free * free_t
+        comp_torque.append(torque_a)
+    axes_t = [[m.axes[j][a][k] for a in range(3)] for k in range(3)]
+    tau_w = _rotate(Rp, [_dot(axes_t[k], comp_torque) for k in range(3)])
+
+    for k in range(3):  # force on child, reaction on parent
+        force[c][k] = force[c][k] + fj[k]
+        force[p][k] = force[p][k] - fj[k]
+        torque[c][k] = torque[c][k] + tb[k] + tau_w[k]
+        torque[p][k] = torque[p][k] - ta[k] - tau_w[k]
+
+
+def _contact_wrench(m, i, pos, vel, ang, R, force, torque):
+    """Sphere ``i`` against the ground: penalty normal force and clamped
+    viscous friction, added onto its body's ``force`` / ``torque`` rows."""
+    b, radius = m.sph_body[i], m.sph_radius[i]
+    r_off = _rotate(R[b], m.sph_offset[i])
+    pen = radius - (pos[b][2] + r_off[2])
+    rel = [r_off[0], r_off[1], r_off[2] - radius]  # the sphere's lowest point
+    spin = _cross(ang[b], rel)
+    vc = [vel[b][k] + spin[k] for k in range(3)]
+
+    fn = _maximum(m.contact_k * pen - m.contact_c * vc[2], 0.0)
+    fn = _where(pen > 0.0, fn, 0.0)
+    vt_norm = _sqrt(vc[0] * vc[0] + vc[1] * vc[1])
+    # clamped viscous friction: viscous at small slip, Coulomb cap mu*N above
+    ft_mag = _minimum(m.friction_mu * fn, m.tangent_damping * vt_norm)
+    slip = ft_mag / _maximum(vt_norm, 1e-6)
+    fc = [-(vc[0] * slip), -(vc[1] * slip), fn]
+    tc = _cross(rel, fc)
+    for k in range(3):
+        force[b][k] = force[b][k] + fc[k]
+        torque[b][k] = torque[b][k] + tc[k]
+
+
+def _substep_rows(m, state, actions, h: float, atan2):
+    """One semi-implicit Euler substep on rows. ``state`` is ``(pos, quat,
+    vel, ang)``, each a list over bodies of lists over components; a row and
+    each of the ``num_act`` ``actions`` is an array of one common shape, which
+    this function never looks at: it wraps each in a ``_Row`` and hands the
+    arrays back. ``atan2(s, w)`` takes and gives rows, and is asked for
+    ``s, w >= 0`` only."""
+    pos, quat, vel, ang = jax.tree_util.tree_map(_Row, state)
+    actions = [_Row(a) for a in actions]
+    nb = len(pos)
+    # per-body rotation matrices, built ONCE and shared by every rotation in
+    # the substep (joints, contacts, body-frame angular update)
+    R = [_rotation_rows(q) for q in quat]
+    force = [[0.0, 0.0, 0.0] for _ in range(nb)]
+    torque = [[0.0, 0.0, 0.0] for _ in range(nb)]
+    for j in range(len(m.joint_parent)):
+        _joint_wrenches(m, j, pos, quat, vel, ang, R, actions, atan2, force, torque)
+    for i in range(len(m.sph_body)):
+        _contact_wrench(m, i, pos, vel, ang, R, force, torque)
+
+    new_pos, new_quat, new_vel, new_ang = [], [], [], []
+    for b in range(nb):
+        # stability clamps: cap velocities so stiff-spring transients cannot blow up
+        v = [
+            _clip(
+                vel[b][k] + (h / m.mass[b]) * force[b][k] + h * m.gravity[k],
+                -m.max_vel,
+                m.max_vel,
+            )
+            for k in range(3)
+        ]
+        # angular update in the body frame, where the inertia tensor is diagonal
+        w_body = _rotate_inv(R[b], ang[b])
+        tau_body = _rotate_inv(R[b], torque[b])
+        gyro = _cross(w_body, [m.inertia[b][k] * w_body[k] for k in range(3)])
+        w_body = [
+            w_body[k] + (h / m.inertia[b][k]) * (tau_body[k] - gyro[k]) for k in range(3)
+        ]
+        w = [_clip(x, -m.max_ang, m.max_ang) for x in _rotate(R[b], w_body)]
+
+        # first-order quaternion update from the world-frame angular velocity
+        qw, qx, qy, qz = quat[b]
+        half = 0.5 * h
+        q = [
+            qw + half * (-w[0] * qx - w[1] * qy - w[2] * qz),
+            qx + half * (w[0] * qw + w[1] * qz - w[2] * qy),
+            qy + half * (w[1] * qw + w[2] * qx - w[0] * qz),
+            qz + half * (w[2] * qw + w[0] * qy - w[1] * qx),
+        ]
+        inv_norm = 1.0 / _sqrt(q[0] * q[0] + q[1] * q[1] + q[2] * q[2] + q[3] * q[3])
+        new_pos.append([pos[b][k] + h * v[k] for k in range(3)])
+        new_quat.append([x * inv_norm for x in q])
+        new_vel.append(v)
+        new_ang.append(w)
+    return jax.tree_util.tree_map(_raw, (new_pos, new_quat, new_vel, new_ang))
+
+
+def _to_rows(st: BodyState):
+    return tuple([[x[b, k] for k in range(x.shape[1])] for b in range(x.shape[0])] for x in st)
+
+
+def _from_rows(rows) -> BodyState:
+    return BodyState(*(jnp.stack([jnp.stack(body) for body in field]) for field in rows))
+
+
+def physics_substep_batched(
+    sys: System, st: BodyState, actions: jnp.ndarray, h
+) -> BodyState:
+    """One semi-implicit Euler substep for a population: ``st`` arrays are
+    ``(nb, comp, B)``, ``actions`` ``(num_act, B)``. The plain form."""
+    rows = _substep_rows(_Model(sys), _to_rows(st), list(actions), h, _atan2)
+    return _from_rows(rows)
+
+
+@functools.lru_cache(maxsize=32)
+def _plain_program(m: _Model, h: float, substeps: int):
+    """The plain form's control step for one model: ``substeps`` substeps as
+    a loop over rows. Jitted once, so that an eager caller (the
+    single-instance API in a host loop) does not trace anew at every call;
+    and with a batching rule of its own, so that ``vmap`` over instances (the
+    ``B = 1`` API under ``vmap``: the benchmark's plain reference) puts the
+    instances into the lane axis and steps them as the SAME plain program at
+    ``B = N``, where re-interpreting a substep's traced operations under
+    ``vmap`` cost the reference's set-up tens of seconds (PERF.md, PR 29)."""
+    substep = _substep_program(m, h, False)
+
+    @jax.custom_batching.custom_vmap
+    def step(st, actions):
+        act = [actions[i] for i in range(actions.shape[0])]
+        # the loop carries the rows, not the stacked state
+        rows = jax.lax.fori_loop(0, substeps, lambda _, rows: substep(rows, act), _to_rows(st))
+        return _from_rows(rows)
+
+    @step.def_vmap
+    def instances_into_lanes(axis_size, in_batched, st, actions):
+        def fold(x, batched):  # (N, ..., B) -> (..., N * B)
+            if not batched:
+                x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+            return jnp.moveaxis(x, 0, -2).reshape(x.shape[1:-1] + (-1,))
+
+        def unfold(x):  # (..., N * B) -> (N, ..., B)
+            return jnp.moveaxis(x.reshape(x.shape[:-1] + (axis_size, -1)), -2, 0)
+
+        st = BodyState(*(fold(x, b) for x, b in zip(st, in_batched[0])))
+        out = program(st, fold(actions, in_batched[1]))
+        return BodyState(*(unfold(x) for x in out)), BodyState(True, True, True, True)
+
+    program = jax.jit(step)
+    return program
+
+
+def _plain_step(sys: System, st: BodyState, actions, h: float, substeps: int) -> BodyState:
+    return _plain_program(_Model(sys), h, substeps)(st, actions)
+
+
+def _fused_lanes(lanes: int) -> int:
+    """Lanes the kernel's grid computes for ``lanes`` useful ones: whole blocks."""
+    return -(-lanes // _BLOCK) * _BLOCK
+
+
+def _fused_local(sys, st, actions, h, substeps, interpret=False):
+    """The fused form on the lanes one device holds: relayout into rows of
+    whole ``(8, 128)`` registers, one kernel over blocks of lanes, and back.
+    The tail block is padded with zeros; its lanes are never written back."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    substep = _substep_program(_Model(sys), h, True)
+    B = actions.shape[-1]
+    padded = _fused_lanes(B)
+    sizes = [x.shape[0] * x.shape[1] for x in st]
+    n_state, n_act = sum(sizes), actions.shape[0]
+    rows = jnp.concatenate([x.reshape(-1, B) for x in st] + [actions], axis=0)
+    rows = jnp.pad(rows, ((0, 0), (0, padded - B)))
+    rows = rows.reshape(n_state + n_act, padded // _LANES, _LANES)
+
+    # rows in the order of the concatenate above: field, body, component
+    rows_of = jax.tree_util.tree_structure(
+        tuple([[0] * x.shape[1] for _ in range(x.shape[0])] for x in st)
+    )
+
+    def kernel(in_ref, out_ref):
+        act = [in_ref[n_state + i] for i in range(n_act)]
+        rows = rows_of.unflatten([in_ref[r] for r in range(n_state)])
+        rows = jax.lax.fori_loop(0, substeps, lambda _, rows: substep(rows, act), rows)
+        for r, row in enumerate(jax.tree_util.tree_leaves(rows)):
+            out_ref[r] = row
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(padded // _BLOCK,),
+        in_specs=[pl.BlockSpec((n_state + n_act, 8, _LANES), lambda g: (0, g, 0))],
+        out_specs=pl.BlockSpec((n_state, 8, _LANES), lambda g: (0, g, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_state, padded // _LANES, _LANES), rows.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        # the useful and the computed lanes, where the compiled program's text
+        # shows them (benchmark/layer_metrics/env.fused_lanes_share.py)
+        name=f"{FUSED_KERNEL_NAME}_{B}_of_{padded}",
+        interpret=interpret,
+    )(rows)
+    fields = jnp.split(out.reshape(n_state, padded)[:, :B], np.cumsum(sizes)[:-1].tolist())
+    return BodyState(*(rows.reshape(x.shape) for rows, x in zip(fields, st)))
+
+
+def _fused_step(sys, st, actions, h, substeps, interpret=False):
+    """The fused form on whatever devices the population is spread over. The
+    SPMD partitioner cannot split a kernel and would gather every lane onto
+    every device around one. Lanes are independent, so where the program is
+    traced under a mesh (``parallel/evaluate.py`` traces its GSPMD programs
+    under ``jax.sharding.use_abstract_mesh``; the population lies over all of
+    its axes), a ``shard_map`` over the lane axis has every device step the
+    lanes it holds: no collective is added."""
+    from jax.sharding import AxisType, PartitionSpec
+
+    def local(st, actions):
+        return _fused_local(sys, st, actions, h, substeps, interpret)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    axes = tuple(
+        name
+        for name, kind in zip(mesh.axis_names, mesh.axis_types)
+        if kind == AxisType.Auto and mesh.shape[name] > 1
+    )
+    shards = int(np.prod([mesh.shape[name] for name in axes]))
+    if shards == 1 or actions.shape[-1] % shards:
+        return local(st, actions)
+    lanes = PartitionSpec(None, None, axes)
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(BodyState(lanes, lanes, lanes, lanes), PartitionSpec(None, axes)),
+        out_specs=BodyState(lanes, lanes, lanes, lanes),
+        check_vma=False,
+    )(st, actions)
+
+
+def _by_platform(fused, plain, *args):
+    """``fused`` where the program is lowered for a TPU, ``plain`` elsewhere.
+    In a process whose default backend is the TPU that is known while
+    tracing, and the plain form is not traced at all: a substep's ~4,500
+    operations take seconds to trace on the chip's host, per form and row
+    shape (PERF.md, PR 29). Elsewhere the lowering decides, so a compile for
+    a described TPU from a CPU host holds the kernel and a CPU run the plain
+    form."""
+    if jax.default_backend() == "tpu":
+        return fused(*args)
+    return jax.lax.platform_dependent(*args, tpu=fused, default=plain)
+
+
+def physics_step_batched(
+    sys: System, st: BodyState, actions: jnp.ndarray, dt: float, substeps: int
+) -> BodyState:
+    """One control step = ``substeps`` substeps with the action held: ``st``
+    arrays are ``(nb, comp, B)``, ``actions`` ``(num_act, B)``. One kernel
+    where the program is lowered for a TPU and ``B`` fills at least one block
+    of lanes (``_fused_step``), the plain form everywhere else."""
+    h, substeps = dt / substeps, int(substeps)
+    fits = (
+        st.pos.ndim == 3
+        and actions.shape[-1] >= _BLOCK
+        and all(x.dtype == jnp.float32 for x in (*st, actions))
+    )
+    if not fits:
+        return _plain_step(sys, st, actions, h, substeps)
+    return _by_platform(
+        lambda st, actions: _fused_step(sys, st, actions, h, substeps),
+        lambda st, actions: _plain_step(sys, st, actions, h, substeps),
+        st,
+        actions,
+    )
+
+
+# -- single-instance API: the B=1 special case ------------------------------
+
+
+def _to_batched(st: BodyState) -> BodyState:
+    return BodyState(*(x[..., None] for x in st))
+
+
+def _from_batched(st: BodyState) -> BodyState:
+    return BodyState(*(x[..., 0] for x in st))
+
+
+def physics_substep(sys: System, st: BodyState, actions: jnp.ndarray, h) -> BodyState:
+    """One semi-implicit Euler substep for all bodies (single instance)."""
+    out = physics_substep_batched(sys, _to_batched(st), actions[..., None], h)
+    return _from_batched(out)
+
+
+def physics_step(
+    sys: System, st: BodyState, actions: jnp.ndarray, dt: float, substeps: int
+) -> BodyState:
+    """One control step = ``substeps`` physics substeps with the action held."""
+    out = physics_step_batched(
+        sys, _to_batched(st), actions[..., None], dt, substeps
+    )
+    return _from_batched(out)
+
+
+# ---------------------------------------------------------------------------
+# Measurements (observations)
+# ---------------------------------------------------------------------------
+
+
+# batch-trailing quaternion helpers (component axis -2) of the measurements
 
 
 def _bcross(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -257,237 +839,6 @@ def _bquat_to_rotvec(q: jnp.ndarray) -> jnp.ndarray:
     angle = 2.0 * jnp.arctan2(s, w)
     scale = jnp.where(s < 1e-7, 2.0, angle / jnp.maximum(s, 1e-12))
     return xyz * scale[..., None, :]
-
-
-def _bquat_integrate(q: jnp.ndarray, omega_world: jnp.ndarray, h) -> jnp.ndarray:
-    zero = jnp.zeros_like(omega_world[..., :1, :])
-    omega_q = jnp.concatenate([zero, omega_world], axis=-2)
-    q_new = q + 0.5 * h * _bquat_mul(omega_q, q)
-    return q_new / jnp.sqrt(jnp.sum(q_new * q_new, axis=-2, keepdims=True))
-
-
-def _bquat_to_mat(q: jnp.ndarray) -> jnp.ndarray:
-    """Rotation matrices ``(..., 3, 3, B)`` from quaternions ``(..., 4, B)``.
-
-    The substep rotates ~8 vectors per body quat (joint anchors, relative
-    angular velocities, torques, contact offsets, the body-frame angular
-    update): building the matrix once (~20 flops) and applying it at 15
-    flops/vector halves the rotation arithmetic vs the 30-flop quat-rotate
-    formula — the substep is VPU-flop/fusion bound (the r2b
-    arithmetic, ROADMAP S2), so this is a direct attack on the dominant cost."""
-    w, x, y, z = q[..., 0, :], q[..., 1, :], q[..., 2, :], q[..., 3, :]
-    xx, yy, zz = x * x, y * y, z * z
-    xy, xz, yz = x * y, x * z, y * z
-    wx, wy, wz = w * x, w * y, w * z
-    one = jnp.ones_like(w)
-    r0 = jnp.stack((one - 2 * (yy + zz), 2 * (xy - wz), 2 * (xz + wy)), axis=-2)
-    r1 = jnp.stack((2 * (xy + wz), one - 2 * (xx + zz), 2 * (yz - wx)), axis=-2)
-    r2 = jnp.stack((2 * (xz - wy), 2 * (yz + wx), one - 2 * (xx + yy)), axis=-2)
-    return jnp.stack((r0, r1, r2), axis=-3)
-
-
-def _bmat_rotate(R: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
-    """Apply ``(..., 3, 3, B)`` rotation matrices to ``(..., 3, B)`` vectors."""
-    return jnp.sum(R * v[..., None, :, :], axis=-2)
-
-
-def _bmat_rotate_inv(R: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
-    """Apply the transposed (inverse) rotations."""
-    return jnp.sum(R * v[..., :, None, :], axis=-3)
-
-
-def _one_hot(idx: np.ndarray, n: int, dtype) -> jnp.ndarray:
-    """Static selection matrix (len(idx), n); body scatters become matmuls."""
-    return jnp.asarray(np.eye(n, dtype=np.float32)[np.asarray(idx)], dtype=dtype)
-
-
-def _scatter_bodies(hot: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
-    """Accumulate per-joint/per-sphere wrenches ``(nj, 3, B)`` onto bodies
-    ``(nb, 3, B)`` via a dense one-hot contraction (TPU scatters serialize;
-    a (nb, nj) x (nj, 3B) matmul does not)."""
-    return jnp.einsum("jb,jkB->bkB", hot, v)
-
-
-def _joint_forces_batched(sys: System, st: BodyState, actions: jnp.ndarray, R: jnp.ndarray):
-    """Per-joint constraint + limit + actuation wrenches for a whole
-    population: state arrays ``(nb, comp, B)``, actions ``(num_act, B)``,
-    ``R`` the per-body rotation matrices (built once per substep).
-    Returns force/torque accumulators ``(nb, 3, B)``."""
-    p, c = sys.joint_parent, sys.joint_child
-    pq, cq = st.quat[p], st.quat[c]  # (nj, 4, B) — static row gathers
-    Rp, Rc = R[p], R[c]
-    pp, cp = st.pos[p], st.pos[c]
-    pv, cv = st.vel[p], st.vel[c]
-    pw, cw = st.ang[p], st.ang[c]
-
-    # --- positional constraint: pull the two anchor points together
-    ra = _bmat_rotate(Rp, sys.anchor_p[:, :, None])  # world lever arms
-    rb = _bmat_rotate(Rc, sys.anchor_c[:, :, None])
-    err = (cp + rb) - (pp + ra)
-    verr = (cv + _bcross(cw, rb)) - (pv + _bcross(pw, ra))
-    fj = -sys.pos_k[:, None, None] * err - sys.pos_c[:, None, None] * verr
-
-    nb = st.pos.shape[0]
-    dtype = st.pos.dtype
-    c_hot = _one_hot(c, nb, dtype)
-    p_hot = _one_hot(p, nb, dtype)
-    inc = c_hot - p_hot  # force on child, reaction on parent
-    f = _scatter_bodies(inc, fj)
-    tau = _scatter_bodies(c_hot, _bcross(rb, fj)) - _scatter_bodies(
-        p_hot, _bcross(ra, fj)
-    )
-
-    # --- angular: relative rotation decomposed onto the joint axes
-    q_rel = _bquat_mul(_bquat_conj(pq), cq)
-    phi = _bquat_to_rotvec(q_rel)  # (nj, 3, B), parent frame
-    w_rel = _bmat_rotate_inv(Rp, cw - pw)
-
-    # components along the (orthonormal) joint axes; since the axes form a
-    # complete basis, the whole angular response is expressed per component,
-    # which lets every axis carry its own gain (a thigh's inertia about its
-    # long axis is ~6x smaller than across it — shared gains would put the
-    # twist axis past the explicit-integration stability bound)
-    phi_comp = jnp.einsum("jak,jkB->jaB", sys.axes, phi)  # (nj, 3, B)
-    w_comp = jnp.einsum("jak,jkB->jaB", sys.axes, w_rel)
-
-    limit_hi = sys.limit_hi[:, :, None]
-    limit_lo = sys.limit_lo[:, :, None]
-    gear = sys.gear[:, :, None]
-    over = jnp.maximum(phi_comp - limit_hi, 0.0)
-    under = jnp.maximum(limit_lo - phi_comp, 0.0)
-    act = jnp.concatenate(
-        [actions, jnp.zeros((1,) + actions.shape[1:], dtype=actions.dtype)]
-    )
-    drive = act[sys.act_index]  # (nj, 3, B); 0 for unactuated axes
-    actuated = (gear > 0.0).astype(dtype)
-    if sys.act_mode == "position":
-        # action in [-1, 1] maps to a target angle: 0 is the reference pose,
-        # +/-1 the joint limits; a torque-clipped PD servo tracks it
-        target = jnp.where(drive >= 0.0, drive * limit_hi, -drive * limit_lo)
-        pd = sys.act_kp[:, :, None] * (target - phi_comp) - sys.act_kd[:, :, None] * w_comp
-        act_torque = actuated * jnp.clip(pd, -gear, gear)
-    else:
-        act_torque = gear * drive
-    free = sys.free[:, :, None]
-    locked = 1.0 - free
-    comp_torque = locked * (
-        -sys.ang_k[:, :, None] * phi_comp - sys.ang_c[:, :, None] * w_comp
-    ) + free * (
-        sys.limit_k[:, :, None] * (under - over)
-        - sys.tone_k[:, :, None] * phi_comp
-        - sys.joint_damping[:, :, None] * w_comp
-        + act_torque
-    )
-    tau_j = jnp.einsum("jak,jaB->jkB", sys.axes, comp_torque)
-
-    tau_w = _bmat_rotate(Rp, tau_j)  # parent frame -> world
-    tau = tau + _scatter_bodies(inc, tau_w)
-    return f, tau
-
-
-def _contact_forces_batched(sys: System, st: BodyState, R: jnp.ndarray):
-    """Sphere-vs-ground penalty contacts with clamped viscous friction,
-    population-batched (``(ns, 3, B)`` intermediates)."""
-    b = sys.sph_body
-    dtype = st.pos.dtype
-    r_off = _bmat_rotate(R[b], sys.sph_offset[:, :, None])
-    pen = sys.sph_radius[:, None] - (st.pos[b][..., 2, :] + r_off[..., 2, :])
-    in_contact = pen > 0.0
-
-    # velocity of the lowest point of each sphere
-    e_z = jnp.asarray([0.0, 0.0, 1.0], dtype=dtype)[:, None]
-    rel = r_off - sys.sph_radius[:, None, None] * e_z
-    vc = st.vel[b] + _bcross(st.ang[b], rel)
-
-    fn = jnp.maximum(sys.contact_k * pen - sys.contact_c * vc[..., 2, :], 0.0)
-    fn = jnp.where(in_contact, fn, 0.0)
-
-    vt = vc * jnp.asarray([1.0, 1.0, 0.0], dtype=dtype)[:, None]
-    vt_norm = jnp.sqrt(vt[..., 0, :] ** 2 + vt[..., 1, :] ** 2)
-    # clamped viscous friction: viscous at small slip, Coulomb cap mu*N above
-    ft_mag = jnp.minimum(sys.friction_mu * fn, sys.tangent_damping * vt_norm)
-    ft = -vt * (ft_mag / jnp.maximum(vt_norm, 1e-6))[..., None, :]
-    fc = ft + fn[..., None, :] * e_z
-
-    nb = st.pos.shape[0]
-    s_hot = _one_hot(b, nb, dtype)
-    f = _scatter_bodies(s_hot, fc)
-    tau = _scatter_bodies(s_hot, _bcross(rel, fc))
-    return f, tau
-
-
-def physics_substep_batched(
-    sys: System, st: BodyState, actions: jnp.ndarray, h
-) -> BodyState:
-    """One semi-implicit Euler substep for a population: ``st`` arrays are
-    ``(nb, comp, B)``, ``actions`` ``(num_act, B)``."""
-    # per-body rotation matrices, built ONCE and shared by every rotation in
-    # the substep (joints, contacts, body-frame angular update)
-    R = _bquat_to_mat(st.quat)
-    fj, tj = _joint_forces_batched(sys, st, actions, R)
-    fc, tc = _contact_forces_batched(sys, st, R)
-    mass = sys.mass[:, None, None]
-    f = fj + fc + mass * sys.gravity[None, :, None]
-    tau = tj + tc
-
-    vel = st.vel + h * f / mass
-    # angular update in the body frame, where the inertia tensor is diagonal
-    inertia = sys.inertia[:, :, None]
-    w_body = _bmat_rotate_inv(R, st.ang)
-    tau_body = _bmat_rotate_inv(R, tau)
-    w_body = w_body + h * (tau_body - _bcross(w_body, inertia * w_body)) / inertia
-    ang = _bmat_rotate(R, w_body)
-
-    # stability clamps: cap velocities so stiff-spring transients cannot blow up
-    vel = jnp.clip(vel, -sys.max_vel, sys.max_vel)
-    ang = jnp.clip(ang, -sys.max_ang, sys.max_ang)
-
-    pos = st.pos + h * vel
-    quat = _bquat_integrate(st.quat, ang, h)
-    return BodyState(pos=pos, quat=quat, vel=vel, ang=ang)
-
-
-def physics_step_batched(
-    sys: System, st: BodyState, actions: jnp.ndarray, dt: float, substeps: int
-) -> BodyState:
-    """One control step = ``substeps`` substeps with the action held. Unrolled
-    (``substeps`` is static and small) so XLA can fuse across substeps."""
-    h = dt / substeps
-    for _ in range(int(substeps)):
-        st = physics_substep_batched(sys, st, actions, h)
-    return st
-
-
-# -- single-instance API: the B=1 special case ------------------------------
-
-
-def _to_batched(st: BodyState) -> BodyState:
-    return BodyState(*(x[..., None] for x in st))
-
-
-def _from_batched(st: BodyState) -> BodyState:
-    return BodyState(*(x[..., 0] for x in st))
-
-
-def physics_substep(sys: System, st: BodyState, actions: jnp.ndarray, h) -> BodyState:
-    """One semi-implicit Euler substep for all bodies (single instance)."""
-    out = physics_substep_batched(sys, _to_batched(st), actions[..., None], h)
-    return _from_batched(out)
-
-
-def physics_step(
-    sys: System, st: BodyState, actions: jnp.ndarray, dt: float, substeps: int
-) -> BodyState:
-    """One control step = ``substeps`` physics substeps with the action held."""
-    out = physics_step_batched(
-        sys, _to_batched(st), actions[..., None], dt, substeps
-    )
-    return _from_batched(out)
-
-
-# ---------------------------------------------------------------------------
-# Measurements (observations)
-# ---------------------------------------------------------------------------
 
 
 def joint_angles_batched(sys: System, st: BodyState) -> jnp.ndarray:

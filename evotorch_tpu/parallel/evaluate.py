@@ -22,6 +22,7 @@ strict divisibility errors and per-shard cohort semantics).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from typing import Callable, Optional
@@ -65,6 +66,18 @@ def population_spec(mesh: Mesh) -> P:
     docs/sharding.md)."""
     names = tuple(mesh.axis_names)
     return P(names) if len(names) > 1 else P(names[0])
+
+
+def _population_mesh(mesh: Optional[Mesh]):
+    """The context a GSPMD program traces its evaluation in: what is traced
+    inside can ask ``jax.sharding.get_abstract_mesh()`` which axes the
+    population is spread over. The SPMD partitioner cannot split a Pallas
+    kernel and would gather every lane onto every device around one; a
+    kernel over population lanes (``envs/rigidbody.py``) therefore wraps
+    itself in a ``shard_map`` over those axes. Nothing else reads it."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
 def shard_population(
@@ -327,17 +340,18 @@ def make_resident_rollout_program(
     def _run(values, key, stats, lane_ids, groups, solution_keys):
         if mesh is not None:
             values = _constrain_population(values, mesh)
-        return run_vectorized_rollout(
-            env,
-            policy,
-            values,
-            key,
-            stats,
-            lane_ids=lane_ids,
-            groups=groups,
-            solution_keys=solution_keys,
-            **rollout_kwargs,
-        )
+        with _population_mesh(mesh):
+            return run_vectorized_rollout(
+                env,
+                policy,
+                values,
+                key,
+                stats,
+                lane_ids=lane_ids,
+                groups=groups,
+                solution_keys=solution_keys,
+                **rollout_kwargs,
+            )
 
     # one closure-jitted program: no static arguments at THIS layer means
     # the only thing that can retrace is an aval change — exactly the
@@ -477,15 +491,16 @@ def make_sharded_rollout_evaluator(
             if padded_n != popsize:
                 values = _pad_rows(values, padded_n)
             values = _constrain_population(values, mesh)
-            result = run_vectorized_rollout(
-                env,
-                policy,
-                values,
-                key,
-                stats,
-                num_valid=num_valid,
-                **local_kwargs,
-            )
+            with _population_mesh(mesh):
+                result = run_vectorized_rollout(
+                    env,
+                    policy,
+                    values,
+                    key,
+                    stats,
+                    num_valid=num_valid,
+                    **local_kwargs,
+                )
             if result.telemetry is None:
                 telemetry = jnp.zeros((0,), dtype=jnp.int32)
             else:
@@ -786,15 +801,16 @@ def _generation_body(
         values = ask(k_ask, state)
         evald = _pad_rows(values, padded_n) if padded_n != popsize else values
         evald = _constrain_population(evald, mesh)
-        result = run_vectorized_rollout(
-            env,
-            policy,
-            evald,
-            k_eval,
-            stats,
-            num_valid=num_valid,
-            **rollout_kwargs,
-        )
+        with _population_mesh(mesh):
+            result = run_vectorized_rollout(
+                env,
+                policy,
+                evald,
+                k_eval,
+                stats,
+                num_valid=num_valid,
+                **rollout_kwargs,
+            )
         scores = result.scores[:popsize]
         new_state = tell(state, values, scores)
         if result.telemetry is None:
