@@ -14,7 +14,7 @@ import json
 import time
 from functools import partial
 
-from bench_common import device_record, setup_backend
+from evotorch_tpu.resilience import device_record, setup_backend
 
 
 def _time(fn, *args, iters=200):

@@ -828,13 +828,9 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
         When a sharded evaluator is active (``use_sharded_evaluation`` or
         ``num_actors``) and no interaction budget is set, the pipeline runs
         as one GSPMD program over the mesh — global key, global ranking:
-        the reference's single-process statistics at any mesh shape. Under
-        ``EVOTORCH_SHARD_MAP=1`` it instead reproduces the reference's
-        *exact* distributed statistics (``core.py:3156-3301`` +
-        ``gaussian.py:199-272``): each mesh shard samples its own
-        sub-population, ranks **locally**, computes local gradients, and a
-        ``pmean`` replaces the main-process weighted average (shards are
-        equal-sized, so both weighting conventions coincide).
+        the reference's single-process statistics at any mesh shape (its
+        distributed mode, ``core.py:3156-3301``, where every actor ranks its
+        own sub-population, is a different search and has no form here).
 
         With ``lowrank_rank`` the population is sampled in factored (low-rank)
         form and gradients are computed from the factors in O(L * rank);
@@ -983,9 +979,8 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
         self, distribution, popsize: int, *, obj_index: int, ranking_method, key,
         lowrank_rank: Optional[int] = None,
     ) -> dict:
-        """Sampling/ranking/gradients over the eval mesh — GSPMD global
-        ranking by default, the reference's per-actor local ranking
-        (``core.py:3156-3301``) under ``EVOTORCH_SHARD_MAP=1``."""
+        """Sampling/ranking/gradients over the eval mesh as one GSPMD
+        program with global ranking."""
         from .parallel.grad import make_sharded_grad_estimator
 
         mesh = self._eval_mesh
@@ -1032,11 +1027,8 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
             "mean_eval": aux["mean_eval"],  # device scalar: stays lazy
         }
         if "basis" in aux:
-            # per-shard bases ride out stacked along the pop axis; shard 0's
-            # rows are a representative iid draw for the subspace-exhaustion
-            # diagnostic (every shard's basis is an independent draw at the
-            # same rank, so the capture statistics are exchangeable)
-            result["basis"] = aux["basis"][: self.solution_length]
+            # the global basis, for the subspace-exhaustion diagnostic
+            result["basis"] = aux["basis"]
         return result
 
     # ----------------------------------------------------------------- misc

@@ -14,9 +14,8 @@ Two rollout engines share the vector-env contract:
   lane block, device forward and host physics strictly alternating, each
   solution pinned to one lane for all its episodes. Deliberately kept
   **byte-stable as the PR-2 reference implementation**: the pipelined
-  engine's regression tests compare against it bit-exactly, and it is the
-  "synchronous host path" baseline `bench.py`'s `mj_pipeline_speedup`
-  measures against (`GymNE(host_pipeline="chunked")` routes here).
+  engine's regression tests compare against it bit-exactly
+  (`GymNE(host_pipeline="chunked")` routes here).
 - :func:`run_host_pipelined_rollout` — the Sebulba-style scheduler
   (Podracer, arXiv:2104.06272): the lanes are split into blocks; while the
   device runs the batched policy forward for block A, a host worker thread
@@ -499,7 +498,7 @@ def run_host_pipelined_rollout(
         # measured split for THIS box — observability/timings.py) before
         # the heuristic. Callers that already resolved the group at their
         # own altitude (GymNE) — or that must NOT see tuned configs (the
-        # autotuner's own baseline, bench's BENCH_TUNED=0 path) — pass
+        # autotuner's own baseline) — pass
         # use_tuned_cache=False so the group is resolved exactly once.
         # auto-heuristic: the two-block split only pays when the host
         # physics can genuinely overlap the device forward — on a

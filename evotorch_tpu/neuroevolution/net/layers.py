@@ -461,10 +461,10 @@ class FeedForwardNet(Module):
 
 
 def tanh_mlp(input_size: int, output_size: int, hidden: Sequence) -> Module:
-    """The ``Linear >> Tanh >> ... >> Linear`` policy stack every benchmark
-    surface shares (bench_common's BENCH_HIDDEN policies, the program
-    ledger's gate-shape programs) — ONE builder, so the architecture the
-    perf gate measures cannot drift from the one bench.py benchmarks."""
+    """The ``Linear >> Tanh >> ... >> Linear`` policy stack the program
+    ledger's gate-shape programs, the autotuner, ``chip_smoke.py`` and the
+    examples share — ONE builder, so the architecture the perf gate
+    measures cannot drift from the one the chip runs."""
     sizes = [int(h) for h in hidden]
     if not sizes:
         return Linear(int(input_size), int(output_size))

@@ -843,7 +843,6 @@ def _make_step(
         "action_noise_stdev",
         "compute_dtype",
         "eval_mode",
-        "stats_sync_axis",
         "refill_width",
         "refill_period",
         "seed_stride",
@@ -854,7 +853,6 @@ def _make_step(
         "trunk_block",
         "nonfinite_quarantine",
         "nonfinite_penalty",
-        "nonfinite_sync_axis",
     ),
 )
 def run_vectorized_rollout(
@@ -874,7 +872,6 @@ def run_vectorized_rollout(
     eval_mode: str = "episodes",
     lane_ids=None,
     solution_keys=None,
-    stats_sync_axis: Optional[str] = None,
     refill_width: Optional[int] = None,
     refill_period: int = 1,
     seed_stride: Optional[int] = None,
@@ -886,7 +883,6 @@ def run_vectorized_rollout(
     trunk_block: int = 0,
     nonfinite_quarantine: bool = False,
     nonfinite_penalty: Optional[float] = None,
-    nonfinite_sync_axis: Optional[str] = None,
 ) -> RolloutResult:
     """Evaluate ``N`` policies on ``N`` environments, fully on-device.
 
@@ -895,11 +891,8 @@ def run_vectorized_rollout(
     FINITE score — or the fixed ``nonfinite_penalty`` when given — inside
     the same jitted program, and counts the quarantined solutions in the
     telemetry's ``nonfinite`` slot (per group at G > 1), so one diverged
-    rollout cannot NaN-poison ranking (docs/resilience.md).
-    ``nonfinite_sync_axis`` is for explicit shard_map callers: the
-    worst-finite reduction pmins over that axis so the sharded replacement
-    equals the unsharded one (the GSPMD path needs nothing — its reduction
-    is global by construction).
+    rollout cannot NaN-poison ranking (docs/resilience.md). Under GSPMD
+    the worst-finite reduction is global by construction.
 
     ``trunk_block`` (trunk-delta populations only): static lane-block size
     of the shared-trunk forward — the population batch is chunked into
@@ -922,11 +915,10 @@ def run_vectorized_rollout(
     of the final per-solution mean scores, bit-cast into ``HEALTH_WIDTH``
     extra int32 columns — computed ONCE at program end from the
     post-quarantine scores (no loop-carry cost). ``health=False`` keeps
-    the pre-v4 ``(G, GROUP_TELEMETRY_WIDTH)`` wire byte-compatible (the
-    ``BENCH_HEALTH=0`` escape hatch). Explicit shard_map callers should
-    pass ``health=False`` and append a mesh-global block themselves (see
-    ``parallel/evaluate.py``) — a per-shard block would be garbled by the
-    telemetry psum.
+    the pre-v4 ``(G, GROUP_TELEMETRY_WIDTH)`` wire byte-compatible. The
+    GSPMD evaluators pass ``health=False`` and append one block computed on
+    replicated scores themselves (see ``parallel/evaluate.py``), which keeps
+    the float32 statistics bit-identical across mesh shapes.
 
     ``groups`` / ``num_groups`` (ISSUE 15): per-group telemetry. ``groups``
     is an ``(N,)`` int32 array of group ids in ``[0, num_groups)`` — one per
@@ -962,16 +954,12 @@ def run_vectorized_rollout(
     Randomness is a PER-LANE property: lane ``i``'s PRNG chain is seeded by
     ``fold_in(key, lane_ids[i])`` (default ``lane_ids = arange(N)``) and
     advances with that lane, so realized randomness does not depend on the
-    working width, the batch composition, or the mesh topology. A sharded
-    caller passing each shard's GLOBAL lane ids (and the same ``key``)
-    reproduces the unsharded evaluation bit-for-bit — except under online
-    observation normalization, where each lane is normalized by its
-    cohort's running statistics and sharding changes the cohort (cohort
-    semantics, like the reference's per-actor stats). A sharded caller that
-    additionally passes ``stats_sync_axis`` (its shard_map axis name)
-    psum-merges the stat deltas EVERY STEP, so all shards normalize by the
-    mesh-global cohort and the cohort divergence disappears (at the cost of
-    one tiny collective per control step; ``VecNE(obs_norm_sync="step")``).
+    working width, the batch composition, or the mesh topology. A caller
+    that evaluates a slice of a population and passes the slice's GLOBAL
+    lane ids (and the same ``key``) reproduces those lanes of the whole
+    evaluation bit-for-bit — except under online observation normalization,
+    where each lane is normalized by its cohort's running statistics and a
+    slice is a different cohort.
 
     The logic mirrors ``VecGymNE._evaluate_subbatch``
     (``vecgymne.py:744-916``): one sub-environment per solution, lockstep
@@ -1071,7 +1059,6 @@ def run_vectorized_rollout(
             compute_dtype=compute_dtype,
             lane_ids=lane_ids,
             solution_keys=solution_keys,
-            stats_sync_axis=stats_sync_axis,
             refill_width=refill_width,
             refill_period=refill_period,
             seed_stride=seed_stride,
@@ -1083,7 +1070,6 @@ def run_vectorized_rollout(
             trunk_block=trunk_block,
             nonfinite_quarantine=nonfinite_quarantine,
             nonfinite_penalty=nonfinite_penalty,
-            nonfinite_sync_axis=nonfinite_sync_axis,
         )
     hard_cap = max_t * int(num_episodes) + 1
     budget_mode = eval_mode == "budget"
@@ -1097,7 +1083,6 @@ def run_vectorized_rollout(
         observation_normalization=observation_normalization,
         compute_dtype=compute_dtype,
         lane_ids=lane_ids,
-        stats_sync_axis=stats_sync_axis,
         num_valid=num_valid,
         # episodes-mode padding lanes must look already-finished to the
         # exit condition; budget-mode lanes never finish (masked inactive),
@@ -1117,7 +1102,6 @@ def run_vectorized_rollout(
         action_noise_stdev=action_noise_stdev,
         compute_dtype=compute_dtype,
         budget_mode=budget_mode,
-        stats_sync_axis=stats_sync_axis,
         collect_telemetry=telemetry,
         masked_width=num_valid is not None,
         num_groups=num_groups,
@@ -1135,15 +1119,7 @@ def run_vectorized_rollout(
 
         @_in_scope("contract")
         def cond(c: RolloutCarry):
-            any_active = jnp.any(c.active)
-            if stats_sync_axis is not None:
-                # per-step collectives in the body require every shard to run
-                # the same number of iterations: keep looping while ANY shard
-                # still has an active lane
-                any_active = (
-                    jax.lax.psum(any_active.astype(jnp.int32), stats_sync_axis) > 0
-                )
-            return any_active & (c.t_global < hard_cap)
+            return jnp.any(c.active) & (c.t_global < hard_cap)
 
         final = jax.lax.while_loop(cond, lambda c: step(params_batch, ctx, c), carry)
     # everything below runs once per program, after the loop
@@ -1168,7 +1144,6 @@ def run_vectorized_rollout(
                     else jnp.arange(n_total, dtype=jnp.int32) < num_valid
                 ),
                 penalty=nonfinite_penalty,
-                sync_axis=nonfinite_sync_axis,
             )
         total_episodes = jnp.sum(final.episodes_done)
         if num_valid is not None and not budget_mode:
@@ -1427,7 +1402,6 @@ def _run_refill(
     compute_dtype,
     lane_ids,
     solution_keys,
-    stats_sync_axis,
     refill_width,
     refill_period,
     seed_stride,
@@ -1439,7 +1413,6 @@ def _run_refill(
     trunk_block=0,
     nonfinite_quarantine=False,
     nonfinite_penalty=None,
-    nonfinite_sync_axis=None,
 ) -> RolloutResult:
     """The ``episodes_refill`` evaluation: exact ``episodes`` semantics (each
     solution is scored by the mean return of exactly ``num_episodes``
@@ -1533,8 +1506,6 @@ def _run_refill(
                 new_stats = stats_update(
                     stats, obs0, mask=jnp.ones(width, dtype=bool)
                 )
-            if stats_sync_axis is not None:
-                new_stats = _stats_psum_merge(stats, new_stats, stats_sync_axis)
             stats = new_stats
 
         policy_states0 = _initial_policy_states(policy, width, compute_dtype)
@@ -1774,8 +1745,6 @@ def _run_refill(
                 )
             else:
                 new_stats = stats_update(c.stats, obs_next, mask=active)
-            if observation_normalization and stats_sync_axis is not None:
-                new_stats = _stats_psum_merge(c.stats, new_stats, stats_sync_axis)
 
         with scope("contract"):
             return RefillCarry(
@@ -1817,12 +1786,6 @@ def _run_refill(
         # momentarily idle (all lanes can finish on a step whose refill gate
         # is closed by refill_period)
         any_work = jnp.any(c.active) | (c.next_item < total_items)
-        if stats_sync_axis is not None:
-            # per-step collectives in the body require every shard to run the
-            # same number of iterations (see _make_step)
-            any_work = (
-                jax.lax.psum(any_work.astype(jnp.int32), stats_sync_axis) > 0
-            )
         return any_work & (c.t_global < hard_cap)
 
     final = jax.lax.while_loop(cond, step, carry)
@@ -1838,7 +1801,6 @@ def _run_refill(
                     else jnp.arange(n, dtype=jnp.int32) < nv
                 ),
                 penalty=nonfinite_penalty,
-                sync_axis=nonfinite_sync_axis,
             )
         total_episodes = jnp.sum(final.eps_buf)
         if not telemetry:

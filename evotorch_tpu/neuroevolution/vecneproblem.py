@@ -9,7 +9,7 @@ TPU-first: the environment is a pure-JAX env (``evotorch_tpu.envs``; Brax via
 the gated adapter), and the whole evaluate is ONE jitted program
 (``net/vecrl.py:run_vectorized_rollout``) — no dlpack ping-pong, no Python
 stepping. With ``use_sharded_evaluation()``-style meshes, the population axis
-shards across devices via ``shard_map`` (the rollout being pure makes that a
+shards across devices under GSPMD (the rollout being pure makes that a
 one-liner; see ``evaluate_sharded``).
 """
 
@@ -100,21 +100,21 @@ class VecNE(NEProblem):
         # "episodes" scores (bit-identical; with observation_normalization
         # the masked stat reductions may differ in float summation order
         # only), and WITHOUT observation normalization sharded evaluation is
-        # bit-identical to unsharded. With observation normalization on,
-        # sharding still changes scores semantically under the default
-        # obs_norm_sync="cohort": each lane is normalized by its cohort's
-        # running statistics, and sharding changes the cohort each shard's
-        # stats see mid-rollout (deltas psum-merge only at the end, like the
-        # reference's per-actor stats). obs_norm_sync="step" instead
-        # psum-merges the stat deltas EVERY control step, so all shards
-        # normalize by the mesh-global cohort and the divergence collapses to
-        # float summation order — at the cost of one small collective per
-        # step (measure before defaulting; test_vecrl characterizes both).
+        # bit-identical to unsharded. Over a mesh (GSPMD) the program is the
+        # unsharded one, so the obs-norm cohort is always the mesh-GLOBAL
+        # population and obs_norm_sync is not read. Only "episodes_compact"
+        # over a mesh reads it (its sharded runner is per-shard code): under
+        # the default obs_norm_sync="cohort" each lane is normalized by its
+        # shard's running statistics (deltas psum-merge only at the end, like
+        # the reference's per-actor stats); obs_norm_sync="step" psum-merges
+        # the stat deltas EVERY control step, so all shards normalize by the
+        # mesh-global cohort and the divergence collapses to float summation
+        # order, at the cost of one small collective per step.
         # "episodes_refill" = the same contract again, evaluated by the
         # work-conserving lane-refill scheduler (a fixed lane width kept
         # saturated from an on-device pending-work queue — continuous
         # batching; see net/vecrl.py:_run_refill). One jitted program, so it
-        # also runs INSIDE shard_map on the sharded path. At num_episodes=1
+        # is sharded like any other (GSPMD). At num_episodes=1
         # WITHOUT observation normalization its scores are bit-identical to
         # "episodes" (same per-lane seeding); with obs-norm on, the refill
         # schedule changes the running statistics each lane sees (late-
@@ -170,8 +170,7 @@ class VecNE(NEProblem):
         # search-health plane (docs/observability.md "Search health"): the
         # compiled eval programs append per-group float32 score statistics
         # (count/sum/sumsq/min/max) to the telemetry wire — schema v4.
-        # health_telemetry=False compiles the v3 (health-free) programs,
-        # the library form of the BENCH_HEALTH=0 byte-compat escape hatch
+        # health_telemetry=False compiles the v3 (health-free) programs
         self._health_telemetry = bool(health_telemetry)
         # SLO watchdog (observability/slo.py): declarative rules evaluated
         # against each generation's decoded telemetry; verdicts surface as
@@ -583,12 +582,9 @@ class VecNE(NEProblem):
     def _num_actors_mesh(self, popsize: int):
         """Mesh for a pending ``num_actors`` request. The GSPMD evaluator
         pads an indivisible popsize to the next mesh multiple (the padding
-        lanes are masked), so the request is honored exactly; the paths
-        that still require divisibility (``EVOTORCH_SHARD_MAP=1``, the
-        sharded compact runner) step down to the largest dividing shard
-        count, as before."""
-        from ..parallel.evaluate import _use_shard_map
-
+        lanes are masked), so the request is honored exactly; the sharded
+        compact runner still requires divisibility and steps down to the
+        largest dividing shard count."""
         request = self._num_actors_requested
         if request is None:
             return None
@@ -600,7 +596,7 @@ class VecNE(NEProblem):
         else:
             n = min(int(request), jax.device_count())
         n = max(1, n)
-        if _use_shard_map(None) or self._eval_mode == "episodes_compact":
+        if self._eval_mode == "episodes_compact":
             while popsize % n != 0:
                 n -= 1
         if n <= 1:
@@ -788,9 +784,6 @@ class VecNE(NEProblem):
                 self._policy,
                 mesh=mesh,
                 axis_name=axis_name,
-                stats_sync=(
-                    self._observation_normalization and self._obs_norm_sync == "step"
-                ),
                 **kwargs,
             )
         return evaluator
@@ -800,12 +793,9 @@ class VecNE(NEProblem):
         (``parallel.make_sharded_rollout_evaluator``): the GSPMD form — one
         global program pinned to the mesh layout, bit-identical to the
         unsharded evaluation, popsizes that don't divide the mesh padded
-        and masked, and the obs-norm cohort always mesh-GLOBAL (under
-        ``EVOTORCH_SHARD_MAP=1`` the explicit per-shard form returns, with
-        its strict divisibility and per-shard cohort semantics — the
-        collective analog of the reference's actor delta-sync,
-        ``gymne.py:524-573``, SURVEY.md §2.11). The host-orchestrated
-        ``episodes_compact`` contract keeps its dedicated sharded runner."""
+        and masked, and the obs-norm cohort always mesh-GLOBAL. The
+        host-orchestrated ``episodes_compact`` contract keeps its dedicated
+        sharded runner (strict divisibility, ``obs_norm_sync``)."""
         if mesh is None:
             mesh = default_mesh((axis_name,))
         n_shards = mesh.shape[axis_name]

@@ -47,12 +47,11 @@ Three cooperating pieces (docs/observability.md has the full catalog):
 - :mod:`~evotorch_tpu.observability.metricshub` — streaming export of the
   decoded telemetry + counter registry as schema-versioned JSONL (manifest
   first line) or Prometheus text (``.prom`` suffix); wired to
-  ``EVOTORCH_METRICS=path`` in bench.py and the curve runner.
+  ``EVOTORCH_METRICS=path`` in the curve runner.
 - :mod:`~evotorch_tpu.observability.slo` — declarative SLO watchdog
   (per-group occupancy floor, starvation ceiling off the top queue-wait
   bucket, steady_compiles == 0, min progress) surfaced as searcher status
-  keys (``VecNEProblem(slo=...)``) and a bench-line verdict CLI
-  (``python -m evotorch_tpu.observability.slo --check-bench``).
+  keys (``VecNEProblem(slo=...)``).
 """
 
 from .compilecache import (  # noqa: F401
@@ -75,29 +74,8 @@ from .devicemetrics import (  # noqa: F401
     pack_group_telemetry,
     queue_wait_bucket_index,
 )
-# MetricsHub / SLO / health names resolve lazily (module __getattr__
-# below): an eager `from .slo import ...` here would trip runpy's
-# double-import warning every time the CLI runs as
-# `python -m evotorch_tpu.observability.slo`
-_LAZY_EXPORTS = {
-    "MetricsHub": "metricshub",
-    "Rule": "slo",
-    "SLOReport": "slo",
-    "SLOWatchdog": "slo",
-    "EWMATrend": "health",
-    "HealthMonitor": "health",
-}
-
-
-def __getattr__(name):
-    submodule = _LAZY_EXPORTS.get(name)
-    if submodule is None:
-        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-    import importlib
-
-    value = getattr(importlib.import_module(f".{submodule}", __name__), name)
-    globals()[name] = value
-    return value
+from .health import EWMATrend, HealthMonitor  # noqa: F401
+from .metricshub import MetricsHub  # noqa: F401
 from .programs import (  # noqa: F401
     DonationReport,
     ProgramLedger,
@@ -117,6 +95,7 @@ from .registry import (  # noqa: F401
     ensure_compile_counter,
     ensure_compile_timer,
 )
+from .slo import Rule, SLOReport, SLOWatchdog  # noqa: F401
 from .timings import (  # noqa: F401
     SOURCE_CACHE,
     SOURCE_FALLBACK,

@@ -20,7 +20,6 @@ The loop::
                                               (checked in)    VecNE · GymNE ·
                                                                hostvecenv ·
                                                                parallel.evaluate
-                                                               · bench.py
 
 Three layers:
 
@@ -42,8 +41,9 @@ Three layers:
   candidate config as span args — a tuning run under ``EVOTORCH_TRACE``
   is inspectable in Perfetto next to the ask/eval/tell spans.
 - **The CLI** — ``python -m evotorch_tpu.observability.autotune``:
-  tunes the requested knob groups at bench-compatible shapes (the
-  ``BENCH_*`` env knobs are honored), records every candidate in the
+  tunes the requested knob groups at the shape its arguments give
+  (``--env``, ``--popsize``, ``--episode-length``, ``--hidden``,
+  ``--bf16``), records every candidate in the
   measured-timing ledger, and persists each winner to the tuned-config
   cache (:mod:`~evotorch_tpu.observability.timings`) that the eval stack
   consults at setup time. It requires an accelerator unless the CPU is
@@ -423,7 +423,7 @@ def autotune_search(
 
 @dataclass(frozen=True)
 class TuneShape:
-    """The workload shape a tuning run measures at (bench-compatible)."""
+    """The workload shape a tuning run measures at."""
 
     env_name: str = "humanoid"
     popsize: int = 1024
@@ -1156,7 +1156,7 @@ class SpanHarness(_BespokeHarness):
         if warmup:
             # donated GSPMD programs reach the steady-state layout on the
             # SECOND call — run one more untimed so no compile can land
-            # inside a timed trial (the bench A/B warms the same way)
+            # inside a timed trial
             jax.block_until_ready(scores)
             scores, steps = call(self._next_key())
             jax.block_until_ready(scores)
@@ -1650,43 +1650,24 @@ def _cache_shape(harness) -> Dict[str, Any]:
 
 
 def _shape_from_args(args, use_cpu: bool) -> TuneShape:
-    """The tuning shape, honoring the same BENCH_* knobs with the same
-    defaults as bench_common.bench_config — KEEP THE TWO IN SYNC: a cache
-    hit requires exact (env, popsize, params, dtype) equality, so a
-    default drifting here (or there) silently turns every bench lookup
-    into a fallback. (Duplicated rather than imported: the package must
-    not depend on the repo-root bench scripts.)"""
-    import json as _json
-    import os
-
+    """The tuning shape from the CLI's own arguments. A cache hit requires
+    exact (env, popsize, params, dtype) equality with the consumer's shape,
+    so tune at the shape that will look the entry up."""
     import jax.numpy as jnp
 
     popsize = args.popsize
     if popsize is None:
-        popsize = int(os.environ.get("BENCH_POPSIZE", 1024 if use_cpu else 10_000))
+        popsize = 1024 if use_cpu else 10_000
     episode_length = args.episode_length
     if episode_length is None:
-        episode_length = int(
-            os.environ.get("BENCH_EPISODE_LENGTH", 100 if use_cpu else 200)
-        )
-    hidden_raw = args.hidden or os.environ.get("BENCH_HIDDEN", "64,64")
-    hidden = tuple(int(h) for h in hidden_raw.split(",") if h)
-    env_name = args.env or os.environ.get("BENCH_ENV", "humanoid")
-    env_kwargs = _json.loads(os.environ.get("BENCH_ENV_ARGS", "{}"))
-    if env_kwargs:
-        raise SystemExit(
-            "autotune keys the tuned-config cache by plain env name; "
-            "BENCH_ENV_ARGS would make the entry ambiguous — unset it"
-        )
-    compute_dtype = (
-        jnp.bfloat16 if os.environ.get("BENCH_BF16", "0") == "1" else None
-    )
+        episode_length = 100 if use_cpu else 200
+    hidden = tuple(int(h) for h in (args.hidden or "64,64").split(",") if h)
     return TuneShape(
-        env_name=env_name,
+        env_name=args.env or "humanoid",
         popsize=popsize,
         episode_length=episode_length,
         hidden=hidden,
-        compute_dtype=compute_dtype,
+        compute_dtype=jnp.bfloat16 if args.bf16 else None,
     )
 
 
@@ -1703,7 +1684,7 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m evotorch_tpu.observability.autotune",
         description="Occupancy-driven autotuner: search the eval-schedule "
-        "knobs at bench-compatible shapes, record measured timings, persist "
+        "knobs at the given shape, record measured timings, persist "
         "winners to the tuned-config cache (docs/observability.md).",
     )
     parser.add_argument(
@@ -1714,10 +1695,12 @@ def main(argv=None) -> int:
     )
     parser.add_argument("--cpu", action="store_true",
                         help="force the 8-virtual-device CPU backend")
-    parser.add_argument("--env", default=None, help="env name (BENCH_ENV)")
+    parser.add_argument("--env", default=None, help="env name (default humanoid)")
     parser.add_argument("--popsize", type=int, default=None)
     parser.add_argument("--episode-length", type=int, default=None)
     parser.add_argument("--hidden", default=None, help="comma list, e.g. 64,64")
+    parser.add_argument("--bf16", action="store_true",
+                        help="tune at compute_dtype=bfloat16 (default float32)")
     parser.add_argument("--trials", type=int, default=3,
                         help="timed trials per candidate per round (median "
                         "of >=3 — the CLAUDE.md variance rule)")

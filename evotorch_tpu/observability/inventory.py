@@ -4,7 +4,7 @@ One place declares *which* compiled programs constitute the framework —
 the four eval-contract rollout programs (plus their trunk-delta policy
 forms, ``docs/policies.md``), the sharded evaluator, the gaussian
 functional ask/tell, the batched functional search, and the
-bench/multichip/GSPMD whole-generation steps (dense and trunk-delta) —
+functional and GSPMD whole-generation steps (dense and trunk-delta) —
 so the program ledger
 (:mod:`~evotorch_tpu.observability.programs`), the report CLI and the
 fast-tier perf-regression gate all see the same surface.
@@ -167,7 +167,7 @@ def _trunk_delta_batch(policy, popsize: int, rank: int):
 def _trunk_generation_program(
     env, policy, popsize: int, episode_length: int, rank: int
 ):
-    """The trunk-delta analog of the bench generation: factored ask ->
+    """The trunk-delta analog of ``bench.generation``: factored ask ->
     budget rollout (shared-trunk + per-lane delta forward) -> factored
     tell, one jitted program donating the optimizer state."""
     import jax
@@ -201,7 +201,7 @@ def _trunk_generation_program(
 
 @functools.lru_cache(maxsize=8)
 def _bench_generation_program(env, policy, popsize: int, episode_length: int):
-    """bench.py's monolithic generation: PGPE ask -> budget rollout ->
+    """A functional generation on one device: PGPE ask -> budget rollout ->
     tell, one jitted program donating the optimizer state."""
     import jax
 
@@ -223,61 +223,6 @@ def _bench_generation_program(env, policy, popsize: int, episode_length: int):
         )
         new_state = pgpe_tell(state, values, result.scores)
         return new_state, result.total_steps, result.scores
-
-    return jax.jit(_generation, donate_argnums=(0,))
-
-
-@functools.lru_cache(maxsize=8)
-def _multichip_generation_program(
-    env, policy, mesh_size: int, popsize: int, episode_length: int
-):
-    """bench_multichip.py's generation: the same program shard_mapped over
-    a ("pop",) mesh with psum stat/step merging, state donated."""
-    import jax
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from ..algorithms.functional import pgpe_ask, pgpe_tell
-    from ..neuroevolution.net.vecrl import global_lane_ids, run_vectorized_rollout
-
-    mesh = Mesh(np.asarray(jax.devices()[:mesh_size]), axis_names=("pop",))
-    pop_sharding = NamedSharding(mesh, P("pop"))
-
-    def _local_rollout(values_shard, key, stats):
-        ids = global_lane_ids("pop", values_shard.shape[0])
-        result = run_vectorized_rollout(
-            env,
-            policy,
-            values_shard,
-            key,
-            stats,
-            lane_ids=ids,
-            num_episodes=1,
-            episode_length=episode_length,
-            eval_mode="budget",
-        )
-        delta = jax.tree_util.tree_map(
-            lambda new, old: new - old, result.stats, stats
-        )
-        merged = jax.tree_util.tree_map(
-            lambda old, d: old + jax.lax.psum(d, "pop"), stats, delta
-        )
-        return result.scores, merged, result.total_steps[None]
-
-    sharded = jax.shard_map(
-        _local_rollout,
-        mesh=mesh,
-        in_specs=(P("pop"), P(), P()),
-        out_specs=(P("pop"), P(), P("pop")),
-        check_vma=False,
-    )
-
-    def _generation(state, key, stats):
-        k1, k2 = jax.random.split(key)
-        values = pgpe_ask(k1, state, popsize=popsize)
-        values = jax.lax.with_sharding_constraint(values, pop_sharding)
-        scores, stats, per_shard = sharded(values, k2, stats)
-        return pgpe_tell(state, values, scores), stats, per_shard
 
     return jax.jit(_generation, donate_argnums=(0,))
 
@@ -350,8 +295,7 @@ def capture_compact_chunk(
 ) -> ProgramRecord:
     """Capture the lane-compacting runner's full-width chunk program — the
     dominant cost of the host-orchestrated ``episodes_compact`` contract
-    (the width-descent runs the SAME program at narrower shapes). Shared
-    by the inventory and bench.py so the two cannot drift."""
+    (the width-descent runs the SAME program at narrower shapes)."""
     import jax
     import jax.numpy as jnp
 
@@ -617,21 +561,6 @@ def build_specs(cfg: Optional[GateConfig] = None) -> List[ProgramSpec]:
 
     add("bench.generation.trunk_delta", trunk_shape, trunk_bench_capture)
 
-    def multichip_capture(led):
-        fn = _multichip_generation_program(
-            env, policy, mesh_size, cfg.popsize, cfg.episode_length
-        )
-        return led.capture(
-            "multichip.generation",
-            fn,
-            _abstract(_fresh_pgpe_state(L)),
-            jax.random.key(0),
-            stats,
-            shape=sharded_shape,
-        )
-
-    add("multichip.generation", sharded_shape, multichip_capture)
-
     def gspmd_capture(led):
         fn = _gspmd_generation_program(
             env, policy, mesh_size, cfg.popsize, cfg.episode_length
@@ -721,9 +650,9 @@ def capture_inventory(
 
 def donated_programs(cfg: Optional[GateConfig] = None):
     """``(name, fn, args, donate_argnums)`` for every ``donate_argnums``
-    entry point the repo registers — bench tell, the bench and multichip
-    generation steps, the GSPMD training span, and the batched functional
-    search. Each call builds
+    entry point the repo registers — the gaussian tell, the one-device and
+    GSPMD generation steps, the GSPMD training span, and the batched
+    functional search. Each call builds
     FRESH concrete arguments (the verification executes the program and
     consumes the donated buffers). The dynamic complement of graftlint's
     static ``donation`` checker: these assert XLA *applied* the aliasing."""
@@ -758,14 +687,6 @@ def donated_programs(cfg: Optional[GateConfig] = None):
             "bench.generation.trunk_delta",
             _trunk_generation_program(
                 env, policy, cfg.popsize, cfg.episode_length, cfg.trunk_rank
-            ),
-            (_fresh_pgpe_state(L), jax.random.key(0), stats),
-            (0,),
-        ),
-        (
-            "multichip.generation",
-            _multichip_generation_program(
-                env, policy, mesh_size, cfg.popsize, cfg.episode_length
             ),
             (_fresh_pgpe_state(L), jax.random.key(0), stats),
             (0,),

@@ -17,8 +17,8 @@ The hub never decodes device arrays itself: callers hand it the
 already-decoded :class:`GroupTelemetry` (or plain scalars), so PR 8's
 lag-by-one decode discipline — one metered fetch per generation — is
 preserved; exporting costs zero extra device syncs.  ``MetricsHub.
-from_env()`` wires the ``EVOTORCH_METRICS=path`` knob used by bench.py
-and examples/locomotion_curve.py.
+from_env()`` wires the ``EVOTORCH_METRICS=path`` knob used by
+examples/locomotion_curve.py.
 
 See docs/observability.md "Per-group telemetry & SLOs".
 """
@@ -183,7 +183,7 @@ class MetricsHub:
     def _append_jsonl(self, record: Dict[str, Any]) -> None:
         # crash-safe rows: flush + fsync per line, so a SIGKILL'd run keeps
         # every row already emitted (readers skip at most the partial
-        # trailing line — slo._last_json_line tolerates one)
+        # trailing line)
         with open(self._path, "a", encoding="utf-8") as fh:
             fh.write(json.dumps(record, sort_keys=True))
             fh.write("\n")

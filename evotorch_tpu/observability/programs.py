@@ -44,8 +44,7 @@ Pieces:
   (``python -m evotorch_tpu.observability.report --cpu --write-baseline``
   refreshes it, refusing partial captures).
 
-See docs/observability.md ("Program ledger") for the field catalog and
-bench.py wiring (``BENCH_LEDGER``).
+See docs/observability.md ("Program ledger") for the field catalog.
 """
 
 from __future__ import annotations

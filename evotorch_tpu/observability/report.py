@@ -10,7 +10,8 @@ to the cost-model ceiling (analytic efficiency).
 Modes:
 
 - (default) capture at the fast-tier gate shapes and print the table;
-- ``--flagship`` capture at benchmark scale (Humanoid, BENCH_POPSIZE);
+- ``--flagship`` capture at benchmark scale (Humanoid, popsize 10,000 x 200
+  steps unless ``--popsize`` / ``--episode-length`` say otherwise);
   with ``--json`` it snapshots flagship-shape peak HBM + compile seconds;
 - ``--check`` assert the capture against ``ledger_baseline.json``
   (exit 1 on violations/stale — the CLI form of the tier-1 gate in
@@ -32,7 +33,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import sys
 import time
 from typing import Optional
@@ -81,8 +81,8 @@ def _gate_config(args) -> GateConfig:
     if args.flagship:
         base = GateConfig(
             env_name="humanoid",
-            popsize=int(os.environ.get("BENCH_POPSIZE", 10_000)),
-            episode_length=int(os.environ.get("BENCH_EPISODE_LENGTH", 200)),
+            popsize=10_000,
+            episode_length=200,
             hidden=(64, 64),
             chunk_size=25,
         )
@@ -195,7 +195,7 @@ def main(argv=None) -> int:
                         help="force the 8-virtual-device CPU backend (use for "
                         "baseline writes: matches the pytest mesh)")
     parser.add_argument("--flagship", action="store_true",
-                        help="benchmark-scale shapes (Humanoid, BENCH_POPSIZE)")
+                        help="benchmark-scale shapes (Humanoid, popsize 10,000)")
     parser.add_argument("--env", default=None)
     parser.add_argument("--popsize", type=int, default=None)
     parser.add_argument("--episode-length", type=int, default=None)

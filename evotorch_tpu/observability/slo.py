@@ -21,10 +21,9 @@ contract the multi-tenant eval service and island PBT need:
     every group's (or one group's) env-step count must be >= ``threshold``
     — a starved tenant shows up here even when its occupancy is undefined.
 ``min_model_efficiency``
-    the ``model_efficiency`` status key (the program ledger's achieved
-    fraction of nominal peak FLOPs — a BENCH_LEDGER=1 bench-line column)
-    must be >= ``threshold``. Skipped when the key is absent; per-contract
-    columns are checked by the bench CLI (``--min-model-efficiency``).
+    the ``model_efficiency`` status key (an achieved fraction of nominal
+    peak FLOPs, where a caller puts one in ``status``) must be >=
+    ``threshold``. Skipped when the key is absent.
 ``max_nonfinite_share``
     the share of quarantined (non-finite-scored) solutions must be <=
     ``threshold``. Reads the exact ``eval_nonfinite_share`` status key when
@@ -60,27 +59,14 @@ checkpoint the window state):
     identical) gives infinite SNR and passes.
 
 The watchdog surfaces as searcher status keys (``slo_ok`` /
-``slo_violations`` / ``slo_detail``) via ``VecNEProblem(slo=...)``, and as
-a bench-line verdict via the CLI::
-
-    python -m evotorch_tpu.observability.slo --check-bench bench.log \
-        --verdict-out slo_verdict.txt
-
-which reads the LAST JSON line of a bench log (the bench.py output
-contract), applies the default rules (steady_compiles == 0 plus a
-global occupancy floor), writes a one-word ``pass``/``fail`` verdict
-file, prints a JSON verdict line, and exits 0/1 — or 2
-("insufficient") when the log has no decodable JSON line or the line
-carries none of the checked keys (a BENCH_TELEMETRY=0 line): missing data
-is distinguishable from failing data. A partial trailing line (crashed
-writer) is skipped, never a traceback.
+``slo_violations`` / ``slo_detail``) via ``VecNEProblem(slo=...)`` and as
+the evaluation server's per-dispatch verdict (``serving/server.py``).
 
 See docs/observability.md "Per-group telemetry & SLOs".
 """
 
 from __future__ import annotations
 
-import json
 from dataclasses import dataclass, field
 from typing import Any, Dict, Iterable, Optional, Tuple, Union
 
@@ -92,7 +78,6 @@ __all__ = [
     "RULE_KINDS",
     "SLOReport",
     "SLOWatchdog",
-    "DEFAULT_BENCH_RULES",
 ]
 
 
@@ -352,262 +337,3 @@ DEFAULT_RULES: Tuple[Rule, ...] = (
     Rule("starvation_ceiling", threshold=0.5),
     Rule("min_progress", threshold=1),
 )
-
-#: verdict defaults for ``--check-bench``: the flagship bench line
-#: must be retrace-free and show a sane primary-mode occupancy
-DEFAULT_BENCH_RULES: Tuple[Rule, ...] = (
-    Rule("no_steady_compiles"),
-    Rule("occupancy_floor", threshold=0.1),
-)
-
-
-# ---------------------------------------------------------------- bench CLI
-def _score_snr(mean: float, std: float) -> float:
-    """|mean| / std; infinite when the spread is exactly zero."""
-    return float("inf") if float(std) <= 0.0 else abs(float(mean)) / float(std)
-
-
-def check_bench_line(
-    line: Dict[str, Any],
-    *,
-    occupancy_floor: float = 0.1,
-    min_model_efficiency: Optional[float] = None,
-    max_nonfinite_share: Optional[float] = None,
-    max_score_collapse: Optional[float] = None,
-    min_score_snr: Optional[float] = None,
-    max_queue_wait_p99: Optional[float] = None,
-) -> SLOReport:
-    """Apply the verdict rules to one decoded bench.py JSON line.
-
-    The bench line carries scalars, not a (G, K) matrix, so this reads the
-    top-level ``occupancy`` / ``steady_compiles`` keys (plus per-mode
-    occupancies under ``modes``) directly. With ``min_model_efficiency``
-    set, the program-ledger efficiency columns (``model_efficiency``,
-    top-level and per contract under ``modes`` — present when the line was
-    produced with BENCH_LEDGER=1) must each clear the floor; a line with
-    no ledger columns skips those checks (missing analysis degrades, it
-    doesn't fail).
-
-    The health-plane flags read the ``score_mean`` / ``score_std`` columns
-    (present when the line was produced with BENCH_HEALTH=1, the default):
-    ``max_score_collapse`` fails when the score SNR ``|mean| / std``
-    EXCEEDS the ceiling (the population's spread collapsed below 1/T of
-    its mean scale — stdev-collapse seen from the score side);
-    ``min_score_snr`` fails when the SNR is below the floor (the scores
-    are noise-dominated). Lines without the columns skip both.
-
-    ``max_queue_wait_p99`` gates the tail of the refill queue-wait
-    distribution (in loop steps, from the on-device histograms): the
-    top-level ``queue_wait_p99``, every per-mode one under ``modes``, and
-    the serving A/B's ``serve_queue_wait_p99`` (a BENCH_SERVE=1 line) must
-    each stay at or below the ceiling — the multi-tenant fairness gate.
-    Lines without the columns skip the check.
-    """
-    violations = []
-    checked = 0
-
-    def _check_queue_wait(value, label):
-        nonlocal checked
-        if max_queue_wait_p99 is None or value is None:
-            return
-        checked += 1
-        if float(value) > max_queue_wait_p99:
-            violations.append(
-                f"{label}queue_wait_p99={float(value):g} > {max_queue_wait_p99:g}"
-            )
-
-    _check_queue_wait(line.get("queue_wait_p99"), "")
-    _check_queue_wait(line.get("serve_queue_wait_p99"), "serve_")
-    compiles = line.get("steady_compiles")
-    if compiles is not None:
-        checked += 1
-        if int(compiles) > 0:
-            violations.append(f"steady_compiles={int(compiles)} (expected 0)")
-    occ = line.get("occupancy")
-    if occ is not None:
-        checked += 1
-        if float(occ) < occupancy_floor:
-            violations.append(f"occupancy={float(occ):.3f} < {occupancy_floor:g}")
-    nfs = line.get("eval_nonfinite_share")
-    if max_nonfinite_share is not None and nfs is not None:
-        checked += 1
-        if float(nfs) > max_nonfinite_share:
-            violations.append(
-                f"eval_nonfinite_share={float(nfs):.3f} > {max_nonfinite_share:g}"
-            )
-    eff = line.get("model_efficiency")
-    if min_model_efficiency is not None and eff is not None:
-        checked += 1
-        if float(eff) < min_model_efficiency:
-            violations.append(
-                f"model_efficiency={float(eff):.4g} < {min_model_efficiency:g}"
-            )
-
-    def _check_health(mean, std, label):
-        nonlocal checked
-        if mean is None or std is None:
-            return
-        snr = _score_snr(mean, std)
-        if max_score_collapse is not None:
-            checked += 1
-            if snr > max_score_collapse:
-                violations.append(
-                    f"{label}score_snr={snr:.3g} > {max_score_collapse:g} "
-                    "(score spread collapsed)"
-                )
-        if min_score_snr is not None:
-            checked += 1
-            if snr < min_score_snr:
-                violations.append(
-                    f"{label}score_snr={snr:.3g} < {min_score_snr:g}"
-                )
-
-    _check_health(line.get("score_mean"), line.get("score_std"), "")
-    modes = line.get("modes") or {}
-    for mode, rec in sorted(modes.items()):
-        if not isinstance(rec, dict):
-            continue
-        mocc = rec.get("occupancy")
-        if mocc is not None:
-            checked += 1
-            if float(mocc) < occupancy_floor:
-                violations.append(
-                    f"modes.{mode}.occupancy={float(mocc):.3f} < {occupancy_floor:g}"
-                )
-        meff = rec.get("model_efficiency")
-        if min_model_efficiency is not None and meff is not None:
-            checked += 1
-            if float(meff) < min_model_efficiency:
-                violations.append(
-                    f"modes.{mode}.model_efficiency={float(meff):.4g} < "
-                    f"{min_model_efficiency:g}"
-                )
-        _check_health(
-            rec.get("score_mean"), rec.get("score_std"), f"modes.{mode}."
-        )
-        _check_queue_wait(rec.get("queue_wait_p99"), f"modes.{mode}.")
-    return SLOReport(ok=not violations, violations=tuple(violations), checked=checked)
-
-
-def _last_json_line(path: str) -> Optional[Dict[str, Any]]:
-    """The last decodable JSON line of the log, or None when there is none.
-
-    A crashed writer leaves a partial trailing line; that (and any other
-    non-JSON noise) is skipped, not raised — the last COMPLETE line wins.
-    """
-    last = None
-    with open(path, "r", encoding="utf-8") as fh:
-        for raw in fh:
-            raw = raw.strip()
-            if not raw or not raw.startswith("{"):
-                continue
-            try:
-                last = json.loads(raw)
-            except json.JSONDecodeError:  # partial/corrupt row — skip it
-                continue
-    return last
-
-
-def _main(argv=None) -> int:
-    import argparse
-
-    parser = argparse.ArgumentParser(
-        description="SLO watchdog: verdict over a bench.py JSON log"
-    )
-    parser.add_argument(
-        "--check-bench",
-        metavar="LOG",
-        required=True,
-        help="bench log; the LAST JSON line is checked",
-    )
-    parser.add_argument(
-        "--occupancy-floor",
-        type=float,
-        default=0.1,
-        help="minimum acceptable occupancy, global and per mode (default 0.1)",
-    )
-    parser.add_argument(
-        "--min-model-efficiency",
-        type=float,
-        default=None,
-        help="minimum acceptable program-ledger model_efficiency, global "
-        "and per contract (default: unchecked; needs a BENCH_LEDGER=1 line)",
-    )
-    parser.add_argument(
-        "--max-nonfinite-share",
-        type=float,
-        default=None,
-        help="maximum acceptable eval_nonfinite_share (quarantined share of "
-        "the population; default: unchecked)",
-    )
-    parser.add_argument(
-        "--max-score-collapse",
-        type=float,
-        default=None,
-        help="maximum acceptable score SNR |score_mean|/score_std, global "
-        "and per contract — above it the population spread has collapsed "
-        "(default: unchecked; needs a BENCH_HEALTH=1 line)",
-    )
-    parser.add_argument(
-        "--min-score-snr",
-        type=float,
-        default=None,
-        help="minimum acceptable score SNR |score_mean|/score_std — below "
-        "it the scores are noise-dominated (default: unchecked)",
-    )
-    parser.add_argument(
-        "--max-queue-wait-p99",
-        type=float,
-        default=None,
-        help="maximum acceptable refill queue-wait p99 (loop steps), "
-        "top-level, per contract and for the serving A/B "
-        "(default: unchecked; needs histogrammed refill events)",
-    )
-    parser.add_argument(
-        "--verdict-out",
-        metavar="PATH",
-        default=None,
-        help="write a one-word pass/fail verdict file",
-    )
-    args = parser.parse_args(argv)
-
-    line = _last_json_line(args.check_bench)
-    if line is None:
-        report = SLOReport(ok=False, violations=(), checked=0)
-    else:
-        report = check_bench_line(
-            line,
-            occupancy_floor=args.occupancy_floor,
-            min_model_efficiency=args.min_model_efficiency,
-            max_nonfinite_share=args.max_nonfinite_share,
-            max_score_collapse=args.max_score_collapse,
-            min_score_snr=args.min_score_snr,
-            max_queue_wait_p99=args.max_queue_wait_p99,
-        )
-    if report.checked == 0:
-        # no decodable line, or a line with none of the checked keys (e.g.
-        # BENCH_TELEMETRY=0): missing data is not a pass and not a fail
-        verdict, code = "insufficient", 2
-    elif report.ok:
-        verdict, code = "pass", 0
-    else:
-        verdict, code = "fail", 1
-    if args.verdict_out:
-        with open(args.verdict_out, "w", encoding="utf-8") as fh:
-            fh.write(verdict + "\n")
-    print(
-        json.dumps(
-            {
-                "slo_verdict": verdict,
-                "slo_checked": report.checked,
-                "slo_violations": list(report.violations),
-                "source": args.check_bench,
-            },
-            sort_keys=True,
-        )
-    )
-    return code
-
-
-if __name__ == "__main__":
-    raise SystemExit(_main())

@@ -29,7 +29,7 @@ Three pieces:
   cache; a cache hit overrides the built-in fallback** — and every
   consumer reports which branch fired as a ``tuned_config_source``
   provenance key (``"override"`` / ``"cache"`` / ``"fallback"``) so a
-  bench line or status row always says where its schedule came from.
+  status row always says where its schedule came from.
 
 The file format is append-friendly JSON (one entry per
 ``(group, shape, machine)`` key, last write wins) and the checked-in
@@ -483,8 +483,7 @@ def resolve_knobs(
       the cache is not consulted at all — ``"override"``;
     - else a cache hit supplies the tuned config — ``"cache"``;
     - else the empty config: the caller's built-in default applies —
-      ``"fallback"`` (also the forced branch under ``use_cache=False``,
-      e.g. ``BENCH_TUNED=0``)."""
+      ``"fallback"`` (also the forced branch under ``use_cache=False``)."""
     passed = {k: v for k, v in explicit.items() if v is not None}
     if passed:
         return passed, SOURCE_OVERRIDE

@@ -8,14 +8,12 @@ with GSPMD over a ``jax.sharding.Mesh`` (``docs/sharding.md``):
 
 - population evaluation  -> the GLOBAL program jitted once, population rows
   pinned to the mesh with ``NamedSharding`` / ``with_sharding_constraint``;
-  XLA's SPMD partitioner inserts the collectives (the explicit
-  ``shard_map`` + ``psum`` form survives behind ``EVOTORCH_SHARD_MAP=1``);
+  XLA's SPMD partitioner inserts the collectives;
 - whole generations      -> ``make_generation_step``: ask -> rollout -> tell
   as ONE donated-buffer program (steady-state HBM = one generation's live
   set, verified by the program ledger);
 - ES-gradient estimation -> global sample/rank/grad under GSPMD (the
-  reference's single-process semantics at any popsize; the compat knob keeps
-  the per-actor local-ranking form, ``gaussian.py:246-271``);
+  reference's single-process semantics at any popsize);
 - obs-norm stat merging  -> the global program's cohort IS the mesh-global
   population — see ``neuroevolution.net.runningnorm``;
 - multi-host             -> ``jax.distributed.initialize`` over DCN +
@@ -32,7 +30,6 @@ from .mesh import (
     device_count,
     make_mesh,
     mesh_label,
-    parse_mesh_shape,
 )
 from .evaluate import (
     make_generation_step,
@@ -52,7 +49,6 @@ __all__ = [
     "device_count",
     "make_mesh",
     "mesh_label",
-    "parse_mesh_shape",
     "make_generation_step",
     "make_sharded_evaluator",
     "make_sharded_rollout_evaluator",

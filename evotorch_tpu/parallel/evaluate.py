@@ -7,24 +7,18 @@ program, the ``(N, L)`` population is pinned to the mesh's population layout
 with ``NamedSharding`` / ``with_sharding_constraint``, and XLA's SPMD
 partitioner inserts the collectives — no pickling, no RPC, and no hand-written
 per-shard wiring (the per-lane PRNG chains, the obs-stat delta psums and the
-counter collectives of the old ``shard_map`` path all become compiler
-business). The global program IS the single-device program, so sharded
+counter collectives all become compiler business). The global program IS
+the single-device program, so sharded
 evaluation is bit-identical to unsharded at any mesh shape (1-D ``pop`` or
 2-D ``pop x model``), and popsizes that don't divide the mesh are padded
 with first-row copies and masked via the engine's ``num_valid`` contract
 (``docs/sharding.md``).
-
-The pre-GSPMD explicit ``shard_map`` path is kept behind
-``use_shard_map=True`` / ``EVOTORCH_SHARD_MAP=1`` (the compat knob for A/B
-measurement — ``BENCH_SPMD=ab`` in ``bench_multichip.py``; it keeps the old
-strict divisibility errors and per-shard cohort semantics).
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-import os
 from typing import Callable, Optional
 
 import jax
@@ -46,14 +40,6 @@ __all__ = [
     "population_spec",
     "shard_population",
 ]
-
-
-def _use_shard_map(flag: Optional[bool]) -> bool:
-    """Resolve the compat knob: explicit argument wins, else the
-    ``EVOTORCH_SHARD_MAP=1`` environment toggle (default GSPMD)."""
-    if flag is None:
-        return os.environ.get("EVOTORCH_SHARD_MAP", "0") == "1"
-    return bool(flag)
 
 
 def population_spec(mesh: Mesh) -> P:
@@ -167,7 +153,6 @@ def make_sharded_evaluator(
     *,
     mesh: Optional[Mesh] = None,
     axis_name: str = "pop",
-    use_shard_map: Optional[bool] = None,
 ) -> Callable:
     """Wrap a vectorized fitness function ``f(values (n,L)) -> (n,) | (n,K)``
     into a jitted evaluator that shards the population axis over the mesh.
@@ -176,15 +161,11 @@ def make_sharded_evaluator(
     their first row and the padding results are discarded (the analog of the
     reference's uneven ``split_workload``, ``tools/misc.py:1113``).
 
-    Default GSPMD: the function is traced once globally and the population is
+    GSPMD: the function is traced once globally and the population is
     pinned to ``population_spec(mesh)`` — XLA partitions the computation.
-    ``use_shard_map=True`` (or ``EVOTORCH_SHARD_MAP=1``) keeps the explicit
-    per-shard ``shard_map`` form.
     """
     if mesh is None:
         mesh = default_mesh((axis_name,))
-    if _use_shard_map(use_shard_map):
-        return _shard_map_evaluator(fitness_func, mesh=mesh, axis_name=axis_name)
 
     n_grid = _mesh_grid_size(mesh)
     sharding = NamedSharding(mesh, population_spec(mesh))
@@ -201,32 +182,6 @@ def make_sharded_evaluator(
     return evaluator
 
 
-def _shard_map_evaluator(fitness_func, *, mesh, axis_name):
-    """The pre-GSPMD explicit form (compat knob)."""
-    n_shards = mesh.shape[axis_name]
-
-    def local_eval(values_shard):
-        return fitness_func(values_shard)
-
-    @jax.jit
-    def evaluator(values):
-        n = values.shape[0]
-        padded_n = -(-n // n_shards) * n_shards
-        padded = _pad_rows(values, padded_n) if padded_n != n else values
-        out_struct = jax.eval_shape(fitness_func, padded)
-        out_specs = jax.tree_util.tree_map(lambda _: P(axis_name), out_struct)
-        result = jax.shard_map(
-            local_eval,
-            mesh=mesh,
-            in_specs=P(axis_name),
-            out_specs=out_specs,
-            check_vma=False,
-        )(padded)
-        return jax.tree_util.tree_map(lambda r: r[:n], result)
-
-    return evaluator
-
-
 def _normalize_kind(kind) -> str:
     """Accept the historical boolean ``lowrank`` flag on the
     ``program_builder`` surface and map it onto the kind tags
@@ -236,13 +191,7 @@ def _normalize_kind(kind) -> str:
     return str(kind)
 
 
-_RESERVED_ROLLOUT_KWARGS = {
-    "lane_ids",
-    "stats_sync_axis",
-    "seed_stride",
-    "num_valid",
-    "nonfinite_sync_axis",
-}
+_RESERVED_ROLLOUT_KWARGS = {"lane_ids", "seed_stride", "num_valid"}
 
 
 def _check_reserved(rollout_kwargs, what: str):
@@ -382,32 +331,21 @@ def make_sharded_rollout_evaluator(
     *,
     mesh: Optional[Mesh] = None,
     axis_name: str = "pop",
-    stats_sync: bool = False,
-    use_shard_map: Optional[bool] = None,
     **rollout_kwargs,
 ):
     """Shard the monolithic rollout engine
     (``neuroevolution.net.vecrl.run_vectorized_rollout``) over the mesh —
     the reusable form of the sharded-evaluation recipe (``dryrun_multichip``
-    and ``VecNE._evaluate_all`` call it; ``bench_multichip`` carries the A/B
-    harness over both forms).
+    and ``VecNE.evaluate_sharded`` call it).
 
-    Default GSPMD: the GLOBAL rollout program is jitted once, the population
+    GSPMD: the GLOBAL rollout program is jitted once, the population
     pinned to ``population_spec(mesh)`` (all mesh axes flattened over the
     population rows), and XLA partitions the loop — the program IS the
     unsharded program, so scores are bit-identical to single-device at any
-    mesh shape, the obs-norm cohort is always the mesh-GLOBAL population
-    (``stats_sync`` is moot here: per-shard cohorts were an artifact of the
-    explicit per-shard wiring), and popsizes that don't divide the mesh are
-    padded with first-row copies whose lanes are masked out of score credit
-    and every counter/telemetry slot via the engine's ``num_valid`` contract.
-
-    ``use_shard_map=True`` (or ``EVOTORCH_SHARD_MAP=1``) selects the
-    pre-GSPMD explicit path: per-shard ``run_vectorized_rollout`` calls with
-    GLOBAL lane ids, psum'd stat deltas/counters/telemetry, per-shard refill
-    queues (``refill_width`` divided across the 1-D mesh; raises when an
-    explicit width is not divisible), ``stats_sync`` selecting per-step vs
-    end-of-rollout stat merges, and strict popsize divisibility.
+    mesh shape, the obs-norm cohort is always the mesh-GLOBAL population,
+    and popsizes that don't divide the mesh are padded with first-row copies
+    whose lanes are masked out of score credit and every counter/telemetry
+    slot via the engine's ``num_valid`` contract.
 
     Refill evaluations with NO explicit knobs consult the tuned-config cache
     (``observability/timings.py``) per popsize — the autotuner's measured
@@ -425,15 +363,6 @@ def make_sharded_rollout_evaluator(
     _check_reserved(rollout_kwargs, "make_sharded_rollout_evaluator")
     if mesh is None:
         mesh = default_mesh((axis_name,))
-    if _use_shard_map(use_shard_map):
-        return _shard_map_rollout_evaluator(
-            env,
-            policy,
-            mesh=mesh,
-            axis_name=axis_name,
-            stats_sync=stats_sync,
-            **rollout_kwargs,
-        )
 
     # imported lazily: parallel.* must stay importable before neuroevolution
     from ..neuroevolution.net.vecrl import (
@@ -558,193 +487,6 @@ def make_sharded_rollout_evaluator(
     )[0]
     # provenance of the LAST dispatched popsize's refill knobs ("override" /
     # "cache" / "fallback"; None before the first refill-mode dispatch)
-    evaluator.tuned_config_source = None
-    return evaluator
-
-
-def _shard_map_rollout_evaluator(
-    env,
-    policy,
-    *,
-    mesh,
-    axis_name: str = "pop",
-    stats_sync: bool = False,
-    **rollout_kwargs,
-):
-    """The pre-GSPMD explicit shard_map path (compat knob; see
-    ``make_sharded_rollout_evaluator``)."""
-    from ..neuroevolution.net.vecrl import (
-        _params_kind,
-        _params_popsize,
-        _params_shard_spec,
-        global_lane_ids,
-        run_vectorized_rollout,
-        RolloutResult,
-    )
-
-    refill_mode = rollout_kwargs.get("eval_mode") == "episodes_refill"
-    if refill_mode and rollout_kwargs.get("refill_width") is not None:
-        width = int(rollout_kwargs["refill_width"])
-        n_shards = mesh.shape[axis_name]
-        if width % n_shards != 0:
-            raise ValueError(
-                f"refill_width={width} is global and must be divisible by "
-                f"the mesh axis size {n_shards}"
-            )
-        rollout_kwargs["refill_width"] = width // n_shards
-
-    # per-group telemetry rides in as an explicit 4th sharded input: each
-    # shard segment-sums over its local lanes and the additive (G, K) block
-    # psums mesh-global like every other telemetry slot
-    groups_global = rollout_kwargs.pop("groups", None)
-    num_groups = int(rollout_kwargs.pop("num_groups", 1) or 1)
-    collect_groups = groups_global is not None and num_groups > 1
-    if collect_groups:
-        groups_global = jnp.asarray(groups_global, dtype=jnp.int32)
-
-    # non-finite quarantine on this explicit path: the worst-finite
-    # reduction must pmin over the mesh so the sharded replacement score is
-    # the GLOBAL worst finite one (the GSPMD path's reduction is global by
-    # construction); a fixed penalty needs no collective
-    if (
-        rollout_kwargs.get("nonfinite_quarantine")
-        and rollout_kwargs.get("nonfinite_penalty") is None
-    ):
-        rollout_kwargs["nonfinite_sync_axis"] = axis_name
-
-    # the per-shard engine must NOT append its own health block — the
-    # telemetry psum below would sum the bit-cast float columns across
-    # shards into garbage; the local fn all_gathers the scores and appends
-    # ONE mesh-global block (shard-0 masked) instead
-    health = bool(rollout_kwargs.pop("health", True))
-    rollout_kwargs["health"] = False
-    from ..observability.devicemetrics import (
-        append_health_block,
-        compute_health_block,
-    )
-
-    def build(kind: str, popsize: int):
-        # tuned-config cache: cache widths are GLOBAL, divided per shard with
-        # the convenience-knob flooring (only an explicit width gets the
-        # strict divisibility check above)
-        local_kwargs = dict(rollout_kwargs)
-        source = None
-        if refill_mode:
-            local_kwargs, source = _lookup_refill_config(
-                env, policy, mesh, rollout_kwargs, popsize
-            )
-            from ..observability.timings import SOURCE_CACHE
-
-            if source == SOURCE_CACHE:
-                n_shards = mesh.shape[axis_name]
-                local_kwargs["refill_width"] = max(
-                    1, int(local_kwargs["refill_width"]) // n_shards
-                )
-
-        def local(values_shard, key, stats, groups_shard=None):
-            result = run_vectorized_rollout(
-                env,
-                policy,
-                values_shard,
-                key,
-                stats,
-                lane_ids=global_lane_ids(axis_name, _params_popsize(values_shard)),
-                stats_sync_axis=axis_name if stats_sync else None,
-                seed_stride=popsize,
-                groups=groups_shard,
-                num_groups=num_groups if groups_shard is not None else 1,
-                **local_kwargs,
-            )
-            if stats_sync:
-                merged = result.stats  # per-step psums already mesh-global
-            else:
-                delta = jax.tree_util.tree_map(
-                    lambda new, old: new - old, result.stats, stats
-                )
-                merged = jax.tree_util.tree_map(
-                    lambda old, d: old + jax.lax.psum(d, axis_name), stats, delta
-                )
-            if result.telemetry is None:
-                telemetry = jnp.zeros((0,), dtype=jnp.int32)
-            else:
-                telemetry = result.telemetry
-                if health:
-                    # mesh-global health block: gather the final scores into
-                    # GLOBAL lane order (shards hold contiguous blocks, so
-                    # tiled all_gather IS the unsharded order), compute the
-                    # identical full-population reduction on every shard,
-                    # then zero all but shard 0's copy so the integer psum
-                    # carries the bit-cast float columns through exactly
-                    g_scores = jax.lax.all_gather(
-                        result.scores, axis_name, tiled=True
-                    )
-                    g_groups = (
-                        jax.lax.all_gather(groups_shard, axis_name, tiled=True)
-                        if groups_shard is not None
-                        else None
-                    )
-                    block = compute_health_block(
-                        g_scores,
-                        g_groups,
-                        num_groups if groups_shard is not None else 1,
-                    )
-                    shard0 = (jax.lax.axis_index(axis_name) == 0).astype(
-                        block.dtype
-                    )
-                    telemetry = append_health_block(telemetry, block * shard0)
-                # all telemetry slots are additive: the mesh-global
-                # observability vector is one psum, in the same program
-                telemetry = jax.lax.psum(telemetry, axis_name)
-            return (
-                result.scores,
-                merged,
-                jax.lax.psum(result.total_steps, axis_name),
-                jax.lax.psum(result.total_episodes, axis_name),
-                result.total_steps[None],
-                telemetry,
-            )
-
-        values_spec = _params_shard_spec(kind, axis_name)
-        in_specs = (values_spec, P(), P())
-        if collect_groups:
-            in_specs = in_specs + (P(axis_name),)
-        fn = jax.jit(
-            jax.shard_map(
-                local,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=(P(axis_name), P(), P(), P(), P(axis_name), P()),
-                check_vma=False,
-            )
-        )
-        return fn, source
-
-    build = functools.lru_cache(maxsize=_EVALUATOR_CACHE_SIZE)(build)
-
-    def evaluator(values, key, stats):
-        popsize = _params_popsize(values)
-        fn, source = build(_params_kind(values), popsize)
-        evaluator.tuned_config_source = source
-        if collect_groups:
-            scores, merged, steps, episodes, per_shard, telemetry = fn(
-                values, key, stats, groups_global
-            )
-        else:
-            scores, merged, steps, episodes, per_shard, telemetry = fn(
-                values, key, stats
-            )
-        result = RolloutResult(
-            scores=scores,
-            stats=merged,
-            total_steps=steps,
-            total_episodes=episodes,
-            telemetry=telemetry if telemetry.size else None,
-        )
-        return result, per_shard
-
-    evaluator.program_builder = lambda kind, popsize: build(
-        _normalize_kind(kind), popsize
-    )[0]
     evaluator.tuned_config_source = None
     return evaluator
 

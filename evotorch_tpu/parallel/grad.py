@@ -1,21 +1,16 @@
 """Sharded ES-gradient estimation: the TPU form of the reference's
 distributed mode.
 
-Default GSPMD: the sample/evaluate/rank/grad pipeline is written ONCE as the
+GSPMD: the sample/evaluate/rank/grad pipeline is written ONCE as the
 global program — sample the full population, rank GLOBALLY, compute the
 gradients — with the sample matrix pinned to the mesh's population layout;
 XLA partitions the math and inserts the reductions. Global ranking is the
 reference's SINGLE-PROCESS semantics (``gaussian.py:199-272`` without the
 actor split), so the estimate is exactly what a one-device run computes, at
 any mesh shape and ANY population size (no divisibility constraint — GSPMD
-handles uneven layouts).
-
-``use_shard_map=True`` / ``EVOTORCH_SHARD_MAP=1`` keeps the pre-GSPMD
-explicit form, which reproduces the reference's DISTRIBUTED-mode semantics
-(``core.py:2762-3073``): each shard samples its own sub-population with a
-device-unique key, ranks *locally*, computes local gradients, and a ``pmean``
-averages them — per-actor local ranking is a semantic, not just a layout
-(rank weights depend on the cohort), which is why the knob preserves it.
+handles uneven layouts). The reference's DISTRIBUTED mode
+(``core.py:2762-3073``: every actor ranks its own sub-population) is a
+different search, not a layout, and has no form here.
 """
 
 from __future__ import annotations
@@ -24,11 +19,11 @@ from typing import Callable, Optional, Type
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
 
 from ..tools.lowrank import dense_values
 from ..tools.ranking import rank
-from .evaluate import _use_shard_map, population_spec
+from .evaluate import population_spec
 from .mesh import default_mesh
 
 __all__ = ["make_sharded_grad_estimator"]
@@ -44,17 +39,13 @@ def make_sharded_grad_estimator(
     axis_name: str = "pop",
     with_aux: bool = False,
     lowrank_rank: Optional[int] = None,
-    use_shard_map: Optional[bool] = None,
 ) -> Callable:
     """Build ``g(key, num_solutions, parameters) -> grads`` where the
     sample/evaluate/rank/grad pipeline runs sharded over the mesh and the
     returned gradient dict is replicated on all devices.
 
-    Default GSPMD (global ranking = the reference's single-process
-    semantics): ``num_solutions`` may be ANY size. Under the
-    ``use_shard_map`` compat knob (the reference's distributed per-actor
-    local-ranking semantics) it must be divisible by the mesh axis size (and
-    the local size even for symmetric distributions).
+    Ranking is global (the reference's single-process semantics) and
+    ``num_solutions`` may be ANY size.
 
     With ``with_aux=True`` the estimator returns ``(grads, aux)`` where
     ``aux["mean_eval"]`` is the population-mean fitness (what the
@@ -64,13 +55,10 @@ def make_sharded_grad_estimator(
     With ``lowrank_rank`` the population is sampled in factored (low-rank)
     form and the gradients come from the factors in O(L * rank); only the
     fitness evaluation materializes the dense matrix (plain fitness
-    functions consume dense rows). Under the compat knob each shard samples
-    its own basis (per-actor independent sampling)."""
+    functions consume dense rows)."""
     if mesh is None:
         mesh = default_mesh((axis_name,))
     higher_is_better = {"max": True, "min": False}[objective_sense]
-    legacy = _use_shard_map(use_shard_map)
-    n_shards = mesh.shape[axis_name] if legacy else None
     pop_sharding = NamedSharding(mesh, population_spec(mesh))
 
     # one jitted program per (popsize, static params): repeated calls must
@@ -111,61 +99,8 @@ def make_sharded_grad_estimator(
 
         return jax.jit(fn)
 
-    def _build_shard_map(local_popsize: int, static_items: tuple):
-        static_params = dict(static_items)
-
-        def local(key, array_params):
-            parameters = {**array_params, **static_params}
-            my_key = jax.random.fold_in(key, jax.lax.axis_index(axis_name))
-            if lowrank_rank is not None:
-                samples = distribution_class._sample_lowrank(
-                    my_key, parameters, local_popsize, lowrank_rank
-                )
-                fitnesses = fitness_func(dense_values(samples))
-            else:
-                samples = distribution_class._sample(my_key, parameters, local_popsize)
-                fitnesses = fitness_func(samples)
-            weights = rank(fitnesses, ranking_method, higher_is_better=higher_is_better)
-            grads = distribution_class._compute_gradients(
-                parameters, samples, weights, ranking_method
-            )
-            out = jax.tree_util.tree_map(
-                lambda g: jax.lax.pmean(g, axis_name), grads
-            )
-            if with_aux:
-                aux = {"mean_eval": jax.lax.pmean(jnp.mean(fitnesses), axis_name)}
-                if lowrank_rank is not None:
-                    # each shard's basis rides out stacked along the pop axis
-                    # (shard i's rows at [i*L:(i+1)*L]) so the caller can run
-                    # the subspace-exhaustion diagnostic on a representative
-                    # per-shard basis without an extra collective
-                    aux["basis"] = samples.basis
-                return out, aux
-            return out
-
-        aux_specs = {"mean_eval": P()}
-        if lowrank_rank is not None:
-            aux_specs["basis"] = P(axis_name)
-        return jax.jit(
-            jax.shard_map(
-                local,
-                mesh=mesh,
-                in_specs=(P(), P()),
-                out_specs=(P(), aux_specs) if with_aux else P(),
-                check_vma=False,
-            )
-        )
-
     def estimator(key, num_solutions: int, parameters: dict):
         num_solutions = int(num_solutions)
-        if legacy:
-            if num_solutions % n_shards != 0:
-                raise ValueError(
-                    f"num_solutions={num_solutions} must be divisible by the mesh axis size {n_shards}"
-                )
-            build_size = num_solutions // n_shards
-        else:
-            build_size = num_solutions
 
         # strings ("divide_mu_grad_by", ...) and structural floats
         # ("parenthood_ratio") are not JAX types: close over them statically
@@ -176,11 +111,10 @@ def make_sharded_grad_estimator(
         }
         array_params = {k: v for k, v in parameters.items() if k not in static_params}
 
-        cache_key = (build_size, tuple(sorted(static_params.items())))
+        cache_key = (num_solutions, tuple(sorted(static_params.items())))
         fn = compiled.get(cache_key)
         if fn is None:
-            builder = _build_shard_map if legacy else _build_global
-            fn = compiled[cache_key] = builder(build_size, cache_key[1])
+            fn = compiled[cache_key] = _build_global(num_solutions, cache_key[1])
         return fn(key, array_params)
 
     return estimator

@@ -23,7 +23,6 @@ __all__ = [
     "make_mesh",
     "mesh_label",
     "model_axis_size",
-    "parse_mesh_shape",
 ]
 
 #: the named mesh axes of the parallel layer (docs/sharding.md): ``"pop"``
@@ -91,28 +90,3 @@ def model_axis_size(mesh: Optional[Mesh]) -> int:
         return 1
     return int(mesh.shape["model"])
 
-
-def parse_mesh_shape(spec) -> dict:
-    """Parse a mesh-shape knob (``BENCH_MESH``) into ``{axis: size}``:
-
-    - ``"8"`` / ``8``      -> ``{"pop": 8}`` (the historical 1-D form)
-    - ``"4x2"``            -> ``{"pop": 4, "model": 2}``
-    - ``"pop=4,model=2"``  -> ``{"pop": 4, "model": 2}`` (explicit names)
-    """
-    if isinstance(spec, int):
-        return {"pop": int(spec)}
-    text = str(spec).strip()
-    if "=" in text:
-        out = {}
-        for part in text.split(","):
-            name, _, size = part.partition("=")
-            out[name.strip()] = int(size)
-        return out
-    if "x" in text:
-        sizes = [int(p) for p in text.split("x")]
-        if len(sizes) > len(MESH_AXES):
-            raise ValueError(
-                f"mesh shape {text!r} has {len(sizes)} axes; named axes are {MESH_AXES}"
-            )
-        return {name: size for name, size in zip(MESH_AXES, sizes)}
-    return {"pop": int(text)}

@@ -13,7 +13,7 @@ The contracts under test (docs/observability.md "The autotuner"):
 - the tuned-config cache resolves with ONE precedence rule everywhere:
   explicit knobs ("override") > cache hit ("cache") > built-in default
   ("fallback"), and every consumer — VecNE status, the sharded
-  evaluator, the host pipeline, bench_common — reports the branch taken
+  evaluator, the host pipeline — reports the branch taken
   as `tuned_config_source`.
 """
 
@@ -368,7 +368,7 @@ def test_resolve_knobs_precedence(tuned_cache):
     # a miss is the engine default
     config, source = resolve_knobs({}, "refill", dict(shape, popsize=99))
     assert source == "fallback" and config == {}
-    # use_cache=False (BENCH_TUNED=0) forces the fallback branch
+    # use_cache=False forces the fallback branch
     config, source = resolve_knobs({}, "refill", shape, use_cache=False)
     assert source == "fallback" and config == {}
 
@@ -416,8 +416,8 @@ def test_seeded_cache_has_the_r8_refill_entries():
     checked_in = pathlib.Path(obs.__file__).parent / "tuned_configs.json"
     entries = json.loads(checked_in.read_text())["entries"]
     by_key = {e["key"]: e for e in entries}
-    # the bench policy at BENCH_HIDDEN default (64,64), f32, CPU bench
-    # episode length — the shape the r8 lines were measured at
+    # the (64,64) policy, f32, episode length 100 — the shape the r8 lines
+    # were measured at
     shape = {
         "env": "humanoid",
         "episode_length": 100,
@@ -752,58 +752,6 @@ def test_host_pipeline_reports_tuned_source(tmp_path, monkeypatch, empty_cache):
     out = run()
     assert out["tuned_config_source"] == "fallback"
     assert len(out["block_iters"]) == heuristic  # not the entry's split
-
-
-def test_bench_common_tuned_resolution(tuned_cache, monkeypatch):
-    import bench_common
-
-    L = _cartpole_linear_params()
-    base_cfg = {
-        "env_name": "cartpole",
-        "env_kwargs": {},
-        "popsize": 8,
-        "episode_length": 8,
-        "tuned": True,
-        "compute_dtype": None,
-        "refill_width": None,
-        "refill_period": 1,
-        "refill_period_explicit": False,
-        "compact_chunk": 25,
-        "compact_chunk_explicit": False,
-        "compact_min_width": None,
-    }
-    # cache hit: the r8-style entry supplies the schedule
-    kwargs, source = bench_common.tuned_refill(base_cfg, params=L)
-    assert source == "cache"
-    assert kwargs == {"refill_period": 1, "refill_width": 4}
-    # explicit BENCH_REFILL_WIDTH wins, and the global width divides per shard
-    kwargs, source = bench_common.tuned_refill(
-        dict(base_cfg, refill_width=8), n_shards=2, params=L
-    )
-    assert source == "override" and kwargs["refill_width"] == 4
-    # BENCH_TUNED=0: byte-compatible fallback, no cache consult
-    kwargs, source = bench_common.tuned_refill(
-        dict(base_cfg, tuned=False), params=L
-    )
-    assert source == "fallback" and kwargs == {"refill_period": 1}
-    # BENCH_ENV_ARGS mutates the env: the plain-name cache entry is wrong
-    # evidence, so the consult is skipped; same for an unknown policy size
-    kwargs, source = bench_common.tuned_refill(
-        dict(base_cfg, env_kwargs={"n_links": 6}), params=L
-    )
-    assert source == "fallback"
-    kwargs, source = bench_common.tuned_refill(base_cfg, params=None)
-    assert source == "fallback"
-    # a different policy size is a different workload: no hit
-    kwargs, source = bench_common.tuned_refill(base_cfg, params=L + 1)
-    assert source == "fallback"
-    # compact goes through the same rule
-    kwargs, source = bench_common.tuned_compact(base_cfg, params=L)
-    assert source == "fallback" and kwargs == {"chunk_size": 25}
-    kwargs, source = bench_common.tuned_compact(
-        dict(base_cfg, compact_min_width=128), params=L
-    )
-    assert source == "override" and kwargs == {"chunk_size": 25, "min_width": 128}
 
 
 def test_gymne_reports_tuned_source(empty_cache):

@@ -1,14 +1,11 @@
-"""OO ``distributed=True`` semantics under both SPMD forms.
+"""OO ``distributed=True`` semantics.
 
 The reference's distributed mode (``core.py:3156-3301`` +
 ``algorithms/distributed/gaussian.py:199-272``) has each actor sample its own
 sub-population, rank **locally**, and compute local gradients; the main
-process averages them. After the GSPMD rewrite those exact statistics live
-behind the ``EVOTORCH_SHARD_MAP=1`` compat knob (local ranking is a
-*semantic*, not a layout — rank weights depend on the cohort), and the
-default is the reference's SINGLE-process semantics: one global program,
-global key, global ranking, identical at any mesh shape. Both are pinned
-here.
+process averages them. Here ``distributed=True`` is the reference's
+SINGLE-process semantics: one global GSPMD program, global key, global
+ranking, identical at any mesh shape.
 """
 
 import jax
@@ -40,28 +37,8 @@ def _dist_params():
     }
 
 
-def _local_ranking_oracle(key, params, popsize, n_shards):
-    """Hand-rolled reference semantics: per-shard sample + local centered
-    ranking + local grads, equal-weight average (equal shard sizes)."""
-    local = popsize // n_shards
-    grads = []
-    all_samples, all_fits = [], []
-    for i in range(n_shards):
-        ki = jax.random.fold_in(key, i)
-        samples = SymmetricSeparableGaussian._sample(ki, params, local)
-        fits = sphere(samples)
-        weights = rank(fits, "centered", higher_is_better=False)
-        grads.append(
-            SymmetricSeparableGaussian._compute_gradients(params, samples, weights, "centered")
-        )
-        all_samples.append(samples)
-        all_fits.append(fits)
-    avg = {k: np.mean([np.asarray(g[k]) for g in grads], axis=0) for k in grads[0]}
-    return avg, jnp.concatenate(all_samples), jnp.concatenate(all_fits)
-
-
 def test_distributed_gradients_gspmd_ranks_globally():
-    # the GSPMD default: global key, global ranking — the estimate is
+    # global key, global ranking — the estimate is
     # exactly what a one-device run computes, at any mesh shape
     p = _make_problem(num_actors="max")
     dist = SymmetricSeparableGaussian(_dist_params())
@@ -80,31 +57,6 @@ def test_distributed_gradients_gspmd_ranks_globally():
     for k in ("mu", "sigma"):
         assert np.allclose(np.asarray(got["gradients"][k]), np.asarray(oracle[k]), atol=1e-5), k
     assert np.isclose(got["mean_eval"], float(jnp.mean(fits)), atol=1e-4)
-
-
-def test_distributed_gradients_rank_locally(monkeypatch):
-    monkeypatch.setenv("EVOTORCH_SHARD_MAP", "1")
-    p = _make_problem(num_actors="max")
-    dist = SymmetricSeparableGaussian(_dist_params())
-    key = jax.random.key(123)
-    results = p.sample_and_compute_gradients(dist, 16, ranking_method="centered", key=key)
-    assert len(results) == 1
-    got = results[0]
-    assert got["num_solutions"] == 16
-
-    oracle, all_samples, all_fits = _local_ranking_oracle(key, _dist_params(), 16, 8)
-    for k in ("mu", "sigma"):
-        assert np.allclose(np.asarray(got["gradients"][k]), oracle[k], atol=1e-5), k
-
-    # and local ranking is genuinely different from global ranking: the
-    # globally-ranked gradient over the same concatenated samples must differ
-    global_grads = dist.compute_gradients(
-        all_samples, all_fits, objective_sense="min", ranking_method="centered"
-    )
-    assert not np.allclose(
-        np.asarray(got["gradients"]["mu"]), np.asarray(global_grads["mu"]), atol=1e-6
-    )
-    assert np.isclose(got["mean_eval"], float(jnp.mean(all_fits)), atol=1e-4)
 
 
 def test_distributed_gradients_round_up_uneven_popsize():
@@ -135,7 +87,7 @@ def test_pgpe_distributed_converges_on_sphere():
 
 def test_distributed_non_traceable_objective_falls_back():
     # review regression: a host-side objective with num_actors must degrade
-    # to the single-program (global-ranking) path, not crash inside shard_map
+    # to the single-program (global-ranking) path, not crash inside the mesh program
     import numpy as onp
 
     @vectorized

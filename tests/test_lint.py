@@ -820,6 +820,28 @@ def test_repo_is_clean_modulo_baseline():
     )
 
 
+def test_library_names_no_bench_variable():
+    """The library takes its shapes from arguments: no file under
+    ``evotorch_tpu/`` reads or names a variable of the deleted bench scripts
+    (the benchmark is ``BENCHMARK.json`` + ``benchmark/``, which the library
+    does not know)."""
+    import pathlib
+    import re
+
+    import evotorch_tpu
+
+    package = pathlib.Path(evotorch_tpu.__file__).parent
+    named = re.compile("BENCH" + r"_[A-Z]")
+    hits = [
+        f"{path.relative_to(package)}:{number}: {line.strip()}"
+        for path in sorted(package.rglob("*"))
+        if path.suffix in (".py", ".json", ".md")
+        for number, line in enumerate(path.read_text().splitlines(), 1)
+        if named.search(line)
+    ]
+    assert hits == [], "\n".join(hits)
+
+
 def test_baseline_is_multiset_matched():
     findings = _lint(
         """

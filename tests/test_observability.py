@@ -390,27 +390,6 @@ def test_slo_watchdog_flags_starved_group():
         Rule("bogus")
 
 
-def test_slo_bench_line_verdict(tmp_path):
-    from evotorch_tpu.observability.slo import _main, check_bench_line
-
-    good = {"occupancy": 0.62, "steady_compiles": 0,
-            "modes": {"budget": {"occupancy": 0.9}}}
-    assert check_bench_line(good).ok
-    bad = {"occupancy": 0.02, "steady_compiles": 1}
-    report = check_bench_line(bad)
-    assert not report.ok and len(report.violations) == 2
-    # the CLI form: last JSON line of
-    # the log, one-word verdict file, exit status as the step verdict
-    log = tmp_path / "bench.log"
-    log.write_text("noise\n" + json.dumps(good) + "\n" + json.dumps(bad) + "\n")
-    verdict = tmp_path / "slo_verdict.txt"
-    rc = _main(["--check-bench", str(log), "--verdict-out", str(verdict)])
-    assert rc == 1 and verdict.read_text().strip() == "fail"
-    log.write_text(json.dumps(good) + "\n")
-    rc = _main(["--check-bench", str(log), "--verdict-out", str(verdict)])
-    assert rc == 0 and verdict.read_text().strip() == "pass"
-
-
 # ---------------------------------------------------------------------------
 # MetricsHub
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ The contracts under test (docs/observability.md "Program ledger"):
   dropped is detected both statically (missing alias entry) and at
   runtime (input buffers left alive);
 - every ``donate_argnums`` entry point the repo registers (gaussian tell,
-  the bench and multichip generation steps, the batched functional
+  the one-device and GSPMD generation steps, the batched functional
   search) has its aliasing verified at runtime — the dynamic complement
   of graftlint's static ``donation`` checker;
 - the fast-tier REGRESSION GATE: the inventory captured at the gate
@@ -180,7 +180,7 @@ def test_capture_detects_silently_dropped_donation():
 _DONATED_PROGRAM_NAMES = [
     "gaussian.tell",
     "bench.generation",
-    "multichip.generation",
+    "gspmd.generation",
     "gspmd.training_span",
     "functional_batched_search",
 ]

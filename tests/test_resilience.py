@@ -388,7 +388,7 @@ def test_rank_guard_sanitizes_nonfinite():
 
 
 def test_slo_max_nonfinite_share_rule():
-    from evotorch_tpu.observability.slo import SLOWatchdog, check_bench_line
+    from evotorch_tpu.observability.slo import SLOWatchdog
 
     dog = SLOWatchdog([{"kind": "max_nonfinite_share", "threshold": 0.1}])
     ok = dog.check(None, status={"eval_nonfinite_share": 0.05})
@@ -397,42 +397,6 @@ def test_slo_max_nonfinite_share_rule():
     assert not bad.ok and "nonfinite_share" in bad.violations[0]
     # no status key + no telemetry: rule skips (missing data is not a fail)
     assert dog.check(None, status={}).checked == 0
-    # bench-line form
-    report = check_bench_line(
-        {"steady_compiles": 0, "occupancy": 0.9, "eval_nonfinite_share": 0.3},
-        max_nonfinite_share=0.02,
-    )
-    assert not report.ok and any("eval_nonfinite_share" in v for v in report.violations)
-
-
-def test_slo_cli_exit_codes(tmp_path):
-    def verdict(text):
-        log = tmp_path / "bench.log"
-        log.write_text(text)
-        proc = subprocess.run(
-            [
-                sys.executable, "-m", "evotorch_tpu.observability.slo",
-                "--check-bench", str(log),
-            ],
-            cwd=_REPO, env=_CPU_ENV, capture_output=True, text=True, timeout=120,
-        )
-        return proc.returncode, proc.stdout
-
-    ok_line = json.dumps({"steady_compiles": 0, "occupancy": 0.8})
-    rc, _ = verdict(ok_line + "\n")
-    assert rc == 0
-    rc, _ = verdict(json.dumps({"steady_compiles": 3, "occupancy": 0.8}) + "\n")
-    assert rc == 1
-    # a BENCH_TELEMETRY=0-style line carries none of the checked keys:
-    # "insufficient data" is its own exit code, distinct from pass and fail
-    rc, out = verdict(json.dumps({"value": 123.0}) + "\n")
-    assert rc == 2 and "insufficient" in out
-    rc, _ = verdict("")  # empty log: insufficient too
-    assert rc == 2
-    # a partial trailing line (crashed writer) is skipped, the last COMPLETE
-    # line wins — no traceback, normal verdict
-    rc, _ = verdict(ok_line + "\n" + '{"steady_compiles": 9, "occup')
-    assert rc == 0
 
 
 # ---------------------------------------------------------------------------

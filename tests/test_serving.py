@@ -9,8 +9,7 @@ the right tenant whatever the lane rebinding.
 
 Warm-up discipline: VecNE's eager counter bump compiles on its first TWO
 evaluations (int+array then array+array), so every retrace-sentinel window
-over a VecNE path warms twice first — same reason bench.py warms each A/B
-leg twice.
+over a VecNE path warms twice first.
 """
 
 import io
@@ -490,28 +489,6 @@ def test_stdio_errors_do_not_kill_the_server():
 
 
 # ----------------------------------------------------------------- SLO plumbing
-
-
-def test_check_bench_max_queue_wait_flag(tmp_path):
-    from evotorch_tpu.observability.slo import _main, check_bench_line
-
-    line = {
-        "queue_wait_p99": 2.0,
-        "serve_queue_wait_p99": 70.0,
-        "modes": {"episodes_refill": {"queue_wait_p99": 8.0}},
-    }
-    assert check_bench_line(line, max_queue_wait_p99=100.0).ok
-    report = check_bench_line(line, max_queue_wait_p99=10.0)
-    assert not report.ok
-    assert any("serve_queue_wait_p99" in v for v in report.violations)
-    # the flag threads through the CLI; a line with NONE of the checked
-    # keys exits 2 ("insufficient"), not 1
-    log = tmp_path / "bench.log"
-    log.write_text(json.dumps(line) + "\n")
-    assert _main(["--check-bench", str(log), "--max-queue-wait-p99", "1"]) == 1
-    assert _main(["--check-bench", str(log), "--max-queue-wait-p99", "1000"]) == 0
-    log.write_text(json.dumps({"unrelated": 1}) + "\n")
-    assert _main(["--check-bench", str(log), "--max-queue-wait-p99", "1"]) == 2
 
 
 def test_tuned_cache_writes_are_atomic(tmp_path):

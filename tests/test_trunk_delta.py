@@ -394,25 +394,3 @@ def test_slo_min_model_efficiency_rule():
     report = dog.check(None, status={"model_efficiency": 0.31})
     assert not report.ok
     assert "model_efficiency=0.31" in report.violations[0]
-
-
-def test_check_bench_line_min_model_efficiency():
-    from evotorch_tpu.observability.slo import check_bench_line
-
-    line = {
-        "steady_compiles": 0,
-        "occupancy": 0.9,
-        "model_efficiency": 0.4,
-        "modes": {
-            "budget": {"occupancy": 0.9, "model_efficiency": 0.4},
-            "episodes": {"occupancy": 0.5, "model_efficiency": 0.05},
-        },
-    }
-    # floor unset: ledger columns are not checked at all
-    assert check_bench_line(line).ok
-    report = check_bench_line(line, min_model_efficiency=0.1)
-    assert not report.ok
-    assert any("modes.episodes.model_efficiency" in v for v in report.violations)
-    # a BENCH_LEDGER=0 line (no efficiency columns) skips the checks
-    bare = {"steady_compiles": 0, "occupancy": 0.9, "modes": {}}
-    assert check_bench_line(bare, min_model_efficiency=0.1).ok

@@ -679,10 +679,10 @@ def test_vecne_sharded_obs_norm_divergence_bounded():
 
 
 def test_vecne_sharded_obs_norm_step_sync_matches_unsharded():
-    # obs_norm_sync="step": the stat deltas psum-merge every control step, so
-    # every shard normalizes by the MESH-GLOBAL cohort — the cohort
-    # divergence (characterized in the test above) collapses to float
-    # summation order. Reduction-order noise is amplified exponentially by
+    # over a mesh the program is the global one (GSPMD), whatever
+    # obs_norm_sync says: every device normalizes by the MESH-GLOBAL cohort
+    # and what differs from one device is float summation order.
+    # Reduction-order noise is amplified exponentially by
     # the contact dynamics (measured on hopper: max per-lane score diff
     # 9e-7 at T=2, 4e-3 at T=10, 0.3 at T=40), so the per-lane assertion
     # runs at a short horizon where it is meaningful; the absorbed
@@ -709,7 +709,7 @@ def test_vecne_sharded_obs_norm_step_sync_matches_unsharded():
     b_plain = SolutionBatch(p_plain, values=values)
     b_sync = SolutionBatch(p_sync, values=values)
     p_plain.evaluate(b_plain)         # unsharded: the global cohort
-    p_sync.evaluate_sharded(b_sync)   # sharded with per-step stat sync
+    p_sync.evaluate_sharded(b_sync)   # sharded: the same cohort
 
     np.testing.assert_allclose(
         np.asarray(b_sync.evals_of(0)), np.asarray(b_plain.evals_of(0)),
