@@ -61,7 +61,9 @@ from typing import Any, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ...envs.rigidbody import _by_platform
 from ...observability.scopes import scope
+from . import grouped
 from .layers import Module
 
 __all__ = [
@@ -379,12 +381,14 @@ class SparseExperts(_LaneModule):
         return params
 
     def initial_state(self):
-        """What the last step chose, and two counters that never reset: the
-        lane's (lane, expert) pairs that hit a held expert, and the pairs on
-        the fullest held expert of each step (one number for all lanes of a
-        population)."""
+        """What the last step chose, and three counters that never reset:
+        the lane's (lane, expert) pairs that hit a held expert, the pairs on
+        the fullest held expert of each step, and the row tiles the grouped
+        product's kernel visited (the last two one number for all lanes of a
+        population; no tile where the plain form runs)."""
         zero = jnp.zeros((), jnp.int32)
-        return {"chosen": jnp.zeros((self.top_k,), jnp.int32), "hits": zero, "fullest": zero}
+        chosen = jnp.zeros((self.top_k,), jnp.int32)
+        return {"chosen": chosen, "hits": zero, "fullest": zero, "tiles": zero}
 
     def reset_state(self, state, mask):
         return state  # nothing of an episode lives here
@@ -413,18 +417,15 @@ class SparseExperts(_LaneModule):
         mixed = jnp.sum(out.astype(F32) * per_expert[:, None], axis=0)[None].astype(y.dtype)
         return mixed, (per_expert > 0).astype(jnp.int32)
 
-    def _experts_grouped(self, center, factors, z, y, chosen, weights):
-        """All lanes' held experts as one grouped product: the (lane, expert)
+    def _experts_plain(self, center, factors, z, y, local, weights):
+        """The grouped product in XLA's own operations: the (lane, expert)
         pairs that hit a held expert, sorted by expert, against the stacked
-        weights (``jax.lax.ragged_dot``). No pair is dropped and there is no
-        capacity factor: the product has room for every lane hitting
-        ``min(top_k, held)`` experts (on the v5e its time does not depend on
-        that room: PERF.md, PR 28). Rows are gathered and summed back by
-        one-hot matmuls. Returns the lanes' sums and the pairs on each held
-        expert."""
+        weights (``jax.lax.ragged_dot``), with room for every lane hitting
+        ``min(top_k, held)`` experts; rows are gathered and summed back by
+        one-hot matmuls. What runs off the TPU and at widths the kernel does
+        not take."""
         n, held = y.shape[0], len(self.held)
         rows = n * min(self.top_k, held)
-        local = chosen - self.held.start
         key = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
         order = jnp.argsort(key, stable=True)[:rows]  # the held pairs come first, by expert
         key = key[order]
@@ -445,7 +446,25 @@ class SparseExperts(_LaneModule):
         hidden = jax.nn.silu(grouped("gate", x_rows)) * grouped("up", x_rows)
         out = grouped("down", hidden).astype(F32) * weights.reshape(-1)[order][:, None]
         out = jnp.where(hit[:, None], out, 0.0)  # rows past the last group hold no pair
-        return place.T @ out.astype(y.dtype), sizes
+        return place.T @ out.astype(y.dtype), sizes, jnp.zeros((), jnp.int32)
+
+    def _experts_grouped(self, center, factors, z, y, chosen, weights):
+        """All lanes' held experts as one grouped product over the (lane,
+        expert) pairs that hit a held expert. No pair is dropped and there is
+        no capacity factor. One kernel that streams each expert's matrices
+        once (``net/grouped.py``) where the program is lowered for a TPU, the
+        sizes are the kernel's and no mesh spreads the lanes; the plain form
+        everywhere else. Returns the lanes' sums, the pairs on each held
+        expert and the row tiles the kernel visited."""
+        local = chosen - self.held.start
+        mesh = jax.sharding.get_abstract_mesh()
+        if not grouped.fits(*y.shape, self.width, y.dtype, z.shape[-1]) or any(
+            size > 1 for size in mesh.shape.values()
+        ):
+            return self._experts_plain(center, factors, z, y, local, weights)
+        return _by_platform(
+            grouped.held_experts, self._experts_plain, center, factors, z, y, local, weights
+        )
 
     def _forward(self, acc, x, state):
         with scope("fwd_router"):
@@ -454,8 +473,9 @@ class SparseExperts(_LaneModule):
         with scope("fwd_experts"):
             if acc.z is None:
                 mixed, load = self._experts_dense(acc.p["experts"], y, chosen, weights)
+                tiles = 0
             else:
-                mixed, load = self._experts_grouped(
+                mixed, load, tiles = self._experts_grouped(
                     acc.p["experts"], acc.f["experts"], acc.z, y, chosen, weights
                 )
             if self.shared is not None:
@@ -467,6 +487,7 @@ class SparseExperts(_LaneModule):
                 "chosen": chosen.astype(jnp.int32),
                 "hits": state["hits"] + hits,
                 "fullest": state["fullest"] + jnp.max(load).astype(jnp.int32),
+                "tiles": state["tiles"] + tiles,
             }
         return out, state
 
@@ -619,11 +640,13 @@ class AfmoeDecoder(_LaneModule):
         made (the rollout engine returns it beside its telemetry).
         Whole-population counters: (lane, expert) pairs that hit held
         experts, the pairs on the fullest held expert summed over steps and
-        layers, the expert-layer steps they are sums over, and the cache
-        slots written. Per lane, in step order (the last ``max_positions``
-        steps): the id each step consumed and the lane's position in its
-        episode there, ``(n, steps)``; the model's token for position ``t``
-        of an episode is the id consumed at ``t + 1``."""
+        layers, the row tiles the grouped product's kernel visited (with a
+        tile's rows; 0 tiles where the plain form ran), the expert-layer
+        steps they are sums over, and the cache slots written. Per lane, in
+        step order (the last ``max_positions`` steps): the id each step
+        consumed and the lane's position in its episode there, ``(n,
+        steps)``; the model's token for position ``t`` of an episode is the
+        id consumed at ``t + 1``."""
         layers = state["layers"]
         sparse = [s["mlp"] for s in layers if s["mlp"] is not None]
         zero = jnp.zeros((), jnp.int32)
@@ -633,6 +656,8 @@ class AfmoeDecoder(_LaneModule):
         return {
             "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),
             "expert_pairs_fullest": sum((m["fullest"][0] for m in sparse), zero),
+            "expert_row_tiles": sum((m["tiles"][0] for m in sparse), zero),
+            "expert_tile_rows": jnp.asarray(grouped.ROW_TILE, jnp.int32),
             "expert_layer_steps": sum((s["attn"]["step"][0] for s in layers if s["mlp"] is not None), zero),
             "cache_slots_written": sum((jnp.sum(s["attn"]["step"]) for s in layers), zero),
             "ids_seen": jnp.roll(seen["ids"], -first, axis=1),

@@ -22,6 +22,7 @@ from evotorch_tpu.distributions import SymmetricSeparableGaussian
 from evotorch_tpu.envs.tokens import TokenCopyEnv
 from evotorch_tpu.neuroevolution import VecNE
 from evotorch_tpu.neuroevolution.net import LSTM, Linear, Tanh
+from evotorch_tpu.neuroevolution.net import decoder as decoder_module
 from evotorch_tpu.neuroevolution.net.decoder import (
     AfmoeDecoder,
     SparseExperts,
@@ -225,11 +226,20 @@ from evotorch_tpu.neuroevolution.net.decoder import _Dense  # noqa: E402  (the o
 
 
 @pytest.mark.parametrize("crowded", [False, True])
-def test_the_grouped_product_drops_no_pair(crowded):
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_the_grouped_product_drops_no_pair(form, crowded, monkeypatch):
     """512 lanes on a chip that holds 1 expert of 8: a quarter of the lanes
     hit it, or (with a router that sends every lane there) all 512, the whole
-    of the product's room. Either way every lane equals its own dense apply."""
-    layer = SparseExperts(64, 32, 8, 2, experts_held=range(2, 3), route_scale=2.826)
+    of the product's room and four row tiles of the kernel. Either way every
+    lane equals its own dense apply, in XLA's plain form (which the CPU gets)
+    and in the kernel of ``net/grouped.py`` (interpreted here, at widths it
+    takes)."""
+    dim, width = (64, 32) if form == "plain" else (128, 128)
+    if form == "kernel":
+        monkeypatch.setattr(
+            decoder_module, "_by_platform", lambda fused, plain, *args: fused(*args, interpret=True)
+        )
+    layer = SparseExperts(dim, width, 8, 2, experts_held=range(2, 3), route_scale=2.826)
     policy = FlatParamsPolicy(layer)
     flat = seeded(policy)
     params = policy.unravel(flat)
@@ -237,13 +247,15 @@ def test_the_grouped_product_drops_no_pair(crowded):
         params["expert_bias"] = params["expert_bias"].at[2].set(10.0)
         flat = jax.flatten_util.ravel_pytree(params)[0]
     batch = trunk_batch(policy, flat, lanes=512, rank=2)
-    x = jax.random.normal(jax.random.key(9), (512, 64))
+    x = jax.random.normal(jax.random.key(9), (512, dim))
     got, state = jax.jit(
         lambda b, x: layer.trunk_delta_apply(policy.unravel(b.center), b.factors, b.coeffs, x, None)
     )(batch, x)
     hits = int(jnp.sum(state["hits"]))
     assert (hits == 512) if crowded else (0 < hits <= 256)
     assert int(state["fullest"][0]) == hits  # one held expert: it is the fullest
+    # the kernel walks the expert's lanes in tiles of 128 rows; the plain form has none
+    assert int(state["tiles"][0]) == (0 if form == "plain" else -(-hits // 128))
     want, _ = jax.jit(jax.vmap(lambda p, x: layer.apply(policy.unravel(p), x, None)))(batch.materialize(), x)
     assert relative_rms(got, want) < 1e-5
 
