@@ -1,0 +1,95 @@
+"""The latent-attention decoder's forward by inner scope: the evaluation
+program's device seconds under ``fwd_attention`` (outside the cache span),
+``fwd_latent_cache``, ``fwd_router``, ``fwd_experts``, ``fwd_dense_mlp``,
+``fwd_head`` (``evotorch_tpu/observability/scopes.py:FORWARD_SCOPES``, names
+INSIDE ``policy_forward``; an op under two of them counts under the
+innermost), joined by instruction name as harness/scopes.py joins the
+rollout's scopes. By SCOPE alone: no array's shape is looked for.
+
+Control steps are ``session.decode_steps`` times the traced generations: the
+session says what it ran. Everything here returns None where there is no
+device trace, no session that lowers its evaluation, or a library without the
+latent cache's scope.
+"""
+
+import json
+
+from benchmark.harness import mla_floors, scopes
+
+CACHE_SCOPE = "fwd_latent_cache"
+
+
+def forward_seconds(run):
+    def compute():
+        session = run.session
+        problem = getattr(session, "problem", None)
+        lower = getattr(problem, "lower_evaluation", None)
+        steps = getattr(session, "decode_steps", None)
+        if run.trace is None or not run.trace.planes or lower is None or steps is None:
+            return None
+        try:
+            from evotorch_tpu.observability.scopes import FORWARD_SCOPES, instruction_scopes
+        except ImportError:
+            return None
+        if CACHE_SCOPE not in FORWARD_SCOPES:
+            return None
+        text = scopes.compiled_text(lower, run.popsize, instruction_scopes)
+        ops = run.trace.evaluation_ops()
+        generations = len(run.trace.generations())
+        if not ops or generations <= 0:
+            return None
+        inner = {
+            name.lstrip("%"): scope
+            for name, scope in instruction_scopes(text, names=FORWARD_SCOPES).items()
+        }
+        outer = {name.lstrip("%"): scope for name, scope in instruction_scopes(text).items()}
+        if CACHE_SCOPE not in inner.values():
+            scopes.say("no instruction of the evaluation program carries the latent cache's scope: nothing read")
+            return None
+        seconds, forward_s, total_s = {}, 0.0, 0.0
+        for hlo, (self_seconds, _) in ops.items():
+            name = scopes.instruction_name(hlo)
+            total_s += self_seconds
+            if outer.get(name) == "policy_forward":
+                forward_s += self_seconds
+            scope = inner.get(name)
+            if scope is not None:
+                seconds[scope] = seconds.get(scope, 0.0) + self_seconds
+        split = {
+            "seconds": seconds,
+            "policy_forward_s": forward_s,
+            "inner_share_of_policy_forward": sum(seconds.values()) / forward_s if forward_s else None,
+            "evaluation_s": total_s,
+            "steps": steps * generations,
+        }
+        scopes.say("mla forward: " + json.dumps(split))
+        return split
+
+    return run.memo("mla_scopes.forward_seconds", compute)
+
+
+def per_step_ms(run, scope):
+    split = forward_seconds(run)
+    return None if split is None else 1e3 * split["seconds"].get(scope, 0.0) / split["steps"]
+
+
+def positions_per_step(run):
+    """Readable positions of the latent cache in one control step, summed over
+    lanes and layers: counted by the program over the last evaluation
+    (``latent_positions_read``), else what an episode no lane ends early
+    gives."""
+    session = run.session
+    counters = session.policy_counters()
+    if counters and counters.get("latent_positions_read"):
+        return counters["latent_positions_read"] / session.decode_steps
+    return mla_floors.expected_positions_per_step(session.mla_sizes, run.popsize, session.decode_steps)
+
+
+def peaks(run):
+    from benchmark.harness import device
+
+    return device.peaks(run.device_record["kind"])
+
+
+def dtype_bytes(run):
+    return 2 if run.session.compute_dtype is not None else 4

@@ -1,7 +1,9 @@
 """A sparse-expert decoder as an evolvable policy, decoded stepwise.
 
-The modules of the ``afmoe`` family (Trinity): token embedding, RMSNorm,
-gated grouped-query attention with a per-lane cache as the policy's
+The modules of two families: ``afmoe`` (Trinity: gated grouped-query
+attention, a norm before and after every block) and ``glm4_moe_lite``
+(GLM-4.7-Flash: latent attention, a norm before a block only). Token
+embedding, RMSNorm, an attention with a per-lane cache as the policy's
 recurrent state, SwiGLU, and a sigmoid-routed expert layer that is told which
 experts it holds. They follow the ``Module`` protocol of ``layers.py``
 (``init`` / ``initial_state`` / ``apply(params, x, state)``), so a decoder is
@@ -43,6 +45,19 @@ applied to a key with its lane's position when it is written.
 ``reset_state`` zeroes the cache rows and ``t`` of the lanes that ended an
 episode, lane by lane, and leaves ``step``.
 
+**The latent cache.** ``LatentAttention`` keeps, per lane and layer, one
+compressed row ``c`` ``(slots, kv_lora_rank)`` (after its norm) and one RoPE
+key ``kr`` ``(slots, qk_rope_head_dim)`` shared by all heads, and nothing per
+head: written, aged and reset as above, ``slots = max_positions`` (every
+layer is full attention). It decodes in the **absorbed** form: with
+``kv_b`` viewed ``(heads, qk_nope + v, kv_lora_rank)`` = ``[W_UK[h];
+W_UV[h]]``, a head's no-position query goes INTO the latent space (``q_n
+W_UK[h]``), scores and the weighted sum run over ``c`` once for all heads,
+and the result comes back through ``W_UV[h]``. In the shared-trunk form
+every lane's ``kv_b`` is its own, so the accessors carry the product with a
+head's ROW BLOCK of a leaf, untransposed or transposed (``head_mm``); no
+lane's ``kv_b`` is written out and no per-head key or value exists.
+
 **What a lane consumed.** The decoder's state also keeps, per lane, the token
 id and the lane's position ``t`` of each of the last ``max_positions`` steps
 (``seen``; written like the cache, never reset): the generated text of an
@@ -71,9 +86,11 @@ __all__ = [
     "RMSNorm",
     "SwiGLU",
     "GatedAttention",
+    "LatentAttention",
     "SparseExperts",
     "DecoderLayer",
     "AfmoeDecoder",
+    "Glm4MoeLiteDecoder",
     "stepwise_logits",
 ]
 
@@ -106,11 +123,25 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
+def _closed(module, acc, out):
+    """A block's output as it joins the residual: through the block's closing
+    norm where the family has one."""
+    return rms_norm(out, acc.vec("post_norm"), module.eps) if module.post_norm else out
+
+
 # -- the two accessors ---------------------------------------------------------
 # A module's equations read its parameters through ``mm(name, x)`` (x times
-# the transposed ``(out, in)`` weight) and ``vec(name)`` (a 1-D parameter,
-# broadcast against the lanes). Dense: one lane, its own weights, a lane axis
-# of one. Trunk-delta: all lanes, shared trunk plus per-lane rank-k delta.
+# the transposed ``(out, in)`` weight), ``head_mm(name, x, heads, rows,
+# into=...)`` (x ``(n, heads, .)`` times each head's row block of the leaf
+# viewed ``(heads, out / heads, in)``: ``into`` the leaf's input space, ``x @
+# W[h, rows]``, or out of it, ``x @ W[h, rows]^T``) and ``vec(name)`` (a 1-D
+# parameter, broadcast against the lanes). Dense: one lane, its own weights,
+# a lane axis of one. Trunk-delta: all lanes, shared trunk plus per-lane
+# rank-k delta ``B diag(z) A^T``, whose row block is ``B[rows] diag(z) A^T``.
+
+
+def _head_block(leaf, heads, rows, dtype):
+    return leaf.reshape(heads, leaf.shape[0] // heads, leaf.shape[1])[:, rows].astype(dtype)
 
 
 class _Dense:
@@ -123,6 +154,10 @@ class _Dense:
 
     def mm(self, name, x, precision=None):
         return jnp.matmul(x, self.p[name].T.astype(x.dtype), precision=precision)
+
+    def head_mm(self, name, x, heads, rows, *, into):
+        w = _head_block(self.p[name], heads, rows, x.dtype)
+        return jnp.einsum("nhr,hri->nhi" if into else "nhi,hri->nhr", x, w)
 
     def vec(self, name):
         return self.p[name][None]
@@ -145,6 +180,17 @@ class _Trunk:
         trunk = jnp.matmul(x, w.T.astype(x.dtype), precision=precision)
         thin = jnp.matmul(x, a, precision=precision) * z
         return trunk + jnp.matmul(thin, b.T, precision=precision)
+
+    def head_mm(self, name, x, heads, rows, *, into):
+        f = self.f[name]
+        w = _head_block(self.p[name], heads, rows, x.dtype)
+        a, b = f.a.astype(x.dtype), _head_block(f.b, heads, rows, x.dtype)
+        z = self.z.astype(x.dtype)[:, None, :]
+        if into:  # x @ W[rows] = x @ W_c[rows] + ((x @ B[rows]) * z) @ A^T
+            thin = jnp.einsum("nhr,hrk->nhk", x, b) * z
+            return jnp.einsum("nhr,hri->nhi", x, w) + jnp.einsum("nhk,ik->nhi", thin, a)
+        thin = jnp.einsum("nhi,ik->nhk", x, a) * z
+        return jnp.einsum("nhi,hri->nhr", x, w) + jnp.einsum("nhk,hrk->nhr", thin, b)
 
     def vec(self, name):
         return self.p[name] + self.z @ self.f[name].b.T
@@ -327,12 +373,124 @@ class GatedAttention(_LaneModule):
         return y, {"k": kc, "v": vc, "t": t + 1, "step": state["step"] + 1}
 
 
+class LatentAttention(_LaneModule):
+    """``x + W_o [a_1 .. a_H]`` with latent attention (MLA) over ``xn =
+    RMSNorm(x)``: ``c_q = RMSNorm(W_qa xn)``, ``[q_n | q_r]_h = (W_qb c_q)_h``;
+    ``[c | k_r] = W_kva xn``, ``c = RMSNorm(c)``, ``k_r`` ONE key for all
+    heads; RoPE on ``q_r`` and ``k_r``; ``[k_n | v]_{h,s} = (W_kvb c_s)_h``;
+    ``score_{h,s} = (q_n . k_n + q_r . k_r) / sqrt(qk_nope + qk_rope)``;
+    softmax in float32; ``a_h = sum_s p_{h,s} v_{h,s}``. No bias, no gate, no
+    norm after the block. Computed in the absorbed form over the latent
+    cache of the module docstring, which is its state."""
+
+    def __init__(
+        self,
+        dim: int,
+        num_heads: int,
+        *,
+        q_rank: int,
+        kv_rank: int,
+        nope_dim: int,
+        rope_dim: int,
+        v_dim: int,
+        slots: int,
+        rope_theta: float,
+        eps: float = 1e-5,
+    ):
+        self.dim, self.heads = int(dim), int(num_heads)
+        self.q_rank, self.kv_rank = int(q_rank), int(kv_rank)
+        self.nope, self.rope, self.v = int(nope_dim), int(rope_dim), int(v_dim)
+        self.slots, self.rope_theta, self.eps = int(slots), float(rope_theta), float(eps)
+
+    def init(self, key):
+        kqa, kqb, kva, kvb, ko = jax.random.split(key, 5)
+        return {
+            "in_norm": jnp.ones((self.dim,), F32),
+            "q_a": _normal(kqa, (self.q_rank, self.dim)),
+            "q_a_norm": jnp.ones((self.q_rank,), F32),
+            "q_b": _normal(kqb, (self.heads * (self.nope + self.rope), self.q_rank)),
+            "kv_a": _normal(kva, (self.kv_rank + self.rope, self.dim)),
+            "kv_a_norm": jnp.ones((self.kv_rank,), F32),
+            "kv_b": _normal(kvb, (self.heads * (self.nope + self.v), self.kv_rank)),
+            "o": _normal(ko, (self.dim, self.heads * self.v)),
+        }
+
+    def initial_state(self):
+        """The latent cache, the lane's position, the write pointer, and a
+        counter that never resets: the positions the lane could read, summed
+        over its steps (``min(t + 1, slots)`` a step)."""
+        zero = jnp.zeros((), jnp.int32)
+        return {
+            "c": jnp.zeros((self.slots, self.kv_rank), F32),
+            "kr": jnp.zeros((self.slots, self.rope), F32),
+            "t": zero,
+            "step": zero,
+            "read": zero,
+        }
+
+    def reset_state(self, state, mask):
+        """As ``GatedAttention.reset_state``: ``t`` and the cache rows of the
+        lanes in ``mask``, one lane at a time; ``step`` and ``read`` stay."""
+        n = mask.shape[0]
+        ended = jnp.nonzero(mask, size=n, fill_value=0)[0]
+        blanks = tuple(jnp.zeros((1,) + state[name].shape[1:], state[name].dtype) for name in ("c", "kr"))
+
+        def zero_lane(i, caches):
+            at = (ended[i], 0, 0)
+            return tuple(jax.lax.dynamic_update_slice(c, blank, at) for c, blank in zip(caches, blanks))
+
+        c, kr = jax.lax.fori_loop(
+            0, jnp.sum(mask.astype(jnp.int32)), zero_lane, (state["c"], state["kr"])
+        )
+        return {**state, "c": c, "kr": kr, "t": jnp.where(mask, 0, state["t"])}
+
+    def _forward(self, acc, x, state):
+        n, heads, slots = x.shape[0], self.heads, self.slots
+        nope, v_rows = slice(0, self.nope), slice(self.nope, self.nope + self.v)
+        with scope("fwd_attention"):
+            xn = rms_norm(x, acc.vec("in_norm"), self.eps)
+            cq = rms_norm(acc.mm("q_a", xn), acc.vec("q_a_norm"), self.eps)
+            q = acc.mm("q_b", cq).reshape(n, heads, self.nope + self.rope)
+            kv = acc.mm("kv_a", xn)
+            c = rms_norm(kv[:, : self.kv_rank], acc.vec("kv_a_norm"), self.eps)
+            t = state["t"]
+            q_r = rope(q[..., self.nope :], t[:, None], self.rope_theta)
+            k_r = rope(kv[:, self.kv_rank :], t, self.rope_theta)
+            q_lat = acc.head_mm("kv_b", q[..., nope], heads, nope, into=True)  # q_n W_UK[h]
+            with scope("fwd_latent_cache"):
+                cache_dtype = state["c"].dtype
+                slot = jnp.mod(state["step"][0], slots)
+                at = (0, slot, 0)
+                cc = jax.lax.dynamic_update_slice(state["c"], c[:, None, :].astype(cache_dtype), at)
+                kc = jax.lax.dynamic_update_slice(state["kr"], k_r[:, None, :].astype(cache_dtype), at)
+                age = jnp.mod(slot - jnp.arange(slots, dtype=jnp.int32), slots)
+                readable = age[None, :] <= t[:, None]  # (n, slots)
+                scores = jnp.einsum(
+                    "nhr,nsr->nhs", q_lat.astype(cache_dtype), cc, preferred_element_type=F32
+                ) + jnp.einsum("nhd,nsd->nhs", q_r.astype(cache_dtype), kc, preferred_element_type=F32)
+                scores = scores / math.sqrt(self.nope + self.rope)
+                scores = jnp.where(readable[:, None, :], scores, -jnp.inf)
+                weights = jax.nn.softmax(scores, axis=-1).astype(cache_dtype)
+                o_lat = jnp.einsum("nhs,nsr->nhr", weights, cc, preferred_element_type=F32)
+            mixed = acc.head_mm("kv_b", o_lat.astype(x.dtype), heads, v_rows, into=False)  # W_UV[h] o~
+            y = x + acc.mm("o", mixed.reshape(n, heads * self.v))
+        return y, {
+            "c": cc,
+            "kr": kc,
+            "t": t + 1,
+            "step": state["step"] + 1,
+            "read": state["read"] + jnp.minimum(t + 1, slots),
+        }
+
+
 class SparseExperts(_LaneModule):
     """``RMSNorm``-wrapped sigmoid-routed experts with a shared expert:
-    ``x + RMSNorm(sum_e w_e E_e(y) + S(y))``, ``y = RMSNorm(x)``; router
-    logits, sigmoid and top-k in float32; the ``expert_bias`` selects and
-    does not weigh; the weights are the selected scores, normalised over the
-    selected (``route_norm``) and times ``route_scale``. ``experts_held``:
+    ``x + RMSNorm(sum_e w_e E_e(y) + S(y))``, ``y = RMSNorm(x)`` (``x +
+    sum_e w_e E_e(y) + S(y)`` without ``post_norm``); router logits, sigmoid
+    and top-k in float32; the ``expert_bias`` selects and does not weigh; the
+    weights are the selected scores, normalised over the selected
+    (``route_norm``; their sum plus ``route_norm_eps``) and times
+    ``route_scale``. ``experts_held``:
     the range of expert ids whose weights this module holds; the others'
     terms are left out. Held experts are stacked ``(held, in, out)``: the
     grouped product's right-hand side."""
@@ -350,6 +508,8 @@ class SparseExperts(_LaneModule):
         route_norm: bool = True,
         score_func: str = "sigmoid",
         eps: float = 1e-5,
+        post_norm: bool = True,
+        route_norm_eps: float = 0.0,
     ):
         if score_func != "sigmoid":
             raise ValueError(f"score_func {score_func!r}: only the sigmoid router is implemented")
@@ -361,6 +521,7 @@ class SparseExperts(_LaneModule):
         self.held = held
         self.shared = SwiGLU(dim, int(num_shared_experts) * width) if num_shared_experts else None
         self.route_scale, self.route_norm, self.eps = float(route_scale), bool(route_norm), float(eps)
+        self.post_norm, self.route_norm_eps = bool(post_norm), float(route_norm_eps)
 
     def init(self, key):
         kr, kg, ku, kd, ks = jax.random.split(key, 5)
@@ -374,8 +535,9 @@ class SparseExperts(_LaneModule):
                 "up": _normal(ku, (e, self.dim, self.width)),
                 "down": _normal(kd, (e, self.width, self.dim)),
             },
-            "post_norm": jnp.ones((self.dim,), F32),
         }
+        if self.post_norm:
+            params["post_norm"] = jnp.ones((self.dim,), F32)
         if self.shared is not None:
             params["shared"] = self.shared.init(ks)
         return params
@@ -400,7 +562,10 @@ class SparseExperts(_LaneModule):
         _, chosen = jax.lax.top_k(scores + acc.vec("expert_bias").astype(F32), self.top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.route_norm:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            total = jnp.sum(weights, axis=-1, keepdims=True)
+            if self.route_norm_eps:
+                total = total + self.route_norm_eps
+            weights = weights / total
         return chosen, weights * self.route_scale
 
     def _experts_dense(self, params, y, chosen, weights):
@@ -480,7 +645,7 @@ class SparseExperts(_LaneModule):
                 )
             if self.shared is not None:
                 mixed = mixed + self.shared._forward(acc.sub("shared"), y, None)[0]
-            out = x + rms_norm(mixed, acc.vec("post_norm"), self.eps)
+            out = x + _closed(self, acc, mixed)
             local = chosen - self.held.start
             hits = jnp.sum((local >= 0) & (local < len(self.held)), axis=-1, dtype=jnp.int32)
             state = {
@@ -493,30 +658,30 @@ class SparseExperts(_LaneModule):
 
 
 class _DenseMLP(_LaneModule):
-    """``x + RMSNorm(SwiGLU(RMSNorm(x)))``: the MLP of the first
-    ``num_dense_layers`` layers."""
+    """``x + RMSNorm(SwiGLU(RMSNorm(x)))`` (``x + SwiGLU(RMSNorm(x))``
+    without ``post_norm``): the MLP of the leading dense layers."""
 
-    def __init__(self, dim: int, width: int, *, eps: float = 1e-5):
+    def __init__(self, dim: int, width: int, *, eps: float = 1e-5, post_norm: bool = True):
         self.inner, self.dim, self.eps = SwiGLU(dim, width), int(dim), float(eps)
+        self.post_norm = bool(post_norm)
 
     def init(self, key):
-        return {
-            "in_norm": jnp.ones((self.dim,), F32),
-            "mlp": self.inner.init(key),
-            "post_norm": jnp.ones((self.dim,), F32),
-        }
+        params = {"in_norm": jnp.ones((self.dim,), F32), "mlp": self.inner.init(key)}
+        if self.post_norm:
+            params["post_norm"] = jnp.ones((self.dim,), F32)
+        return params
 
     def _forward(self, acc, x, state):
         with scope("fwd_dense_mlp"):
             y = rms_norm(x, acc.vec("in_norm"), self.eps)
             out, _ = self.inner._forward(acc.sub("mlp"), y, None)
-            return x + rms_norm(out, acc.vec("post_norm"), self.eps), state
+            return x + _closed(self, acc, out), state
 
 
 class DecoderLayer(_LaneModule):
     """Attention, then the layer's MLP (dense or sparse)."""
 
-    def __init__(self, attention: GatedAttention, mlp: _LaneModule):
+    def __init__(self, attention: _LaneModule, mlp: _LaneModule):
         self.attention, self.mlp = attention, mlp
 
     def init(self, key):
@@ -539,81 +704,22 @@ class DecoderLayer(_LaneModule):
         return x, {"attn": attn, "mlp": mlp}
 
 
-class AfmoeDecoder(_LaneModule):
-    """The decoder under the published configuration's own keys, plus the
-    share this process holds: ``layers_held`` (ids into the published stack;
-    a layer's kind follows from its id), ``experts_held`` (a range of expert
-    ids, every sparse layer's), ``vocab_held`` (rows of embedding and head),
-    and ``max_positions``, the longest episode the cache must hold. Input:
-    one token id; output: float logits over the held vocabulary."""
+class _Decoder(_LaneModule):
+    """What every family's decoder is around its layers: embedding rows
+    (times ``embed_scale``), the held ``layers``, the final norm and the head
+    over the held vocabulary; the ``seen`` record, ``state_report`` and
+    ``reset_state``. Input: one token id; output: float logits over the held
+    vocabulary. A family's constructor builds its layers from its published
+    keys and hands them over."""
 
-    def __init__(
-        self,
-        *,
-        hidden_size: int,
-        num_attention_heads: int,
-        num_key_value_heads: int,
-        head_dim: int,
-        intermediate_size: int,
-        moe_intermediate_size: int,
-        num_experts: int,
-        num_experts_per_tok: int,
-        num_shared_experts: int,
-        num_dense_layers: int,
-        layer_types: Sequence[str],
-        sliding_window: int,
-        rope_theta: float,
-        route_scale: float,
-        route_norm: bool,
-        score_func: str,
-        rms_norm_eps: float,
-        vocab_size: int,
-        mup_enabled: bool,
-        max_positions: int,
-        layers_held: Optional[Sequence[int]] = None,
-        experts_held: Optional[range] = None,
-        vocab_held: Optional[int] = None,
-    ):
-        self.hidden_size, self.eps = int(hidden_size), float(rms_norm_eps)
+    def __init__(self, *, hidden_size, eps, vocab_size, vocab_held, max_positions, layers, embed_scale=1.0):
+        self.hidden_size, self.eps = int(hidden_size), float(eps)
         self.vocab_held = int(vocab_size if vocab_held is None else vocab_held)
         if not 0 < self.vocab_held <= int(vocab_size):
             raise ValueError("vocab_held must lie in (0, vocab_size]")
-        self.layers_held = tuple(range(len(layer_types)) if layers_held is None else layers_held)
         self.max_positions = int(max_positions)
-        layers = []
-        for index in self.layers_held:
-            kind = layer_types[index]
-            if kind not in ("sliding_attention", "full_attention"):
-                raise ValueError(f"layer_types[{index}] = {kind!r}")
-            sliding = kind == "sliding_attention"
-            attention = GatedAttention(
-                hidden_size,
-                num_attention_heads,
-                num_key_value_heads,
-                head_dim,
-                slots=min(int(sliding_window), self.max_positions) if sliding else self.max_positions,
-                rope_theta=rope_theta if sliding else None,  # no positions in full layers
-                eps=self.eps,
-            )
-            if index < int(num_dense_layers):
-                mlp = _DenseMLP(hidden_size, intermediate_size, eps=self.eps)
-            else:
-                mlp = SparseExperts(
-                    hidden_size,
-                    moe_intermediate_size,
-                    num_experts,
-                    num_experts_per_tok,
-                    experts_held=experts_held,
-                    num_shared_experts=num_shared_experts,
-                    route_scale=route_scale,
-                    route_norm=route_norm,
-                    score_func=score_func,
-                    eps=self.eps,
-                )
-            layers.append(DecoderLayer(attention, mlp))
         self.layers = tuple(layers)
-        scale = math.sqrt(hidden_size) if mup_enabled else 1.0
-        self.embedding = Embedding(self.vocab_held, hidden_size, scale=scale)
+        self.embedding = Embedding(self.vocab_held, hidden_size, scale=embed_scale)
 
     def init(self, key):
         ke, kh, *kl = jax.random.split(key, 2 + len(self.layers))
@@ -642,18 +748,23 @@ class AfmoeDecoder(_LaneModule):
         experts, the pairs on the fullest held expert summed over steps and
         layers, the row tiles the grouped product's kernel visited (with a
         tile's rows; 0 tiles where the plain form ran), the expert-layer
-        steps they are sums over, and the cache slots written. Per lane, in
-        step order (the last ``max_positions`` steps): the id each step
-        consumed and the lane's position in its episode there, ``(n,
-        steps)``; the model's token for position ``t`` of an episode is the
-        id consumed at ``t + 1``."""
+        steps they are sums over, the cache slots written and, where the
+        layers keep a latent cache, the positions the lanes could read in it
+        (``latent_positions_read``: summed over steps, lanes and layers, the
+        count its floor is taken from). Per lane, in step order (the last
+        ``max_positions`` steps): the id each step consumed and the lane's
+        position in its episode there, ``(n, steps)``; the model's token for
+        position ``t`` of an episode is the id consumed at ``t + 1``."""
         layers = state["layers"]
         sparse = [s["mlp"] for s in layers if s["mlp"] is not None]
         zero = jnp.zeros((), jnp.int32)
         seen = state["seen"]
         # the ring's oldest step comes first
         first = jnp.where(seen["step"][0] > self.max_positions, seen["step"][0] % self.max_positions, 0)
+        latent = [s["attn"]["read"] for s in layers if "read" in s["attn"]]
+        report = {"latent_positions_read": sum((jnp.sum(r) for r in latent), zero)} if latent else {}
         return {
+            **report,
             "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),
             "expert_pairs_fullest": sum((m["fullest"][0] for m in sparse), zero),
             "expert_row_tiles": sum((m["tiles"][0] for m in sparse), zero),
@@ -688,6 +799,176 @@ class AfmoeDecoder(_LaneModule):
         return logits, {"layers": tuple(new_state), "seen": seen}
 
 
+class AfmoeDecoder(_Decoder):
+    """The ``afmoe`` decoder (Trinity) under the published configuration's
+    own keys, plus the share this process holds: ``layers_held`` (ids into
+    the published stack; a layer's kind follows from its id),
+    ``experts_held`` (a range of expert ids, every sparse layer's),
+    ``vocab_held`` (rows of embedding and head), and ``max_positions``, the
+    longest episode the cache must hold."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size: int,
+        num_attention_heads: int,
+        num_key_value_heads: int,
+        head_dim: int,
+        intermediate_size: int,
+        moe_intermediate_size: int,
+        num_experts: int,
+        num_experts_per_tok: int,
+        num_shared_experts: int,
+        num_dense_layers: int,
+        layer_types: Sequence[str],
+        sliding_window: int,
+        rope_theta: float,
+        route_scale: float,
+        route_norm: bool,
+        score_func: str,
+        rms_norm_eps: float,
+        vocab_size: int,
+        mup_enabled: bool,
+        max_positions: int,
+        layers_held: Optional[Sequence[int]] = None,
+        experts_held: Optional[range] = None,
+        vocab_held: Optional[int] = None,
+    ):
+        self.layers_held = tuple(range(len(layer_types)) if layers_held is None else layers_held)
+        eps, max_positions = float(rms_norm_eps), int(max_positions)
+        layers = []
+        for index in self.layers_held:
+            kind = layer_types[index]
+            if kind not in ("sliding_attention", "full_attention"):
+                raise ValueError(f"layer_types[{index}] = {kind!r}")
+            sliding = kind == "sliding_attention"
+            attention = GatedAttention(
+                hidden_size,
+                num_attention_heads,
+                num_key_value_heads,
+                head_dim,
+                slots=min(int(sliding_window), max_positions) if sliding else max_positions,
+                rope_theta=rope_theta if sliding else None,  # no positions in full layers
+                eps=eps,
+            )
+            if index < int(num_dense_layers):
+                mlp = _DenseMLP(hidden_size, intermediate_size, eps=eps)
+            else:
+                mlp = SparseExperts(
+                    hidden_size,
+                    moe_intermediate_size,
+                    num_experts,
+                    num_experts_per_tok,
+                    experts_held=experts_held,
+                    num_shared_experts=num_shared_experts,
+                    route_scale=route_scale,
+                    route_norm=route_norm,
+                    score_func=score_func,
+                    eps=eps,
+                )
+            layers.append(DecoderLayer(attention, mlp))
+        super().__init__(
+            hidden_size=hidden_size,
+            eps=eps,
+            vocab_size=vocab_size,
+            vocab_held=vocab_held,
+            max_positions=max_positions,
+            layers=layers,
+            embed_scale=math.sqrt(hidden_size) if mup_enabled else 1.0,
+        )
+
+
+class Glm4MoeLiteDecoder(_Decoder):
+    """The ``glm4_moe_lite`` decoder (GLM-4.7-Flash) under the published
+    configuration's own keys, plus the share this process holds, as
+    ``AfmoeDecoder`` takes it: latent attention in every layer, a norm before
+    a block only, a dense MLP in the first ``first_k_dense_replace`` layers
+    and ``noaux_tc``-routed experts with a shared one after them, no
+    embedding scale. The next-token-prediction block
+    (``num_nextn_predict_layers``) is no part of the causal forward and is
+    not built."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size: int,
+        num_attention_heads: int,
+        q_lora_rank: int,
+        kv_lora_rank: int,
+        qk_nope_head_dim: int,
+        qk_rope_head_dim: int,
+        v_head_dim: int,
+        intermediate_size: int,
+        moe_intermediate_size: int,
+        n_routed_experts: int,
+        num_experts_per_tok: int,
+        n_shared_experts: int,
+        first_k_dense_replace: int,
+        num_hidden_layers: int,
+        routed_scaling_factor: float,
+        norm_topk_prob: bool,
+        topk_method: str,
+        n_group: int,
+        topk_group: int,
+        rope_theta: float,
+        rope_scaling: Optional[dict],
+        rms_norm_eps: float,
+        vocab_size: int,
+        max_positions: int,
+        layers_held: Optional[Sequence[int]] = None,
+        experts_held: Optional[range] = None,
+        vocab_held: Optional[int] = None,
+    ):
+        if topk_method != "noaux_tc":
+            raise ValueError(f"topk_method {topk_method!r}: only noaux_tc is implemented")
+        if int(n_group) != 1 or int(topk_group) != 1:
+            raise ValueError("n_group and topk_group other than 1: group-limited routing is not implemented")
+        if rope_scaling is not None:
+            raise ValueError("rope_scaling is not implemented")
+        self.layers_held = tuple(range(int(num_hidden_layers)) if layers_held is None else layers_held)
+        if not all(0 <= index < int(num_hidden_layers) for index in self.layers_held):
+            raise ValueError(f"layers_held {self.layers_held!r} is not within {num_hidden_layers} layers")
+        eps, max_positions = float(rms_norm_eps), int(max_positions)
+        layers = []
+        for index in self.layers_held:
+            attention = LatentAttention(
+                hidden_size,
+                num_attention_heads,
+                q_rank=q_lora_rank,
+                kv_rank=kv_lora_rank,
+                nope_dim=qk_nope_head_dim,
+                rope_dim=qk_rope_head_dim,
+                v_dim=v_head_dim,
+                slots=max_positions,  # every layer is full attention: no ring
+                rope_theta=rope_theta,
+                eps=eps,
+            )
+            if index < int(first_k_dense_replace):
+                mlp = _DenseMLP(hidden_size, intermediate_size, eps=eps, post_norm=False)
+            else:
+                mlp = SparseExperts(
+                    hidden_size,
+                    moe_intermediate_size,
+                    n_routed_experts,
+                    num_experts_per_tok,
+                    experts_held=experts_held,
+                    num_shared_experts=n_shared_experts,
+                    route_scale=routed_scaling_factor,
+                    route_norm=norm_topk_prob,
+                    eps=eps,
+                    post_norm=False,
+                    route_norm_eps=1e-20,
+                )
+            layers.append(DecoderLayer(attention, mlp))
+        super().__init__(
+            hidden_size=hidden_size,
+            eps=eps,
+            vocab_size=vocab_size,
+            vocab_held=vocab_held,
+            max_positions=max_positions,
+            layers=layers,
+        )
+
 def stepwise_logits(policy, params_batch, ids, *, positions=None, lanes=None, compute_dtype=None):
     """Decode the id sequences ``ids`` ``(n, T)`` one token a step,
     teacher-forced, through the population-wide forward the rollout engine
@@ -700,7 +981,7 @@ def stepwise_logits(policy, params_batch, ids, *, positions=None, lanes=None, co
     and its state is reset first, as the engine resets it at an episode's
     end. ``lanes``: indices of the lanes whose logits and experts are
     returned (all of them step; default all). Call under ``jit``. ``policy``
-    wraps an :class:`AfmoeDecoder`."""
+    wraps a decoder of this module."""
     from .vecrl import _batched_forward, _forward_ctx, _initial_policy_states, _params_cast
 
     params_batch = _params_cast(params_batch, compute_dtype)

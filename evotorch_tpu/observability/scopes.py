@@ -35,7 +35,11 @@ apart (the decoder of ``net/decoder.py``), INSIDE ``policy_forward``:
 cache), ``fwd_router`` (the expert layer's norm, router, top-k),
 ``fwd_experts`` (the sort of the pairs, the grouped product over the held
 experts, the shared expert), ``fwd_dense_mlp``, ``fwd_head`` (embedding
-gather, final norm, head). ``instruction_scopes`` keeps reading the OUTERMOST
+gather, final norm, head), and ``fwd_latent_cache``, worn INSIDE
+``fwd_attention`` by what latent attention does over its compressed cache
+(the write, the scores over ``c`` and ``k_r``, the softmax, the weighted sum
+over ``c``; the up-projections folded into the query and output paths stay
+``fwd_attention``). ``instruction_scopes`` keeps reading the OUTERMOST
 rollout scope, so what read ``policy_forward`` before still does;
 ``instruction_scopes(..., names=FORWARD_SCOPES)`` reads the INNERMOST
 component among the forward's names.
@@ -72,6 +76,7 @@ FORWARD_SCOPES = (
     "fwd_experts",
     "fwd_dense_mlp",
     "fwd_head",
+    "fwd_latent_cache",
 )
 SCOPE_PREFIX = "evotorch_tpu."
 

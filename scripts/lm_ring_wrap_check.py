@@ -5,6 +5,7 @@ precision in the program's place (the control its bounds were set against).
 
     python scripts/lm_ring_wrap_check.py [--steps 2304] [--lanes 4] [--seed 1] [--cpu --tiny]
     python scripts/lm_ring_wrap_check.py --control int8,float8_e4m3,bfloat16 [--seed 1] [--cpu --tiny]
+        [--cell glm47_flash_ep8.decode512] [--seeds 10]
 
 Default: ``trinity_mini_ep8``'s share of the model (``benchmark/configs/``), a
 seeded trunk and one generation's rank-4 factors; ``--lanes`` lanes are
@@ -19,9 +20,12 @@ past the wrap alone, and the share of top-k sets that differ; exits non-zero
 where the error past the wrap (of all positions, in a replay too short to
 wrap) exceeds the cell's bound (``drivers/oo_lm_searcher.py:LOGIT_RTOL``).
 
-``--control``: the cell's own session (``drivers/oo_lm_searcher.py``, the
-cell's lanes and steps; two generations so that a population has been
-evaluated), then its ``reference_checks`` as the benchmark runs them, and
+``--control``: the cell's own session (``--cell``, default
+``trinity_mini_ep8.decode256``: its driver, its lanes and steps; two
+generations so that a population has been evaluated), then its
+``reference_checks`` as the benchmark runs them (with ``--seeds N`` once for
+each of N seeds from ``--seed`` on, each drawing other lanes: the system's
+readings a cell's bounds are set from), and
 once more per named precision with the REFERENCE, its matrices rounded to
 that precision (int8 and float8 e4m3 scaled to the largest entry of a leaf),
 in the program's place, through the same comparison and the same limits.
@@ -60,7 +64,7 @@ def rounded(kind):
     return to
 
 
-def control(args, files, config):
+def control(args, files):
     import jax
 
     from evotorch_tpu.observability import enable_persistent_cache
@@ -68,13 +72,15 @@ def control(args, files, config):
 
     setup_backend(force_cpu=args.cpu)
     enable_persistent_cache()  # the cell's own programs: the benchmark's cache has them
-    workload = files.workload("trinity_mini_ep8.decode256")
+    workload = files.workload(args.cell)
+    config = files.config(workload["config"])
     scale = {key: (value if args.tiny else config[key]) for key, value in config["rehearse"].items()}
     session = files.driver(workload["driver"]).build(files, config, workload, args.seed, scale)
     for _ in range(2):
         session.generation()
     session.block()
     out = {"device": jax.devices()[0].device_kind, "scale": scale, "system": session.reference_checks(args.seed)}
+    others = [session.reference_checks(seed) for seed in range(args.seed + 1, args.seed + args.seeds)]
     for kind in args.control.split(","):
         out[kind] = session.reference_checks(args.seed, control=rounded(kind))
     verdict = {
@@ -82,6 +88,9 @@ def control(args, files, config):
         for name, checks in out.items()
         if name not in ("device", "scale")
     }
+    if others:
+        out["system_other_seeds"] = others
+        verdict["system"] = verdict["system"] and all(c["ok"] for checks in others for c in checks.values())
     out["ok"] = verdict
     out["peak_bytes_in_use"] = int((jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use", 0))
     print(json.dumps(out))
@@ -96,6 +105,8 @@ def main(argv):
     parser.add_argument("--cpu", action="store_true")
     parser.add_argument("--tiny", action="store_true")
     parser.add_argument("--control", default=None, help="precisions, comma-separated: int8,float8_e4m3,bfloat16")
+    parser.add_argument("--cell", default="trinity_mini_ep8.decode256", help="the cell --control runs")
+    parser.add_argument("--seeds", type=int, default=1, help="--control: seeds the system's comparison is drawn with")
     args = parser.parse_args(argv)
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -103,9 +114,9 @@ def main(argv):
     from benchmark.harness.loader import BenchmarkFiles
 
     files = BenchmarkFiles(ROOT)
-    config = files.config("trinity_mini_ep8")
     if args.control:
-        return control(args, files, config)
+        return control(args, files)
+    config = files.config("trinity_mini_ep8")
 
     import jax
     import jax.numpy as jnp

@@ -42,7 +42,10 @@ def _bases():
 def _named_paths(text):
     for ticked in _TICKED.findall(_FENCE.sub("", text)):
         for path in _PATH.findall(ticked):
-            if not set(path) & set("<>*{}$"):  # a pattern, not a file
+            # a pattern is no file, and an absolute path lies outside the
+            # checkout (``/root/TESTS_LAST_RUN.json`` exists only while a
+            # session runs): neither is this tree's to keep true
+            if not set(path) & set("<>*{}$") and not path.startswith("/"):
                 yield path.removeprefix("./")
 
 

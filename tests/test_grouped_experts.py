@@ -158,6 +158,58 @@ def test_the_width_is_walked_in_tiles_when_a_block_would_be_too_large(monkeypatc
     assert distance(whole, in_float32(center, factors, z, y, local, weights)) < 1e-5
 
 
+def test_eight_held_experts_walked_in_two_width_tiles():
+    """The other cell's shape of the walk (GLM-4.7-Flash holds 8 experts of
+    width 1,536, which a 2,048-wide bfloat16 layer walks in two tiles of 768):
+    8 held experts whose width splits in two under the block limit as it
+    stands. The limit is in bytes, so a width of two registers splits only
+    past 4,096 float32 inputs: 4,224 here. Against the plain form and the
+    float32 evaluation."""
+    lanes, dim, width, held, rank = 256, 4224, 256, 8, 2
+    dtype = jnp.dtype("float32")
+    assert grouped.fits(lanes, dim, width, dtype, rank)
+    assert grouped._width_tile(dim, width, 4) == 128  # two tiles
+    assert grouped._width_tile(2048, 1536, 2) == 768  # the cell's: two tiles
+    keys = jax.random.split(jax.random.key(7), 12)
+    center, factors = {}, {}
+    for at, (name, (fan_in, fan_out)) in enumerate(
+        {"gate": (dim, width), "up": (dim, width), "down": (width, dim)}.items()
+    ):
+        std = fan_in**-0.5
+        center[name] = std * jax.random.normal(keys[3 * at], (held, fan_in, fan_out))
+        factors[name] = DeltaFactor(
+            a=jax.random.normal(keys[3 * at + 1], (held, fan_in, rank)),
+            b=0.1 * std * jax.random.normal(keys[3 * at + 2], (held, fan_out, rank)),
+        )
+    z = jax.random.normal(keys[9], (lanes, rank))
+    y = jax.random.normal(keys[10], (lanes, dim))
+    weights = jax.random.uniform(keys[11], (lanes, 4), minval=0.2, maxval=1.0)
+    lane = np.arange(lanes)
+    # top 4 of 64: a lane's four experts are distinct; ids past 7 are other chips'
+    local = jnp.asarray(np.stack([np.where(lane % 2, lane % 7, 8 + lane % 7), 16 + lane % 5, 24 + lane % 3, np.where(lane < 150, 7, 40)], axis=1), jnp.int32)
+    layer = SparseExperts(dim, width, 64, 4, experts_held=range(held))
+    got, sizes, tiles = jax.jit(lambda *a: grouped.held_experts(*a, interpret=True))(
+        center, factors, z, y, local, weights
+    )
+    want, want_sizes, _ = jax.jit(layer._experts_plain)(center, factors, z, y, local, weights)
+    expected = np.bincount(np.asarray(local)[np.asarray(local) < held], minlength=held)
+    assert np.array_equal(sizes, expected) and np.array_equal(want_sizes, expected)
+    assert expected[7] > grouped.ROW_TILE  # expert 7 takes two row tiles as well
+    assert int(tiles) == int(np.sum(-(-expected // grouped.ROW_TILE)))
+    assert distance(got, want) < 1e-5
+
+    def exact():
+        out = jnp.zeros(y.shape, jnp.float32)
+        for e in range(held):
+            m = lambda name, x: x @ center[name][e] + ((x @ factors[name].a[e]) * z) @ factors[name].b[e].T
+            hidden = jax.nn.silu(m("gate", y)) * m("up", y)
+            out = out + m("down", hidden) * jnp.sum(jnp.where(local == e, weights, 0.0), -1)[:, None]
+        return out
+
+    with jax.default_matmul_precision("highest"):
+        assert distance(got, exact()) < 1e-5
+
+
 def test_a_mesh_over_the_lanes_gets_the_plain_form(monkeypatch):
     """No cell shards a decoder's lanes; a program traced under a mesh that
     does keeps XLA's form, which the partitioner can split."""
@@ -191,9 +243,20 @@ def v5e():
 
 
 @pytest.mark.filterwarnings("ignore:Error reading persistent compilation cache entry")
-def test_a_sparse_layers_step_compiles_for_v5e(v5e):
-    """The real TPU compiler, Mosaic included, on the benchmark's sparse layer
-    (Trinity-Mini's widths, 16 of 128 experts held, 512 lanes, rank 4,
+@pytest.mark.parametrize(
+    "build",
+    [
+        lambda: SparseExperts(2048, 1024, 128, 8, experts_held=range(16), route_scale=2.826),
+        lambda: SparseExperts(
+            2048, 1536, 64, 4, experts_held=range(8), route_scale=1.8, post_norm=False, route_norm_eps=1e-20
+        ),
+    ],
+    ids=["trinity_mini_16_of_128_x_1024", "glm47_flash_8_of_64_x_1536"],
+)
+def test_a_sparse_layers_step_compiles_for_v5e(v5e, build):
+    """The real TPU compiler, Mosaic included, on the benchmark's sparse
+    layers (Trinity-Mini's widths, 16 of 128 experts held; GLM-4.7-Flash's, 8
+    of 64, the width walked in two tiles of 768; 512 lanes, rank 4,
     bfloat16): the step holds the kernel, once, and no ``ragged-dot``."""
     from jax.sharding import SingleDeviceSharding
 
@@ -201,7 +264,7 @@ def test_a_sparse_layers_step_compiles_for_v5e(v5e):
     from evotorch_tpu.neuroevolution.net.lowrank import sample_trunk_delta_factors
 
     lanes, rank, bf16 = 512, 4, jnp.bfloat16
-    layer = SparseExperts(2048, 1024, 128, 8, experts_held=range(16), route_scale=2.826)
+    layer = build()
     policy = FlatParamsPolicy(layer)
     one_chip = SingleDeviceSharding(v5e.devices[0])
 
