@@ -57,6 +57,15 @@ and the result comes back through ``W_UV[h]``. In the shared-trunk form
 every lane's ``kv_b`` is its own, so the accessors carry the product with a
 head's ROW BLOCK of a leaf, untransposed or transposed (``head_mm``); no
 lane's ``kv_b`` is written out and no per-head key or value exists.
+Between the write and ``W_UV`` the pass over the cache (``_cache_pass``) is
+ONE kernel a layer where the program is lowered for a TPU, the sizes are the
+kernel's and no mesh spreads the lanes (``net/latent.py``: a block of a lane's
+rows comes into VMEM once for scores, blockwise softmax and weighted sum, and
+the walk stops at what the lane has filled, counted back from the write
+pointer), and XLA's plain form everywhere else (``_cache_plain``: every slot
+under the mask; the statement of the equations). The state counts what a lane
+could read (``read``) and the positions in the blocks fetched for it
+(``fetched``: 0 where the plain form ran); neither is ever reset.
 
 **What a lane consumed.** The decoder's state also keeps, per lane, the token
 id and the lane's position ``t`` of each of the last ``max_positions`` steps
@@ -70,6 +79,7 @@ policies only.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional, Sequence, Tuple
 
@@ -78,7 +88,7 @@ import jax.numpy as jnp
 
 from ...envs.rigidbody import _by_platform
 from ...observability.scopes import scope
-from . import grouped
+from . import grouped, latent
 from .layers import Module
 
 __all__ = [
@@ -416,9 +426,11 @@ class LatentAttention(_LaneModule):
         }
 
     def initial_state(self):
-        """The latent cache, the lane's position, the write pointer, and a
-        counter that never resets: the positions the lane could read, summed
-        over its steps (``min(t + 1, slots)`` a step)."""
+        """The latent cache, the lane's position, the write pointer, and two
+        counters that never reset: the positions the lane could read, summed
+        over its steps (``min(t + 1, slots)`` a step), and the positions in
+        the blocks the cache pass's kernel fetched for it (none where the
+        plain form runs)."""
         zero = jnp.zeros((), jnp.int32)
         return {
             "c": jnp.zeros((self.slots, self.kv_rank), F32),
@@ -426,6 +438,7 @@ class LatentAttention(_LaneModule):
             "t": zero,
             "step": zero,
             "read": zero,
+            "fetched": zero,
         }
 
     def reset_state(self, state, mask):
@@ -443,6 +456,37 @@ class LatentAttention(_LaneModule):
             0, jnp.sum(mask.astype(jnp.int32)), zero_lane, (state["c"], state["kr"])
         )
         return {**state, "c": c, "kr": kr, "t": jnp.where(mask, 0, state["t"])}
+
+    def _cache_plain(self, q_lat, q_r, cc, kc, t, slot, *, out_dtype=F32):
+        """The pass over the written caches in XLA's own operations: all
+        heads' scores over every slot, the slots older than the lane's ``t``
+        masked, softmax in float32, the weighted sum over ``c`` accumulated
+        in float32. The statement of the equations, and what runs off the
+        TPU and at sizes the kernel does not take. No block is fetched."""
+        age = jnp.mod(slot - jnp.arange(self.slots, dtype=jnp.int32), self.slots)
+        readable = age[None, :] <= t[:, None]  # (n, slots)
+        scores = jnp.einsum("nhr,nsr->nhs", q_lat, cc, preferred_element_type=F32) + jnp.einsum(
+            "nhd,nsd->nhs", q_r, kc, preferred_element_type=F32
+        )
+        scores = scores / math.sqrt(self.nope + self.rope)
+        scores = jnp.where(readable[:, None, :], scores, -jnp.inf)
+        weights = jax.nn.softmax(scores, axis=-1).astype(cc.dtype)
+        o_lat = jnp.einsum("nhs,nsr->nhr", weights, cc, preferred_element_type=F32)
+        return o_lat.astype(out_dtype), jnp.zeros(t.shape, jnp.int32)
+
+    def _cache_pass(self, q_lat, q_r, cc, kc, t, slot, out_dtype):
+        """``o = softmax((q_lat c^T + q_r kr^T) / sqrt(d)) c`` over each
+        lane's readable slots, rounded once to ``out_dtype``, and the
+        positions fetched for it. One kernel that reads a lane's filled blocks
+        once (``net/latent.py``) where the program is lowered for a TPU and
+        the sizes are the kernel's; the plain form everywhere else."""
+        plain = functools.partial(self._cache_plain, out_dtype=out_dtype)
+        if not latent.fits(q_lat.shape[0], self.kv_rank, self.slots, cc.dtype):
+            return plain(q_lat, q_r, cc, kc, t, slot)
+        kernel = functools.partial(
+            latent.attend, scale=1.0 / math.sqrt(self.nope + self.rope), out_dtype=out_dtype
+        )
+        return _by_platform(kernel, plain, q_lat, q_r, cc, kc, t, slot)
 
     def _forward(self, acc, x, state):
         n, heads, slots = x.shape[0], self.heads, self.slots
@@ -463,16 +507,10 @@ class LatentAttention(_LaneModule):
                 at = (0, slot, 0)
                 cc = jax.lax.dynamic_update_slice(state["c"], c[:, None, :].astype(cache_dtype), at)
                 kc = jax.lax.dynamic_update_slice(state["kr"], k_r[:, None, :].astype(cache_dtype), at)
-                age = jnp.mod(slot - jnp.arange(slots, dtype=jnp.int32), slots)
-                readable = age[None, :] <= t[:, None]  # (n, slots)
-                scores = jnp.einsum(
-                    "nhr,nsr->nhs", q_lat.astype(cache_dtype), cc, preferred_element_type=F32
-                ) + jnp.einsum("nhd,nsd->nhs", q_r.astype(cache_dtype), kc, preferred_element_type=F32)
-                scores = scores / math.sqrt(self.nope + self.rope)
-                scores = jnp.where(readable[:, None, :], scores, -jnp.inf)
-                weights = jax.nn.softmax(scores, axis=-1).astype(cache_dtype)
-                o_lat = jnp.einsum("nhs,nsr->nhr", weights, cc, preferred_element_type=F32)
-            mixed = acc.head_mm("kv_b", o_lat.astype(x.dtype), heads, v_rows, into=False)  # W_UV[h] o~
+                o_lat, fetched = self._cache_pass(
+                    q_lat.astype(cache_dtype), q_r.astype(cache_dtype), cc, kc, t, slot, x.dtype
+                )
+            mixed = acc.head_mm("kv_b", o_lat, heads, v_rows, into=False)  # W_UV[h] o~
             y = x + acc.mm("o", mixed.reshape(n, heads * self.v))
         return y, {
             "c": cc,
@@ -480,6 +518,7 @@ class LatentAttention(_LaneModule):
             "t": t + 1,
             "step": state["step"] + 1,
             "read": state["read"] + jnp.minimum(t + 1, slots),
+            "fetched": state["fetched"] + fetched,
         }
 
 
@@ -751,7 +790,9 @@ class _Decoder(_LaneModule):
         steps they are sums over, the cache slots written and, where the
         layers keep a latent cache, the positions the lanes could read in it
         (``latent_positions_read``: summed over steps, lanes and layers, the
-        count its floor is taken from). Per lane, in step order (the last
+        count its floor is taken from) and the positions in the blocks the
+        cache pass's kernel fetched for them (``latent_positions_fetched``; 0
+        where the plain form ran). Per lane, in step order (the last
         ``max_positions`` steps): the id each step consumed and the lane's
         position in its episode there, ``(n, steps)``; the model's token for
         position ``t`` of an episode is the id consumed at ``t + 1``."""
@@ -761,8 +802,9 @@ class _Decoder(_LaneModule):
         seen = state["seen"]
         # the ring's oldest step comes first
         first = jnp.where(seen["step"][0] > self.max_positions, seen["step"][0] % self.max_positions, 0)
-        latent = [s["attn"]["read"] for s in layers if "read" in s["attn"]]
-        report = {"latent_positions_read": sum((jnp.sum(r) for r in latent), zero)} if latent else {}
+        latent_layers = [s["attn"] for s in layers if "read" in s["attn"]]
+        counted = {"latent_positions_read": "read", "latent_positions_fetched": "fetched"} if latent_layers else {}
+        report = {key: sum((jnp.sum(s[name]) for s in latent_layers), zero) for key, name in counted.items()}
         return {
             **report,
             "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),

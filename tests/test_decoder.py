@@ -403,7 +403,7 @@ def test_absorbed_attention_equals_the_plain_form():
     assert relative_rms(got - h, want - h) < 1e-5
     # the state: one compressed row and one shared RoPE key a position, nothing per head
     shapes = {name: leaf.shape for name, leaf in state.items()}
-    assert shapes == {"c": (STEPS, s["kv_rank"]), "kr": (STEPS, s["rope"]), "t": (), "step": (), "read": ()}
+    assert shapes == {"c": (STEPS, s["kv_rank"]), "kr": (STEPS, s["rope"]), "t": (), "step": (), "read": (), "fetched": ()}
     assert int(state["read"]) == STEPS * (STEPS + 1) // 2
 
 
