@@ -1,14 +1,17 @@
-"""A sparse-expert decoder as an evolvable policy, decoded stepwise.
+"""A language-model decoder as an evolvable policy, decoded stepwise.
 
-The modules of two families: ``afmoe`` (Trinity: gated grouped-query
-attention, a norm before and after every block) and ``glm4_moe_lite``
-(GLM-4.7-Flash: latent attention, a norm before a block only). Token
-embedding, RMSNorm, an attention with a per-lane cache as the policy's
-recurrent state, SwiGLU, and a sigmoid-routed expert layer that is told which
-experts it holds. They follow the ``Module`` protocol of ``layers.py``
-(``init`` / ``initial_state`` / ``apply(params, x, state)``), so a decoder is
-a policy like any other: the observation is one token id, the output the
-logits over the held vocabulary, the state the attention cache.
+The modules of three families: ``afmoe`` (Trinity: gated grouped-query
+attention, a norm before and after every block, sigmoid-routed experts),
+``glm4_moe_lite`` (GLM-4.7-Flash: latent attention, a norm before a block
+only) and ``granitemoehybrid`` (Granite 4.0-H: Mamba-2 mixers with one
+grouped-query attention layer in ten, dense MLPs, a tied head, four
+multipliers). Token embedding, RMSNorm, an attention with a per-lane cache
+or a recurrence with a per-lane matrix state as the policy's recurrent state,
+SwiGLU, and a sigmoid-routed expert layer that is told which experts it
+holds. They follow the ``Module`` protocol of ``layers.py`` (``init`` /
+``initial_state`` / ``apply(params, x, state)``), so a decoder is a policy
+like any other: the observation is one token id, the output the logits over
+the held vocabulary, the state what its layers carry from step to step.
 
 Two forwards per module, one set of equations (``_forward`` of each class
 takes the two accessors that differ):
@@ -67,11 +70,29 @@ under the mask; the statement of the equations). The state counts what a lane
 could read (``read``) and the positions in the blocks fetched for it
 (``fetched``: 0 where the plain form ran); neither is ever reset.
 
-**What a lane consumed.** The decoder's state also keeps, per lane, the token
-id and the lane's position ``t`` of each of the last ``max_positions`` steps
-(``seen``; written like the cache, never reset): the generated text of an
-evaluation is its observations, so ``state_report`` hands back what every
-lane read and wrote, and ``stepwise_logits`` replays such a record.
+**The recurrent state.** ``Mamba2Mixer`` keeps, per lane and layer, the
+window of its convolution's last ``width - 1`` inputs and one matrix state
+``(heads, head_dim, state_dim)`` (1 MiB in bfloat16 at Granite's widths), in
+the compute dtype like the caches. Where a cache is written one slot a step
+and read under a mask, ALL of this state is rewritten every step: decay,
+outer product, readout and write-back are one elementwise pass over it in
+float32 (``fwd_ssm_state``), so the loop holds each state once and no step
+copies or selects over one. ``reset_state`` zeroes the lanes that ended an
+episode lane by lane, as the caches are. Each lane's transition is its own:
+``A_log``, ``dt_bias`` and ``D`` are 1-D leaves, perturbed like any other.
+A layer's state sits under its first block's kind (``"attn"`` or ``"ssm"``).
+When a lane ends an episode the mixer keeps, before it zeroes the lane, what
+its matrix states held, summed over their last axis (``ended``): nothing a
+step pays for, and what an evaluation's report holds against a reference's
+recurrence.
+
+**What a lane consumed.** The decoder's state keeps each lane's position in
+its episode (``t``, its own: reset with the lane, whatever kinds of layer it
+holds) and, per lane, the token id and that position of each of the last
+``max_positions`` steps (``seen``; written like the cache, never reset): the
+generated text of an evaluation is its observations, so ``state_report``
+hands back what every lane read and wrote, and ``stepwise_logits`` replays
+such a record.
 
 No reference counterpart: the reference evaluates MLP and small recurrent
 policies only.
@@ -97,10 +118,12 @@ __all__ = [
     "SwiGLU",
     "GatedAttention",
     "LatentAttention",
+    "Mamba2Mixer",
     "SparseExperts",
     "DecoderLayer",
     "AfmoeDecoder",
     "Glm4MoeLiteDecoder",
+    "GraniteMoeHybridDecoder",
     "stepwise_logits",
 ]
 
@@ -139,15 +162,24 @@ def _closed(module, acc, out):
     return rms_norm(out, acc.vec("post_norm"), module.eps) if module.post_norm else out
 
 
+def _joined(module, x, out):
+    """``x + out``, ``out`` times the family's residual multiplier where it
+    has one."""
+    return x + (out if module.residual_scale == 1.0 else out * module.residual_scale)
+
+
 # -- the two accessors ---------------------------------------------------------
 # A module's equations read its parameters through ``mm(name, x)`` (x times
 # the transposed ``(out, in)`` weight), ``head_mm(name, x, heads, rows,
 # into=...)`` (x ``(n, heads, .)`` times each head's row block of the leaf
 # viewed ``(heads, out / heads, in)``: ``into`` the leaf's input space, ``x @
-# W[h, rows]``, or out of it, ``x @ W[h, rows]^T``) and ``vec(name)`` (a 1-D
-# parameter, broadcast against the lanes). Dense: one lane, its own weights,
-# a lane axis of one. Trunk-delta: all lanes, shared trunk plus per-lane
-# rank-k delta ``B diag(z) A^T``, whose row block is ``B[rows] diag(z) A^T``.
+# W[h, rows]``, or out of it, ``x @ W[h, rows]^T``), ``vec(name)`` (a 1-D
+# parameter, broadcast against the lanes), ``mat(name)`` (a SMALL ``(out,
+# in)`` leaf written out per lane, for elementwise use: the depthwise
+# convolution's taps) and ``rows(name, ids)`` (rows of a table). Dense: one
+# lane, its own weights, a lane axis of one. Trunk-delta: all lanes, shared
+# trunk plus per-lane rank-k delta ``B diag(z) A^T``, whose row block is
+# ``B[rows] diag(z) A^T``.
 
 
 def _head_block(leaf, heads, rows, dtype):
@@ -170,6 +202,9 @@ class _Dense:
         return jnp.einsum("nhr,hri->nhi" if into else "nhi,hri->nhr", x, w)
 
     def vec(self, name):
+        return self.p[name][None]
+
+    def mat(self, name):
         return self.p[name][None]
 
     def rows(self, name, ids):
@@ -204,6 +239,10 @@ class _Trunk:
 
     def vec(self, name):
         return self.p[name] + self.z @ self.f[name].b.T
+
+    def mat(self, name):
+        f = self.f[name]
+        return self.p[name] + jnp.einsum("ok,nk,ik->noi", f.b, self.z, f.a)
 
     def rows(self, name, ids):
         f = self.f[name]
@@ -293,7 +332,12 @@ class GatedAttention(_LaneModule):
     """``x + RMSNorm(W_o((softmax(q.k / sqrt(d)) . v) * sigmoid(W_g xn)))``
     with ``xn = RMSNorm(x)``, per-head RMSNorm of ``q`` and ``k``, RoPE where
     ``rope_theta`` is given (the sliding layers), grouped-query heads, and the
-    cache of the module docstring as its state."""
+    cache of the module docstring as its state. What a family lacks is left
+    out: the output gate (``gate``), the per-head norms (``qk_norm``), the
+    closing norm (``post_norm``); ``score_scale`` takes the place of ``1 /
+    sqrt(d)`` and ``residual_scale`` multiplies what joins the residual."""
+
+    block_key = "attn"  # a layer's first block in parameters and state
 
     def __init__(
         self,
@@ -305,27 +349,39 @@ class GatedAttention(_LaneModule):
         slots: int,
         rope_theta: Optional[float],
         eps: float = 1e-5,
+        gate: bool = True,
+        qk_norm: bool = True,
+        post_norm: bool = True,
+        score_scale: Optional[float] = None,
+        residual_scale: float = 1.0,
     ):
         self.dim, self.heads, self.kv_heads = int(dim), int(num_heads), int(num_kv_heads)
         self.head_dim, self.slots, self.eps = int(head_dim), int(slots), float(eps)
         self.rope_theta = None if rope_theta is None else float(rope_theta)
+        self.gate, self.qk_norm, self.post_norm = bool(gate), bool(qk_norm), bool(post_norm)
+        self.score_scale = None if score_scale is None else float(score_scale)
+        self.residual_scale = float(residual_scale)
         if self.heads % self.kv_heads:
             raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
 
     def init(self, key):
         kq, kk, kv, kg, ko = jax.random.split(key, 5)
         wide, narrow = self.heads * self.head_dim, self.kv_heads * self.head_dim
-        return {
+        params = {
             "in_norm": jnp.ones((self.dim,), F32),
             "q": _normal(kq, (wide, self.dim)),
             "k": _normal(kk, (narrow, self.dim)),
             "v": _normal(kv, (narrow, self.dim)),
-            "g": _normal(kg, (wide, self.dim)),
-            "q_norm": jnp.ones((self.head_dim,), F32),
-            "k_norm": jnp.ones((self.head_dim,), F32),
             "o": _normal(ko, (self.dim, wide)),
-            "post_norm": jnp.ones((self.dim,), F32),
         }
+        if self.gate:
+            params["g"] = _normal(kg, (wide, self.dim))
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((self.head_dim,), F32)
+            params["k_norm"] = jnp.ones((self.head_dim,), F32)
+        if self.post_norm:
+            params["post_norm"] = jnp.ones((self.dim,), F32)
+        return params
 
     def initial_state(self):
         cache = jnp.zeros((self.kv_heads, self.slots, self.head_dim), F32)
@@ -357,9 +413,11 @@ class GatedAttention(_LaneModule):
             q = acc.mm("q", xn).reshape(n, kv, group, hd)
             k = acc.mm("k", xn).reshape(n, kv, hd)
             v = acc.mm("v", xn).reshape(n, kv, hd)
-            gate = acc.mm("g", xn)
-            q = rms_norm(q, acc.vec("q_norm")[:, None, None, :], self.eps)
-            k = rms_norm(k, acc.vec("k_norm")[:, None, :], self.eps)
+            if self.gate:
+                gate = acc.mm("g", xn)
+            if self.qk_norm:
+                q = rms_norm(q, acc.vec("q_norm")[:, None, None, :], self.eps)
+                k = rms_norm(k, acc.vec("k_norm")[:, None, :], self.eps)
             t = state["t"]
             if self.rope_theta is not None:
                 q = rope(q, t[:, None, None], self.rope_theta)
@@ -371,15 +429,15 @@ class GatedAttention(_LaneModule):
             vc = jax.lax.dynamic_update_slice(state["v"], v[:, :, None, :].astype(cache_dtype), at)
             age = jnp.mod(slot - jnp.arange(slots, dtype=jnp.int32), slots)
             readable = age[None, :] <= t[:, None]  # (n, slots)
-            scores = jnp.einsum(
-                "nkgd,nksd->nkgs", q.astype(cache_dtype), kc, preferred_element_type=F32
-            ) / math.sqrt(hd)
+            scores = jnp.einsum("nkgd,nksd->nkgs", q.astype(cache_dtype), kc, preferred_element_type=F32)
+            scores = scores / math.sqrt(hd) if self.score_scale is None else scores * self.score_scale
             scores = jnp.where(readable[:, None, None, :], scores, -jnp.inf)
             weights = jax.nn.softmax(scores, axis=-1).astype(cache_dtype)
             mixed = jnp.einsum("nkgs,nksd->nkgd", weights, vc, preferred_element_type=F32)
-            mixed = mixed.reshape(n, self.heads * hd) * jax.nn.sigmoid(gate.astype(F32))
-            out = acc.mm("o", mixed.astype(x.dtype))
-            y = x + rms_norm(out, acc.vec("post_norm"), self.eps)
+            mixed = mixed.reshape(n, self.heads * hd)
+            if self.gate:
+                mixed = mixed * jax.nn.sigmoid(gate.astype(F32))
+            y = _joined(self, x, _closed(self, acc, acc.mm("o", mixed.astype(x.dtype))))
         return y, {"k": kc, "v": vc, "t": t + 1, "step": state["step"] + 1}
 
 
@@ -392,6 +450,8 @@ class LatentAttention(_LaneModule):
     softmax in float32; ``a_h = sum_s p_{h,s} v_{h,s}``. No bias, no gate, no
     norm after the block. Computed in the absorbed form over the latent
     cache of the module docstring, which is its state."""
+
+    block_key = "attn"
 
     def __init__(
         self,
@@ -519,6 +579,141 @@ class LatentAttention(_LaneModule):
             "step": state["step"] + 1,
             "read": state["read"] + jnp.minimum(t + 1, slots),
             "fetched": state["fetched"] + fetched,
+        }
+
+
+class Mamba2Mixer(_LaneModule):
+    """``x + r W_out(RMSNorm(y * silu(z)))`` with the Mamba-2 recurrence over
+    ``xn = RMSNorm(x)``: ``[z | xBC | dt] = W_in xn`` (``inner | inner + 2
+    state_dim | heads``, ``inner = heads x head_dim``); ``xBC = silu(sum_k
+    w[k] xBC_{t-width+1+k} + b)``, a depthwise causal convolution over the
+    lane's window of its last inputs; ``[x | B | C] = xBC``; ``dt =
+    softplus(dt + dt_bias)``, ``a = exp(-dt exp(A_log))`` a head; ``S_t = a
+    S_{t-1} + dt x_t (outer) B_t`` a head, ``(head_dim, state_dim)``; ``y =
+    S_t C_t + D x_t``; the gated norm over all of ``inner``. One group of
+    ``B`` and ``C`` for all heads; no bias but the convolution's; no clamp on
+    ``dt``. ``A_log``, ``dt_bias`` and ``D`` are 1-D leaves, so every lane's
+    transition is its own, and the convolution's ``(width, channels)`` leaf
+    (taps first: a leaf whose last axis is 4 would pad 32-fold in the TPU's
+    tiles, and the compiler reshapes the whole flat trunk around it) is
+    written out per lane and used elementwise (``mat``).
+
+    **The state** is what the lane carries from step to step, and unlike a
+    cache ALL of it is rewritten every step: the window ``(width - 1,
+    channels)`` of the convolution's last inputs and the matrix state
+    ``(heads, head_dim, state_dim)``, stored in the compute dtype and updated
+    in float32 (decay, outer product and readout are one elementwise pass
+    over it, under ``fwd_ssm_state``). A lane that starts an episode has both
+    zero. Kept beyond an episode: what the matrix state held when the lane
+    last ENDED an episode, summed over its last axis (``ended``: ``sum_s S[h,
+    p, s]``, ``(heads, head_dim)``, written by ``reset_state`` before it
+    zeroes the lane), the steps the lane's state was rewritten and the times
+    it was zeroed."""
+
+    block_key = "ssm"
+
+    def __init__(
+        self,
+        dim: int,
+        num_heads: int,
+        head_dim: int,
+        state_dim: int,
+        *,
+        conv_width: int = 4,
+        eps: float = 1e-5,
+        residual_scale: float = 1.0,
+    ):
+        self.dim, self.heads, self.head_dim = int(dim), int(num_heads), int(head_dim)
+        self.state_dim, self.width, self.eps = int(state_dim), int(conv_width), float(eps)
+        self.inner = self.heads * self.head_dim
+        self.channels = self.inner + 2 * self.state_dim  # what the convolution runs over: x, B, C
+        self.residual_scale = float(residual_scale)
+
+    def init(self, key):
+        """Matrices as the family draws them; the convolution and the
+        transition as ``mamba_ssm``'s Mamba-2 does: taps and bias uniform
+        within ``1 / sqrt(width)`` (``nn.Conv1d``), ``A`` uniform in [1, 16],
+        ``dt`` log-uniform in [1e-3, 1e-1] with ``dt_bias`` its inverse
+        softplus, ``D`` ones."""
+        ki, ko, kw, kb, ka, kd = jax.random.split(key, 6)
+        bound = 1.0 / math.sqrt(self.width)
+        dt = jnp.exp(jax.random.uniform(kd, (self.heads,), F32, math.log(1e-3), math.log(1e-1)))
+        return {
+            "in_norm": jnp.ones((self.dim,), F32),
+            "in_proj": _normal(ki, (2 * self.inner + 2 * self.state_dim + self.heads, self.dim)),
+            "conv": jax.random.uniform(kw, (self.width, self.channels), F32, -bound, bound),
+            "conv_bias": jax.random.uniform(kb, (self.channels,), F32, -bound, bound),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(jax.random.uniform(ka, (self.heads,), F32, 1.0, 16.0)),
+            "D": jnp.ones((self.heads,), F32),
+            "norm": jnp.ones((self.inner,), F32),
+            "out_proj": _normal(ko, (self.dim, self.inner)),
+        }
+
+    def initial_state(self):
+        zero = jnp.zeros((), jnp.int32)
+        return {
+            "conv": jnp.zeros((self.width - 1, self.channels), F32),
+            "ssm": jnp.zeros((self.heads, self.head_dim, self.state_dim), F32),
+            "ended": jnp.zeros((self.heads, self.head_dim), F32),
+            "updates": zero,
+            "resets": zero,
+        }
+
+    def reset_state(self, state, mask):
+        """As ``GatedAttention.reset_state``: the window and the matrix state
+        of the lanes in ``mask`` are zeroed one lane at a time (a select over
+        a whole state would read and write all of it in every control step;
+        an episode's end is rare). What the lane's matrix state held is kept
+        first, summed over its last axis (``ended``)."""
+        conv, ssm, kept = state["conv"], state["ssm"], state["ended"]
+        ended = jnp.nonzero(mask, size=mask.shape[0], fill_value=0)[0]
+        no_window = jnp.zeros((1,) + conv.shape[1:], conv.dtype)
+        no_state = jnp.zeros((1,) + ssm.shape[1:], ssm.dtype)
+
+        def end_lane(i, held):
+            conv, ssm, kept = held
+            lane = ended[i]
+            was = jax.lax.dynamic_slice(ssm, (lane, 0, 0, 0), no_state.shape)
+            summed = jnp.sum(was.astype(F32), axis=-1).astype(kept.dtype)
+            return (
+                jax.lax.dynamic_update_slice(conv, no_window, (lane, 0, 0)),
+                jax.lax.dynamic_update_slice(ssm, no_state, (lane, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(kept, summed, (lane, 0, 0)),
+            )
+
+        conv, ssm, kept = jax.lax.fori_loop(0, jnp.sum(mask.astype(jnp.int32)), end_lane, (conv, ssm, kept))
+        return {**state, "conv": conv, "ssm": ssm, "ended": kept, "resets": state["resets"] + mask.astype(jnp.int32)}
+
+    def _forward(self, acc, x, state):
+        n, heads, inner = x.shape[0], self.heads, self.inner
+        with scope("fwd_ssm"):
+            xn = rms_norm(x, acc.vec("in_norm"), self.eps)
+            gate, xbc, dt = jnp.split(acc.mm("in_proj", xn), [inner, inner + self.channels], axis=-1)
+            held = state["conv"]
+            taps = jnp.concatenate([held, xbc[:, None, :].astype(held.dtype)], axis=1)  # oldest first
+            weights = acc.mat("conv").astype(F32)  # (lanes or 1, width, channels)
+            xbc = jnp.sum(taps.astype(F32) * weights, axis=1) + acc.vec("conv_bias").astype(F32)
+            xs, b, c = jnp.split(jax.nn.silu(xbc), [inner, inner + self.state_dim], axis=-1)
+            xs = xs.reshape(n, heads, self.head_dim)
+            dt = jax.nn.softplus(dt.astype(F32) + acc.vec("dt_bias").astype(F32))  # (n, heads)
+            rate = -jnp.exp(acc.vec("A_log").astype(F32))
+            with scope("fwd_ssm_state"):
+                decay = jnp.exp(dt * rate)[:, :, None, None]
+                fed = (dt[:, :, None] * xs)[:, :, :, None] * b[:, None, None, :]
+                ssm = state["ssm"].astype(F32) * decay + fed
+                y = jnp.sum(ssm * c[:, None, None, :], axis=-1)  # (n, heads, head_dim)
+                ssm = ssm.astype(state["ssm"].dtype)
+            y = y + acc.vec("D").astype(F32)[:, :, None] * xs
+            y = y.reshape(n, inner) * jax.nn.silu(gate.astype(F32))
+            y = rms_norm(y, acc.vec("norm"), self.eps).astype(x.dtype)
+            y = _joined(self, x, acc.mm("out_proj", y))
+        return y, {
+            "conv": taps[:, 1:],
+            "ssm": ssm,
+            "ended": state["ended"],
+            "updates": state["updates"] + 1,
+            "resets": state["resets"],
         }
 
 
@@ -698,11 +893,13 @@ class SparseExperts(_LaneModule):
 
 class _DenseMLP(_LaneModule):
     """``x + RMSNorm(SwiGLU(RMSNorm(x)))`` (``x + SwiGLU(RMSNorm(x))``
-    without ``post_norm``): the MLP of the leading dense layers."""
+    without ``post_norm``; the block's output times ``residual_scale``): the
+    MLP of the leading dense layers, and of a family whose every MLP is
+    dense."""
 
-    def __init__(self, dim: int, width: int, *, eps: float = 1e-5, post_norm: bool = True):
+    def __init__(self, dim: int, width: int, *, eps: float = 1e-5, post_norm: bool = True, residual_scale: float = 1.0):
         self.inner, self.dim, self.eps = SwiGLU(dim, width), int(dim), float(eps)
-        self.post_norm = bool(post_norm)
+        self.post_norm, self.residual_scale = bool(post_norm), float(residual_scale)
 
     def init(self, key):
         params = {"in_norm": jnp.ones((self.dim,), F32), "mlp": self.inner.init(key)}
@@ -714,44 +911,64 @@ class _DenseMLP(_LaneModule):
         with scope("fwd_dense_mlp"):
             y = rms_norm(x, acc.vec("in_norm"), self.eps)
             out, _ = self.inner._forward(acc.sub("mlp"), y, None)
-            return x + _closed(self, acc, out), state
+            return _joined(self, x, _closed(self, acc, out)), state
 
 
 class DecoderLayer(_LaneModule):
-    """Attention, then the layer's MLP (dense or sparse)."""
+    """The layer's first block (an attention over a cache, or a recurrence),
+    then its MLP (dense or sparse). Parameters and state hold the first block
+    under its kind's name (``block_key``: ``"attn"`` or ``"ssm"``)."""
 
     def __init__(self, attention: _LaneModule, mlp: _LaneModule):
-        self.attention, self.mlp = attention, mlp
+        self.attention, self.mlp, self.first = attention, mlp, attention.block_key
 
     def init(self, key):
         ka, km = jax.random.split(key)
-        return {"attn": self.attention.init(ka), "mlp": self.mlp.init(km)}
+        return {self.first: self.attention.init(ka), "mlp": self.mlp.init(km)}
 
     def initial_state(self):
-        return {"attn": self.attention.initial_state(), "mlp": self.mlp.initial_state()}
+        return {self.first: self.attention.initial_state(), "mlp": self.mlp.initial_state()}
 
     def reset_state(self, state, mask):
         mlp = state["mlp"]
         return {
-            "attn": self.attention.reset_state(state["attn"], mask),
+            self.first: self.attention.reset_state(state[self.first], mask),
             "mlp": None if mlp is None else self.mlp.reset_state(mlp, mask),
         }
 
     def _forward(self, acc, x, state):
-        x, attn = self.attention._forward(acc.sub("attn"), x, state["attn"])
+        x, first = self.attention._forward(acc.sub(self.first), x, state[self.first])
         x, mlp = self.mlp._forward(acc.sub("mlp"), x, state["mlp"])
-        return x, {"attn": attn, "mlp": mlp}
+        return x, {self.first: first, "mlp": mlp}
 
 
 class _Decoder(_LaneModule):
     """What every family's decoder is around its layers: embedding rows
     (times ``embed_scale``), the held ``layers``, the final norm and the head
-    over the held vocabulary; the ``seen`` record, ``state_report`` and
-    ``reset_state``. Input: one token id; output: float logits over the held
-    vocabulary. A family's constructor builds its layers from its published
-    keys and hands them over."""
+    over the held vocabulary (``tie_embeddings``: the embedding's own leaf,
+    gathered by ``rows`` on the way in and multiplied by ``mm`` on the way
+    out, one factor pair for both; the logits over ``logits_divisor``); the
+    ``seen`` record, ``state_report`` and ``reset_state``. Input: one token
+    id; output: float logits over the held vocabulary. A family's constructor
+    builds its layers from its published keys and hands them over.
 
-    def __init__(self, *, hidden_size, eps, vocab_size, vocab_held, max_positions, layers, embed_scale=1.0):
+    A lane's position in its episode (``t``) and the steps since the state
+    was made (``seen["step"]``) are the decoder's own: it looks into no
+    layer's state for them, so a set of held layers needs no attention."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size,
+        eps,
+        vocab_size,
+        vocab_held,
+        max_positions,
+        layers,
+        embed_scale=1.0,
+        tie_embeddings=False,
+        logits_divisor=1.0,
+    ):
         self.hidden_size, self.eps = int(hidden_size), float(eps)
         self.vocab_held = int(vocab_size if vocab_held is None else vocab_held)
         if not 0 < self.vocab_held <= int(vocab_size):
@@ -759,26 +976,31 @@ class _Decoder(_LaneModule):
         self.max_positions = int(max_positions)
         self.layers = tuple(layers)
         self.embedding = Embedding(self.vocab_held, hidden_size, scale=embed_scale)
+        self.tie_embeddings, self.logits_divisor = bool(tie_embeddings), float(logits_divisor)
 
     def init(self, key):
         ke, kh, *kl = jax.random.split(key, 2 + len(self.layers))
-        return {
+        params = {
             "embed": self.embedding.init(ke)["weight"],
             "layers": tuple(layer.init(k) for layer, k in zip(self.layers, kl)),
             "final_norm": jnp.ones((self.hidden_size,), F32),
-            "head": _normal(kh, (self.vocab_held, self.hidden_size)),
         }
+        if not self.tie_embeddings:
+            params["head"] = _normal(kh, (self.vocab_held, self.hidden_size))
+        return params
 
     def initial_state(self):
-        slots = jnp.zeros((self.max_positions,), jnp.int32)
+        slots, zero = jnp.zeros((self.max_positions,), jnp.int32), jnp.zeros((), jnp.int32)
         return {
             "layers": tuple(layer.initial_state() for layer in self.layers),
-            "seen": {"ids": slots, "positions": slots, "step": jnp.zeros((), jnp.int32)},
+            "seen": {"ids": slots, "positions": slots, "step": zero},
+            "t": zero,
         }
 
     def reset_state(self, state, mask):
         layers = tuple(layer.reset_state(s, mask) for layer, s in zip(self.layers, state["layers"]))
-        return {"layers": layers, "seen": state["seen"]}  # the record outlives an episode
+        # the record outlives an episode
+        return {"layers": layers, "seen": state["seen"], "t": jnp.where(mask, 0, state["t"])}
 
     def state_report(self, state) -> dict:
         """What the lane-batched ``state`` says of the steps since it was
@@ -792,7 +1014,15 @@ class _Decoder(_LaneModule):
         (``latent_positions_read``: summed over steps, lanes and layers, the
         count its floor is taken from) and the positions in the blocks the
         cache pass's kernel fetched for them (``latent_positions_fetched``; 0
-        where the plain form ran). Per lane, in step order (the last
+        where the plain form ran); where layers carry a recurrence, the
+        lane-layer states rewritten, summed over steps
+        (``ssm_state_updates``), the lanes zeroed at an episode's end
+        (``ssm_lane_resets``), the bytes of windows and matrix states the
+        lanes hold (``ssm_state_bytes``, a float) and, per lane, what every
+        recurrent layer's matrix state held when the lane last ended an
+        episode, summed over its last axis (``ssm_ended_state``, ``(n,
+        recurrent layers, heads, head_dim)``, as stored; zeros for a lane
+        that ended none). Per lane, in step order (the last
         ``max_positions`` steps): the id each step consumed and the lane's
         position in its episode there, ``(n, steps)``; the model's token for
         position ``t`` of an episode is the id consumed at ``t + 1``."""
@@ -802,17 +1032,27 @@ class _Decoder(_LaneModule):
         seen = state["seen"]
         # the ring's oldest step comes first
         first = jnp.where(seen["step"][0] > self.max_positions, seen["step"][0] % self.max_positions, 0)
-        latent_layers = [s["attn"] for s in layers if "read" in s["attn"]]
+        caches = [s["attn"] for s in layers if "attn" in s]
+        latent_layers = [c for c in caches if "read" in c]
         counted = {"latent_positions_read": "read", "latent_positions_fetched": "fetched"} if latent_layers else {}
         report = {key: sum((jnp.sum(s[name]) for s in latent_layers), zero) for key, name in counted.items()}
+        recurrent = [s["ssm"] for s in layers if "ssm" in s]
+        if recurrent:
+            held = sum(math.prod(m[name].shape) * m[name].dtype.itemsize for m in recurrent for name in ("conv", "ssm"))
+            report.update(
+                ssm_state_updates=sum((jnp.sum(m["updates"]) for m in recurrent), zero),
+                ssm_lane_resets=jnp.sum(recurrent[0]["resets"]),
+                ssm_state_bytes=jnp.asarray(held, F32),  # past int32 at the cell's size
+                ssm_ended_state=jnp.stack([m["ended"] for m in recurrent], axis=1),
+            )
         return {
             **report,
             "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),
             "expert_pairs_fullest": sum((m["fullest"][0] for m in sparse), zero),
             "expert_row_tiles": sum((m["tiles"][0] for m in sparse), zero),
             "expert_tile_rows": jnp.asarray(grouped.ROW_TILE, jnp.int32),
-            "expert_layer_steps": sum((s["attn"]["step"][0] for s in layers if s["mlp"] is not None), zero),
-            "cache_slots_written": sum((jnp.sum(s["attn"]["step"]) for s in layers), zero),
+            "expert_layer_steps": sum((seen["step"][0] for s in layers if s["mlp"] is not None), zero),
+            "cache_slots_written": sum((jnp.sum(c["step"]) for c in caches), zero),
             "ids_seen": jnp.roll(seen["ids"], -first, axis=1),
             "positions_seen": jnp.roll(seen["positions"], -first, axis=1),
         }
@@ -825,7 +1065,7 @@ class _Decoder(_LaneModule):
                 h = h.astype(acc.z.dtype)  # the lanes' compute dtype (ids carry none)
             seen = state["seen"]
             at = (0, jnp.mod(seen["step"][0], self.max_positions))
-            positions = state["layers"][0]["attn"]["t"]
+            positions = state["t"]
             seen = {
                 "ids": jax.lax.dynamic_update_slice(seen["ids"], ids[:, None], at),
                 "positions": jax.lax.dynamic_update_slice(seen["positions"], positions[:, None], at),
@@ -837,8 +1077,10 @@ class _Decoder(_LaneModule):
             h, s = layer._forward(layers.sub(i), h, state["layers"][i])
             new_state.append(s)
         with scope("fwd_head"):
-            logits = acc.mm("head", rms_norm(h, acc.vec("final_norm"), self.eps))
-        return logits, {"layers": tuple(new_state), "seen": seen}
+            logits = acc.mm("embed" if self.tie_embeddings else "head", rms_norm(h, acc.vec("final_norm"), self.eps))
+            if self.logits_divisor != 1.0:
+                logits = logits / self.logits_divisor
+        return logits, {"layers": tuple(new_state), "seen": seen, "t": positions + 1}
 
 
 class AfmoeDecoder(_Decoder):
@@ -1010,6 +1252,105 @@ class Glm4MoeLiteDecoder(_Decoder):
             max_positions=max_positions,
             layers=layers,
         )
+
+
+class GraniteMoeHybridDecoder(_Decoder):
+    """The ``granitemoehybrid`` decoder (Granite 4.0-H) under the published
+    configuration's own keys, plus the share this process holds
+    (``layers_held``, ``vocab_held``, ``max_positions``, as ``AfmoeDecoder``
+    takes them). A layer's kind follows from ``layer_types``: ``"mamba"`` a
+    Mamba-2 mixer, ``"attention"`` grouped-query attention without positions
+    (``position_embedding_type`` ``"nope"``), without gate or per-head norms,
+    its scores times ``attention_multiplier``. Every layer's MLP is the shared
+    one (``num_local_experts`` 0: no routed experts) of width
+    ``shared_intermediate_size``; a norm before a block only, a block's
+    output times ``residual_multiplier``; embedding rows times
+    ``embedding_multiplier``; the head is the embedding's own leaf
+    (``tie_word_embeddings``) and the logits are over ``logits_scaling``.
+    ``mamba_chunk_size`` belongs to the whole-sequence scan and is no part of
+    a stepwise forward."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size: int,
+        num_attention_heads: int,
+        num_key_value_heads: int,
+        shared_intermediate_size: int,
+        layer_types: Sequence[str],
+        mamba_n_heads: int,
+        mamba_d_head: int,
+        mamba_d_state: int,
+        mamba_n_groups: int,
+        mamba_d_conv: int,
+        mamba_expand: int,
+        mamba_conv_bias: bool,
+        mamba_proj_bias: bool,
+        num_local_experts: int,
+        attention_bias: bool,
+        attention_multiplier: float,
+        embedding_multiplier: float,
+        residual_multiplier: float,
+        logits_scaling: float,
+        position_embedding_type: str,
+        tie_word_embeddings: bool,
+        rms_norm_eps: float,
+        vocab_size: int,
+        max_positions: int,
+        layers_held: Optional[Sequence[int]] = None,
+        vocab_held: Optional[int] = None,
+    ):
+        if int(num_local_experts) != 0:
+            raise ValueError("num_local_experts other than 0: routed experts are not implemented in this family")
+        if int(mamba_n_groups) != 1:
+            raise ValueError("mamba_n_groups other than 1 is not implemented")
+        if int(mamba_n_heads) * int(mamba_d_head) != int(mamba_expand) * int(hidden_size):
+            raise ValueError("mamba_n_heads x mamba_d_head is not mamba_expand x hidden_size")
+        if not mamba_conv_bias or mamba_proj_bias or attention_bias:
+            raise ValueError("only the convolution carries a bias (mamba_conv_bias alone)")
+        if position_embedding_type != "nope":
+            raise ValueError(f"position_embedding_type {position_embedding_type!r}: only 'nope' is implemented")
+        self.layers_held = tuple(range(len(layer_types)) if layers_held is None else layers_held)
+        eps, max_positions, scale = float(rms_norm_eps), int(max_positions), float(residual_multiplier)
+        layers = []
+        for index in self.layers_held:
+            kind = layer_types[index]
+            if kind == "mamba":
+                first = Mamba2Mixer(
+                    hidden_size, mamba_n_heads, mamba_d_head, mamba_d_state,
+                    conv_width=mamba_d_conv, eps=eps, residual_scale=scale,
+                )
+            elif kind == "attention":
+                first = GatedAttention(
+                    hidden_size,
+                    num_attention_heads,
+                    num_key_value_heads,
+                    int(hidden_size) // int(num_attention_heads),
+                    slots=max_positions,
+                    rope_theta=None,
+                    eps=eps,
+                    gate=False,
+                    qk_norm=False,
+                    post_norm=False,
+                    score_scale=attention_multiplier,
+                    residual_scale=scale,
+                )
+            else:
+                raise ValueError(f"layer_types[{index}] = {kind!r}")
+            mlp = _DenseMLP(hidden_size, shared_intermediate_size, eps=eps, post_norm=False, residual_scale=scale)
+            layers.append(DecoderLayer(first, mlp))
+        super().__init__(
+            hidden_size=hidden_size,
+            eps=eps,
+            vocab_size=vocab_size,
+            vocab_held=vocab_held,
+            max_positions=max_positions,
+            layers=layers,
+            embed_scale=embedding_multiplier,
+            tie_embeddings=tie_word_embeddings,
+            logits_divisor=logits_scaling,
+        )
+
 
 def stepwise_logits(policy, params_batch, ids, *, positions=None, lanes=None, compute_dtype=None):
     """Decode the id sequences ``ids`` ``(n, T)`` one token a step,

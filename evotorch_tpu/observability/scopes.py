@@ -39,7 +39,10 @@ gather, final norm, head), and ``fwd_latent_cache``, worn INSIDE
 ``fwd_attention`` by what latent attention does over its compressed cache
 (the write, the scores over ``c`` and ``k_r``, the softmax, the weighted sum
 over ``c``; the up-projections folded into the query and output paths stay
-``fwd_attention``). ``instruction_scopes`` keeps reading the OUTERMOST
+``fwd_attention``); ``fwd_ssm`` (a recurrent mixer: norm, ``in_proj``, the
+convolution over the lane's window, the gated norm, ``out_proj``) and,
+INSIDE it, ``fwd_ssm_state`` (whatever touches the matrix state: decay,
+outer product, readout). ``instruction_scopes`` keeps reading the OUTERMOST
 rollout scope, so what read ``policy_forward`` before still does;
 ``instruction_scopes(..., names=FORWARD_SCOPES)`` reads the INNERMOST
 component among the forward's names.
@@ -77,6 +80,8 @@ FORWARD_SCOPES = (
     "fwd_dense_mlp",
     "fwd_head",
     "fwd_latent_cache",
+    "fwd_ssm",
+    "fwd_ssm_state",
 )
 SCOPE_PREFIX = "evotorch_tpu."
 

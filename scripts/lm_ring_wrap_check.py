@@ -6,6 +6,7 @@ precision in the program's place (the control its bounds were set against).
     python scripts/lm_ring_wrap_check.py [--steps 2304] [--lanes 4] [--seed 1] [--cpu --tiny]
     python scripts/lm_ring_wrap_check.py --control int8,float8_e4m3,bfloat16 [--seed 1] [--cpu --tiny]
         [--cell glm47_flash_ep8.decode512] [--seeds 10]
+    python scripts/lm_ring_wrap_check.py --cell granite4_h_micro_pp4.decode256 --control int8,bfloat16
 
 Default: ``trinity_mini_ep8``'s share of the model (``benchmark/configs/``), a
 seeded trunk and one generation's rank-4 factors; ``--lanes`` lanes are

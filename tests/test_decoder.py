@@ -650,6 +650,7 @@ def test_budget_counts_are_exact_and_a_reset_lane_starts_clean(family, compute_d
         assert float(jnp.abs(attn[cache][1]).max()) == 0.0
         assert float(attn[cache][0].min()) == 1.0 and float(attn[cache][2].min()) == 1.0
     assert attn["t"].tolist() == [1, 0, 1] and attn["step"].tolist() == [1, 1, 1]
+    assert after["t"].tolist() == [1, 0, 1]  # the decoder's own position, reset with the lane
     assert after["seen"]["ids"].tolist() == state["seen"]["ids"].tolist()  # the record outlives an episode
     # what the evaluation recorded: every lane's ids and positions, step by
     # step; a lane begins at 0, goes on by one or begins again; some did
@@ -719,7 +720,8 @@ def test_inner_scopes_sit_inside_policy_forward(family):
     outer = instruction_scopes(text, inherit=False)
     inner = instruction_scopes(text, inherit=False, names=FORWARD_SCOPES)
     named = {name: scope for name, scope in inner.items() if scope is not None}
-    worn = set(FORWARD_SCOPES) - ({"fwd_latent_cache"} if family.name == "afmoe" else set())
+    worn = set(FORWARD_SCOPES) - {"fwd_ssm", "fwd_ssm_state"}  # a recurrent mixer's (tests/test_decoder_ssm.py)
+    worn -= {"fwd_latent_cache"} if family.name == "afmoe" else set()
     assert set(named.values()) == worn
     assert all(outer[name] == "policy_forward" for name in named)  # the outermost name stays
     paths = re.findall(r'op_name="([^"]*evotorch_tpu\.fwd_latent_cache[^"]*)"', text)
