@@ -71,18 +71,23 @@ could read (``read``) and the positions in the blocks fetched for it
 (``fetched``: 0 where the plain form ran); neither is ever reset.
 
 **The recurrent state.** ``Mamba2Mixer`` keeps, per lane and layer, the
-window of its convolution's last ``width - 1`` inputs and one matrix state
-``(heads, head_dim, state_dim)`` (1 MiB in bfloat16 at Granite's widths), in
-the compute dtype like the caches. Where a cache is written one slot a step
-and read under a mask, ALL of this state is rewritten every step: decay,
-outer product, readout and write-back are one elementwise pass over it in
-float32 (``fwd_ssm_state``), so the loop holds each state once and no step
-copies or selects over one. ``reset_state`` zeroes the lanes that ended an
+window of its convolution's last ``width - 1`` inputs and one matrix state,
+stored turned, ``(state_dim, heads x head_dim)`` (1 MiB in bfloat16 at
+Granite's widths), in the compute dtype like the caches. Where a cache is
+written one slot a step and read under a mask, ALL of this state is rewritten
+every step: decay, outer product, readout and write-back are one pass over it
+in float32 (``fwd_ssm_state``): ONE kernel a layer that updates a lane's
+state in VMEM and writes it back in place where the program is lowered for a
+TPU and the sizes are the kernel's (``net/ssmstate.py``), XLA's plain form
+everywhere else (``_state_plain``: the statement of the equations). Either
+way the loop holds each state once and no step copies or selects over one.
+The state counts the steps it was rewritten (``updates``) and those of them
+the kernel rewrote (``kernel_updates``). ``reset_state`` zeroes the lanes that ended an
 episode lane by lane, as the caches are. Each lane's transition is its own:
 ``A_log``, ``dt_bias`` and ``D`` are 1-D leaves, perturbed like any other.
 A layer's state sits under its first block's kind (``"attn"`` or ``"ssm"``).
 When a lane ends an episode the mixer keeps, before it zeroes the lane, what
-its matrix states held, summed over their last axis (``ended``): nothing a
+its matrix states held, summed over ``state_dim`` (``ended``): nothing a
 step pays for, and what an evaluation's report holds against a reference's
 recurrence.
 
@@ -109,7 +114,7 @@ import jax.numpy as jnp
 
 from ...envs.rigidbody import _by_platform
 from ...observability.scopes import scope
-from . import grouped, latent
+from . import grouped, latent, ssmstate
 from .layers import Module
 
 __all__ = [
@@ -600,15 +605,18 @@ class Mamba2Mixer(_LaneModule):
 
     **The state** is what the lane carries from step to step, and unlike a
     cache ALL of it is rewritten every step: the window ``(width - 1,
-    channels)`` of the convolution's last inputs and the matrix state
-    ``(heads, head_dim, state_dim)``, stored in the compute dtype and updated
-    in float32 (decay, outer product and readout are one elementwise pass
-    over it, under ``fwd_ssm_state``). A lane that starts an episode has both
-    zero. Kept beyond an episode: what the matrix state held when the lane
-    last ENDED an episode, summed over its last axis (``ended``: ``sum_s S[h,
-    p, s]``, ``(heads, head_dim)``, written by ``reset_state`` before it
-    zeroes the lane), the steps the lane's state was rewritten and the times
-    it was zeroed."""
+    channels)`` of the convolution's last inputs and the matrix state,
+    stored TURNED, ``(state_dim, heads x head_dim)`` (``S[h, p, s]`` lies at
+    ``[s, h head_dim + p]``: (head, row) runs along a register's lanes, so
+    the readout adds registers and the decay is a row vector), in the compute
+    dtype and updated in float32 (decay, outer product and readout are one
+    pass over it, under ``fwd_ssm_state``: ``_state_pass``). A lane that
+    starts an episode has both zero. Kept beyond an episode: what the matrix
+    state held when the lane last ENDED an episode, summed over ``state_dim``
+    (``ended``: ``sum_s S[h, p, s]``, ``(heads, head_dim)``, written by
+    ``reset_state`` before it zeroes the lane), the steps the lane's state
+    was rewritten, those of them the kernel rewrote, and the times it was
+    zeroed."""
 
     block_key = "ssm"
 
@@ -654,9 +662,10 @@ class Mamba2Mixer(_LaneModule):
         zero = jnp.zeros((), jnp.int32)
         return {
             "conv": jnp.zeros((self.width - 1, self.channels), F32),
-            "ssm": jnp.zeros((self.heads, self.head_dim, self.state_dim), F32),
+            "ssm": jnp.zeros((self.state_dim, self.inner), F32),  # turned: (head, row) runs along a register's lanes
             "ended": jnp.zeros((self.heads, self.head_dim), F32),
             "updates": zero,
+            "kernel_updates": zero,
             "resets": zero,
         }
 
@@ -665,7 +674,7 @@ class Mamba2Mixer(_LaneModule):
         of the lanes in ``mask`` are zeroed one lane at a time (a select over
         a whole state would read and write all of it in every control step;
         an episode's end is rare). What the lane's matrix state held is kept
-        first, summed over its last axis (``ended``)."""
+        first, summed over ``state_dim`` (``ended``)."""
         conv, ssm, kept = state["conv"], state["ssm"], state["ended"]
         ended = jnp.nonzero(mask, size=mask.shape[0], fill_value=0)[0]
         no_window = jnp.zeros((1,) + conv.shape[1:], conv.dtype)
@@ -674,16 +683,42 @@ class Mamba2Mixer(_LaneModule):
         def end_lane(i, held):
             conv, ssm, kept = held
             lane = ended[i]
-            was = jax.lax.dynamic_slice(ssm, (lane, 0, 0, 0), no_state.shape)
-            summed = jnp.sum(was.astype(F32), axis=-1).astype(kept.dtype)
+            was = jax.lax.dynamic_slice(ssm, (lane, 0, 0), no_state.shape)
+            summed = jnp.sum(was.astype(F32), axis=1).astype(kept.dtype).reshape((1,) + kept.shape[1:])
             return (
                 jax.lax.dynamic_update_slice(conv, no_window, (lane, 0, 0)),
-                jax.lax.dynamic_update_slice(ssm, no_state, (lane, 0, 0, 0)),
+                jax.lax.dynamic_update_slice(ssm, no_state, (lane, 0, 0)),
                 jax.lax.dynamic_update_slice(kept, summed, (lane, 0, 0)),
             )
 
         conv, ssm, kept = jax.lax.fori_loop(0, jnp.sum(mask.astype(jnp.int32)), end_lane, (conv, ssm, kept))
         return {**state, "conv": conv, "ssm": ssm, "ended": kept, "resets": state["resets"] + mask.astype(jnp.int32)}
+
+    @staticmethod
+    def _state_plain(ssm, decay, fed, b, c):
+        """The pass over the matrix states in XLA's own operations: ``S_t = a
+        S_{t-1} + (dt x_t) (outer) B_t``, ``y = S_t C_t``. ``ssm`` ``(n,
+        state_dim, inner)`` as stored; ``decay`` (``a``) ``(n, heads)``,
+        ``fed`` (``dt x_t``) ``(n, inner)``, ``b`` and ``c`` ``(n,
+        state_dim)``, all float32. The stored state converted to
+        float32, one multiply-add an entry, the readout of the UNROUNDED
+        state, one rounding to the stored dtype. The statement of the
+        equations, and what runs off the TPU and at sizes the kernel does not
+        take. Returns the new state, the float32 readout ``(n, inner)`` and,
+        per lane, the states a kernel rewrote: none."""
+        decay = jnp.repeat(decay, fed.shape[1] // decay.shape[1], axis=1)  # a head's, for each of its rows
+        new = ssm.astype(F32) * decay[:, None, :] + b[:, :, None] * fed[:, None, :]
+        y = jnp.sum(new * c[:, :, None], axis=1)
+        return new.astype(ssm.dtype), y, jnp.zeros(ssm.shape[:1], jnp.int32)
+
+    def _state_pass(self, ssm, decay, fed, b, c):
+        """``_state_plain``'s results: one kernel that brings a lane's state
+        through VMEM once and rewrites it in place (``net/ssmstate.py``) where
+        the program is lowered for a TPU and the sizes are the kernel's; the
+        plain form everywhere else."""
+        if not ssmstate.fits(ssm.shape[0], self.heads, self.head_dim, self.state_dim, ssm.dtype):
+            return self._state_plain(ssm, decay, fed, b, c)
+        return _by_platform(ssmstate.state_pass, self._state_plain, ssm, decay, fed, b, c)
 
     def _forward(self, acc, x, state):
         n, heads, inner = x.shape[0], self.heads, self.inner
@@ -699,12 +734,9 @@ class Mamba2Mixer(_LaneModule):
             dt = jax.nn.softplus(dt.astype(F32) + acc.vec("dt_bias").astype(F32))  # (n, heads)
             rate = -jnp.exp(acc.vec("A_log").astype(F32))
             with scope("fwd_ssm_state"):
-                decay = jnp.exp(dt * rate)[:, :, None, None]
-                fed = (dt[:, :, None] * xs)[:, :, :, None] * b[:, None, None, :]
-                ssm = state["ssm"].astype(F32) * decay + fed
-                y = jnp.sum(ssm * c[:, None, None, :], axis=-1)  # (n, heads, head_dim)
-                ssm = ssm.astype(state["ssm"].dtype)
-            y = y + acc.vec("D").astype(F32)[:, :, None] * xs
+                fed = (dt[:, :, None] * xs).reshape(n, inner)
+                ssm, y, rewrote = self._state_pass(state["ssm"], jnp.exp(dt * rate), fed, b, c)
+            y = y.reshape(xs.shape) + acc.vec("D").astype(F32)[:, :, None] * xs
             y = y.reshape(n, inner) * jax.nn.silu(gate.astype(F32))
             y = rms_norm(y, acc.vec("norm"), self.eps).astype(x.dtype)
             y = _joined(self, x, acc.mm("out_proj", y))
@@ -713,6 +745,7 @@ class Mamba2Mixer(_LaneModule):
             "ssm": ssm,
             "ended": state["ended"],
             "updates": state["updates"] + 1,
+            "kernel_updates": state["kernel_updates"] + rewrote,
             "resets": state["resets"],
         }
 
@@ -1016,11 +1049,13 @@ class _Decoder(_LaneModule):
         cache pass's kernel fetched for them (``latent_positions_fetched``; 0
         where the plain form ran); where layers carry a recurrence, the
         lane-layer states rewritten, summed over steps
-        (``ssm_state_updates``), the lanes zeroed at an episode's end
+        (``ssm_state_updates``), those of them the state pass's kernel
+        rewrote (``ssm_state_kernel_updates``; 0 where the plain form ran),
+        the lanes zeroed at an episode's end
         (``ssm_lane_resets``), the bytes of windows and matrix states the
         lanes hold (``ssm_state_bytes``, a float) and, per lane, what every
         recurrent layer's matrix state held when the lane last ended an
-        episode, summed over its last axis (``ssm_ended_state``, ``(n,
+        episode, summed over ``state_dim`` (``ssm_ended_state``, ``(n,
         recurrent layers, heads, head_dim)``, as stored; zeros for a lane
         that ended none). Per lane, in step order (the last
         ``max_positions`` steps): the id each step consumed and the lane's
@@ -1041,6 +1076,7 @@ class _Decoder(_LaneModule):
             held = sum(math.prod(m[name].shape) * m[name].dtype.itemsize for m in recurrent for name in ("conv", "ssm"))
             report.update(
                 ssm_state_updates=sum((jnp.sum(m["updates"]) for m in recurrent), zero),
+                ssm_state_kernel_updates=sum((jnp.sum(m["kernel_updates"]) for m in recurrent), zero),
                 ssm_lane_resets=jnp.sum(recurrent[0]["resets"]),
                 ssm_state_bytes=jnp.asarray(held, F32),  # past int32 at the cell's size
                 ssm_ended_state=jnp.stack([m["ended"] for m in recurrent], axis=1),

@@ -42,7 +42,8 @@ over ``c``; the up-projections folded into the query and output paths stay
 ``fwd_attention``); ``fwd_ssm`` (a recurrent mixer: norm, ``in_proj``, the
 convolution over the lane's window, the gated norm, ``out_proj``) and,
 INSIDE it, ``fwd_ssm_state`` (whatever touches the matrix state: decay,
-outer product, readout). ``instruction_scopes`` keeps reading the OUTERMOST
+outer product, readout; on a TPU the kernel of ``net/ssmstate.py`` and the
+gathering of its small operands). ``instruction_scopes`` keeps reading the OUTERMOST
 rollout scope, so what read ``policy_forward`` before still does;
 ``instruction_scopes(..., names=FORWARD_SCOPES)`` reads the INNERMOST
 component among the forward's names.

@@ -476,13 +476,15 @@ def test_the_evaluation_rewrites_the_lanes_state_in_place_on_a_v5e(v5e):
     """The real TPU compiler on the whole evaluation program at the published
     widths (a Mamba-2 layer and the attention layer, 32 lanes): every step
     replaces all of a layer's matrix states, and the loop holds them ONCE: one
-    fusion a layer reads the carry's ``bf16[lanes, 64, 64, 128]`` and writes
-    it, no copy of it and no select over it (at the cell's 256 lanes and ten
-    layers the program's temporaries are 4.61 GB: the bfloat16 trunk 1.60, the
-    lanes' state 2.61, and 0.40 besides). (A leaf whose
-    last axis is 4, the convolution's as published, made the compiler reshape
-    the whole flat trunk into rows of 4, padded 32-fold: the leaf is held taps
-    first.)"""
+    kernel a Mamba layer (``net/ssmstate.py``) takes the carry's ``bf16[lanes,
+    128, 4096]`` (a lane's state is stored ``(state_dim, heads x head_dim)``)
+    and writes it IN PLACE, its result aliased to that operand; no copy of a
+    layer's states, no select over them and no other fusion writes them under
+    the state's scope (at the cell's 256 lanes and ten layers the program's
+    temporaries are 4.29 GB: the bfloat16 trunk 1.60, the lanes' state 2.61).
+    (A leaf whose last axis is 4, the convolution's as published, made the
+    compiler reshape the whole flat trunk into rows of 4, padded 32-fold: the
+    leaf is held taps first.)"""
     from jax.sharding import SingleDeviceSharding
 
     from evotorch_tpu.neuroevolution.net.vecrl import run_vectorized_rollout
@@ -514,10 +516,16 @@ def test_the_evaluation_rewrites_the_lanes_state_in_place_on_a_v5e(v5e):
         .compile()
         .as_text()
     )
-    state = f"bf16[{lanes},64,64,128]"
+    from evotorch_tpu.neuroevolution.net import ssmstate
+
+    state = f"bf16[{lanes},128,4096]"
     # every instruction whose result, alone or in a tuple, is a lane-batched matrix state
     results = [re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [a-z][\w.-]*\(", line) for line in text.splitlines()]
     written = [found.string for found in results if found and state in found.group(1)]
-    passes = [line for line in written if " fusion(" in line and "fwd_ssm_state" in line]
+    passes = [line for line in written if "fwd_ssm_state" in line and (" fusion(" in line or " custom-call(" in line)]
     assert len(passes) == 1  # decay, outer product, readout and write-back: one pass over the carry
+    (kernel,) = passes
+    assert 'custom_call_target="tpu_custom_call"' in kernel and ssmstate.KERNEL_NAME in kernel
+    assert "output_to_operand_aliasing={{0}: (1, {})}" in kernel  # the new states lie where the old ones lay
     assert not [line for line in written if " copy(" in line or " select(" in line]
+    assert "bf16[%d,64,64,128]" % lanes not in text  # no second form of a layer's states
