@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import Problem, Solution, SolutionBatch
+from ..observability.scopes import phase
 from .functional.funccmaes import CMAESState, cmaes, cmaes_ask, cmaes_tell
 from .searchalgorithm import SearchAlgorithm, SinglePopulationAlgorithmMixin
 
@@ -113,11 +114,13 @@ class CMAES(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         return float(self._state.sigma)
 
     def _step(self):
-        state, xs = cmaes_ask(self._problem.next_rng_key(), self._state)
-        self._population.set_values(xs)
+        with phase("ask"):
+            state, xs = cmaes_ask(self._problem.next_rng_key(), self._state)
+            self._population.set_values(xs)
         self._problem.evaluate(self._population)
-        fitnesses = self._population.evals[:, self._obj_index]
-        self._state = cmaes_tell(state, xs, fitnesses)
+        with phase("update"):  # cmaes_tell ranks inside: no `grad` of its own
+            fitnesses = self._population.evals[:, self._obj_index]
+            self._state = cmaes_tell(state, xs, fitnesses)
 
 
 class PyCMAES(SearchAlgorithm, SinglePopulationAlgorithmMixin):
@@ -156,12 +159,14 @@ class PyCMAES(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         return self._population
 
     def _step(self):
-        asked = self._es.ask()
-        xs = jnp.asarray(np.asarray(asked), dtype=self._problem.dtype)
-        self._population.set_values(xs)
+        with phase("ask"):
+            asked = self._es.ask()
+            xs = jnp.asarray(np.asarray(asked), dtype=self._problem.dtype)
+            self._population.set_values(xs)
         self._problem.evaluate(self._population)
-        fitnesses = np.asarray(self._population.evals[:, self._obj_index], dtype=np.float64)
-        sense = self._problem.senses[self._obj_index]
-        if sense == "max":
-            fitnesses = -fitnesses
-        self._es.tell(asked, list(fitnesses))
+        with phase("update"):
+            fitnesses = np.asarray(self._population.evals[:, self._obj_index], dtype=np.float64)
+            sense = self._problem.senses[self._obj_index]
+            if sense == "max":
+                fitnesses = -fitnesses
+            self._es.tell(asked, list(fitnesses))

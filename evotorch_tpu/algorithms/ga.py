@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Iterable, Optional, Union
 
 from ..core import Problem, SolutionBatch
+from ..observability.scopes import phase
 from ..operators.base import CrossOver
 from ..operators.real import (
     CosynePermutation,
@@ -24,11 +25,13 @@ __all__ = ["ExtendedPopulationMixin", "GeneticAlgorithm", "SteadyStateGA", "Cosy
 
 
 def _use_operators(population: SolutionBatch, operators: Iterable) -> SolutionBatch:
-    """Apply an operator pipeline to produce children (reference ``ga.py:56``)."""
-    result = population
-    for op in operators:
-        result = op(result)
-    return result
+    """Apply an operator pipeline to produce children (reference ``ga.py:56``):
+    a GA's ``ask`` phase."""
+    with phase("ask"):
+        result = population
+        for op in operators:
+            result = op(result)
+        return result
 
 
 class ExtendedPopulationMixin:
@@ -134,17 +137,19 @@ class GeneticAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin, Extended
         popsize = self._popsize
         if self._elitist:
             extended = self._make_extended_population(split=False)
-            self._population = extended.take_best(popsize)
+            with phase("update"):  # the selection
+                self._population = extended.take_best(popsize)
         else:
             parents, children = self._make_extended_population(split=True)
-            num_children = len(children)
-            if num_children < popsize:
-                chosen_parents = self._population.take_best(popsize - num_children)
-                self._population = SolutionBatch.cat([chosen_parents, children])
-            elif num_children == popsize:
-                self._population = children
-            else:
-                self._population = children.take_best(popsize)
+            with phase("update"):
+                num_children = len(children)
+                if num_children < popsize:
+                    chosen_parents = self._population.take_best(popsize - num_children)
+                    self._population = SolutionBatch.cat([chosen_parents, children])
+                elif num_children == popsize:
+                    self._population = children
+                else:
+                    self._population = children.take_best(popsize)
 
 
 class SteadyStateGA(GeneticAlgorithm):
@@ -253,19 +258,21 @@ class Cosyne(SearchAlgorithm, SinglePopulationAlgorithmMixin):
             self._first_generation = False
             self._problem.evaluate(self._population)
 
-        to_merge = []
-        num_elites = self._num_elites
-        num_parents = int(self._popsize / 4)
-        num_relevant = max((0 if num_elites is None else num_elites), num_parents)
-        sorted_relevant = self._population.take_best(num_relevant)
-        if num_elites is not None and num_elites >= 1:
-            to_merge.append(sorted_relevant[:num_elites].clone())
-        parents = sorted_relevant[:num_parents]
-        children = self._cross_over_op(parents)
-        if self.mutation_op is not None:
-            children = self.mutation_op(children)
-        permuted = self._permutation_op(self._population)
-        to_merge.extend([children, permuted])
-        extended = SolutionBatch(merging_of=to_merge)
+        with phase("ask"):
+            to_merge = []
+            num_elites = self._num_elites
+            num_parents = int(self._popsize / 4)
+            num_relevant = max((0 if num_elites is None else num_elites), num_parents)
+            sorted_relevant = self._population.take_best(num_relevant)
+            if num_elites is not None and num_elites >= 1:
+                to_merge.append(sorted_relevant[:num_elites].clone())
+            parents = sorted_relevant[:num_parents]
+            children = self._cross_over_op(parents)
+            if self.mutation_op is not None:
+                children = self.mutation_op(children)
+            permuted = self._permutation_op(self._population)
+            to_merge.extend([children, permuted])
+            extended = SolutionBatch(merging_of=to_merge)
         self._problem.evaluate(extended)
-        self._population = extended.take_best(self._popsize)
+        with phase("update"):  # the selection
+            self._population = extended.take_best(self._popsize)

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import Problem, SolutionBatch
-from ..observability.tracer import span
+from ..observability.scopes import phase, phase_jit
 from ..distributions import (
     _split_params,
     Distribution,
@@ -332,34 +332,36 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
         In factored (low-rank) mode the generation's first round draws the
         basis and every later round samples fresh coefficients against it, so
         the per-round batches stay concatenable (SolutionBatch.cat of
-        shared-basis factored batches)."""
+        shared-basis factored batches). ``ask`` is the sampling alone: the
+        evaluation wears its own phase, the loop's reading of the interaction
+        counter (a wait for the evaluation) is ``status``."""
         problem = self._problem
         if self._num_interactions is None:
-            with span("ask", "algo"):
+            with phase("ask"):
                 self._population = self._sample_population(self._popsize)
-            with span("eval", "algo", popsize=self._popsize):
-                problem.evaluate(self._population)
+            problem.evaluate(self._population)
             return
-        first_count = int(problem.status.get("total_interaction_count", 0))
+        with phase("status"):
+            first_count = int(problem.status.get("total_interaction_count", 0))
         batches = []
         total_popsize = 0
         prev_made = -1
         gen_basis = None
         while True:
-            with span("ask", "algo"):
+            with phase("ask"):
                 batch = self._sample_population(self._popsize, basis=gen_basis)
-            if gen_basis is None:
-                if self._lowrank_rank is not None:
-                    gen_basis = batch.values.basis
-                elif self._trunk_delta_rank is not None:
-                    gen_basis = batch.values.factors
-            with span("eval", "algo", popsize=len(batch)):
-                problem.evaluate(batch)
+                if gen_basis is None:
+                    if self._lowrank_rank is not None:
+                        gen_basis = batch.values.basis
+                    elif self._trunk_delta_rank is not None:
+                        gen_basis = batch.values.factors
+            problem.evaluate(batch)
             batches.append(batch)
             total_popsize += len(batch)
             if self._popsize_max is not None and total_popsize >= self._popsize_max:
                 break
-            interactions_made = int(problem.status.get("total_interaction_count", 0)) - first_count
+            with phase("status"):
+                interactions_made = int(problem.status.get("total_interaction_count", 0)) - first_count
             if interactions_made > self._num_interactions:
                 break
             if "total_interaction_count" not in problem.status:
@@ -367,7 +369,8 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
             if interactions_made <= prev_made:
                 break  # counter stopped advancing; the budget is unreachable
             prev_made = interactions_made
-        self._population = batches[0] if len(batches) == 1 else SolutionBatch.cat(batches)
+        with phase("ask"):
+            self._population = batches[0] if len(batches) == 1 else SolutionBatch.cat(batches)
 
     # capture below this for _CAPTURE_WARN_STREAK consecutive generations =>
     # subspace exhaustion warning. 0.1 sits between sqrt(k/L) of configs
@@ -433,74 +436,73 @@ class GaussianSearchAlgorithm(SearchAlgorithm, SinglePopulationAlgorithmMixin):
     def _step_non_distributed(self):
         """Reference ``gaussian.py:274-367``: from generation 1 on, compute
         gradients from the previous population, update the distribution, then
-        resample and evaluate."""
+        resample and evaluate. The phases (``observability/scopes.py``) are
+        siblings: ``grad``, ``update``, ``ask``, ``evaluate`` (worn by
+        ``Problem.evaluate``), ``status``."""
         if self._first_iter:
             self._first_iter = False
-            self._fill_and_eval_pop()
+        else:
+            pop = self._population
+            obj_sense = self._problem.senses[self._obj_index]
+            if self._trunk_delta_rank is not None:
+                # rank, gradient and update are one donated program
+                with phase("update"):
+                    self._update_trunk_delta(pop.values, pop.evals[:, self._obj_index], obj_sense)
+            else:
+                with phase("grad"):
+                    samples = pop.values
+                    grads = self._distribution.compute_gradients(
+                        samples,
+                        pop.evals[:, self._obj_index],
+                        objective_sense=obj_sense,
+                        ranking_method=self._ranking_method if self._ranking_method is not None else "raw",
+                    )
+                    if self._lowrank_rank is not None:
+                        # basis_capture guardrail: measured against the basis the
+                        # gradient was just estimated in, BEFORE that gradient
+                        # enters the direction EMA
+                        self._update_basis_capture(samples.basis, grads["mu"])
+                with phase("update"):
+                    self._update_distribution(grads)
+        self._fill_and_eval_pop()
+        with phase("status"):
             self._mean_eval = jnp.nanmean(self._population.evals[:, self._obj_index])
-            return
-        pop = self._population
-        samples = pop.values
-        fitnesses = pop.evals[:, self._obj_index]
-        obj_sense = self._problem.senses[self._obj_index]
-        if self._trunk_delta_rank is not None:
-            with span("tell", "algo"), jax.profiler.TraceAnnotation("evotorch_tpu.update"):
-                self._update_trunk_delta(samples, fitnesses, obj_sense)
-            with jax.profiler.TraceAnnotation("evotorch_tpu.ask"):
-                self._fill_and_eval_pop()
-            self._mean_eval = jnp.nanmean(self._population.evals[:, self._obj_index])
-            return
-        with span("tell", "algo"):
-            with jax.profiler.TraceAnnotation("evotorch_tpu.grad"):
-                grads = self._distribution.compute_gradients(
-                    samples,
-                    fitnesses,
-                    objective_sense=obj_sense,
-                    ranking_method=self._ranking_method if self._ranking_method is not None else "raw",
-                )
-            if self._lowrank_rank is not None:
-                # basis_capture guardrail: measured against the basis the
-                # gradient was just estimated in, BEFORE that gradient enters
-                # the direction EMA
-                self._update_basis_capture(samples.basis, grads["mu"])
-            with jax.profiler.TraceAnnotation("evotorch_tpu.update"):
-                self._update_distribution(grads)
-        with jax.profiler.TraceAnnotation("evotorch_tpu.ask"):
-            self._fill_and_eval_pop()
-        self._mean_eval = jnp.nanmean(self._population.evals[:, self._obj_index])
 
     # ------------------------------------------------------------ distributed
     def _step_distributed(self):
         """Reference ``gaussian.py:199-272``: gather per-shard gradient dicts
-        and average them (weighted by sub-population size when configured)."""
-        with span("sample_and_grad", "algo"):
-            results = self._problem.sample_and_compute_gradients(
-                self._distribution,
-                self._popsize,
-                popsize_max=self._popsize_max,
-                num_interactions=self._num_interactions,
-                ranking_method=self._ranking_method if self._ranking_method is not None else "raw",
-                obj_index=self._obj_index,
-                lowrank_rank=self._lowrank_rank,
-            )
-        grads_list = [r["gradients"] for r in results]
-        nums = np.asarray([r["num_solutions"] for r in results], dtype=np.float64)
-        rel = nums / nums.sum()  # population-size weighting (host-side floats)
-        weights = rel if self._popsize_weighted_grad_avg else np.full(
-            len(results), 1.0 / len(results)
+        and average them (weighted by sub-population size when configured).
+        ``sample_and_compute_gradients`` wears ``ask``, ``evaluate`` and
+        ``grad`` itself."""
+        results = self._problem.sample_and_compute_gradients(
+            self._distribution,
+            self._popsize,
+            popsize_max=self._popsize_max,
+            num_interactions=self._num_interactions,
+            ranking_method=self._ranking_method if self._ranking_method is not None else "raw",
+            obj_index=self._obj_index,
+            lowrank_rank=self._lowrank_rank,
         )
-        avg = {}
-        for k in grads_list[0]:
-            avg[k] = sum(w * g[k] for w, g in zip(weights, grads_list))
-        # mean_eval stays a device scalar until the status is read
-        self._mean_eval = sum(w * r["mean_eval"] for w, r in zip(rel, results))
-        if self._lowrank_rank is not None and results[0].get("basis") is not None:
-            # same guardrail as the non-distributed step; the sharded
-            # estimator surfaces shard 0's basis as a representative iid
-            # draw (capture statistics are exchangeable across shards)
-            self._update_basis_capture(results[0]["basis"], avg["mu"])
-        with span("tell", "algo"):
+        with phase("grad"):
+            grads_list = [r["gradients"] for r in results]
+            nums = np.asarray([r["num_solutions"] for r in results], dtype=np.float64)
+            rel = nums / nums.sum()  # population-size weighting (host-side floats)
+            weights = rel if self._popsize_weighted_grad_avg else np.full(
+                len(results), 1.0 / len(results)
+            )
+            avg = {}
+            for k in grads_list[0]:
+                avg[k] = sum(w * g[k] for w, g in zip(weights, grads_list))
+            if self._lowrank_rank is not None and results[0].get("basis") is not None:
+                # same guardrail as the non-distributed step; the sharded
+                # estimator surfaces shard 0's basis as a representative iid
+                # draw (capture statistics are exchangeable across shards)
+                self._update_basis_capture(results[0]["basis"], avg["mu"])
+        with phase("update"):
             self._update_distribution(avg)
+        with phase("status"):
+            # mean_eval stays a device scalar until the status is read
+            self._mean_eval = sum(w * r["mean_eval"] for w, r in zip(rel, results))
 
     # --------------------------------------------------------------- updates
     def _update_trunk_delta(self, samples, fitnesses, obj_sense: str):
@@ -599,7 +601,7 @@ def _make_trunk_delta_tell(
     from ..tools.lowrank import TrunkDeltaParamsBatch
     from ..tools.ranking import rank
 
-    def tell(mu, sigma, optimizer_state, coeffs, factors, fitnesses, clamps):
+    def trunk_delta_tell(mu, sigma, optimizer_state, coeffs, factors, fitnesses, clamps):
         parameters = {"mu": mu, "sigma": sigma, **dict(static_items)}
         samples = TrunkDeltaParamsBatch(center=mu, coeffs=coeffs, factors=factors)
         weights = rank(fitnesses, ranking_method, higher_is_better=higher_is_better)
@@ -627,7 +629,7 @@ def _make_trunk_delta_tell(
         )
         return mu, new_sigma, optimizer_state, update_norm
 
-    return jax.jit(tell, donate_argnums=(0, 1, 2))
+    return phase_jit("update", trunk_delta_tell, donate_argnums=(0, 1, 2))
 
 
 def _factored_form(lowrank_rank):

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import Problem, SolutionBatch
+from ..observability.scopes import phase, phase_jit
 from ..tools.misc import to_jax_dtype
 from .ga import ExtendedPopulationMixin
 from .searchalgorithm import SearchAlgorithm, SinglePopulationAlgorithmMixin
@@ -40,7 +41,7 @@ def _best_solution_considering_feature(objective_sense, decision_values, evals, 
     return decision_values[index], evals[index], suitable[index]
 
 
-@partial(jax.jit, static_argnames=("objective_sense",))
+@partial(phase_jit, "update", static_argnames=("objective_sense",))
 def _best_solutions_for_all_cells(objective_sense, decision_values, evals, feature_grid):
     """vmap over grid cells (reference ``mapelites.py:56-67``)."""
     return jax.vmap(
@@ -109,15 +110,16 @@ class MAPElites(SearchAlgorithm, SinglePopulationAlgorithmMixin, ExtendedPopulat
 
     def _step(self):
         extended = self._make_extended_population(split=False)
-        values, evals, suitable = _best_solutions_for_all_cells(
-            self._sense,
-            jnp.asarray(extended.values),
-            extended.evals,
-            self._feature_grid,
-        )
-        self._population.set_values(values, keep_evals=True)
-        self._population.set_evals(evals)
-        self._filled = suitable
+        with phase("update"):
+            values, evals, suitable = _best_solutions_for_all_cells(
+                self._sense,
+                jnp.asarray(extended.values),
+                extended.evals,
+                self._feature_grid,
+            )
+            self._population.set_values(values, keep_evals=True)
+            self._population.set_evals(evals)
+            self._filled = suitable
 
     @staticmethod
     def make_feature_grid(

@@ -16,7 +16,7 @@ import numpy as np
 
 from ..core import Problem
 from ..observability import counters, ensure_compile_counter, ensure_compile_timer
-from ..observability.tracer import span
+from ..observability.scopes import phase
 from ..tools.hook import Hook
 from ..tools.lazyreporter import LazyReporter, LazyStatusDict
 
@@ -133,30 +133,35 @@ class SearchAlgorithm(LazyReporter):
         docs/observability.md "Program ledger")."""
         import time
 
-        self._before_step_hook()
-        self.clear_status()
-        if self._first_step_datetime is None:
-            self._first_step_datetime = datetime.now()
-        meters = counters.snapshot(
-            ("compiles", "trace_spans", "telemetry_fetches", "compile_seconds")
-        )
-        t0 = time.perf_counter()
-        with span("generation", "algo", n=self._steps_count + 1):
+        # `generation` encloses the step's phases (observability/scopes.py):
+        # the subclass's `_step` wears grad / update / ask, `Problem.evaluate`
+        # its own, and everything this method does itself is `status`
+        with phase("generation", n=self._steps_count + 1):
+            with phase("status"):
+                self._before_step_hook()
+                self.clear_status()
+                if self._first_step_datetime is None:
+                    self._first_step_datetime = datetime.now()
+                meters = counters.snapshot(
+                    ("compiles", "trace_spans", "telemetry_fetches", "compile_seconds")
+                )
+            t0 = time.perf_counter()
             self._step()
-        step_seconds = time.perf_counter() - t0
-        self._steps_count += 1
-        self.update_status({"iter": self._steps_count, "step_seconds": step_seconds})
-        self.update_status(counters.delta(meters))
-        # absolute gauges (not per-step deltas): the ledger's peak-footprint
-        # high-water mark, so every logger row carries the memory figure
-        self.update_status({"peak_hbm_bytes": counters.get("peak_hbm_bytes")})
-        # refresh the lazy problem-status passthrough (see get_status_value)
-        self._problem_status_keys = tuple(self._problem.iter_status_keys())
-        extra = self._after_step_hook.accumulate_dict()
-        if extra:
-            self.update_status(extra)
-        if len(self._log_hook) >= 1:
-            self._log_hook(dict(self.status.items()))
+            step_seconds = time.perf_counter() - t0
+            with phase("status"):
+                self._steps_count += 1
+                self.update_status({"iter": self._steps_count, "step_seconds": step_seconds})
+                self.update_status(counters.delta(meters))
+                # absolute gauges (not per-step deltas): the ledger's peak-footprint
+                # high-water mark, so every logger row carries the memory figure
+                self.update_status({"peak_hbm_bytes": counters.get("peak_hbm_bytes")})
+                # refresh the lazy problem-status passthrough (see get_status_value)
+                self._problem_status_keys = tuple(self._problem.iter_status_keys())
+                extra = self._after_step_hook.accumulate_dict()
+                if extra:
+                    self.update_status(extra)
+                if len(self._log_hook) >= 1:
+                    self._log_hook(dict(self.status.items()))
 
     def run(
         self,

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .observability.scopes import phase, phase_jit
 from .operators.functional import pareto_ranks, pareto_utility
 from .tools.cloning import Serializable, deep_clone
 from .tools.hook import Hook
@@ -86,7 +87,7 @@ def _as_int(x) -> int:
     return int(x)
 
 
-@functools.partial(jax.jit, static_argnames=("senses",))
+@functools.partial(phase_jit, "evaluate", static_argnames=("senses",))
 def _batch_extremes(values, evdata, senses):
     """Per-objective best/worst rows of ONE batch, computed on the batch's
     own placement (sharded or not) so only ``K`` winner rows ever move
@@ -108,7 +109,7 @@ def _batch_extremes(values, evdata, senses):
     return jnp.stack(bvs), jnp.stack(bes), jnp.stack(wvs), jnp.stack(wes)
 
 
-@functools.partial(jax.jit, static_argnames=("senses",))
+@functools.partial(phase_jit, "evaluate", static_argnames=("senses",))
 def _merge_snapshots(bv, be, wv, we, cbv, cbe, cwv, cwe, senses):
     """Fold one batch's candidate extreme rows into the running snapshots —
     tiny ``(K, L)``/``(K, W)`` arrays, one fused program, no host round-trip."""
@@ -405,17 +406,18 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
         if not isinstance(batch, SolutionBatch):
             raise TypeError(f"evaluate expects a SolutionBatch or Solution, got {type(batch)}")
 
-        self._start_preparations()
-        self.before_eval_hook(batch)
-        # named trace region: shows up as "evotorch_tpu.evaluate" in
-        # jax.profiler / xprof timelines (SearchAlgorithm.run(profile_dir=...))
-        with jax.profiler.TraceAnnotation("evotorch_tpu.evaluate"):
+        # the `evaluate` phase of a generation (observability/scopes.py): shows
+        # up as "evotorch_tpu.evaluate" in jax.profiler / xprof timelines
+        # (SearchAlgorithm.run(profile_dir=...)) and in EVOTORCH_TRACE's
+        with phase("evaluate", popsize=len(batch)):
+            self._start_preparations()
+            self.before_eval_hook(batch)
             self._evaluate_all(batch)
             if self._store_solution_stats:
                 self._update_best_and_worst(batch)
-        hook_results = self.after_eval_hook.accumulate_dict(batch)
-        if hook_results:
-            self.update_status(hook_results)
+            hook_results = self.after_eval_hook.accumulate_dict(batch)
+            if hook_results:
+                self.update_status(hook_results)
 
     def _evaluate_all(self, batch: "SolutionBatch"):
         """Single-program evaluation (reference ``core.py:2573``). When a
@@ -858,11 +860,14 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
             and self._objective_func is not None
         ):
             try:
-                result = self._sharded_sample_and_compute_gradients(
-                    distribution, popsize, obj_index=obj_index,
-                    ranking_method=ranking_method, key=key,
-                    lowrank_rank=lowrank_rank,
-                )
+                # sampling, evaluation, ranking and gradients are ONE program
+                # here; the evaluation is most of it and gives the phase
+                with phase("evaluate", popsize=popsize):
+                    result = self._sharded_sample_and_compute_gradients(
+                        distribution, popsize, obj_index=obj_index,
+                        ranking_method=ranking_method, key=key,
+                        lowrank_rank=lowrank_rank,
+                    )
             except jax.errors.JAXTypeError as e:
                 # the objective is not jax-traceable: degrade to the
                 # single-program path, mirroring _eval_possibly_sharded
@@ -887,14 +892,15 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
                 return [result]
 
         def sample_and_eval(key, n, basis=None):
-            if lowrank_rank is not None:
-                samples = distribution.sample_lowrank(
-                    int(n), int(lowrank_rank), key=key, basis=basis
-                )
-                batch = SolutionBatch(self, values=samples)
-            else:
-                samples = distribution.sample(int(n), key=key)
-                batch = SolutionBatch(self, samples.shape[0], values=samples)
+            with phase("ask"):
+                if lowrank_rank is not None:
+                    samples = distribution.sample_lowrank(
+                        int(n), int(lowrank_rank), key=key, basis=basis
+                    )
+                    batch = SolutionBatch(self, values=samples)
+                else:
+                    samples = distribution.sample(int(n), key=key)
+                    batch = SolutionBatch(self, samples.shape[0], values=samples)
             self.evaluate(batch)
             return samples, batch.evals[:, obj_index]
 
@@ -941,12 +947,13 @@ class Problem(TensorMakerMixin, LazyReporter, Serializable, RecursivePrintable):
                 all_samples = jnp.concatenate(sample_chunks, axis=0)
             all_fitnesses = jnp.concatenate(fitness_chunks, axis=0)
 
-        grads = distribution.compute_gradients(
-            all_samples,
-            all_fitnesses,
-            objective_sense=self._senses[obj_index],
-            ranking_method=ranking_method if ranking_method is not None else "raw",
-        )
+        with phase("grad"):
+            grads = distribution.compute_gradients(
+                all_samples,
+                all_fitnesses,
+                objective_sense=self._senses[obj_index],
+                ranking_method=ranking_method if ranking_method is not None else "raw",
+            )
         num_solutions = (
             all_samples.popsize
             if is_factored(all_samples)

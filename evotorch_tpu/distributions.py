@@ -32,6 +32,7 @@ from typing import Callable, Optional, Type
 import jax
 import jax.numpy as jnp
 
+from .observability.scopes import phase_jit
 from .tools.cloning import Serializable
 from .tools.lowrank import LowRankParamsBatch, TrunkDeltaParamsBatch, is_factored, write_leaves
 from .tools.misc import to_jax_dtype
@@ -85,7 +86,7 @@ def _jitted_sample_for(cls):
             params.update(dict(static_items))
             return cls._sample(key, params, num_solutions)
 
-        fn = jax.jit(sample, static_argnames=("static_items", "num_solutions"))
+        fn = phase_jit("ask", sample, static_argnames=("static_items", "num_solutions"))
         _JITTED_SAMPLE_CACHE[cache_key] = fn
     return fn
 
@@ -94,13 +95,15 @@ def _jitted_sample_lowrank_for(cls):
     fn = _JITTED_SAMPLE_LOWRANK_CACHE.get(cls)
     if fn is None:
 
-        def sample(key, array_params, static_items, num_solutions, rank, basis=None):
+        def sample_lowrank(key, array_params, static_items, num_solutions, rank, basis=None):
             params = dict(array_params)
             params.update(dict(static_items))
             return cls._sample_lowrank(key, params, num_solutions, rank, basis)
 
         # basis=None and basis=<array> trace as distinct jit signatures
-        fn = jax.jit(sample, static_argnames=("static_items", "num_solutions", "rank"))
+        fn = phase_jit(
+            "ask", sample_lowrank, static_argnames=("static_items", "num_solutions", "rank")
+        )
         _JITTED_SAMPLE_LOWRANK_CACHE[cls] = fn
     return fn
 
@@ -111,8 +114,7 @@ def _jitted_sample_trunk_delta(cls, policy, num_solutions, rank, draw_factors):
     # module scope
     from .neuroevolution.net.lowrank import sample_trunk_delta_factors
 
-    @jax.jit
-    def sample(key, sigma, factors):
+    def sample_trunk_delta(key, sigma, factors):
         key_factors, key_coeffs = jax.random.split(key)
         if draw_factors:
             factors = sample_trunk_delta_factors(key_factors, policy, sigma, rank)
@@ -121,7 +123,7 @@ def _jitted_sample_trunk_delta(cls, policy, num_solutions, rank, draw_factors):
         )
         return batch.coeffs, batch.factors
 
-    return sample
+    return phase_jit("ask", sample_trunk_delta)
 
 
 def _jitted_grads_for(cls):
@@ -140,8 +142,8 @@ def _jitted_grads_for(cls):
             weights = rank(fitnesses, ranking_method, higher_is_better=higher_is_better)
             return cls._compute_gradients(params, samples, weights, ranking_method)
 
-        fn = jax.jit(
-            grads, static_argnames=("static_items", "ranking_method", "higher_is_better")
+        fn = phase_jit(
+            "grad", grads, static_argnames=("static_items", "ranking_method", "higher_is_better")
         )
         _JITTED_GRADS_CACHE[cache_key] = fn
     return fn

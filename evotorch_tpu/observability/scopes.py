@@ -54,17 +54,56 @@ program's text carries both. ``instruction_scopes`` reads the text, so
 instruction name is the join key: scope from the text, seconds from the
 trace (``benchmark/harness/scopes.py``). With the HLO proto recorded, xprof
 shows the same names on its own.
+
+``SEARCHER_PHASES`` name the work of one generation OUTSIDE the compiled
+rollout, on the host's side: the parts of ``SearchAlgorithm.step()``.
+``phase(name)`` is the one call a site makes: it enters
+``jax.profiler.TraceAnnotation("evotorch_tpu." + name)`` (the profiler's
+clock: what ``SearchAlgorithm.run(profile_dir=...)`` and the benchmark's
+``--trace 1`` read) and, when the host span tracer is installed
+(``EVOTORCH_TRACE``, ``tracer.start_tracing``), the Chrome span of the same
+name. The phases are SIBLINGS that tile a step inside ``generation``:
+
+- ``grad``: ranking the fitnesses and the gradient estimate;
+- ``update``: the optimizer's step, the distribution's update and its clamps
+  (the trunk-delta ``tell``, which ranks inside its one program, whole);
+- ``ask``: sampling and building the ``SolutionBatch``, nothing else;
+- ``evaluate``: ``Problem.evaluate`` (hooks, the evaluation, best/worst);
+- ``status``: everything else a step does: ``mean_eval``, counters, hooks,
+  the status refresh, loggers.
+
+``phase_jit(name, fn, **jit_kwargs)`` is ``jax.jit`` for a function one of the
+phases dispatches: the program is called ``jit_evotorch_tpu_<phase>_<fn's
+name>``, so a device trace's ``XLA Modules`` line (and xprof) says which phase
+a program belongs to (``benchmark/harness/phases.py`` reads it). The name is
+the ONLY difference in the lowered text. jax's persistent-cache key starts with
+the module's name, so a renamed program misses the cache once. The evaluation
+program keeps its own name.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import re
 from collections import Counter
 from typing import Dict, Optional
 
 import jax
 
-__all__ = ["ROLLOUT_SCOPES", "FORWARD_SCOPES", "SCOPE_PREFIX", "scope", "instruction_scopes"]
+from . import tracer
+
+__all__ = [
+    "ROLLOUT_SCOPES",
+    "FORWARD_SCOPES",
+    "SEARCHER_PHASES",
+    "SCOPE_PREFIX",
+    "PROGRAM_PREFIX",
+    "scope",
+    "phase",
+    "phase_jit",
+    "instruction_scopes",
+]
 
 ROLLOUT_SCOPES = (
     "policy_forward",
@@ -84,7 +123,11 @@ FORWARD_SCOPES = (
     "fwd_ssm",
     "fwd_ssm_state",
 )
+SEARCHER_PHASES = ("grad", "update", "ask", "evaluate", "status")
+_GENERATION = "generation"  # the span that encloses a step's phases
 SCOPE_PREFIX = "evotorch_tpu."
+#: what a program dispatched by a phase is called after ``jit_``; then the phase
+PROGRAM_PREFIX = "evotorch_tpu_"
 
 
 def scope(name: str):
@@ -94,6 +137,40 @@ def scope(name: str):
             f"{name!r} is not one of ROLLOUT_SCOPES {ROLLOUT_SCOPES} or FORWARD_SCOPES {FORWARD_SCOPES}"
         )
     return jax.named_scope(SCOPE_PREFIX + name)
+
+
+@contextlib.contextmanager
+def _both(annotation, span):
+    with annotation, span:
+        yield
+
+
+def phase(name: str, **args):
+    """The context manager of one declared phase of a generation (or of the
+    enclosing ``generation``): the profiler's annotation
+    ``evotorch_tpu.<name>`` and, with the host span tracer on, the Chrome span
+    of the same name carrying ``args``. With both off it costs what the bare
+    annotation costs."""
+    if name not in SEARCHER_PHASES and name != _GENERATION:
+        raise ValueError(f"{name!r} is not {_GENERATION!r} or one of SEARCHER_PHASES {SEARCHER_PHASES}")
+    annotation = jax.profiler.TraceAnnotation(SCOPE_PREFIX + name)
+    if tracer._TRACER is None:
+        return annotation
+    return _both(annotation, tracer.span(SCOPE_PREFIX + name, "algo", **args))
+
+
+def phase_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under the program name
+    ``evotorch_tpu_<phase>_<fn's name>`` (leading underscores dropped)."""
+    if name not in SEARCHER_PHASES:
+        raise ValueError(f"{name!r} is not one of SEARCHER_PHASES {SEARCHER_PHASES}")
+
+    @functools.wraps(fn)
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    named.__name__ = named.__qualname__ = f"{PROGRAM_PREFIX}{name}_{fn.__name__.lstrip('_')}"
+    return jax.jit(named, **jit_kwargs)
 
 
 # `  ROOT %fusion.3 = f32[8]{0} fusion(...), ..., metadata={op_name="..." ...}`
