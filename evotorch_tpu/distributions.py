@@ -31,6 +31,8 @@ from typing import Callable, Optional, Type
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.extend.random import threefry2x32_p
 
 from .observability.scopes import phase_jit
 from .tools.cloning import Serializable
@@ -431,6 +433,68 @@ def _use_fused_sampling() -> bool:
     return os.environ.get("EVOTORCH_TPU_FUSED_SAMPLING", "0") == "1"
 
 
+def _threefry_key_words(key):
+    """The two ``uint32`` words of a threefry key whose draws are a pure
+    function of the key and the draw's row-major index
+    (``jax_threefry_partitionable``, the default of this jax); ``None`` for
+    any other generator, whose stream only ``jax.random`` can reproduce."""
+    if not jax.config.jax_threefry_partitionable:
+        return None
+    if not jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.wrap_key_data(key)  # a raw key is of the default implementation
+    if jax.random.key_impl(key) != "threefry2x32":
+        return None
+    words = jax.random.key_data(key)
+    return words[0], words[1]
+
+
+def _mul_wide_u32(a, b):
+    """``(hi, lo)`` words of the 64-bit product of two ``uint32`` arrays, by
+    16-bit halves: jax has no 64-bit integers unless ``jax_enable_x64``."""
+    half, mask = jnp.uint32(16), jnp.uint32(0xFFFF)
+    a_lo, a_hi, b_lo, b_hi = a & mask, a >> half, b & mask, b >> half
+    low, high = a_lo * b_lo, a_hi * b_hi
+    mid_a, mid_b = a_lo * b_hi, a_hi * b_lo
+    mid = mid_a + mid_b  # may wrap: a carry of 2**32, that is 2**16 into `hi`
+    lo = low + (mid << half)
+    hi = (
+        high
+        + (mid >> half)
+        + ((mid < mid_a).astype(jnp.uint32) << half)
+        + (lo < low).astype(jnp.uint32)
+    )
+    return hi, lo
+
+
+def _linear_index_words(row, col, row_length: int):
+    """``(hi, lo)`` words of ``row * row_length + col``: the counter that
+    ``jax.random.bits(key, (rows, row_length))`` gives draw ``[row, col]``
+    under ``jax_threefry_partitionable`` (``jax._src.prng.iota_2x32_shape``:
+    the row-major index as two 32-bit words). Two words because a dense
+    population's index passes 2**32 within reach (80,000 x 98,321 / 2 is
+    3.9e9). ``row`` and ``col`` are ``uint32`` arrays that broadcast; the
+    product is taken on ``row`` alone, so with a column of rows and a row of
+    columns the wide multiply runs over a vector, not over the matrix."""
+    base_hi, base_lo = _mul_wide_u32(row, jnp.uint32(row_length))
+    lo = base_lo + col
+    return base_hi + (lo < col).astype(jnp.uint32), lo
+
+
+def _float32_bits_to_normal(bits):
+    """``jax.random.normal``'s map from 32 random bits to a float32 standard
+    normal, step for step (``jax._src.random._uniform`` on ``(-1, 1)``, then
+    ``sqrt(2) * erf_inv``): 23 bits as the mantissa of a float in ``[1, 2)``,
+    shifted and scaled into the open interval, through the inverse error
+    function. ``tests/test_distributions.py`` holds it to ``jax.random.normal``
+    bit for bit, so a jax that changes its map fails there, not silently."""
+    one = np.float32(1.0)
+    mantissa = (bits >> jnp.uint32(32 - 23)) | jnp.uint32(one.view(np.uint32))
+    floats = jax.lax.bitcast_convert_type(mantissa, jnp.float32) - one
+    low = np.nextafter(np.float32(-1.0), np.float32(0.0))
+    uniform = jax.lax.max(low, floats * (one - low) + low)
+    return np.float32(np.sqrt(2)) * jax.lax.erf_inv(uniform)
+
+
 class SymmetricSeparableGaussian(SeparableGaussian):
     """Antithetic separable Gaussian, the PGPE default
     (reference ``distributions.py:616-773``)."""
@@ -439,6 +503,44 @@ class SymmetricSeparableGaussian(SeparableGaussian):
 
     @classmethod
     def _sample(cls, key, parameters, num_solutions):
+        """``[mu + e0, mu - e0, mu + e1, mu - e1, ...]`` with ``e =
+        jax.random.normal(key, (N / 2, L)) * sigma``, computed ELEMENTWISE ON
+        THE RESULT'S INDEX: ``out[r, j] = mu[j] + s(r) sigma[j] z[r // 2, j]``,
+        every ``z`` from threefry at the counter ``(r // 2) L + j`` that
+        ``jax.random.normal`` gives that draw, so each normal is computed
+        twice and the population is the one the plain form (draw, stack the
+        pair, reshape) gives: the same normals bit for bit, the samples to a
+        unit in the last place (a compiler may fuse the multiply into the add
+        in one form alone).
+
+        Why: a dense population is gigabytes (50,000 x 12,305 float32: 2.46
+        GB) and the TPU's default layout of an array follows its SHAPE:
+        ``f32[50000,12305]`` lives with the population index in the lanes
+        (``{0,1:T(8,128)}``: 12,305 is no multiple of 128), ``f32[10000,98321]``
+        row-major. Through the plain form XLA pushed the stack's unit axis
+        into the generator (tiles of one sublane: an eighth of every vector
+        register, 77.7 ms where full tiles take 9.6), then relaid the result
+        three times beside 4.97 GB of temporaries. An elementwise program has
+        no layout of its own: one fusion writes each sample once into
+        whatever layout the result has, on whole registers, with no
+        population-sized temporary (104.2 -> about 20 ms a generation at the
+        flagship's shape on a v5e: ``PERF.md`` §6, PR 38).
+
+        It relies on ``jax_threefry_partitionable`` (this jax's default): a
+        draw's bits are a function of the key and the draw's row-major index
+        alone (``jax._src.prng.iota_2x32_shape``), and on ``jax.random.normal``'s
+        float32 map from bits to a normal; ``tests/test_distributions.py``
+        holds both to ``jax.random.normal`` bit for bit, ``tests/test_ops.py``
+        compiles the program for a v5e at the benchmark's shapes and refuses a
+        relayout, a narrow tile or a temporary. Another generator (``rbg``),
+        the flag off, or another dtype than float32 is something the code
+        observes in its input: those take the plain form, same stream as ever.
+
+        The result sits behind ``optimization_barrier``: traced into a larger
+        program (a fused generation, ``make_training_span``) XLA would
+        otherwise recompute this elementwise producer inside every consumer's
+        fusion, each free to round ``mu + eps`` its own way; the searcher's
+        own program, which only returns it, is unchanged by the barrier."""
         if num_solutions % 2 != 0:
             raise ValueError(
                 f"Number of solutions sampled from {cls.__name__} must be even, got {num_solutions}"
@@ -455,14 +557,39 @@ class SymmetricSeparableGaussian(SeparableGaussian):
             return sample_symmetric_gaussian(
                 key, mu, sigma, num_solutions, use_pallas=True
             )
-        num_directions = num_solutions // 2
-        eps = jax.random.normal(key, (num_directions, mu.shape[-1]), dtype=mu.dtype) * sigma
-        # interleaved [mu+e0, mu-e0, mu+e1, mu-e1, ...]
-        pairs = jnp.stack([mu + eps, mu - eps], axis=1)
-        return pairs.reshape(num_solutions, mu.shape[-1])
+        words = _threefry_key_words(key) if mu.dtype == jnp.float32 else None
+        if words is None:
+            # another generator or dtype: draw, then interleave
+            eps = jax.random.normal(key, (num_solutions // 2, mu.shape[-1]), dtype=mu.dtype) * sigma
+            pairs = jnp.stack([mu + eps, mu - eps], axis=1)
+            return pairs.reshape(num_solutions, mu.shape[-1])
+        # row r of the RESULT holds direction r // 2 under the sign of r's parity
+        row = jax.lax.iota(jnp.uint32, num_solutions)[:, None]
+        col = jax.lax.iota(jnp.uint32, mu.shape[-1])[None, :]
+        hi, lo = _linear_index_words(row >> jnp.uint32(1), col, mu.shape[-1])
+        bits_hi, bits_lo = threefry2x32_p.bind(*words, hi, lo)
+        eps = _float32_bits_to_normal(bits_hi ^ bits_lo) * sigma
+        sign = 1.0 - 2.0 * (row & jnp.uint32(1)).astype(mu.dtype)
+        # ONE array whatever program this is traced into (the docstring's last
+        # paragraph): a fitness and the gradient after it in one program cost
+        # 26 + 28 M cycles on a v5e recomputing the draws, 25 + 11 + 11
+        # drawing once and reading twice
+        return jax.lax.optimization_barrier(mu + sign * eps)
 
     @classmethod
     def _compute_gradients(cls, parameters, samples, weights, ranking_used) -> dict:
+        """PGPE's antithetic estimate: ``mu`` follows ``sum_i (f+_i - f-_i) / 2
+        e_i``, ``sigma`` follows ``sum_i (f+_i + f-_i) / 2 (e_i^2 - sigma^2) /
+        sigma``. The dense branch reads ``samples`` WHOLE, each row weighted
+        by its sign: row ``2i`` holds ``mu + e_i`` and row ``2i + 1`` ``mu -
+        e_i``, so the pairwise sums are sums over all rows with ``+-(f+ -
+        f-) / 4`` and ``(f+ + f-) / 4`` on a pair's two rows (twice the
+        terms: equal to the pairwise form to float32 rounding, not bit for
+        bit). ``samples[0::2]`` would be a stride along the LANES where the
+        device keeps the population index there (``_sample``): XLA relaid all
+        2.46 GB to take it. This is one fusion that reads the population once
+        and holds no temporary. ``samples - mu`` stays inside it: ``c @
+        samples`` alone cancels badly though ``sum(c) = 0``."""
         if isinstance(samples, TrunkDeltaParamsBatch):
             # the same algebra leaf by leaf from the factors: no (L, k) basis
             return cls._compute_gradients_trunk_delta(parameters, samples, weights, ranking_used)
@@ -473,16 +600,17 @@ class SymmetricSeparableGaussian(SeparableGaussian):
         mu = parameters["mu"]
         sigma = parameters["sigma"]
         weights = _zero_center_weights(weights, ranking_used)
-        scaled_noises = samples[0::2] - mu
-        fdplus = weights[0::2]
-        fdminus = weights[1::2]
-        mu_grad = _divide_grad(
-            parameters, "mu", ((fdplus - fdminus) / 2) @ scaled_noises, weights
-        )
+        # per ROW: +-(f+ - f-) / 4 and (f+ + f-) / 4 on a pair's two rows
+        pairs = weights.reshape(-1, 2)
+        quarter_diff = (pairs[:, 0] - pairs[:, 1]) / 4
+        mu_weights = jnp.stack([quarter_diff, -quarter_diff], axis=1).reshape(-1)
+        sigma_weights = jnp.repeat((pairs[:, 0] + pairs[:, 1]) / 4, 2)
+        scaled_noises = samples - mu
+        mu_grad = _divide_grad(parameters, "mu", mu_weights @ scaled_noises, weights)
         sigma_grad = _divide_grad(
             parameters,
             "sigma",
-            ((fdplus + fdminus) / 2) @ ((scaled_noises**2 - sigma**2) / sigma),
+            sigma_weights @ ((scaled_noises**2 - sigma**2) / sigma),
             weights,
         )
         return {"mu": mu_grad, "sigma": sigma_grad}

@@ -541,6 +541,11 @@ def _generation_body(
     def generation(state, key, stats):
         k_ask, k_eval = jax.random.split(key)
         values = ask(k_ask, state)
+        if padded_n != popsize:
+            # `tell` reduces over ALL of these rows; sharded unevenly, the
+            # order of that sum is the partitioner's choice, and it chooses
+            # differently in a program and in its scanned form
+            values = jax.lax.with_sharding_constraint(values, NamedSharding(mesh, P()))
         evald = _pad_rows(values, padded_n) if padded_n != popsize else values
         evald = _constrain_population(evald, mesh)
         with _population_mesh(mesh):

@@ -1,3 +1,5 @@
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -122,6 +124,80 @@ def test_fused_sampling_compiles_for_v5e(popsize, length):
         sample_symmetric_gaussian, key, vec, vec, num_solutions=popsize, use_pallas=True
     )
     assert compiled.memory_analysis().output_size_in_bytes >= popsize * length * 4
+
+
+def _population_sized(text, popsize, length):
+    """``(dtype, dims, layout)`` of every array in HLO ``text`` that holds the
+    population's ``popsize x length`` elements or half of them, whatever its
+    rank: the arrays a relayout of which is a pass over gigabytes."""
+    sizes = (popsize * length, popsize * length // 2)
+    return [
+        found.groups()
+        for found in re.finditer(r"\b([a-z]+\d+)\[([\d,]+)\](\{[^}]*\})?", text)
+        if int(np.prod([int(d) for d in found.group(2).split(",")])) in sizes
+    ]
+
+
+@_deviceless
+@pytest.mark.parametrize(
+    "popsize,length",
+    [
+        (50_000, 12_305),  # humanoid_mlp64: the device's layout has the population in the LANES
+        (10_000, 98_321),  # humanoid_mlp256: row-major
+    ],
+)
+@pytest.mark.parametrize("program", ["ask_sample", "grad_grads"])
+def test_dense_antithetic_programs_pass_over_the_population_once_for_v5e(program, popsize, length):
+    # PGPE's dense sampler and its gradient, as the OO searcher jits and names
+    # them, at the benchmark's two shapes: each program one pass over the
+    # population in the layout the array has on the device. Before PR 38 XLA
+    # pushed the interleave's unit axis into the generator (tiles of ONE
+    # sublane: T(1,128)), relaid the 2.46 GB result three times and the
+    # gradient's input once, beside 4.97 and 3.73 GB of temporaries; a CPU
+    # suite sees none of that
+    from evotorch_tpu import distributions
+    from evotorch_tpu.distributions import SymmetricSeparableGaussian
+
+    sharding = _v5e_sharding()
+    vec = jax.ShapeDtypeStruct((length,), jnp.float32, sharding=sharding)
+    parameters = {"mu": vec, "sigma": vec}
+    if program == "ask_sample":
+        key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=sharding)
+        traced = distributions._jitted_sample_for(SymmetricSeparableGaussian).trace(
+            key, parameters, (), popsize
+        )
+    else:
+        samples = jax.ShapeDtypeStruct((popsize, length), jnp.float32, sharding=sharding)
+        fitnesses = jax.ShapeDtypeStruct((popsize,), jnp.float32, sharding=sharding)
+        traced = distributions._jitted_grads_for(SymmetricSeparableGaussian).trace(
+            parameters, samples, fitnesses, (), "centered", True
+        )
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_evotorch_tpu_{program},"), text[:80]
+
+    narrow = [
+        shape
+        for shape in _population_sized(text, popsize, length)
+        if "T(1,128)" in (shape[2] or "") or "T(2,128)" in (shape[2] or "")
+    ]
+    assert narrow == [], narrow  # every vector op on whole (8, 128) registers
+
+    # the entry computation's instructions that RESULT in an array of the population's size
+    passes = [
+        (found.group("op"), found.group(0)[:160])
+        for found in re.finditer(
+            r"^ *(?:ROOT )?\S+ = (?P<type>\(.*?\)|\S+) (?P<op>[\w-]+)\(.*$",
+            text[text.index("\nENTRY ") :],
+            flags=re.MULTILINE,
+        )
+        if found.group("op") != "parameter" and _population_sized(found.group("type"), popsize, length)
+    ]
+    relayouts = [line for op, line in passes if op.startswith(("copy", "reshape", "transpose", "pad"))]
+    assert relayouts == [], relayouts  # no pass that only moves it
+    # the sampler writes it once, in one fusion; the gradient reads it and makes no other
+    assert [op for op, _ in passes] == (["fusion"] if program == "ask_sample" else []), passes
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 def test_fused_centered_rank_degenerate_and_dtype():
