@@ -28,7 +28,8 @@ nothing but:
 
 Everything else is for the per-layer metric readers of the layers this
 session has (a reader asks with ``getattr`` and reads nothing where the
-attribute is missing): ``compute_dtype``, ``weight_blocks``,
+attribute is missing): ``problem`` (whose ``lower_evaluation`` gives the
+text the trace's ops are joined to by scope), ``compute_dtype``,
 ``parameter_count``.
 """
 
@@ -102,7 +103,6 @@ class Session:
         self._rollout = files.module_at(traffic["reference"]["rollout"])
         self._contract = traffic["reference"]["contract"]
         self._sizes = self._forward.sizes(config)
-        self.weight_blocks = self._forward.weight_blocks(self._sizes)
         self.parameter_count = self.problem.solution_length
         if self._forward.parameter_count(self._sizes) != int(config["parameter_count"]):
             raise ValueError("the configuration's parameter_count does not follow from its sizes")

@@ -72,7 +72,7 @@ def check_counts(marks, compiles, per_call):
     failed = []
     telemetry_checked = 0
     executed = counted = 0
-    steps_by_call = []
+    steps_by_call, capacity_by_call = [], []
     for i in range(len(marks) - 1):
         before, after = marks[i], marks[i + 1]
         steps = after["interactions"] - before["interactions"]
@@ -85,7 +85,9 @@ def check_counts(marks, compiles, per_call):
         if per_call["episodes"] is not None:
             ok = ok and episodes == per_call["episodes"]
         at = i + 1 + lag
-        if at < len(marks) and marks[at]["telemetry"] is not None:
+        decoded = at < len(marks) and marks[at]["telemetry"] is not None
+        capacity_by_call.append(marks[at]["telemetry"]["capacity"] if decoded else None)
+        if decoded:
             telemetry = marks[at]["telemetry"]
             telemetry_checked += 1
             ok = ok and telemetry["env_steps"] == steps and telemetry["nonfinite"] == 0
@@ -102,6 +104,9 @@ def check_counts(marks, compiles, per_call):
         "episodes": marks[-1]["episodes"] - marks[0]["episodes"],
         "telemetry_checked": telemetry_checked,
         "occupancy": counted / executed if executed else None,
+        # lane-step slots each call's loop executed; None where its telemetry
+        # came after the window
+        "capacity_by_call": capacity_by_call,
         "compiles_in_window": int(sum(compiles)),
     }
     return failed, facts

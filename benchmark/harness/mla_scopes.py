@@ -7,7 +7,8 @@ innermost), joined by instruction name as harness/scopes.py joins the
 rollout's scopes. By SCOPE alone: no array's shape is looked for.
 
 Control steps are ``session.decode_steps`` times the traced generations: the
-session says what it ran. Everything here returns None where there is no
+session says what it ran; a time per step divides by as many of them as the
+trace holds the ops of (``scopes.kept_steps``). Everything here returns None where there is no
 device trace, no session that lowers its evaluation, or a library without the
 latent cache's scope.
 """
@@ -17,15 +18,14 @@ import json
 from benchmark.harness import mla_floors, scopes
 
 CACHE_SCOPE = "fwd_latent_cache"
+#: the names the ``mla.*`` metrics read
+READS = ("fwd_attention", CACHE_SCOPE, "fwd_experts")
 
 
 def forward_seconds(run):
     def compute():
-        session = run.session
-        problem = getattr(session, "problem", None)
-        lower = getattr(problem, "lower_evaluation", None)
-        steps = getattr(session, "decode_steps", None)
-        if run.trace is None or not run.trace.planes or lower is None or steps is None:
+        steps = getattr(run.session, "decode_steps", None)
+        if not scopes.lowers(run) or steps is None:
             return None
         try:
             from evotorch_tpu.observability.scopes import FORWARD_SCOPES, instruction_scopes
@@ -33,7 +33,7 @@ def forward_seconds(run):
             return None
         if CACHE_SCOPE not in FORWARD_SCOPES:
             return None
-        text = scopes.compiled_text(lower, run.popsize, instruction_scopes)
+        text = scopes.evaluation_text(run, READS)
         ops = run.trace.evaluation_ops()
         generations = len(run.trace.generations())
         if not ops or generations <= 0:
@@ -46,12 +46,13 @@ def forward_seconds(run):
         if CACHE_SCOPE not in inner.values():
             scopes.say("no instruction of the evaluation program carries the latent cache's scope: nothing read")
             return None
-        seconds, forward_s, total_s = {}, 0.0, 0.0
-        for hlo, (self_seconds, _) in ops.items():
+        seconds, forward_s, total_s, most_executed = {}, 0.0, 0.0, 0.0
+        for hlo, (self_seconds, executions) in ops.items():
             name = scopes.instruction_name(hlo)
             total_s += self_seconds
             if outer.get(name) == "policy_forward":
                 forward_s += self_seconds
+                most_executed = max(most_executed, executions)
             scope = inner.get(name)
             if scope is not None:
                 seconds[scope] = seconds.get(scope, 0.0) + self_seconds
@@ -60,7 +61,9 @@ def forward_seconds(run):
             "policy_forward_s": forward_s,
             "inner_share_of_policy_forward": sum(seconds.values()) / forward_s if forward_s else None,
             "evaluation_s": total_s,
-            "steps": steps * generations,
+            "coverage_percent": scopes.coverage(run, ops),
+            "steps": scopes.kept_steps(steps * generations, most_executed),
+            "steps_ran": steps * generations,
         }
         scopes.say("mla forward: " + json.dumps(split))
         return split

@@ -1,14 +1,16 @@
 """The state-space hybrid decoder's forward by inner scope: the evaluation
 program's device seconds under ``fwd_ssm`` (a mixer outside its state's
-span), ``fwd_ssm_state`` (whatever touches the matrix state), ``fwd_attention``,
-``fwd_dense_mlp``, ``fwd_head``
+span), ``fwd_ssm_state`` (whatever touches the matrix state), ``fwd_attention``
+(and ``fwd_kv_cache`` inside it, once the library declares it), ``fwd_dense_mlp``,
+``fwd_head``
 (``evotorch_tpu/observability/scopes.py:FORWARD_SCOPES``, names INSIDE
 ``policy_forward``; an op under two of them counts under the innermost),
 joined by instruction name as harness/scopes.py joins the rollout's scopes. By
 SCOPE alone: no array's shape is looked for.
 
 Control steps are ``session.decode_steps`` times the traced generations: the
-session says what it ran. Everything here returns None where there is no
+session says what it ran; a time per step divides by as many of them as the
+trace holds the ops of (``scopes.kept_steps``). Everything here returns None where there is no
 device trace, no session that lowers its evaluation, or a library without the
 state's scope (a checkout from before the mixer).
 """
@@ -18,15 +20,15 @@ import json
 from benchmark.harness import scopes
 
 STATE_SCOPE = "fwd_ssm_state"
+CACHE_SCOPE = "fwd_kv_cache"  # the attention layer's pass over its cache, once the library declares it
+#: the names the ``ssm.*`` metrics read that the library declares
+READS = (STATE_SCOPE, "fwd_ssm", "fwd_attention", "fwd_dense_mlp", "fwd_head")
 
 
 def forward_seconds(run):
     def compute():
-        session = run.session
-        problem = getattr(session, "problem", None)
-        lower = getattr(problem, "lower_evaluation", None)
-        steps = getattr(session, "decode_steps", None)
-        if run.trace is None or not run.trace.planes or lower is None or steps is None:
+        steps = getattr(run.session, "decode_steps", None)
+        if not scopes.lowers(run) or steps is None:
             return None
         try:
             from evotorch_tpu.observability.scopes import FORWARD_SCOPES, instruction_scopes
@@ -34,25 +36,23 @@ def forward_seconds(run):
             return None
         if STATE_SCOPE not in FORWARD_SCOPES:
             return None
-        text = scopes.compiled_text(lower, run.popsize, instruction_scopes)
+        text = scopes.evaluation_text(run, READS)
         ops = run.trace.evaluation_ops()
         generations = len(run.trace.generations())
         if not ops or generations <= 0:
             return None
-        inner = {
-            name.lstrip("%"): scope
-            for name, scope in instruction_scopes(text, names=FORWARD_SCOPES).items()
-        }
+        inner = {name.lstrip("%"): scope for name, scope in instruction_scopes(text, names=FORWARD_SCOPES).items()}
         outer = {name.lstrip("%"): scope for name, scope in instruction_scopes(text).items()}
         if STATE_SCOPE not in inner.values():
             scopes.say("no instruction of the evaluation program carries the matrix state's scope: nothing read")
             return None
-        seconds, forward_s, total_s = {}, 0.0, 0.0
-        for hlo, (self_seconds, _) in ops.items():
+        seconds, forward_s, total_s, most_executed = {}, 0.0, 0.0, 0.0
+        for hlo, (self_seconds, executions) in ops.items():
             name = scopes.instruction_name(hlo)
             total_s += self_seconds
             if outer.get(name) == "policy_forward":
                 forward_s += self_seconds
+                most_executed = max(most_executed, executions)
             scope = inner.get(name)
             if scope is not None:
                 seconds[scope] = seconds.get(scope, 0.0) + self_seconds
@@ -61,7 +61,9 @@ def forward_seconds(run):
             "policy_forward_s": forward_s,
             "inner_share_of_policy_forward": sum(seconds.values()) / forward_s if forward_s else None,
             "evaluation_s": total_s,
-            "steps": steps * generations,
+            "coverage_percent": scopes.coverage(run, ops),
+            "steps": scopes.kept_steps(steps * generations, most_executed),
+            "steps_ran": steps * generations,
         }
         scopes.say("ssm forward: " + json.dumps(split))
         return split
@@ -69,9 +71,10 @@ def forward_seconds(run):
     return run.memo("ssm_scopes.forward_seconds", compute)
 
 
-def per_step_ms(run, scope):
+def per_step_ms(run, *names):
+    """Device milliseconds per control step under the scopes ``names``."""
     split = forward_seconds(run)
-    return None if split is None else 1e3 * split["seconds"].get(scope, 0.0) / split["steps"]
+    return None if split is None else 1e3 * sum(split["seconds"].get(n, 0.0) for n in names) / split["steps"]
 
 
 def updates_per_step(run):

@@ -243,6 +243,23 @@ class Trace:
                 totals[text][0] += ns / 1e9 / len(self.planes)
         return dict(totals)
 
+    def evaluation_seconds(self):
+        """Device seconds of the evaluation program's runs inside the window,
+        averaged over devices: what its ops' self seconds add up to where the
+        trace kept every op."""
+        evaluation = self.evaluation_module()
+        runs = [
+            clip([(s, e) for s, e, name, _ in plane.modules if name == evaluation], *self.window)
+            for plane in self.planes
+        ]
+        return sum(length(r) for r in runs) / 1e9 / len(runs) if runs else 0.0
+
+    def executions(self, hlo_text, low, high):
+        """Events of the op ``hlo_text`` that start in [low, high), averaged
+        over devices."""
+        counts = [sum(1 for s, _, text in plane.ops if text == hlo_text and low <= s < high) for plane in self.planes]
+        return sum(counts) / len(counts) if counts else 0.0
+
     def outside_eval_ms(self):
         """Per generation: the ``bench.generation`` span minus the device time
         of the evaluation program inside it; the median. Ask, gradient, update,

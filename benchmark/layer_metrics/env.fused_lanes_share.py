@@ -5,7 +5,9 @@ kernel ``rigidbody_fused_step_<useful>_of_<computed>`` with the lanes one
 device holds and the whole 1,024-lane blocks its grid steps, and the name is
 the custom call's in the text. Says that the kernel is in the timed program
 and what its tail block wastes; 0 where the program holds no such kernel
-(XLA's plain form runs, as before PR 29), nothing without a device trace."""
+(XLA's plain form runs, as before PR 29), nothing without a device trace.
+Not in a decoder's cell, which names its forward's own layer (``lm forward``,
+...): its environment emits tokens, with no physics kernel to look for."""
 
 import re
 
@@ -20,7 +22,8 @@ NAME = re.compile(r"rigidbody_fused_step_(\d+)_of_(\d+)")
 
 
 def applies(workload):
-    return LAYER in workload["layers"]
+    layers = workload["layers"]
+    return LAYER in layers and not any(layer.endswith(" forward") and layer != "policy forward" for layer in layers)
 
 
 def share(text):
@@ -33,16 +36,6 @@ def share(text):
 
 
 def measure(run):
-    if run.trace is None or not run.trace.planes:
-        return None
-    lower = getattr(getattr(run.session, "problem", None), "lower_evaluation", None)
-    if lower is None:
-        return None
     from benchmark.harness import scopes
-    from evotorch_tpu.observability.scopes import instruction_scopes
 
-    text = run.memo(
-        "env.fused_lanes_share.text",
-        lambda: scopes.compiled_text(lower, run.popsize, instruction_scopes),
-    )
-    return share(text)
+    return share(scopes.evaluation_text(run, scopes.ROLLOUT_READS)) if scopes.lowers(run) else None

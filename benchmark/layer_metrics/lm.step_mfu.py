@@ -20,5 +20,5 @@ def measure(run):
     if split is None or split["evaluation_s"] <= 0:
         return None
     flops = 2.0 * lm_floors.step_macs_per_lane(run.session.lm_sizes) * run.popsize
-    step_s = split["evaluation_s"] / split["steps"]
+    step_s = split["evaluation_s"] / split["steps_ran"]  # all the time, the loop op's own too
     return 100.0 * flops / lm_scopes.peaks(run)["bf16_flops_per_s"] / step_s

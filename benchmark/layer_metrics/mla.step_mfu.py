@@ -23,5 +23,5 @@ def measure(run):
         return None
     per_lane = mla_scopes.positions_per_step(run) / run.popsize
     flops = 2.0 * mla_floors.step_macs_per_lane(run.session.mla_sizes, per_lane) * run.popsize
-    step_s = split["evaluation_s"] / split["steps"]
+    step_s = split["evaluation_s"] / split["steps_ran"]  # all the time, the loop op's own too
     return 100.0 * flops / mla_scopes.peaks(run)["bf16_flops_per_s"] / step_s

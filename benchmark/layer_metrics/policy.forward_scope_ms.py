@@ -1,8 +1,8 @@
 """Device time of the ``policy_forward`` scope per population-wide control
 step: the forward with its casts into and out of the compute dtype, by the
-names the compiled program carries (harness/scopes.py). Reads at or a little
-above ``policy.forward_ms``, whose recognition by weight shapes misses the
-bias adds and the tanh."""
+names the compiled program carries (harness/scopes.py). In the decoder cells
+it is the whole decoder step that the ``lm.*``, ``mla.*`` and ``ssm.*``
+metrics split."""
 
 LAYER = "policy forward"
 UNIT = "ms"

@@ -1,8 +1,12 @@
-"""The least time the chips could take for one population-wide forward over the
-time it takes inside the evaluation program (``policy.forward_ms``, from the
-trace, relayout included). Every lane multiplies by its OWN weights, so the
-forward is HBM-bound: harness/layers.py:policy_floor_ms over the published
-bytes/s of harness/device.py."""
+"""The least time a chip could take for one control step's policy forward over
+the time the ``policy_forward`` scope takes per control step
+(``policy.forward_scope_ms``, harness/scopes.py). Every lane multiplies by its
+OWN weights, so the forward is HBM-bound: the floor is ``floor_ms`` of the
+lanes a control step runs on a chip (``scopes.lanes_per_step``: the
+telemetry's executed lane-step slots over the traced control steps) at the
+published bytes/s of harness/device.py. Not in a decoder's cell, which names
+its forward's own layer (``lm forward``, ...): there the lanes share one
+trunk, and that layer's readers floor it."""
 
 LAYER = "policy forward"
 UNIT = "%"
@@ -12,21 +16,31 @@ MOVES = "env_steps_per_s"
 
 
 def applies(workload):
-    return LAYER in workload["layers"]
+    layers = workload["layers"]
+    return LAYER in layers and not any(layer.endswith(" forward") and layer != LAYER for layer in layers)
+
+
+def floor_ms(lanes, parameter_count, dtype_bytes, hbm_bytes_per_s):
+    """Every one of ``lanes`` reads its own parameters once (observations and
+    actions are under 1% of that); the FLOP floor, 2 x lanes x parameters
+    over 197 TFLOP/s, is 50 times lower."""
+    return 1e3 * lanes * parameter_count * dtype_bytes / hbm_bytes_per_s
 
 
 def measure(run):
-    from benchmark.harness import device, layers
+    import numpy as np
 
-    split = layers.split_evaluation(run)
-    if split is None:
+    from benchmark.harness import device, scopes
+
+    forward_ms = scopes.per_step_ms(run, "policy_forward")
+    lanes = scopes.lanes_per_step(run)
+    if not forward_ms or lanes is None:
         return None
     session = run.session
-    floor_ms = layers.policy_floor_ms(
-        run.popsize,
+    floor = floor_ms(
+        lanes,
         session.parameter_count,
-        layers.dtype_name(session.compute_dtype),
+        np.dtype(session.compute_dtype or np.float32).itemsize,
         device.peaks(run.device_record["kind"])["hbm_bytes_per_s"],
-        len(session.devices),
     )
-    return 100.0 * floor_ms * split["steps"] / (1e3 * split["forward_s"])
+    return 100.0 * floor / forward_ms

@@ -1,6 +1,7 @@
 """Device milliseconds per control step under ``fwd_attention`` (the one
 attention layer of a period: norm, the four projections, the cache write,
-scores and weighted sum over the lane's key/value ring) (harness/ssm_scopes.py)."""
+scores and weighted sum over the lane's key/value ring, the last three under
+``fwd_kv_cache`` once the library declares it) (harness/ssm_scopes.py)."""
 
 LAYER = "ssm forward"
 UNIT = "ms"
@@ -16,4 +17,4 @@ def applies(workload):
 def measure(run):
     from benchmark.harness import ssm_scopes
 
-    return ssm_scopes.per_step_ms(run, "fwd_attention")
+    return ssm_scopes.per_step_ms(run, "fwd_attention", ssm_scopes.CACHE_SCOPE)

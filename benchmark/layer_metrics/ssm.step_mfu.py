@@ -24,5 +24,5 @@ def measure(run):
         return None
     readable = (run.session.decode_steps + 1) / 2.0  # t + 1 at step t, averaged over an episode
     flops = 2.0 * ssm_floors.step_macs_per_lane(run.session.ssm_sizes, readable) * run.popsize
-    step_s = split["evaluation_s"] / split["steps"]
+    step_s = split["evaluation_s"] / split["steps_ran"]  # all the time, the loop op's own too
     return 100.0 * flops / ssm_scopes.peaks(run)["bf16_flops_per_s"] / step_s

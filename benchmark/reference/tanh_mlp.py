@@ -29,12 +29,6 @@ def parameter_count(sizes):
     return sum(n_out + n_out * n_in for n_in, n_out in zip(sizes[:-1], sizes[1:]))
 
 
-def weight_blocks(sizes):
-    """The (out, in) shape of each layer's weight matrix: what every lane reads
-    of its own in one forward, and what the trace's ops are recognised by."""
-    return [(n_out, n_in) for n_in, n_out in zip(sizes[:-1], sizes[1:])]
-
-
 def unflatten(flat, sizes):
     layers, at = [], 0
     for n_in, n_out in zip(sizes[:-1], sizes[1:]):

@@ -1,5 +1,7 @@
 """A later PR adds a cell, a configuration, a driver or a per-layer metric as
-new files and new ``BENCHMARK.json`` entries, and edits no file that is there.
+new files and new ``BENCHMARK.json`` entries, and edits no file that is there;
+of an entry that is there it may only list its new cells, where the entry's
+metric reads them.
 
 Shown on a scratch copy of the benchmark with the fixtures of
 ``data/extension/`` laid over it (see its README.md): a second traffic for
@@ -57,13 +59,21 @@ def extended(tmp_path_factory):
             target = os.path.join(checkout, "benchmark", kind, name)
             assert not os.path.exists(target), target
             shutil.copy(os.path.join(EXTENSION, kind, name), target)
-    # the PR's new entries: appended, no entry that is there touched
+    # the PR's new entries: appended, no entry that is there touched but for
+    # its new cells, appended to the cells a metric that lists them reads
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         spec = json.load(f)
+    new_cells = set()
     with open(os.path.join(EXTENSION, "benchmark_entries.json")) as f:
         for group, entries in json.load(f).items():
             assert not {e["name"] for e in entries} & {e["name"] for e in spec[group]}
             spec[group] = spec[group] + entries
+            new_cells |= {e["name"] for e in entries} if group == "workloads" else set()
+    with open(os.path.join(EXTENSION, "listed_cells.json")) as f:
+        for metric, cells in json.load(f).items():
+            (entry,) = [m for m in spec["per_layer"] if m["name"] == metric]
+            assert set(cells) <= new_cells and not set(cells) & set(entry["workloads"])
+            entry["workloads"] = entry["workloads"] + cells
     with open(os.path.join(checkout, "BENCHMARK.json"), "w") as f:
         json.dump(spec, f, indent=1)
     after = {
@@ -88,7 +98,7 @@ def run(checkout, *arguments):
 
 
 def test_a_second_traffic_is_one_data_file(extended):
-    cell = "humanoid_mlp64.episodes_refill"
+    cell = "humanoid_mlp64.fixture_refill"  # a fixture: no cell of the benchmark has its name
     line = run(extended, "--workload", cell, "--seed", "3", "--seconds", "1", "--trace", "0")
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] >= 3
     line = run(extended, "--workload", cell, "--seed", "3", "--seconds", "1", "--trace", "1")

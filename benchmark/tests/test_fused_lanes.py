@@ -87,7 +87,7 @@ def test_measure_reads_the_problems_own_text_once(metric):
     )
     assert metric.measure(run) == pytest.approx(97.65625)
     assert metric.measure(run) == pytest.approx(97.65625)
-    assert lowered == [8] and list(memo) == ["env.fused_lanes_share.text"]
+    assert lowered == [8] and list(memo) == ["scopes.evaluation_text"]  # the text the scope readers join to
     # a program from before the kernel (the parent commit): 0, not nothing
     parent = run_of(hand_made_trace(), types.SimpleNamespace(problem=Problem(HLO_TEXT)), {})
     assert metric.measure(parent) == 0.0

@@ -131,16 +131,19 @@ def test_layer_metric_files_agree_with_their_entries(files):
             entry["layer"], entry["unit"], entry["better"], entry["source"], entry["moves"],
         )
         assert entry["moves"] in end_to_end
-        # a metric applies where the cell's file names its layer; an entry that
-        # lists cells lists exactly those, and one that lists none is read in
-        # every cell that has the layer (elsewhere its reader has nothing to read)
+        # a metric applies only where the cell's file names its layer; an entry
+        # that lists cells lists exactly those where it applies (a reader may
+        # narrow its layer's cells to those it finds something to read in),
+        # and one that lists none applies in every cell that has the layer
         for listed in spec["workloads"]:
             workload = files.workload(listed["name"])
-            assert module.applies(workload) == (entry["layer"] in workload["layers"])
+            applies = module.applies(workload)
+            assert not applies or entry["layer"] in workload["layers"], (entry["name"], listed["name"])
             if "workloads" in entry:
-                assert module.applies(workload) == (listed["name"] in entry["workloads"]), (
-                    entry["name"], listed["name"],
-                )
+                assert applies == (listed["name"] in entry["workloads"]), (entry["name"], listed["name"])
+            else:
+                assert applies == (entry["layer"] in workload["layers"]), (entry["name"], listed["name"])
+        assert set(entry.get("workloads", [])) <= {w["name"] for w in spec["workloads"]}, entry["name"]
 
 
 def test_every_cell_reports_what_the_contract_asks(files):

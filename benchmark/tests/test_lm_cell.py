@@ -26,34 +26,38 @@ def files():
 
 
 def test_the_cell_lists_no_layer_that_reads_weight_shapes(files):
-    """``policy.forward_ms``, ``policy.roofline_share`` and ``env.step_ms``
-    recognise per-lane weights by their shapes and floor the forward at
-    popsize x parameters x bytes: 722 GB a step here, a share far over 100%.
-    A layer's metrics apply together (test_lint.py), so the cell leaves out
-    their two layers, the scope readers of those layers with them; the eval
-    contract's metrics read telemetry and scope names alone and apply."""
+    """No reader of the policy forward or the env substep looks for a
+    weight's shape: they read the scopes ``policy_forward``, ``env_step`` and
+    ``env_reset``, which this program carries too, so the cell lists both
+    layers. ``policy.roofline_share`` floors the forward at lanes x parameters
+    x bytes (722 GB a step here: a share far over 100%), and the physics
+    kernel's ``env.fused_lanes_share`` has no kernel to find: neither applies
+    in a decoder's cell, nor lists one. The names are those ``BENCHMARK.json``
+    lists for the cell."""
     workload = files.workload(CELL)
     assert workload["driver"] == "oo_lm_searcher" and workload["chips"] == 1
-    assert not {"policy forward", "env substep"} & set(workload["layers"])
-    applies = {
-        m["name"] for m in files.metrics("per_layer", CELL) if files.layer_metric(m["name"]).applies(workload)
-    }
-    assert {m for m in applies if m.startswith("lm.")} == {
-        "lm.attention_ms", "lm.router_ms", "lm.experts_ms", "lm.head_ms", "lm.step_mfu",
-        "lm.experts_roofline_share", "lm.expert_load_max", "lm.cache_roofline_share", "lm.cache_gb",
-    }
+    assert {"policy forward", "env substep"} <= set(workload["layers"])
+    read = {m["name"] for m in files.metrics("per_layer", CELL) if files.layer_metric(m["name"]).applies(workload)}
+    listing = {m["name"] for m in files.spec["per_layer"] if CELL in m.get("workloads", [])}
+    assert listing <= read and {m for m in read if m.startswith("lm.")} == {m for m in listing if m.startswith("lm.")}
+    assert {"lm.attention_ms", "lm.cache_ms", "lm.cache_roofline_share"} <= listing
+    assert {"policy.forward_scope_ms", "env.substep_scope_ms", "env.reset_scope_ms"} <= listing
+    entries = {m["name"]: m for m in files.spec["per_layer"]}
+    for cell in (CELL, "glm47_flash_ep8.decode512", "granite4_h_micro_pp4.decode256"):
+        for name in ("policy.roofline_share", "env.fused_lanes_share"):
+            assert not files.layer_metric(name).applies(files.workload(cell)), (name, cell)
+            assert cell not in entries[name]["workloads"], (name, cell)
     assert {
         "contract.occupancy", "contract.obs_norm_scope_ms", "contract.bookkeeping_scope_ms",
         "contract.edges_scope_ms", "eval.unscoped_share",
-    } <= applies
-    assert not any(name.startswith(("policy.", "env.")) for name in applies)
-    # in the cells the benchmark had, every metric that applied applies still
+    } <= read
+    # in the Humanoid cells the policy forward's and the env substep's readers all apply, no lm.* one
     for cell in ("humanoid_mlp64.budget", "humanoid_mlp64.episodes", "humanoid_mlp256.budget", "humanoid_mlp64.budget.pop4"):
         there = files.workload(cell)
         names = {m["name"] for m in files.metrics("per_layer", cell) if files.layer_metric(m["name"]).applies(there)}
         assert {
-            "policy.forward_ms", "policy.roofline_share", "env.step_ms", "policy.forward_scope_ms",
-            "env.substep_scope_ms", "env.reset_scope_ms", "contract.occupancy",
+            "policy.roofline_share", "policy.forward_scope_ms", "env.substep_scope_ms", "env.reset_scope_ms",
+            "env.fused_lanes_share", "contract.occupancy",
         } <= names
         assert not any(name.startswith("lm.") for name in names)
 
@@ -152,7 +156,7 @@ HloModule jit_run_vectorized_rollout, is_scheduled=true
 """
 
 
-def test_inner_scope_reader_on_a_hand_written_text():
+def test_inner_scope_reader_on_a_hand_written_text(monkeypatch):
     assert lm_scopes.cache_instructions(HLO_TEXT, {SHAPE}) == {"p", "fusion.1"}
     ops = {  # HLO text as a trace names an op: [self seconds, executions]
         "%fusion.1 = f32[4,2,4,8]{3,2,1,0} fusion(%cache)": [0.30, 16],
@@ -163,7 +167,7 @@ def test_inner_scope_reader_on_a_hand_written_text():
         "%fusion.6 = f32[4]{0} fusion(%scores)": [0.10, 16],
     }
     trace = types.SimpleNamespace(
-        planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1]
+        planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1], evaluation_seconds=lambda: 1.25
     )
     lowered = types.SimpleNamespace(compile=lambda: types.SimpleNamespace(as_text=lambda: HLO_TEXT))
     session = types.SimpleNamespace(
@@ -183,10 +187,29 @@ def test_inner_scope_reader_on_a_hand_written_text():
     assert split["seconds"] == pytest.approx(
         {"fwd_attention": 0.40, "fwd_router": 0.02, "fwd_experts": 0.40, "fwd_head": 0.08}
     )
-    assert split["cache_ops_s"] == pytest.approx(0.30)  # the one op that holds the cache's shape
+    # a library without the cache's scope: the one op that holds the cache's shape
+    assert split["cache_ops_s"] == pytest.approx(0.30) and split["cache_by"] == "shape"
     assert split["policy_forward_s"] == pytest.approx(0.90) and split["evaluation_s"] == pytest.approx(1.00)
     assert split["inner_share_of_policy_forward"] == pytest.approx(1.0)
+    assert split["coverage_percent"] == pytest.approx(80.0)  # the trace kept ops for 1.00 of the program's 1.25 s
     assert lm_scopes.per_step_ms(run, "fwd_experts") == pytest.approx(25.0)
+    assert lm_scopes.cache_ms(run) == pytest.approx(0.30 / 16 * 1e3)
+    # a library that declares the cache's scope and wears it inside fwd_attention:
+    # the pass is read by the name, no shape looked for
+    import evotorch_tpu.observability.scopes as library
+
+    monkeypatch.setattr(library, "FORWARD_SCOPES", library.FORWARD_SCOPES + (lm_scopes.CACHE_SCOPE,))
+    scoped = HLO_TEXT.replace("evotorch_tpu.fwd_attention/nkgd", "evotorch_tpu.fwd_attention/evotorch_tpu.fwd_kv_cache/nkgd")
+    lowered.compile = lambda: types.SimpleNamespace(as_text=lambda: scoped)
+    session.lm_sizes = None  # no shape to look for
+    memo.clear()
+    split = lm_scopes.forward_seconds(run)
+    assert split["cache_by"] == "scope" and split["cache_ops_s"] == pytest.approx(0.30)
+    assert split["seconds"] == pytest.approx(
+        {"fwd_attention": 0.10, "fwd_kv_cache": 0.30, "fwd_router": 0.02, "fwd_experts": 0.40, "fwd_head": 0.08}
+    )
+    # lm.attention_ms keeps its meaning: the attention scope with the cache's pass inside it
+    assert lm_scopes.per_step_ms(run, "fwd_attention", lm_scopes.CACHE_SCOPE) == pytest.approx(0.40 / 16 * 1e3)
     # no device trace (a CPU rehearsal): nothing is read, nothing is lowered
     run.trace = types.SimpleNamespace(planes=[])
     memo.clear()

@@ -4,6 +4,9 @@ configuration counted by hand, the reader of the decoder's inner scopes
 (harness/mla_scopes.py) on a small hand-written compiled text joined to
 hand-made events, the cell traced end to end on the CPU (``--rehearse``), and
 its comparison with int8 in the program's place.
+
+The names of the cell's metrics are read from ``BENCHMARK.json``, never
+written out here: the next ``mla.*`` metric does not break this file.
 """
 
 import json
@@ -19,10 +22,8 @@ from benchmark.harness.loader import BenchmarkFiles
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "glm47_flash_ep8.decode512"
-MLA_METRICS = {
-    "mla.attention_ms", "mla.latent_cache_ms", "mla.latent_cache_roofline_share", "mla.cache_gb",
-    "mla.experts_ms", "mla.experts_roofline_share", "mla.experts_tile_fill", "mla.step_mfu",
-}
+#: the per-layer entries of other layers that list this cell too: by scope, and a generation's phases
+SHARED = {"policy.forward_scope_ms", "env.substep_scope_ms", "env.reset_scope_ms"}
 # GLM-4.7-Flash's config.json as the catalog has it (model-configs guide,
 # architectures.jsonl): what the cell may not change
 PUBLISHED = {
@@ -43,30 +44,39 @@ def files():
     return BenchmarkFiles(ROOT)
 
 
+def cell_metrics(files):
+    """The per-layer entries that list this cell, as ``BENCHMARK.json`` has them."""
+    return [m for m in files.spec["per_layer"] if CELL in m.get("workloads", [])]
+
+
 def test_the_cells_files_agree_with_the_benchmark(files):
     workload = files.workload(CELL)
     assert workload["driver"] == "oo_mla_searcher" and workload["chips"] == 1
     assert workload["traffic"] == {"name": "decode512", "eval_mode": "budget", "num_actors": None, "search_seed": 1}
     assert (workload["warmup_generations"], workload["traced_generations"]) == (3, 2)
     assert set(workload["layers"]) == {
-        "OO searcher", "eval contract", "compile cache", "device", "mla forward", "mla experts", "mla cache",
+        "OO searcher", "eval contract", "policy forward", "env substep", "compile cache", "device", "mla forward",
+        "mla experts", "mla cache",
     }
     applies = {
         m["name"] for m in files.metrics("per_layer", CELL) if files.layer_metric(m["name"]).applies(workload)
     }
-    assert {m for m in applies if m.startswith("mla.")} == MLA_METRICS
+    own = {m["name"] for m in cell_metrics(files) if m["name"].startswith("mla.")}
+    assert own and {m for m in applies if m.startswith("mla.")} == own
     # the readers without a list of cells read this one too; Trinity's and the MLPs' do not
     assert {
         "searcher.steady_compiles", "searcher.outside_eval_ms", "contract.occupancy", "cache.misses",
         "device.idle_share", "device.peak_hbm_gb", "contract.bookkeeping_scope_ms", "contract.edges_scope_ms",
         "eval.unscoped_share",
     } <= applies
-    assert not any(name.startswith(("lm.", "policy.", "env.")) for name in applies)
+    # of the policy forward's and the env substep's readers, those that go by scope
+    assert {m for m in applies if m.startswith(("policy.", "env."))} == SHARED
+    assert not any(name.startswith(("lm.", "ssm.")) for name in applies)
     for entry in files.spec["per_layer"]:
         if entry["name"].startswith("mla."):
             assert entry["workloads"] == [CELL] and entry["moves"] == "env_steps_per_s"
-        else:
-            assert CELL not in entry.get("workloads", [])  # no accepted entry was touched
+        elif CELL in entry.get("workloads", []):  # listed with the cells of its own layer
+            assert entry["name"] in SHARED or entry["name"].startswith("searcher."), entry["name"]
     listed = [w for w in files.spec["workloads"] if w["name"] == CELL]
     assert listed == [{"name": CELL, "config": "glm47_flash_ep8", "traffic": "decode512", "chips": 1, "why": workload["why"]}]
 
@@ -165,7 +175,9 @@ def test_inner_scope_reader_on_a_hand_written_text():
         "%fusion.6 = f32[4]{0} fusion(%scores)": [0.10, 16],
         "%fusion.7 = bf16[4,8,6]{2,1,0} fusion(%cache, %c)": [0.06, 16],
     }
-    trace = types.SimpleNamespace(planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1])
+    trace = types.SimpleNamespace(
+        planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1], evaluation_seconds=lambda: 1.06
+    )
     lowered = types.SimpleNamespace(compile=lambda: types.SimpleNamespace(as_text=lambda: HLO_TEXT))
     session = types.SimpleNamespace(
         problem=types.SimpleNamespace(lower_evaluation=lambda popsize: lowered),
@@ -186,6 +198,7 @@ def test_inner_scope_reader_on_a_hand_written_text():
     )
     assert split["policy_forward_s"] == pytest.approx(0.96) and split["evaluation_s"] == pytest.approx(1.06)
     assert split["inner_share_of_policy_forward"] == pytest.approx(1.0)
+    assert split["coverage_percent"] == pytest.approx(100.0)
     assert mla_scopes.per_step_ms(run, "fwd_latent_cache") == pytest.approx(22.5)
     assert mla_scopes.positions_per_step(run) == 50  # counted by the program: 400 over 8 steps
     session.policy_counters = lambda: None
@@ -201,7 +214,53 @@ def test_inner_scope_reader_on_a_hand_written_text():
     assert mla_scopes.forward_seconds(run) is None
 
 
-def test_the_cell_rehearses_traced_on_the_cpu():
+def test_a_trace_that_lost_steps_reads_the_same_per_step(files, capsys):
+    """The profiler kept the ops of 7 of the 16 control steps that ran (as
+    in one traced run of the GLM cell on the chip, 472 of 1,024): the rest
+    of the program's 1.06 s shows as the loop op's own time. The times per
+    step average the 7 steps the trace holds, and read as a whole trace's
+    (above); the step's share of the peak takes all the time over all 16;
+    the coverage says 7/16."""
+    kept = 7 / 16
+    ops = {
+        "%fusion.1 = f32[4,2,8]{2,1,0} fusion(%cache)": [0.30 * kept, 7],
+        "%fusion.2 = bf16[4,2,6]{2,1,0} fusion(%x)": [0.10 * kept, 7],
+        "%fusion.3 = f32[4,16]{1,0} fusion(%y)": [0.02 * kept, 7],
+        "%custom-call.4 = f32[4,8]{1,0} custom-call(%y, %w)": [0.40 * kept, 7],
+        "%fusion.5 = bf16[4,10]{1,0} fusion(%h)": [0.08 * kept, 7],
+        "%fusion.6 = f32[4]{0} fusion(%scores)": [0.10 * kept, 7],
+        "%fusion.7 = bf16[4,8,6]{2,1,0} fusion(%cache, %c)": [0.06 * kept, 7],
+        "%while.9 = (s32[], bf16[4,8,6]{2,1,0}) while(%tuple.1), condition=%cond, body=%body": [1.06 * (1 - kept), 2],
+    }
+    trace = types.SimpleNamespace(
+        planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1], evaluation_seconds=lambda: 1.06
+    )
+    lowered = types.SimpleNamespace(compile=lambda: types.SimpleNamespace(as_text=lambda: HLO_TEXT))
+    session = types.SimpleNamespace(
+        problem=types.SimpleNamespace(lower_evaluation=lambda popsize: lowered),
+        decode_steps=8,
+        mla_sizes=TINY,
+        compute_dtype="bfloat16",
+        policy_counters=lambda: {"latent_positions_read": 400},
+    )
+    memo = {}
+    run = types.SimpleNamespace(
+        trace=trace, session=session, popsize=4, device_record={"kind": "TPU v5 lite"},
+        memo=lambda key, compute: memo.setdefault(key, compute()),
+    )
+    split = mla_scopes.forward_seconds(run)
+    assert split["steps"] == 7 and split["steps_ran"] == 16
+    assert "holds the ops of 7 of the 16 control steps" in capsys.readouterr().err
+    assert split["coverage_percent"] == pytest.approx(100 * kept)
+    assert mla_scopes.per_step_ms(run, "fwd_latent_cache") == pytest.approx(22.5)  # as in the whole trace
+    assert mla_scopes.per_step_ms(run, "fwd_experts") == pytest.approx(25.0)
+    step_mfu = files.layer_metric("mla.step_mfu").measure(run)
+    # the whole trace's reading: the same 1.06 s over the same 16 steps
+    flops = 2.0 * mla_floors.step_macs_per_lane(TINY, 50 / 4) * 4
+    assert step_mfu == pytest.approx(100 * flops / 197e12 / (1.06 / 16))
+
+
+def test_the_cell_rehearses_traced_on_the_cpu(files):
     """``--rehearse --trace 1``: correct, the counted steps exact, and of the
     per-layer metrics the counters (the CPU's trace has no device plane, so
     the trace's readers find nothing and raise nothing)."""
@@ -216,9 +275,8 @@ def test_the_cell_rehearses_traced_on_the_cpu():
     (detail,) = [json.loads(text[len("detail: "):]) for text in out[:-1] if text.startswith("detail: ")]
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] == 2
     assert line["device"]["platform"] == "cpu"
-    assert set(line["metrics"]) == {
-        "searcher.steady_compiles", "contract.occupancy", "cache.misses", "mla.cache_gb", "mla.experts_tile_fill",
-    }
+    counters = {m["name"] for m in cell_metrics(files) if m["source"] == "program_counter"}
+    assert counters and set(line["metrics"]) == counters | {"searcher.steady_compiles", "contract.occupancy", "cache.misses"}
     assert line["metrics"]["searcher.steady_compiles"]["value"] == 0
     assert line["metrics"]["contract.occupancy"]["value"] == 100.0
     # 4 lanes x 8 slots x 2 layers x (512 + 64) bfloat16 numbers

@@ -16,6 +16,7 @@ from benchmark.harness import scopes, trace
 from evotorch_tpu.observability.scopes import instruction_scopes
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(DATA)))
 
 # -- hand-made events, a hand-written text ---------------------------------------
 
@@ -154,30 +155,59 @@ def test_a_text_without_any_scope_reads_nothing(capsys):
     assert "carries a scope" in capsys.readouterr().err
 
 
-def test_a_stale_cache_is_compiled_past(capsys):
-    """What a compile cache written before the scopes (by the parent commit)
-    hands back has no metadata; its key ignores the scopes. The reader says so
-    and takes the text from a compile the cache cannot answer."""
+def stale_cache_run(stale):
+    """A run whose problem's evaluation, compiled through the persistent
+    cache, prints ``stale`` and, compiled past it, ``HLO_TEXT``."""
     import jax
 
-    class Problem:
-        lowered = 0
+    lowered = []
 
-        def lower_evaluation(self, popsize):
-            Problem.lowered += 1
-            cached = jax.config.jax_enable_compilation_cache
-            compiled = types.SimpleNamespace(as_text=lambda: STALE_TEXT if cached else HLO_TEXT)
-            return types.SimpleNamespace(compile=lambda: compiled)
+    def lower_evaluation(popsize):
+        lowered.append(popsize)
+        cached = jax.config.jax_enable_compilation_cache
+        compiled = types.SimpleNamespace(as_text=lambda: stale if cached else HLO_TEXT)
+        return types.SimpleNamespace(compile=lambda: compiled)
+
+    memo = {}
+    run = types.SimpleNamespace(
+        session=types.SimpleNamespace(problem=types.SimpleNamespace(lower_evaluation=lower_evaluation)),
+        popsize=8,
+        memo=lambda key, compute: memo.setdefault(key, compute()),
+    )
+    return run, lowered
+
+
+@pytest.fixture
+def cache_on():
+    import jax
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", True)
-    try:
-        text = scopes.compiled_text(Problem().lower_evaluation, 8, instruction_scopes)
-        assert jax.config.jax_enable_compilation_cache is True  # put back
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-    assert text == HLO_TEXT and Problem.lowered == 2
+    yield
+    assert jax.config.jax_enable_compilation_cache is True  # put back
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.mark.parametrize("stale", [STALE_TEXT, HLO_TEXT.replace("evotorch_tpu.env_reset", "select_n")])
+def test_a_stale_cache_is_compiled_past(capsys, cache_on, stale):
+    """What a compile cache written before a scope was added (by the parent
+    commit) hands back lacks it: no metadata at all, or one name missing; its
+    key ignores the scopes. The reader says so and takes the text from a
+    compile the cache cannot answer, once a run."""
+    run, lowered = stale_cache_run(stale)
+    assert scopes.evaluation_text(run, scopes.ROLLOUT_READS) == HLO_TEXT and lowered == [8, 8]
     assert "rm -rf compile_cache" in capsys.readouterr().err
+    assert scopes.evaluation_text(run, ("env_reset",)) == HLO_TEXT and len(lowered) == 2
+
+
+def test_a_text_that_carries_every_name_read_is_compiled_once(cache_on):
+    """Where the cache's executable carries every name the reader asks for,
+    nothing is compiled past it; a name is matched as a whole scope, never as
+    the start of a longer one."""
+    run, lowered = stale_cache_run(HLO_TEXT)
+    assert scopes.evaluation_text(run, ("policy_forward", "env_step", "env_reset")) == HLO_TEXT and lowered == [8]
+    assert scopes.carries(HLO_TEXT, "env_reset") and not scopes.carries(HLO_TEXT, "env")
+    assert not scopes.carries(STALE_TEXT, "policy_forward")
 
 
 def test_another_programs_text_is_not_joined(capsys):
@@ -227,7 +257,7 @@ def test_scope_seconds_joins_the_problems_own_text(capsys):
     )
     assert scopes.per_step_ms(run, "env_reset") == pytest.approx(1_500e-6)
     assert scopes.per_step_ms(run, "obs_norm") == pytest.approx(700e-6)
-    assert list(memo) == ["scopes.scope_seconds"]
+    assert set(memo) == {"scopes.scope_seconds", "scopes.evaluation_text"}  # lowered and reduced once
     assert '"lower_compile_s"' in capsys.readouterr().err  # the split and its cost, for PERF.md
 
 
@@ -266,3 +296,108 @@ def test_recorded_trace_joins_its_programs_text():
     assert named["unscoped_s"] == pytest.approx((unscoped + 499 + 498) * 1e-9, abs=3e-9)
     # everything adds up to the two programs' busy time
     assert sum(split["seconds"].values()) + split["unscoped_s"] == pytest.approx(recorded.busy_s, abs=3e-9)
+
+
+def recorded_run(text_edit=lambda text: text, eval_mode="budget", **session):
+    """A traced run of the recording: its text, as ``text_edit`` leaves it,
+    for the program's; 256 lanes a step, whose first generation's telemetry
+    (5 steps x 256 lanes) the window decoded and whose second's it did not."""
+    recorded = trace.load(os.path.join(DATA, "scoped_1chip.xplane.pb"), chips=1)
+    with open(os.path.join(DATA, "scoped_1chip.hlo.txt")) as f:
+        text = text_edit(f.read())
+    compiled = types.SimpleNamespace(as_text=lambda: text)
+    lowered = types.SimpleNamespace(compile=lambda: compiled)
+    memo = {}
+    return types.SimpleNamespace(
+        trace=recorded,
+        session=types.SimpleNamespace(
+            problem=types.SimpleNamespace(lower_evaluation=lambda popsize: lowered), **session
+        ),
+        workload={"traffic": {"eval_mode": eval_mode}},
+        popsize=256,
+        counts={"capacity_by_call": [5 * 256, None]},
+        device_record={"kind": "TPU v5 lite"},
+        memo=lambda key, compute: memo.setdefault(key, compute()),
+    )
+
+
+#: the recording's forward, ``fusion.9`` and the copy before it, per control step (ten steps)
+RECORDED_FORWARD_NS = (20_017 + 20_015 + 499 + 498) / 10
+
+
+def test_policy_roofline_share_on_the_recording():
+    """The recorded forward reads its 512 x 512 float32 ``w`` once a step: as
+    if each of the 256 lanes read its own 1,024 parameters. The lanes a step
+    runs come from the telemetry's lane-step slots over the first generation's
+    five steps; the floor, 256 x 1,024 x 4 B at 819 GB/s, is 1,280.4 ns."""
+    from benchmark.harness.loader import BenchmarkFiles
+
+    run = recorded_run(parameter_count=1_024, compute_dtype=None)
+    assert scopes.lanes_per_step(run) == 256
+    metric = BenchmarkFiles(ROOT).layer_metric("policy.roofline_share")
+    assert metric.measure(run) == pytest.approx(100 * (256 * 1_024 * 4 / 819e9) / (RECORDED_FORWARD_NS * 1e-9), rel=1e-3)
+    assert metric.measure(run) == pytest.approx(31.21, abs=0.01)
+    # a bfloat16 forward reads half the bytes
+    assert metric.measure(recorded_run(parameter_count=1_024, compute_dtype="bfloat16")) == pytest.approx(15.60, abs=0.01)
+    # no generation's telemetry decoded in the window: no floor, nothing substituted
+    silent = recorded_run(parameter_count=1_024, compute_dtype=None)
+    silent.counts = {"capacity_by_call": [None, None]}
+    assert scopes.lanes_per_step(silent) is None and metric.measure(silent) is None
+
+
+@pytest.mark.parametrize(
+    "eval_mode, capacity, lanes",
+    [
+        ("budget", 5 * 256, 256),
+        ("episodes", 5 * 256, 256),
+        ("budget", 5 * 512, None),  # more slots than the population has lanes
+        ("episodes", 5 * 128, None),  # fewer than the population, where every lane runs every step
+        ("episodes_refill", 5 * 128, 128),  # the working width under refill
+        ("episodes_refill", 5 * 256, 256),
+        ("episodes_refill", 5 * 257, None),  # over the population
+    ],
+)
+def test_lanes_per_step_holds_the_capacity_to_the_traffic(capsys, eval_mode, capacity, lanes):
+    """The capacity is a count the program makes about itself: it is held to
+    what the traffic lets a step run (popsize over chips where every lane runs
+    every step, at most popsize under refill), and a count that breaks that
+    sets no floor."""
+    run = recorded_run(eval_mode=eval_mode, parameter_count=1_024, compute_dtype=None)
+    run.counts = {"capacity_by_call": [capacity, None]}
+    assert scopes.lanes_per_step(run) == lanes
+    assert ("does not match the trace" in capsys.readouterr().err) == (lanes is None)
+
+
+@pytest.fixture
+def kv_cache_declared(monkeypatch):
+    """The library as it will be once it declares the attention cache's scope."""
+    import evotorch_tpu.observability.scopes as library
+
+    monkeypatch.setattr(library, "FORWARD_SCOPES", library.FORWARD_SCOPES + ("fwd_kv_cache",))
+
+
+def test_lm_cache_roofline_share_on_the_recording(kv_cache_declared):
+    """The recording with its forward's ops renamed into the decoder's cache
+    pass (``policy_forward/fwd_attention/fwd_kv_cache``): the cache's time is
+    theirs, read by the name alone. One full-attention layer of one KV head of
+    128 over five steps: 256 lanes x 3 positions on average x 2 x 128 x 2 B =
+    393,216 B a step, 480.1 ns at 819 GB/s."""
+    from benchmark.harness import lm_scopes
+    from benchmark.harness.loader import BenchmarkFiles
+
+    renamed = "evotorch_tpu.policy_forward/evotorch_tpu.fwd_attention/evotorch_tpu.fwd_kv_cache/"
+    sizes = {"layers": [0], "layer_types": ["full_attention"], "kv_heads": 1, "head_dim": 128, "window": 8}
+    run = recorded_run(
+        lambda text: text.replace("evotorch_tpu.policy_forward/", renamed),
+        decode_steps=5, lm_sizes=sizes, compute_dtype="bfloat16",
+    )
+    split = lm_scopes.forward_seconds(run)
+    assert split["cache_by"] == "scope" and split["steps"] == 10
+    assert lm_scopes.cache_ms(run) == pytest.approx(RECORDED_FORWARD_NS * 1e-6, abs=3e-7)
+    files = BenchmarkFiles(ROOT)
+    assert files.layer_metric("lm.cache_ms").measure(run) == lm_scopes.cache_ms(run)
+    assert files.layer_metric("lm.cache_roofline_share").measure(run) == pytest.approx(11.70, abs=0.01)
+    # lm.attention_ms keeps its meaning: the attention scope with the cache's pass inside it
+    assert files.layer_metric("lm.attention_ms").measure(run) == pytest.approx(lm_scopes.cache_ms(run))
+    # every op the trace kept of the two programs, against their device time
+    assert 99 < split["coverage_percent"] <= 100

@@ -43,9 +43,9 @@ def files():
     return BenchmarkFiles(ROOT)
 
 
-def cell_metrics(files):
+def cell_metrics(files, prefix=""):
     """The per-layer entries that list this cell, as ``BENCHMARK.json`` has them."""
-    return [m for m in files.spec["per_layer"] if CELL in m.get("workloads", [])]
+    return [m for m in files.spec["per_layer"] if CELL in m.get("workloads", []) and m["name"].startswith(prefix)]
 
 
 def test_the_cells_files_agree_with_the_benchmark(files):
@@ -53,8 +53,8 @@ def test_the_cells_files_agree_with_the_benchmark(files):
     assert workload["driver"] == "oo_ssm_searcher" and workload["chips"] == 1
     assert workload["traffic"] == {"name": "decode256", "eval_mode": "budget", "num_actors": None, "search_seed": 1}
     assert (workload["warmup_generations"], workload["traced_generations"]) == (3, 2)
-    own = cell_metrics(files)
-    assert own and all(m["name"].startswith("ssm.") for m in own)
+    own = cell_metrics(files, "ssm.")
+    assert own
     for entry in own:
         module = files.layer_metric(entry["name"])
         assert entry["workloads"] == [CELL] and entry["moves"] == module.MOVES == "env_steps_per_s"
@@ -70,10 +70,14 @@ def test_the_cells_files_agree_with_the_benchmark(files):
         "device.idle_share", "device.peak_hbm_gb", "contract.bookkeeping_scope_ms", "contract.edges_scope_ms",
         "eval.unscoped_share",
     } <= applies
-    assert not any(name.startswith(("lm.", "mla.", "policy.", "env.")) for name in applies)
+    # of the policy forward's and the env substep's readers, those that go by scope
+    shared = {"policy.forward_scope_ms", "env.substep_scope_ms", "env.reset_scope_ms"}
+    assert {"policy forward", "env substep"} <= set(workload["layers"])
+    assert {m for m in applies if m.startswith(("policy.", "env."))} == shared
+    assert not any(name.startswith(("lm.", "mla.")) for name in applies)
     for entry in files.spec["per_layer"]:
-        if not entry["name"].startswith("ssm."):
-            assert CELL not in entry.get("workloads", [])  # no accepted entry was touched
+        if not entry["name"].startswith("ssm.") and CELL in entry.get("workloads", []):
+            assert entry["name"] in shared or entry["name"].startswith("searcher."), entry["name"]
     listed = [w for w in files.spec["workloads"] if w["name"] == CELL]
     assert listed == [{"name": CELL, "config": "granite4_h_micro_pp4", "traffic": "decode256", "chips": 1, "why": workload["why"]}]
     assert files.spec["workloads"][-1]["name"] == CELL and files.spec["configs"][-1]["name"] == "granite4_h_micro_pp4"
@@ -175,7 +179,9 @@ def test_inner_scope_reader_on_a_hand_written_text(files):
         "%fusion.5 = bf16[4,10]{1,0} fusion(%h)": [0.08, 16],
         "%fusion.6 = f32[4]{0} fusion(%scores)": [0.04, 16],
     }
-    trace = types.SimpleNamespace(planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1])
+    trace = types.SimpleNamespace(
+        planes=[object()], evaluation_ops=lambda: ops, generations=lambda: [0, 1], evaluation_seconds=lambda: 1.00
+    )
     lowered = types.SimpleNamespace(compile=lambda: types.SimpleNamespace(as_text=lambda: HLO_TEXT))
     session = types.SimpleNamespace(
         problem=types.SimpleNamespace(lower_evaluation=lambda popsize: lowered),
@@ -199,7 +205,7 @@ def test_inner_scope_reader_on_a_hand_written_text(files):
     assert split["inner_share_of_policy_forward"] == pytest.approx(1.0)
     assert ssm_scopes.per_step_ms(run, "fwd_ssm_state") == pytest.approx(25.0)
     assert ssm_scopes.updates_per_step(run) == 8  # counted by the program: 64 over 8 steps
-    by_name = {m["name"]: files.layer_metric(m["name"]).measure(run) for m in cell_metrics(files)}
+    by_name = {m["name"]: files.layer_metric(m["name"]).measure(run) for m in cell_metrics(files, "ssm.")}
     assert all(value is not None for value in by_name.values())  # every metric of the cell finds something to read
     # 8 states of 48 bfloat16 numbers, read and written, at 819 GB/s, over 25 ms
     assert by_name["ssm.state_roofline_share"] == pytest.approx(100 * (2 * 8 * 96 / 819e9) / 25e-3)
@@ -219,7 +225,7 @@ def test_inner_scope_reader_on_a_hand_written_text(files):
     lowered.compile = lambda: types.SimpleNamespace(as_text=lambda: other)
     memo.clear()
     assert ssm_scopes.forward_seconds(run) is None and ssm_scopes.per_step_ms(run, "fwd_ssm") is None
-    traced = [m["name"] for m in cell_metrics(files) if m["source"] == "device_trace"]
+    traced = [m["name"] for m in cell_metrics(files, "ssm.") if m["source"] == "device_trace"]
     assert traced and all(files.layer_metric(name).measure(run) is None for name in traced)
     # no device trace (a CPU rehearsal): nothing is read, nothing is lowered
     run.trace = types.SimpleNamespace(planes=[])

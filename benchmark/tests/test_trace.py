@@ -10,9 +10,11 @@ import os
 
 import pytest
 
-from benchmark.harness import layers, trace
+from benchmark.harness import trace
+from benchmark.harness.loader import BenchmarkFiles
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 # -- arithmetic ---------------------------------------------------------------
@@ -64,7 +66,7 @@ def test_op_label():
     assert trace.op_label("%fusion = f32[]{:T(128)} fusion(f32[256,512] %x.1)") == "fusion f32[]"
 
 
-# -- the evaluation program's time by layer -------------------------------------
+# -- the evaluation program's ops ---------------------------------------------
 
 
 def hand_made_trace():
@@ -111,28 +113,28 @@ def test_evaluation_ops_are_those_inside_the_longest_program():
     assert ops[texts["while"]] == pytest.approx([2_400e-9, 2])
 
 
-def test_split_by_weight_shapes():
+def test_executions_and_evaluation_seconds():
     made, texts = hand_made_trace()
-    # one layer of 5 inputs and 3 outputs: 3 biases + 15 weights + 3 spare = 21
-    pattern = layers.weight_shape_pattern([(3, 5)], 21, "bfloat16")
-    assert pattern.search(texts["slice"]) and pattern.search(texts["matvec"])
-    assert pattern.search(texts["convert"])
-    assert not pattern.search(texts["env"]) and not pattern.search(texts["update"])
-    split = layers.split_ops(made.evaluation_ops(), pattern)
-    # forward: slices, matvecs and the conversion; the loop op carries the flat
-    # matrix too, but its own time is overhead and stays with the rest; the
-    # steps are the most-executed weight op's
-    assert split["steps"] == 4
-    assert split["forward_s"] == pytest.approx((4_000 + 1_600 + 400) * 1e-9)
-    assert split["rest_s"] == pytest.approx((4_000 + 2_400) * 1e-9)
-    # float32 weights print as f32 and are recognised as such
-    assert not layers.weight_shape_pattern([(3, 5)], 21, "float32").search(texts["slice"])
+    # two control steps in each generation's evaluation, none in the update
+    assert made.executions(texts["matvec"], 0, 9_000) == 2 and made.executions(texts["matvec"], 10_000, 19_000) == 2
+    assert made.executions(texts["matvec"], 0, 19_000) == 4 and made.executions(texts["update"], 1_000, 9_000) == 0
+    # the evaluation program's two runs, 7,000 ns each; the update's are not counted
+    assert made.evaluation_seconds() == pytest.approx(2 * 7_000e-9)
+    # its ops' self times add up to it but for the gaps between them
+    assert sum(s for s, _ in made.evaluation_ops().values()) == pytest.approx(2 * (200 + 6_000) * 1e-9)
+    assert trace.Trace([], []).evaluation_seconds() == 0.0
 
 
 def test_policy_floor():
-    # 10,000 lanes x 98,321 bf16 parameters over 819 GB/s: 2.40 ms on one chip
-    assert layers.policy_floor_ms(10_000, 98_321, "bfloat16", 819e9, 1) == pytest.approx(2.4010, abs=1e-4)
-    assert layers.policy_floor_ms(50_000, 12_305, "bfloat16", 819e9, 4) == pytest.approx(0.3756, abs=1e-4)
+    """The least time one control step's forward can take on a chip: the lanes
+    it runs there, each reading its own bfloat16 parameters, at 819 GB/s."""
+    floor_ms = BenchmarkFiles(ROOT).layer_metric("policy.roofline_share").floor_ms
+    # 10,000 lanes x 98,321 parameters: 2.40 ms
+    assert floor_ms(10_000, 98_321, 2, 819e9) == pytest.approx(2.4010, abs=1e-4)
+    # popsize 50,000 over four chips: 12,500 lanes a chip
+    assert floor_ms(12_500, 12_305, 2, 819e9) == pytest.approx(0.3756, abs=1e-4)
+    # refill's working width: 8,192 lanes a step whatever the popsize
+    assert floor_ms(8_192, 12_305, 2, 819e9) == pytest.approx(0.2462, abs=1e-4)
 
 
 # -- one chip -----------------------------------------------------------------
