@@ -1,14 +1,15 @@
 """A language-model decoder as an evolvable policy, decoded stepwise.
 
-The modules of three families: ``afmoe`` (Trinity: gated grouped-query
+The modules of four families: ``afmoe`` (Trinity: gated grouped-query
 attention, a norm before and after every block, sigmoid-routed experts),
 ``glm4_moe_lite`` (GLM-4.7-Flash: latent attention, a norm before a block
-only) and ``granitemoehybrid`` (Granite 4.0-H: Mamba-2 mixers with one
+only), ``granitemoehybrid`` (Granite 4.0-H: Mamba-2 mixers with one
 grouped-query attention layer in ten, dense MLPs, a tied head, four
-multipliers). Token embedding, RMSNorm, an attention with a per-lane cache
-or a recurrence with a per-lane matrix state as the policy's recurrent state,
-SwiGLU, and a sigmoid-routed expert layer that is told which experts it
-holds. They follow the ``Module`` protocol of ``layers.py`` (``init`` /
+multipliers) and ``kimi_linear`` (Kimi-Linear: gated delta-rule layers, KDA,
+with one latent-attention layer in four, sigmoid-routed experts). Token
+embedding, RMSNorm, an attention with a per-lane cache or a recurrence with a
+per-lane matrix state as the policy's recurrent state, SwiGLU, and a
+sigmoid-routed expert layer that is told which experts it holds. They follow the ``Module`` protocol of ``layers.py`` (``init`` /
 ``initial_state`` / ``apply(params, x, state)``), so a decoder is a policy
 like any other: the observation is one token id, the output the logits over
 the held vocabulary, the state what its layers carry from step to step.
@@ -85,7 +86,12 @@ The state counts the steps it was rewritten (``updates``) and those of them
 the kernel rewrote (``kernel_updates``). ``reset_state`` zeroes the lanes that ended an
 episode lane by lane, as the caches are. Each lane's transition is its own:
 ``A_log``, ``dt_bias`` and ``D`` are 1-D leaves, perturbed like any other.
-A layer's state sits under its first block's kind (``"attn"`` or ``"ssm"``).
+A layer's state sits under its first block's kind (``"attn"``, ``"ssm"`` or
+``"kda"``). ``KimiDeltaAttention`` keeps the same two things in the same
+layout (windows of ``q``, ``k`` and ``v``; a matrix state a head, ``(key,
+value)``, stored turned), decayed by one factor a KEY CHANNEL and corrected by
+a rank-1 delta rule before its outer product is added, so the pass reads the
+state twice (``fwd_kda_state``, XLA's plain form).
 When a lane ends an episode the mixer keeps, before it zeroes the lane, what
 its matrix states held, summed over ``state_dim`` (``ended``): nothing a
 step pays for, and what an evaluation's report holds against a reference's
@@ -124,11 +130,13 @@ __all__ = [
     "GatedAttention",
     "LatentAttention",
     "Mamba2Mixer",
+    "KimiDeltaAttention",
     "SparseExperts",
     "DecoderLayer",
     "AfmoeDecoder",
     "Glm4MoeLiteDecoder",
     "GraniteMoeHybridDecoder",
+    "KimiLinearDecoder",
     "stepwise_logits",
 ]
 
@@ -454,7 +462,10 @@ class LatentAttention(_LaneModule):
     ``score_{h,s} = (q_n . k_n + q_r . k_r) / sqrt(qk_nope + qk_rope)``;
     softmax in float32; ``a_h = sum_s p_{h,s} v_{h,s}``. No bias, no gate, no
     norm after the block. Computed in the absorbed form over the latent
-    cache of the module docstring, which is its state."""
+    cache of the module docstring, which is its state. ``q_rank=None``: the
+    query straight from ``xn`` (``[q_n | q_r]_h = (W_q xn)_h``, no LoRA and
+    no norm); ``rotary=False``: ``q_r`` and ``k_r`` are kept, cached and
+    scored unrotated."""
 
     block_key = "attn"
 
@@ -463,7 +474,7 @@ class LatentAttention(_LaneModule):
         dim: int,
         num_heads: int,
         *,
-        q_rank: int,
+        q_rank: Optional[int],
         kv_rank: int,
         nope_dim: int,
         rope_dim: int,
@@ -471,19 +482,28 @@ class LatentAttention(_LaneModule):
         slots: int,
         rope_theta: float,
         eps: float = 1e-5,
+        rotary: bool = True,
     ):
         self.dim, self.heads = int(dim), int(num_heads)
-        self.q_rank, self.kv_rank = int(q_rank), int(kv_rank)
+        self.q_rank, self.kv_rank = None if q_rank is None else int(q_rank), int(kv_rank)
         self.nope, self.rope, self.v = int(nope_dim), int(rope_dim), int(v_dim)
         self.slots, self.rope_theta, self.eps = int(slots), float(rope_theta), float(eps)
+        self.rotary = bool(rotary)
 
     def init(self, key):
         kqa, kqb, kva, kvb, ko = jax.random.split(key, 5)
+        wide = self.heads * (self.nope + self.rope)
+        if self.q_rank is None:  # the query straight from xn
+            query = {"q": _normal(kqa, (wide, self.dim))}
+        else:
+            query = {
+                "q_a": _normal(kqa, (self.q_rank, self.dim)),
+                "q_a_norm": jnp.ones((self.q_rank,), F32),
+                "q_b": _normal(kqb, (wide, self.q_rank)),
+            }
         return {
             "in_norm": jnp.ones((self.dim,), F32),
-            "q_a": _normal(kqa, (self.q_rank, self.dim)),
-            "q_a_norm": jnp.ones((self.q_rank,), F32),
-            "q_b": _normal(kqb, (self.heads * (self.nope + self.rope), self.q_rank)),
+            **query,
             "kv_a": _normal(kva, (self.kv_rank + self.rope, self.dim)),
             "kv_a_norm": jnp.ones((self.kv_rank,), F32),
             "kv_b": _normal(kvb, (self.heads * (self.nope + self.v), self.kv_rank)),
@@ -558,13 +578,17 @@ class LatentAttention(_LaneModule):
         nope, v_rows = slice(0, self.nope), slice(self.nope, self.nope + self.v)
         with scope("fwd_attention"):
             xn = rms_norm(x, acc.vec("in_norm"), self.eps)
-            cq = rms_norm(acc.mm("q_a", xn), acc.vec("q_a_norm"), self.eps)
-            q = acc.mm("q_b", cq).reshape(n, heads, self.nope + self.rope)
+            if self.q_rank is None:
+                q = acc.mm("q", xn).reshape(n, heads, self.nope + self.rope)
+            else:
+                cq = rms_norm(acc.mm("q_a", xn), acc.vec("q_a_norm"), self.eps)
+                q = acc.mm("q_b", cq).reshape(n, heads, self.nope + self.rope)
             kv = acc.mm("kv_a", xn)
             c = rms_norm(kv[:, : self.kv_rank], acc.vec("kv_a_norm"), self.eps)
             t = state["t"]
-            q_r = rope(q[..., self.nope :], t[:, None], self.rope_theta)
-            k_r = rope(kv[:, self.kv_rank :], t, self.rope_theta)
+            turned = functools.partial(rope, theta=self.rope_theta) if self.rotary else lambda x, positions: x
+            q_r = turned(q[..., self.nope :], t[:, None])
+            k_r = turned(kv[:, self.kv_rank :], t)
             q_lat = acc.head_mm("kv_b", q[..., nope], heads, nope, into=True)  # q_n W_UK[h]
             with scope("fwd_latent_cache"):
                 cache_dtype = state["c"].dtype
@@ -585,6 +609,32 @@ class LatentAttention(_LaneModule):
             "read": state["read"] + jnp.minimum(t + 1, slots),
             "fetched": state["fetched"] + fetched,
         }
+
+
+def _end_lanes(state, mask, matrix):
+    """A recurrent block's ``reset_state``: the window ``conv`` and the
+    matrix state ``state[matrix]`` of the lanes in ``mask`` zeroed one lane
+    at a time, what the matrix state held kept first, summed over its first
+    axis after the lane's (``ended``), and the lanes' resets counted."""
+    conv, held, kept = state["conv"], state[matrix], state["ended"]
+    ended = jnp.nonzero(mask, size=mask.shape[0], fill_value=0)[0]
+    no_window = jnp.zeros((1,) + conv.shape[1:], conv.dtype)
+    no_state = jnp.zeros((1,) + held.shape[1:], held.dtype)
+    at = lambda lane, array: (lane,) + (0,) * (array.ndim - 1)
+
+    def end_lane(i, carried):
+        conv, held, kept = carried
+        lane = ended[i]
+        was = jax.lax.dynamic_slice(held, at(lane, held), no_state.shape)
+        summed = jnp.sum(was.astype(F32), axis=1).astype(kept.dtype).reshape((1,) + kept.shape[1:])
+        return (
+            jax.lax.dynamic_update_slice(conv, no_window, at(lane, conv)),
+            jax.lax.dynamic_update_slice(held, no_state, at(lane, held)),
+            jax.lax.dynamic_update_slice(kept, summed, at(lane, kept)),
+        )
+
+    conv, held, kept = jax.lax.fori_loop(0, jnp.sum(mask.astype(jnp.int32)), end_lane, (conv, held, kept))
+    return {**state, "conv": conv, matrix: held, "ended": kept, "resets": state["resets"] + mask.astype(jnp.int32)}
 
 
 class Mamba2Mixer(_LaneModule):
@@ -675,24 +725,7 @@ class Mamba2Mixer(_LaneModule):
         a whole state would read and write all of it in every control step;
         an episode's end is rare). What the lane's matrix state held is kept
         first, summed over ``state_dim`` (``ended``)."""
-        conv, ssm, kept = state["conv"], state["ssm"], state["ended"]
-        ended = jnp.nonzero(mask, size=mask.shape[0], fill_value=0)[0]
-        no_window = jnp.zeros((1,) + conv.shape[1:], conv.dtype)
-        no_state = jnp.zeros((1,) + ssm.shape[1:], ssm.dtype)
-
-        def end_lane(i, held):
-            conv, ssm, kept = held
-            lane = ended[i]
-            was = jax.lax.dynamic_slice(ssm, (lane, 0, 0), no_state.shape)
-            summed = jnp.sum(was.astype(F32), axis=1).astype(kept.dtype).reshape((1,) + kept.shape[1:])
-            return (
-                jax.lax.dynamic_update_slice(conv, no_window, (lane, 0, 0)),
-                jax.lax.dynamic_update_slice(ssm, no_state, (lane, 0, 0)),
-                jax.lax.dynamic_update_slice(kept, summed, (lane, 0, 0)),
-            )
-
-        conv, ssm, kept = jax.lax.fori_loop(0, jnp.sum(mask.astype(jnp.int32)), end_lane, (conv, ssm, kept))
-        return {**state, "conv": conv, "ssm": ssm, "ended": kept, "resets": state["resets"] + mask.astype(jnp.int32)}
+        return _end_lanes(state, mask, "ssm")
 
     @staticmethod
     def _state_plain(ssm, decay, fed, b, c):
@@ -746,6 +779,155 @@ class Mamba2Mixer(_LaneModule):
             "ended": state["ended"],
             "updates": state["updates"] + 1,
             "kernel_updates": state["kernel_updates"] + rewrote,
+            "resets": state["resets"],
+        }
+
+
+def l2_normalized(x, eps=1e-6):
+    """``x / sqrt(sum(x^2) + eps)`` over the last axis, in float32."""
+    x = x.astype(F32)
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+class KimiDeltaAttention(_LaneModule):
+    """``x + W_o[RMSNorm(o) * sigmoid(W_gb W_ga xn)]`` with the gated delta
+    rule (KDA) over ``xn = RMSNorm(x)``: ``q, k, v = silu(conv(W_q xn)),
+    silu(conv(W_k xn)), silu(conv(W_v xn))``, each a depthwise causal
+    convolution of ``width`` taps, no bias, over the lane's window of its last
+    inputs; per head ``q`` and ``k`` L2-normalised, ``q`` times ``head_dim ^
+    -1/2``; ``log a = -exp(A_log[h]) softplus(W_fb W_fa xn + dt_bias)``, ONE
+    DECAY A KEY CHANNEL of each head; ``beta = sigmoid(W_b xn)`` a head; the
+    gates' low ranks are ``head_dim``; a head's state ``S`` ``(head_dim key,
+    head_dim value)``: ``S' = Diag(a) S_{t-1}``, ``S_t = S' + beta k (v -
+    S'^T k)^T`` (``(I - beta k k^T) Diag(a) S_{t-1} + beta k v^T``), ``o =
+    S_t^T q``; the output norm is over a head's ``head_dim`` with one weight
+    for all heads. ``A_log`` (a head)
+    and ``dt_bias`` (a key channel of a head) are 1-D leaves, so every lane's
+    decay is its own; the three convolutions' leaves are held taps first, as
+    ``Mamba2Mixer``'s, and written out per lane (``mat``).
+
+    **The state** is what the lane carries: the window ``(width - 1, 3 x
+    heads x head_dim)`` of the convolutions' last inputs (``q | k | v``) and
+    the matrix state, stored TURNED, ``(head_dim key, heads, head_dim
+    value)`` (``S[h, k, v]`` lies at ``[k, h, v]``: in row-major order the
+    bytes of ``Mamba2Mixer``'s ``(state_dim, heads x head_dim)``, with the
+    head split from the value so that a (head, key) factor, the decay, ``k``
+    or ``q``, broadcasts along the value axis alone: over a merged ``heads x
+    value`` axis XLA writes each such factor out at the state's size in
+    float32, 1 GB a factor at 512 lanes), in the compute dtype and updated in
+    float32. All of it is rewritten every step; the decay, ``S'^T k``, the
+    rank-1 update, the readout and the write-back are one span
+    (``fwd_kda_state``), XLA's plain form (``_state_plain``) on every
+    platform. A lane that starts an episode has both zero. Kept beyond an
+    episode, as the mixer keeps them: what the matrix state held when the lane
+    last ENDED an episode, summed over the key axis (``ended``, ``(heads,
+    head_dim)``), the steps the state was rewritten and the times it was
+    zeroed."""
+
+    block_key = "kda"
+
+    def __init__(
+        self,
+        dim: int,
+        num_heads: int,
+        head_dim: int,
+        *,
+        conv_width: int = 4,
+        eps: float = 1e-5,
+    ):
+        self.dim, self.heads, self.head_dim = int(dim), int(num_heads), int(head_dim)
+        self.width, self.eps = int(conv_width), float(eps)
+        self.inner = self.heads * self.head_dim
+
+    def init(self, key):
+        """Matrices as the family draws them; the convolutions' taps uniform
+        within ``1 / sqrt(width)`` (``nn.Conv1d``), ``A`` uniform in [1, 16],
+        ``dt`` log-uniform in [1e-3, 1e-1] with ``dt_bias`` its inverse
+        softplus (Mamba-2's initial values)."""
+        keys = jax.random.split(key, 12)
+        bound = 1.0 / math.sqrt(self.width)
+        dt = jnp.exp(jax.random.uniform(keys[11], (self.inner,), F32, math.log(1e-3), math.log(1e-1)))
+        params = {
+            "in_norm": jnp.ones((self.dim,), F32),
+            "A_log": jnp.log(jax.random.uniform(keys[10], (self.heads,), F32, 1.0, 16.0)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "b": _normal(keys[0], (self.heads, self.dim)),
+            "f_a": _normal(keys[1], (self.head_dim, self.dim)),
+            "f_b": _normal(keys[2], (self.inner, self.head_dim)),
+            "g_a": _normal(keys[3], (self.head_dim, self.dim)),
+            "g_b": _normal(keys[4], (self.inner, self.head_dim)),
+            "o_norm": jnp.ones((self.head_dim,), F32),
+            "o": _normal(keys[5], (self.dim, self.inner)),
+        }
+        for at, name in enumerate("qkv"):
+            params[name] = _normal(keys[6 + at], (self.inner, self.dim))
+            params[name + "_conv"] = jax.random.uniform(
+                jax.random.fold_in(keys[9], at), (self.width, self.inner), F32, -bound, bound
+            )
+        return params
+
+    def initial_state(self):
+        zero = jnp.zeros((), jnp.int32)
+        return {
+            "conv": jnp.zeros((self.width - 1, 3 * self.inner), F32),
+            "state": jnp.zeros((self.head_dim, self.heads, self.head_dim), F32),  # turned: (key, head, value)
+            "ended": jnp.zeros((self.heads, self.head_dim), F32),
+            "updates": zero,
+            "resets": zero,
+        }
+
+    def reset_state(self, state, mask):
+        """As ``Mamba2Mixer.reset_state``: the window and the matrix state of
+        the lanes in ``mask``, one lane at a time, what the matrix state held
+        kept first, summed over the key axis (``ended``)."""
+        return _end_lanes(state, mask, "state")
+
+    @staticmethod
+    def _state_plain(held, decay, k, v, beta, q):
+        """The pass over the matrix states in XLA's own operations: ``S' =
+        Diag(a) S``, ``S_t = S' + beta k (v - S'^T k)^T``, ``o = S_t^T q``.
+        ``held`` ``(n, key, heads, value)`` as stored; ``decay`` (``a``),
+        ``k`` and ``q`` ``(n, heads, key)``, ``v`` ``(n, heads, value)``,
+        ``beta`` ``(n, heads)``, all float32. The stored state converted to
+        float32, both products over the key axis as multiplies and sums (no
+        matrix unit, no pass of lower precision), the readout of the
+        UNROUNDED state, one rounding to the stored dtype: the statement of
+        the equations. Returns the new state and the float32 readout ``(n,
+        heads, value)``."""
+        turned = lambda a: jnp.swapaxes(a, 1, 2)[..., None]  # (n, heads, key) -> (n, key, heads, 1)
+        s = held.astype(F32) * turned(decay)
+        read = jnp.sum(s * turned(k), axis=1)  # S'^T k: (n, heads, value)
+        s = s + turned(k) * (beta[:, :, None] * (v - read))[:, None]
+        out = jnp.sum(s * turned(q), axis=1)
+        return s.astype(held.dtype), out
+
+    def _forward(self, acc, x, state):
+        n, heads, hd, inner = x.shape[0], self.heads, self.head_dim, self.inner
+        with scope("fwd_kda"):
+            xn = rms_norm(x, acc.vec("in_norm"), self.eps)
+            fresh = jnp.concatenate([acc.mm(name, xn) for name in "qkv"], axis=-1)
+            window = state["conv"]
+            taps = jnp.concatenate([window, fresh[:, None, :].astype(window.dtype)], axis=1)  # oldest first
+            weights = jnp.concatenate([acc.mat(name + "_conv") for name in "qkv"], axis=-1).astype(F32)
+            q, k, v = jnp.split(jax.nn.silu(jnp.sum(taps.astype(F32) * weights, axis=1)), 3, axis=-1)
+            q = l2_normalized(q.reshape(n, heads, hd)) * hd**-0.5
+            k = l2_normalized(k.reshape(n, heads, hd))
+            v = v.reshape(n, heads, hd)
+            fed = acc.mm("f_b", acc.mm("f_a", xn)).astype(F32) + acc.vec("dt_bias").astype(F32)
+            rate = -jnp.exp(acc.vec("A_log").astype(F32))[:, :, None]  # (lanes or 1, heads, 1)
+            beta = jax.nn.sigmoid(acc.mm("b", xn).astype(F32))
+            with scope("fwd_kda_state"):
+                decay = jnp.exp(rate * jax.nn.softplus(fed).reshape(n, heads, hd))
+                held, o = self._state_plain(state["state"], decay, k, v, beta, q)
+            o = rms_norm(o, acc.vec("o_norm")[:, None, :], self.eps)
+            gate = acc.mm("g_b", acc.mm("g_a", xn)).astype(F32).reshape(n, heads, hd)
+            o = (o * jax.nn.sigmoid(gate)).reshape(n, inner).astype(x.dtype)
+            y = x + acc.mm("o", o)
+        return y, {
+            "conv": taps[:, 1:],
+            "state": held,
+            "ended": state["ended"],
+            "updates": state["updates"] + 1,
             "resets": state["resets"],
         }
 
@@ -950,7 +1132,8 @@ class _DenseMLP(_LaneModule):
 class DecoderLayer(_LaneModule):
     """The layer's first block (an attention over a cache, or a recurrence),
     then its MLP (dense or sparse). Parameters and state hold the first block
-    under its kind's name (``block_key``: ``"attn"`` or ``"ssm"``)."""
+    under its kind's name (``block_key``: ``"attn"``, ``"ssm"`` or
+    ``"kda"``)."""
 
     def __init__(self, attention: _LaneModule, mlp: _LaneModule):
         self.attention, self.mlp, self.first = attention, mlp, attention.block_key
@@ -1057,7 +1240,11 @@ class _Decoder(_LaneModule):
         recurrent layer's matrix state held when the lane last ended an
         episode, summed over ``state_dim`` (``ssm_ended_state``, ``(n,
         recurrent layers, heads, head_dim)``, as stored; zeros for a lane
-        that ended none). Per lane, in step order (the last
+        that ended none); where layers carry the gated delta rule, the same
+        four under ``kda_``: ``kda_state_updates``, ``kda_lane_resets``,
+        ``kda_state_bytes`` and ``kda_ended_state`` (each state summed over
+        its key axis, ``(n, KDA layers, heads, head_dim)``). Per lane, in
+        step order (the last
         ``max_positions`` steps): the id each step consumed and the lane's
         position in its episode there, ``(n, steps)``; the model's token for
         position ``t`` of an episode is the id consumed at ``t + 1``."""
@@ -1071,16 +1258,20 @@ class _Decoder(_LaneModule):
         latent_layers = [c for c in caches if "read" in c]
         counted = {"latent_positions_read": "read", "latent_positions_fetched": "fetched"} if latent_layers else {}
         report = {key: sum((jnp.sum(s[name]) for s in latent_layers), zero) for key, name in counted.items()}
-        recurrent = [s["ssm"] for s in layers if "ssm" in s]
-        if recurrent:
-            held = sum(math.prod(m[name].shape) * m[name].dtype.itemsize for m in recurrent for name in ("conv", "ssm"))
+        for kind, matrix in (("ssm", "ssm"), ("kda", "state")):  # a recurrent block's kind, its matrix state
+            blocks = [s[kind] for s in layers if kind in s]
+            if not blocks:
+                continue
+            steps = {"state_updates": "updates", "state_kernel_updates": "kernel_updates"}
             report.update(
-                ssm_state_updates=sum((jnp.sum(m["updates"]) for m in recurrent), zero),
-                ssm_state_kernel_updates=sum((jnp.sum(m["kernel_updates"]) for m in recurrent), zero),
-                ssm_lane_resets=jnp.sum(recurrent[0]["resets"]),
-                ssm_state_bytes=jnp.asarray(held, F32),  # past int32 at the cell's size
-                ssm_ended_state=jnp.stack([m["ended"] for m in recurrent], axis=1),
+                {f"{kind}_{key}": sum((jnp.sum(m[name]) for m in blocks), zero) for key, name in steps.items() if name in blocks[0]}
             )
+            held = sum(math.prod(m[name].shape) * m[name].dtype.itemsize for m in blocks for name in ("conv", matrix))
+            report.update({
+                f"{kind}_lane_resets": jnp.sum(blocks[0]["resets"]),
+                f"{kind}_state_bytes": jnp.asarray(held, F32),  # past int32 at the cell's size
+                f"{kind}_ended_state": jnp.stack([m["ended"] for m in blocks], axis=1),
+            })
         return {
             **report,
             "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),
@@ -1385,6 +1576,124 @@ class GraniteMoeHybridDecoder(_Decoder):
             embed_scale=embedding_multiplier,
             tie_embeddings=tie_word_embeddings,
             logits_divisor=logits_scaling,
+        )
+
+
+class KimiLinearDecoder(_Decoder):
+    """The ``kimi_linear`` decoder (Kimi-Linear) under the published
+    configuration's own keys, plus the share this process holds
+    (``layers_held``, ``experts_held``, ``vocab_held``, ``max_positions``, as
+    ``Glm4MoeLiteDecoder`` takes them). A layer's kind follows from
+    ``linear_attn_config``: the 1-based ids of ``kda_layers`` are gated
+    delta-rule layers (``KimiDeltaAttention``, ``num_heads`` x ``head_dim``,
+    convolutions of ``short_conv_kernel_size``, gate ranks of ``head_dim``),
+    those of ``full_attn_layers`` latent attention (``LatentAttention``, the
+    query straight from the input where ``q_lora_rank`` is null, no rotary
+    embedding under ``mla_use_nope``); a dense MLP in the first
+    ``first_k_dense_replace`` layers and sigmoid-routed experts with a
+    selection bias, renormalised, times ``routed_scaling_factor``, and
+    ``num_shared_experts`` after them; a norm before a block only; an untied
+    head."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size: int,
+        num_attention_heads: int,
+        q_lora_rank: Optional[int],
+        kv_lora_rank: int,
+        qk_nope_head_dim: int,
+        qk_rope_head_dim: int,
+        v_head_dim: int,
+        mla_use_nope: bool,
+        linear_attn_config: dict,
+        intermediate_size: int,
+        moe_intermediate_size: int,
+        num_experts: int,
+        num_experts_per_token: int,
+        num_shared_experts: int,
+        first_k_dense_replace: int,
+        moe_layer_freq: int,
+        moe_renormalize: bool,
+        moe_router_activation_func: str,
+        routed_scaling_factor: float,
+        num_expert_group: int,
+        topk_group: int,
+        num_hidden_layers: int,
+        rope_theta: float,
+        rope_scaling: Optional[dict],
+        rms_norm_eps: float,
+        tie_word_embeddings: bool,
+        vocab_size: int,
+        max_positions: int,
+        layers_held: Optional[Sequence[int]] = None,
+        experts_held: Optional[range] = None,
+        vocab_held: Optional[int] = None,
+    ):
+        if int(num_expert_group) != 1 or int(topk_group) != 1:
+            raise ValueError("num_expert_group and topk_group other than 1: group-limited routing is not implemented")
+        if int(moe_layer_freq) != 1:
+            raise ValueError("moe_layer_freq other than 1 is not implemented")
+        if rope_scaling is not None:
+            raise ValueError("rope_scaling is not implemented")
+        kda = {int(i) - 1 for i in linear_attn_config["kda_layers"]}  # published 1-based
+        full = {int(i) - 1 for i in linear_attn_config["full_attn_layers"]}
+        if kda & full or kda | full != set(range(int(num_hidden_layers))):
+            raise ValueError("kda_layers and full_attn_layers do not split the 1-based layers 1..num_hidden_layers")
+        self.layers_held = tuple(range(int(num_hidden_layers)) if layers_held is None else layers_held)
+        if not all(0 <= index < int(num_hidden_layers) for index in self.layers_held):
+            raise ValueError(f"layers_held {self.layers_held!r} is not within {num_hidden_layers} layers")
+        eps, max_positions = float(rms_norm_eps), int(max_positions)
+        layers = []
+        for index in self.layers_held:
+            if index in kda:
+                first = KimiDeltaAttention(
+                    hidden_size,
+                    linear_attn_config["num_heads"],
+                    linear_attn_config["head_dim"],
+                    conv_width=linear_attn_config["short_conv_kernel_size"],
+                    eps=eps,
+                )
+            else:
+                first = LatentAttention(
+                    hidden_size,
+                    num_attention_heads,
+                    q_rank=q_lora_rank,
+                    kv_rank=kv_lora_rank,
+                    nope_dim=qk_nope_head_dim,
+                    rope_dim=qk_rope_head_dim,
+                    v_dim=v_head_dim,
+                    slots=max_positions,  # every such layer is full attention: no ring
+                    rope_theta=rope_theta,
+                    eps=eps,
+                    rotary=not mla_use_nope,
+                )
+            if index < int(first_k_dense_replace):
+                mlp = _DenseMLP(hidden_size, intermediate_size, eps=eps, post_norm=False)
+            else:
+                mlp = SparseExperts(
+                    hidden_size,
+                    moe_intermediate_size,
+                    num_experts,
+                    num_experts_per_token,
+                    experts_held=experts_held,
+                    num_shared_experts=num_shared_experts,
+                    route_scale=routed_scaling_factor,
+                    route_norm=moe_renormalize,
+                    score_func=moe_router_activation_func,
+                    eps=eps,
+                    post_norm=False,
+                    route_norm_eps=1e-20,
+                )
+            layers.append(DecoderLayer(first, mlp))
+        super().__init__(
+            hidden_size=hidden_size,
+            eps=eps,
+            vocab_size=vocab_size,
+            vocab_held=vocab_held,
+            max_positions=max_positions,
+            layers=layers,
+            tie_embeddings=tie_word_embeddings,
         )
 
 

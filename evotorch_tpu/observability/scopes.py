@@ -43,7 +43,11 @@ over ``c``; the up-projections folded into the query and output paths stay
 convolution over the lane's window, the gated norm, ``out_proj``) and,
 INSIDE it, ``fwd_ssm_state`` (whatever touches the matrix state: decay,
 outer product, readout; on a TPU the kernel of ``net/ssmstate.py`` and the
-gathering of its small operands). ``instruction_scopes`` keeps reading the OUTERMOST
+gathering of its small operands); ``fwd_kda`` (a gated delta-rule block:
+norm, projections, the three convolutions, the gates, the output's gated
+norm, ``o_proj``) and, INSIDE it, ``fwd_kda_state`` (whatever touches its
+matrix state: decay, ``S'^T k``, the rank-1 update, readout, write-back).
+``instruction_scopes`` keeps reading the OUTERMOST
 rollout scope, so what read ``policy_forward`` before still does;
 ``instruction_scopes(..., names=FORWARD_SCOPES)`` reads the INNERMOST
 component among the forward's names.
@@ -122,6 +126,8 @@ FORWARD_SCOPES = (
     "fwd_latent_cache",
     "fwd_ssm",
     "fwd_ssm_state",
+    "fwd_kda",
+    "fwd_kda_state",
 )
 SEARCHER_PHASES = ("grad", "update", "ask", "evaluate", "status")
 _GENERATION = "generation"  # the span that encloses a step's phases

@@ -7,6 +7,7 @@ precision in the program's place (the control its bounds were set against).
     python scripts/lm_ring_wrap_check.py --control int8,float8_e4m3,bfloat16 [--seed 1] [--cpu --tiny]
         [--cell glm47_flash_ep8.decode512] [--seeds 10]
     python scripts/lm_ring_wrap_check.py --cell granite4_h_micro_pp4.decode256 --control int8,bfloat16
+    python scripts/lm_ring_wrap_check.py --cell kimi_linear_ep32.decode256 --control int8,bfloat16,no_correction,bf16_state
 
 Default: ``trinity_mini_ep8``'s share of the model (``benchmark/configs/``), a
 seeded trunk and one generation's rank-4 factors; ``--lanes`` lanes are
@@ -29,7 +30,9 @@ each of N seeds from ``--seed`` on, each drawing other lanes: the system's
 readings a cell's bounds are set from), and
 once more per named precision with the REFERENCE, its matrices rounded to
 that precision (int8 and float8 e4m3 scaled to the largest entry of a leaf),
-in the program's place, through the same comparison and the same limits.
+in the program's place, through the same comparison and the same limits (a
+name of the cell's driver's ``EQUATION_CONTROLS``, such as ``no_correction``,
+stands for the reference with that equation changed instead).
 Prints one JSON line with every comparison; exits non-zero unless the system
 comes out ok and int8, the nearest precision below bfloat16, does not.
 ``--cpu --tiny`` rehearses either mode on the CPU (a window of 16; the cell's
@@ -76,14 +79,16 @@ def control(args, files):
     workload = files.workload(args.cell)
     config = files.config(workload["config"])
     scale = {key: (value if args.tiny else config[key]) for key, value in config["rehearse"].items()}
-    session = files.driver(workload["driver"]).build(files, config, workload, args.seed, scale)
+    driver = files.driver(workload["driver"])
+    session = driver.build(files, config, workload, args.seed, scale)
+    equations = getattr(driver, "EQUATION_CONTROLS", {})  # a changed equation of the reference, by name
     for _ in range(2):
         session.generation()
     session.block()
     out = {"device": jax.devices()[0].device_kind, "scale": scale, "system": session.reference_checks(args.seed)}
     others = [session.reference_checks(seed) for seed in range(args.seed + 1, args.seed + args.seeds)]
     for kind in args.control.split(","):
-        out[kind] = session.reference_checks(args.seed, control=rounded(kind))
+        out[kind] = session.reference_checks(args.seed, control=kind if kind in equations else rounded(kind))
     verdict = {
         name: all(check["ok"] for check in checks.values())
         for name, checks in out.items()

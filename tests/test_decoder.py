@@ -720,7 +720,8 @@ def test_inner_scopes_sit_inside_policy_forward(family):
     outer = instruction_scopes(text, inherit=False)
     inner = instruction_scopes(text, inherit=False, names=FORWARD_SCOPES)
     named = {name: scope for name, scope in inner.items() if scope is not None}
-    worn = set(FORWARD_SCOPES) - {"fwd_ssm", "fwd_ssm_state"}  # a recurrent mixer's (tests/test_decoder_ssm.py)
+    # a recurrent mixer's (tests/test_decoder_ssm.py) and a gated delta-rule block's (tests/test_kimi_linear.py)
+    worn = set(FORWARD_SCOPES) - {"fwd_ssm", "fwd_ssm_state", "fwd_kda", "fwd_kda_state"}
     worn -= {"fwd_latent_cache"} if family.name == "afmoe" else set()
     assert set(named.values()) == worn
     assert all(outer[name] == "policy_forward" for name in named)  # the outermost name stays
