@@ -90,8 +90,12 @@ A layer's state sits under its first block's kind (``"attn"``, ``"ssm"`` or
 ``"kda"``). ``KimiDeltaAttention`` keeps the same two things in the same
 layout (windows of ``q``, ``k`` and ``v``; a matrix state a head, ``(key,
 value)``, stored turned), decayed by one factor a KEY CHANNEL and corrected by
-a rank-1 delta rule before its outer product is added, so the pass reads the
-state twice (``fwd_kda_state``, XLA's plain form).
+a rank-1 delta rule before its outer product is added, so the pass reduces
+over the whole state before it can write any of it (``fwd_kda_state``): ONE
+kernel a layer that holds a lane's state in VMEM for both (``net/kdastate.py``,
+on ``net/ssmstate.py``'s walk) where the program is lowered for a TPU and the
+sizes are the kernel's, XLA's plain form everywhere else, counted as the
+mixer's are (``updates``, ``kernel_updates``).
 When a lane ends an episode the mixer keeps, before it zeroes the lane, what
 its matrix states held, summed over ``state_dim`` (``ended``): nothing a
 step pays for, and what an evaluation's report holds against a reference's
@@ -120,7 +124,7 @@ import jax.numpy as jnp
 
 from ...envs.rigidbody import _by_platform
 from ...observability.scopes import scope
-from . import grouped, latent, ssmstate
+from . import grouped, kdastate, latent, ssmstate
 from .layers import Module
 
 __all__ = [
@@ -817,12 +821,12 @@ class KimiDeltaAttention(_LaneModule):
     float32, 1 GB a factor at 512 lanes), in the compute dtype and updated in
     float32. All of it is rewritten every step; the decay, ``S'^T k``, the
     rank-1 update, the readout and the write-back are one span
-    (``fwd_kda_state``), XLA's plain form (``_state_plain``) on every
-    platform. A lane that starts an episode has both zero. Kept beyond an
-    episode, as the mixer keeps them: what the matrix state held when the lane
-    last ENDED an episode, summed over the key axis (``ended``, ``(heads,
-    head_dim)``), the steps the state was rewritten and the times it was
-    zeroed."""
+    (``fwd_kda_state``: ``_state_pass``, one in-place kernel a layer on the
+    TPU, ``_state_plain`` elsewhere). A lane that starts an episode has both
+    zero. Kept beyond an episode, as the mixer keeps them: what the matrix
+    state held when the lane last ENDED an episode, summed over the key axis
+    (``ended``, ``(heads, head_dim)``), the steps the state was rewritten,
+    those of them the kernel rewrote, and the times it was zeroed."""
 
     block_key = "kda"
 
@@ -873,6 +877,7 @@ class KimiDeltaAttention(_LaneModule):
             "state": jnp.zeros((self.head_dim, self.heads, self.head_dim), F32),  # turned: (key, head, value)
             "ended": jnp.zeros((self.heads, self.head_dim), F32),
             "updates": zero,
+            "kernel_updates": zero,
             "resets": zero,
         }
 
@@ -892,14 +897,26 @@ class KimiDeltaAttention(_LaneModule):
         float32, both products over the key axis as multiplies and sums (no
         matrix unit, no pass of lower precision), the readout of the
         UNROUNDED state, one rounding to the stored dtype: the statement of
-        the equations. Returns the new state and the float32 readout ``(n,
-        heads, value)``."""
+        the equations, and what runs off the TPU and at sizes the kernel does
+        not take. Returns the new state and the float32 readout ``(n, heads,
+        value)``."""
         turned = lambda a: jnp.swapaxes(a, 1, 2)[..., None]  # (n, heads, key) -> (n, key, heads, 1)
         s = held.astype(F32) * turned(decay)
         read = jnp.sum(s * turned(k), axis=1)  # S'^T k: (n, heads, value)
         s = s + turned(k) * (beta[:, :, None] * (v - read))[:, None]
         out = jnp.sum(s * turned(q), axis=1)
         return s.astype(held.dtype), out
+
+    def _state_pass(self, held, decay, k, v, beta, q):
+        """``_state_plain``'s results and, per lane, the states a kernel
+        rewrote: one kernel that brings a lane's state through VMEM once and
+        rewrites it in place (``net/kdastate.py``) where the program is
+        lowered for a TPU and the sizes are the kernel's; the plain form
+        (none rewritten by a kernel) everywhere else."""
+        plain = lambda *args: (*self._state_plain(*args), jnp.zeros(held.shape[:1], jnp.int32))
+        if not kdastate.fits(held.shape[0], self.heads, self.head_dim, held.dtype):
+            return plain(held, decay, k, v, beta, q)
+        return _by_platform(kdastate.state_pass, plain, held, decay, k, v, beta, q)
 
     def _forward(self, acc, x, state):
         n, heads, hd, inner = x.shape[0], self.heads, self.head_dim, self.inner
@@ -918,7 +935,7 @@ class KimiDeltaAttention(_LaneModule):
             beta = jax.nn.sigmoid(acc.mm("b", xn).astype(F32))
             with scope("fwd_kda_state"):
                 decay = jnp.exp(rate * jax.nn.softplus(fed).reshape(n, heads, hd))
-                held, o = self._state_plain(state["state"], decay, k, v, beta, q)
+                held, o, rewrote = self._state_pass(state["state"], decay, k, v, beta, q)
             o = rms_norm(o, acc.vec("o_norm")[:, None, :], self.eps)
             gate = acc.mm("g_b", acc.mm("g_a", xn)).astype(F32).reshape(n, heads, hd)
             o = (o * jax.nn.sigmoid(gate)).reshape(n, inner).astype(x.dtype)
@@ -928,6 +945,7 @@ class KimiDeltaAttention(_LaneModule):
             "state": held,
             "ended": state["ended"],
             "updates": state["updates"] + 1,
+            "kernel_updates": state["kernel_updates"] + rewrote,
             "resets": state["resets"],
         }
 
@@ -1241,9 +1259,10 @@ class _Decoder(_LaneModule):
         episode, summed over ``state_dim`` (``ssm_ended_state``, ``(n,
         recurrent layers, heads, head_dim)``, as stored; zeros for a lane
         that ended none); where layers carry the gated delta rule, the same
-        four under ``kda_``: ``kda_state_updates``, ``kda_lane_resets``,
-        ``kda_state_bytes`` and ``kda_ended_state`` (each state summed over
-        its key axis, ``(n, KDA layers, heads, head_dim)``). Per lane, in
+        five under ``kda_``: ``kda_state_updates``,
+        ``kda_state_kernel_updates``, ``kda_lane_resets``, ``kda_state_bytes``
+        and ``kda_ended_state`` (each state summed over its key axis, ``(n,
+        KDA layers, heads, head_dim)``). Per lane, in
         step order (the last
         ``max_positions`` steps): the id each step consumed and the lane's
         position in its episode there, ``(n, steps)``; the model's token for

@@ -22,7 +22,9 @@ into the next slot and THEN block ``i - 1`` written back from the third; the
 read and the write never overlap each other, the float32 work runs under
 both. A lane's state comes into VMEM once, is updated there and goes back to
 where it lay (the state operand is aliased to the state result: the compiled
-loop goes on holding each state once).
+loop goes on holding each state once). The walk and its ``pallas_call``
+(``walk``, ``call``) take the lane's body as an argument: ``net/kdastate.py``
+runs a KDA layer's gated delta rule through the same schedule of DMAs.
 
 **The stored layout** is ``(state_dim, heads x head_dim)`` a lane: ``state_dim``
 runs along the sublanes of a register and (head, row) along its 128 lanes. The
@@ -56,11 +58,12 @@ differs from XLA's.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["KERNEL_NAME", "fits", "lane_group", "state_pass"]
+__all__ = ["KERNEL_NAME", "call", "fits", "lane_group", "state_pass", "walk"]
 
 #: the kernel's name in a compiled program's text
 KERNEL_NAME = "ssm_state_pass"
@@ -151,15 +154,18 @@ def _lane(l, small, state, y, a_spread, b_spread, c_spread, *, heads):
     jax.lax.fori_loop(0, wide, column, 0, unroll=True)
 
 
-def _kernel(small_hbm, state_hbm, out_hbm, y_hbm, states, smalls, ys, a_spread, b_spread, c_spread, sems, *, group, heads):
+def walk(small_hbm, state_hbm, out_hbm, y_hbm, states, smalls, ys, *scratch, group, lane):
     """Blocks of ``group`` lanes through three slots: block ``i`` is updated
     in slot ``i mod 3`` while block ``i + 1`` is read and then, from the
     block's middle lane on, block ``i - 1`` written: one direction at a time
     (the small operands and the readouts ride with their block, through two
-    slots each)."""
+    slots each). ``lane(l, small, state, y, *scratch)`` is one lane's pass
+    over the slots of its block; the last of ``scratch`` is the DMAs'
+    semaphores, the others are the body's own."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    *own, sems = scratch
     blocks = state_hbm.shape[0] // group
     lanes = lambda i: pl.ds(i * group, group)
 
@@ -190,22 +196,58 @@ def _kernel(small_hbm, state_hbm, out_hbm, y_hbm, states, smalls, ys, a_spread, 
         more, behind = i + 1 < blocks, i > 0
         pl.when(more)(lambda: begin(read(i + 1)))
 
-        def lane(l, carry):
+        def one(l, carry):
             @pl.when(l == group // 2)
             def _():  # the read has had half the block's arithmetic to end in; now the other direction
                 pl.when(more)(lambda: finish(read(i + 1)))
                 pl.when(behind)(lambda: begin(write(i - 1)))
 
-            _lane(l, smalls.at[i % 2], states.at[i % 3], ys.at[i % 2], a_spread, b_spread, c_spread, heads=heads)
+            lane(l, smalls.at[i % 2], states.at[i % 3], ys.at[i % 2], *own)
             return carry
 
-        jax.lax.fori_loop(0, group, lane, 0)
+        jax.lax.fori_loop(0, group, one, 0)
         pl.when(behind)(lambda: finish(write(i - 1)))
         return carry
 
     jax.lax.fori_loop(0, blocks, block, 0)
     begin(write(blocks - 1))
     finish(write(blocks - 1))
+
+
+def call(lane, small, state, y_rows, scratch, *, group, flops, beside=_VMEM_BESIDE, name, interpret):
+    """``walk`` as a ``pallas_call`` over ``state`` ``(n, ...)`` as stored,
+    rewritten in place (the state operand is aliased to the state result),
+    and ``small`` ``(n, rows, 128)`` float32, the lanes' small operands:
+    returns the new state and the float32 readouts ``(n,) + y_rows``.
+    ``scratch`` is the body's own VMEM beside the slots, ``flops`` what the
+    call computes (for XLA's scheduler, with the bytes it moves), ``beside``
+    the VMEM it asks for beyond the three slots of states."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = state.shape[0]
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(walk, group=group, lane=lane),
+        in_specs=[anywhere, anywhere],
+        out_specs=[anywhere, anywhere],
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype), jax.ShapeDtypeStruct((n,) + y_rows, F32)],
+        scratch_shapes=[
+            pltpu.VMEM((3, group) + state.shape[1:], state.dtype),
+            pltpu.VMEM((2, group) + small.shape[1:], F32),
+            pltpu.VMEM((2, group) + y_rows, F32),
+            *scratch,
+            pltpu.SemaphoreType.DMA((4, 3)),
+        ],
+        input_output_aliases={1: 0},
+        # what XLA's scheduler takes the call's length from: without it little is prefetched across the call
+        cost_estimate=pl.CostEstimate(
+            flops=flops, transcendentals=0, bytes_accessed=2 * state.nbytes + small.nbytes + 4 * n * math.prod(y_rows)
+        ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=3 * group * (state.nbytes // n) + beside),
+        name=name,
+        interpret=interpret,
+    )(small, state)
 
 
 def state_pass(state, decay, fed, b, c, *, interpret=False):
@@ -223,39 +265,22 @@ def state_pass(state, decay, fed, b, c, *, interpret=False):
 # a caller outside any program; inside one the call is inlined and the aliasing below decides)
 @functools.partial(jax.jit, static_argnames=("group", "interpret"), donate_argnums=(0,))
 def _state_pass(state, decay, fed, b, c, *, group, interpret):
-    from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, state_dim, inner = state.shape
     heads = decay.shape[1]
     decay = jnp.pad(decay.astype(F32), ((0, 0), (0, -heads % _LANES)))  # whole rows of 128
     small = jnp.concatenate([decay] + [x.astype(F32) for x in (fed, b, c)], axis=1).reshape(n, -1, _LANES)
-    y_rows = (inner // _LANES, _LANES)
     spread = lambda numbers: pltpu.VMEM((numbers, _LANES), F32)
-    anywhere = pl.BlockSpec(memory_space=pl.ANY)
-    new, y = pl.pallas_call(
-        functools.partial(_kernel, group=group, heads=heads),
-        in_specs=[anywhere, anywhere],
-        out_specs=[anywhere, anywhere],
-        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype), jax.ShapeDtypeStruct((n,) + y_rows, F32)],
-        scratch_shapes=[
-            pltpu.VMEM((3, group, state_dim, inner), state.dtype),
-            pltpu.VMEM((2, group) + small.shape[1:], F32),
-            pltpu.VMEM((2, group) + y_rows, F32),
-            spread(decay.shape[1]),
-            spread(state_dim),
-            spread(state_dim),
-            pltpu.SemaphoreType.DMA((4, 3)),
-        ],
-        input_output_aliases={1: 0},
-        # what XLA's scheduler takes the call's length from: without it little is prefetched across the call
-        cost_estimate=pl.CostEstimate(
-            flops=5 * state.size, transcendentals=0, bytes_accessed=2 * state.nbytes + small.nbytes + 4 * n * inner
-        ),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=3 * group * state_dim * inner * state.dtype.itemsize + _VMEM_BESIDE
-        ),
+    new, y = call(
+        functools.partial(_lane, heads=heads),
+        small,
+        state,
+        (inner // _LANES, _LANES),
+        [spread(decay.shape[1]), spread(state_dim), spread(state_dim)],
+        group=group,
+        flops=5 * state.size,
         name=KERNEL_NAME,
         interpret=interpret,
-    )(small, state)
+    )
     return new, y.reshape(n, inner), jnp.ones((n,), jnp.int32)
