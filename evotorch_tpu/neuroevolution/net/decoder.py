@@ -1,15 +1,19 @@
 """A language-model decoder as an evolvable policy, decoded stepwise.
 
-The modules of four families: ``afmoe`` (Trinity: gated grouped-query
+The modules of five families: ``afmoe`` (Trinity: gated grouped-query
 attention, a norm before and after every block, sigmoid-routed experts),
 ``glm4_moe_lite`` (GLM-4.7-Flash: latent attention, a norm before a block
 only), ``granitemoehybrid`` (Granite 4.0-H: Mamba-2 mixers with one
 grouped-query attention layer in ten, dense MLPs, a tied head, four
-multipliers) and ``kimi_linear`` (Kimi-Linear: gated delta-rule layers, KDA,
-with one latent-attention layer in four, sigmoid-routed experts). Token
+multipliers), ``kimi_linear`` (Kimi-Linear: gated delta-rule layers, KDA,
+with one latent-attention layer in four, sigmoid-routed experts) and
+``smallthinker`` (SmallThinker: grouped-query attention, full and without
+positions in one layer of four, a RoPE window in the others, and experts
+chosen BEFORE the attention by a softmax router: ReGLU experts). Token
 embedding, RMSNorm, an attention with a per-lane cache or a recurrence with a
-per-lane matrix state as the policy's recurrent state, SwiGLU, and a
-sigmoid-routed expert layer that is told which experts it holds. They follow the ``Module`` protocol of ``layers.py`` (``init`` /
+per-lane matrix state as the policy's recurrent state, SwiGLU, and an expert
+layer (sigmoid- or softmax-routed; SwiGLU or ReGLU experts) that is told
+which experts it holds. They follow the ``Module`` protocol of ``layers.py`` (``init`` /
 ``initial_state`` / ``apply(params, x, state)``), so a decoder is a policy
 like any other: the observation is one token id, the output the logits over
 the held vocabulary, the state what its layers carry from step to step.
@@ -137,10 +141,12 @@ __all__ = [
     "KimiDeltaAttention",
     "SparseExperts",
     "DecoderLayer",
+    "RouteAheadLayer",
     "AfmoeDecoder",
     "Glm4MoeLiteDecoder",
     "GraniteMoeHybridDecoder",
     "KimiLinearDecoder",
+    "SmallThinkerDecoder",
     "stepwise_logits",
 ]
 
@@ -423,38 +429,47 @@ class GatedAttention(_LaneModule):
         return {"k": k, "v": v, "t": jnp.where(mask, 0, state["t"]), "step": state["step"]}
 
     def _forward(self, acc, x, state):
+        with scope("fwd_attention"):
+            return self._attended(acc, x, rms_norm(x, acc.vec("in_norm"), self.eps), state)
+
+    def attend(self, acc, x, xn, state):
+        """The block on ``xn``, ``x`` already normed by the block's
+        ``in_norm``: the entry of a layer that reads the same norm before the
+        block (``RouteAheadLayer``)."""
+        with scope("fwd_attention"):
+            return self._attended(acc, x, xn, state)
+
+    def _attended(self, acc, x, xn, state):
         n, kv, hd, slots = x.shape[0], self.kv_heads, self.head_dim, self.slots
         group = self.heads // kv
-        with scope("fwd_attention"):
-            xn = rms_norm(x, acc.vec("in_norm"), self.eps)
-            q = acc.mm("q", xn).reshape(n, kv, group, hd)
-            k = acc.mm("k", xn).reshape(n, kv, hd)
-            v = acc.mm("v", xn).reshape(n, kv, hd)
-            if self.gate:
-                gate = acc.mm("g", xn)
-            if self.qk_norm:
-                q = rms_norm(q, acc.vec("q_norm")[:, None, None, :], self.eps)
-                k = rms_norm(k, acc.vec("k_norm")[:, None, :], self.eps)
-            t = state["t"]
-            if self.rope_theta is not None:
-                q = rope(q, t[:, None, None], self.rope_theta)
-                k = rope(k, t[:, None], self.rope_theta)
-            cache_dtype = state["k"].dtype
-            slot = jnp.mod(state["step"][0], slots)
-            at = (0, 0, slot, 0)
-            kc = jax.lax.dynamic_update_slice(state["k"], k[:, :, None, :].astype(cache_dtype), at)
-            vc = jax.lax.dynamic_update_slice(state["v"], v[:, :, None, :].astype(cache_dtype), at)
-            age = jnp.mod(slot - jnp.arange(slots, dtype=jnp.int32), slots)
-            readable = age[None, :] <= t[:, None]  # (n, slots)
-            scores = jnp.einsum("nkgd,nksd->nkgs", q.astype(cache_dtype), kc, preferred_element_type=F32)
-            scores = scores / math.sqrt(hd) if self.score_scale is None else scores * self.score_scale
-            scores = jnp.where(readable[:, None, None, :], scores, -jnp.inf)
-            weights = jax.nn.softmax(scores, axis=-1).astype(cache_dtype)
-            mixed = jnp.einsum("nkgs,nksd->nkgd", weights, vc, preferred_element_type=F32)
-            mixed = mixed.reshape(n, self.heads * hd)
-            if self.gate:
-                mixed = mixed * jax.nn.sigmoid(gate.astype(F32))
-            y = _joined(self, x, _closed(self, acc, acc.mm("o", mixed.astype(x.dtype))))
+        q = acc.mm("q", xn).reshape(n, kv, group, hd)
+        k = acc.mm("k", xn).reshape(n, kv, hd)
+        v = acc.mm("v", xn).reshape(n, kv, hd)
+        if self.gate:
+            gate = acc.mm("g", xn)
+        if self.qk_norm:
+            q = rms_norm(q, acc.vec("q_norm")[:, None, None, :], self.eps)
+            k = rms_norm(k, acc.vec("k_norm")[:, None, :], self.eps)
+        t = state["t"]
+        if self.rope_theta is not None:
+            q = rope(q, t[:, None, None], self.rope_theta)
+            k = rope(k, t[:, None], self.rope_theta)
+        cache_dtype = state["k"].dtype
+        slot = jnp.mod(state["step"][0], slots)
+        at = (0, 0, slot, 0)
+        kc = jax.lax.dynamic_update_slice(state["k"], k[:, :, None, :].astype(cache_dtype), at)
+        vc = jax.lax.dynamic_update_slice(state["v"], v[:, :, None, :].astype(cache_dtype), at)
+        age = jnp.mod(slot - jnp.arange(slots, dtype=jnp.int32), slots)
+        readable = age[None, :] <= t[:, None]  # (n, slots)
+        scores = jnp.einsum("nkgd,nksd->nkgs", q.astype(cache_dtype), kc, preferred_element_type=F32)
+        scores = scores / math.sqrt(hd) if self.score_scale is None else scores * self.score_scale
+        scores = jnp.where(readable[:, None, None, :], scores, -jnp.inf)
+        weights = jax.nn.softmax(scores, axis=-1).astype(cache_dtype)
+        mixed = jnp.einsum("nkgs,nksd->nkgd", weights, vc, preferred_element_type=F32)
+        mixed = mixed.reshape(n, self.heads * hd)
+        if self.gate:
+            mixed = mixed * jax.nn.sigmoid(gate.astype(F32))
+        y = _joined(self, x, _closed(self, acc, acc.mm("o", mixed.astype(x.dtype))))
         return y, {"k": kc, "v": vc, "t": t + 1, "step": state["step"] + 1}
 
 
@@ -951,16 +966,23 @@ class KimiDeltaAttention(_LaneModule):
 
 
 class SparseExperts(_LaneModule):
-    """``RMSNorm``-wrapped sigmoid-routed experts with a shared expert:
-    ``x + RMSNorm(sum_e w_e E_e(y) + S(y))``, ``y = RMSNorm(x)`` (``x +
-    sum_e w_e E_e(y) + S(y)`` without ``post_norm``); router logits, sigmoid
-    and top-k in float32; the ``expert_bias`` selects and does not weigh; the
-    weights are the selected scores, normalised over the selected
-    (``route_norm``; their sum plus ``route_norm_eps``) and times
+    """``RMSNorm``-wrapped routed experts with a shared expert: ``x +
+    RMSNorm(sum_e w_e E_e(y) + S(y))``, ``y = RMSNorm(x)`` (``x + sum_e w_e
+    E_e(y) + S(y)`` without ``post_norm``, no ``S`` without shared experts);
+    ``E_e(y) = W_down(act(W_gate y) * W_up y)`` with ``act`` the family's
+    ``activation`` (``"silu"``: SwiGLU; ``"relu"``: ReGLU). Router logits,
+    scores and top-k in float32. ``score_func="sigmoid"``: the scores are
+    the logits' sigmoids, and the ``expert_bias`` leaf, which every sigmoid
+    family has, selects and does not weigh; ``score_func="softmax"`` (no
+    bias leaf): the scores are the softmax over all logits and the ``top_k``
+    largest logits are chosen. The weights are the chosen scores, normalised
+    over the chosen (``route_norm``; their sum plus ``route_norm_eps``: for
+    the softmax, the softmax over the chosen logits alone), times
     ``route_scale``. ``experts_held``:
     the range of expert ids whose weights this module holds; the others'
     terms are left out. Held experts are stacked ``(held, in, out)``: the
-    grouped product's right-hand side."""
+    grouped product's right-hand side. ``routed`` is the entry of a layer
+    that decides the routes itself (``RouteAheadLayer``)."""
 
     def __init__(
         self,
@@ -977,9 +999,13 @@ class SparseExperts(_LaneModule):
         eps: float = 1e-5,
         post_norm: bool = True,
         route_norm_eps: float = 0.0,
+        activation: str = "silu",
     ):
-        if score_func != "sigmoid":
-            raise ValueError(f"score_func {score_func!r}: only the sigmoid router is implemented")
+        if score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func {score_func!r}: only the sigmoid and softmax routers are implemented")
+        if activation not in grouped.ACTIVATIONS:
+            raise ValueError(f"activation {activation!r}: one of {sorted(grouped.ACTIVATIONS)}")
+        self.score_func, self.activation = score_func, activation
         self.dim, self.width = int(dim), int(width)
         self.num_experts, self.top_k = int(num_experts), int(experts_per_token)
         held = range(self.num_experts) if experts_held is None else experts_held
@@ -996,13 +1022,14 @@ class SparseExperts(_LaneModule):
         params = {
             "in_norm": jnp.ones((self.dim,), F32),
             "router": _normal(kr, (self.num_experts, self.dim)),
-            "expert_bias": jnp.zeros((self.num_experts,), F32),
             "experts": {
                 "gate": _normal(kg, (e, self.dim, self.width)),
                 "up": _normal(ku, (e, self.dim, self.width)),
                 "down": _normal(kd, (e, self.width, self.dim)),
             },
         }
+        if self.score_func == "sigmoid":
+            params["expert_bias"] = jnp.zeros((self.num_experts,), F32)
         if self.post_norm:
             params["post_norm"] = jnp.ones((self.dim,), F32)
         if self.shared is not None:
@@ -1025,8 +1052,12 @@ class SparseExperts(_LaneModule):
     def route(self, acc, y):
         """Expert ids ``(n, top_k)`` and their float32 weights."""
         logits = acc.mm("router", y.astype(F32), precision=jax.lax.Precision.HIGHEST)
-        scores = jax.nn.sigmoid(logits)
-        _, chosen = jax.lax.top_k(scores + acc.vec("expert_bias").astype(F32), self.top_k)
+        if self.score_func == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = jax.lax.top_k(logits, self.top_k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(scores + acc.vec("expert_bias").astype(F32), self.top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if self.route_norm:
             total = jnp.sum(weights, axis=-1, keepdims=True)
@@ -1034,6 +1065,9 @@ class SparseExperts(_LaneModule):
                 total = total + self.route_norm_eps
             weights = weights / total
         return chosen, weights * self.route_scale
+
+    def _act(self, gate):
+        return grouped.ACTIVATIONS[self.activation](gate)
 
     def _experts_dense(self, params, y, chosen, weights):
         """One lane's held experts, all computed and weighed by the router's
@@ -1045,7 +1079,7 @@ class SparseExperts(_LaneModule):
         x = y[0]
         gate = jnp.einsum("i,eiw->ew", x, params["gate"].astype(x.dtype))
         up = jnp.einsum("i,eiw->ew", x, params["up"].astype(x.dtype))
-        out = jnp.einsum("ew,ewo->eo", jax.nn.silu(gate) * up, params["down"].astype(x.dtype))
+        out = jnp.einsum("ew,ewo->eo", self._act(gate) * up, params["down"].astype(x.dtype))
         mixed = jnp.sum(out.astype(F32) * per_expert[:, None], axis=0)[None].astype(y.dtype)
         return mixed, (per_expert > 0).astype(jnp.int32)
 
@@ -1075,7 +1109,7 @@ class SparseExperts(_LaneModule):
                 thin, jnp.swapaxes(f.b, 1, 2).astype(x.dtype), sizes
             )
 
-        hidden = jax.nn.silu(grouped("gate", x_rows)) * grouped("up", x_rows)
+        hidden = self._act(grouped("gate", x_rows)) * grouped("up", x_rows)
         out = grouped("down", hidden).astype(F32) * weights.reshape(-1)[order][:, None]
         out = jnp.where(hit[:, None], out, 0.0)  # rows past the last group hold no pair
         return place.T @ out.astype(y.dtype), sizes, jnp.zeros((), jnp.int32)
@@ -1094,14 +1128,20 @@ class SparseExperts(_LaneModule):
             size > 1 for size in mesh.shape.values()
         ):
             return self._experts_plain(center, factors, z, y, local, weights)
-        return _by_platform(
-            grouped.held_experts, self._experts_plain, center, factors, z, y, local, weights
-        )
+        kernel = grouped.held_experts_for(self.activation)
+        return _by_platform(kernel, self._experts_plain, center, factors, z, y, local, weights)
 
     def _forward(self, acc, x, state):
         with scope("fwd_router"):
             y = rms_norm(x, acc.vec("in_norm"), self.eps)
             chosen, weights = self.route(acc, y)
+        return self.routed(acc, x, y, chosen, weights, state)
+
+    def routed(self, acc, x, y, chosen, weights, state):
+        """``x`` and what the experts add for ``y``, the block's normed input,
+        under routes decided by the caller (``chosen`` ``(n, top_k)`` and
+        their float32 ``weights``, as ``route`` gives them), with the state
+        the block carries on."""
         with scope("fwd_experts"):
             if acc.z is None:
                 mixed, load = self._experts_dense(acc.p["experts"], y, chosen, weights)
@@ -1174,6 +1214,36 @@ class DecoderLayer(_LaneModule):
         x, first = self.attention._forward(acc.sub(self.first), x, state[self.first])
         x, mlp = self.mlp._forward(acc.sub("mlp"), x, state["mlp"])
         return x, {self.first: first, "mlp": mlp}
+
+
+class RouteAheadLayer(DecoderLayer):
+    """A layer that chooses its experts BEFORE its attention runs: ``u =
+    RMSNorm(x)`` with the attention's ``in_norm``, read once by the router
+    and by the attention alike; the routes decided from ``u``
+    (``fwd_router``, which holds that norm too); the attention on ``u``
+    (``GatedAttention.attend``); then the experts on the post-attention
+    stream, ``y = RMSNorm(h)`` with the expert block's own ``in_norm``, under
+    the routes decided ahead (``SparseExperts.routed``, ``fwd_experts``).
+    Parameters as ``DecoderLayer``'s; the expert block's state also counts,
+    per lane, the (lane, expert) pairs routed ahead (``ahead``: ``top_k`` a
+    step, never reset)."""
+
+    def initial_state(self):
+        state = super().initial_state()
+        return {**state, "mlp": {**state["mlp"], "ahead": jnp.zeros((), jnp.int32)}}
+
+    def _forward(self, acc, x, state):
+        attention, experts = self.attention, self.mlp
+        first, mlp = acc.sub(self.first), acc.sub("mlp")
+        with scope("fwd_router"):
+            u = rms_norm(x, first.vec("in_norm"), attention.eps)
+            chosen, weights = experts.route(mlp, u)
+        h, attended = attention.attend(first, x, u, state[self.first])
+        with scope("fwd_experts"):
+            y = rms_norm(h, mlp.vec("in_norm"), experts.eps)
+        out, routed = experts.routed(mlp, h, y, chosen, weights, state["mlp"])
+        routed = {**routed, "ahead": state["mlp"]["ahead"] + experts.top_k}
+        return out, {self.first: attended, "mlp": routed}
 
 
 class _Decoder(_LaneModule):
@@ -1262,7 +1332,9 @@ class _Decoder(_LaneModule):
         five under ``kda_``: ``kda_state_updates``,
         ``kda_state_kernel_updates``, ``kda_lane_resets``, ``kda_state_bytes``
         and ``kda_ended_state`` (each state summed over its key axis, ``(n,
-        KDA layers, heads, head_dim)``). Per lane, in
+        KDA layers, heads, head_dim)``); where layers route ahead of their
+        attention (``RouteAheadLayer``), the pairs so routed, summed over
+        steps, lanes and layers (``expert_pairs_routed_ahead``). Per lane, in
         step order (the last
         ``max_positions`` steps): the id each step consumed and the lane's
         position in its episode there, ``(n, steps)``; the model's token for
@@ -1291,6 +1363,9 @@ class _Decoder(_LaneModule):
                 f"{kind}_state_bytes": jnp.asarray(held, F32),  # past int32 at the cell's size
                 f"{kind}_ended_state": jnp.stack([m["ended"] for m in blocks], axis=1),
             })
+        ahead = [m["ahead"] for m in sparse if "ahead" in m]
+        if ahead:
+            report["expert_pairs_routed_ahead"] = sum((jnp.sum(a) for a in ahead), zero)
         return {
             **report,
             "expert_pairs_held": sum((jnp.sum(m["hits"]) for m in sparse), zero),
@@ -1364,6 +1439,8 @@ class AfmoeDecoder(_Decoder):
         experts_held: Optional[range] = None,
         vocab_held: Optional[int] = None,
     ):
+        if score_func != "sigmoid":
+            raise ValueError(f"score_func {score_func!r}: the family selects with a bias, under the sigmoid router only")
         self.layers_held = tuple(range(len(layer_types)) if layers_held is None else layers_held)
         eps, max_positions = float(rms_norm_eps), int(max_positions)
         layers = []
@@ -1653,6 +1730,11 @@ class KimiLinearDecoder(_Decoder):
             raise ValueError("num_expert_group and topk_group other than 1: group-limited routing is not implemented")
         if int(moe_layer_freq) != 1:
             raise ValueError("moe_layer_freq other than 1 is not implemented")
+        if moe_router_activation_func != "sigmoid":
+            raise ValueError(
+                f"moe_router_activation_func {moe_router_activation_func!r}: the family selects with a bias,"
+                " under the sigmoid score_func only"
+            )
         if rope_scaling is not None:
             raise ValueError("rope_scaling is not implemented")
         kda = {int(i) - 1 for i in linear_attn_config["kda_layers"]}  # published 1-based
@@ -1705,6 +1787,101 @@ class KimiLinearDecoder(_Decoder):
                     route_norm_eps=1e-20,
                 )
             layers.append(DecoderLayer(first, mlp))
+        super().__init__(
+            hidden_size=hidden_size,
+            eps=eps,
+            vocab_size=vocab_size,
+            vocab_held=vocab_held,
+            max_positions=max_positions,
+            layers=layers,
+            tie_embeddings=tie_word_embeddings,
+        )
+
+
+class SmallThinkerDecoder(_Decoder):
+    """The ``smallthinker`` decoder (SmallThinker-21BA3B) under the published
+    configuration's own keys, plus the share this process holds
+    (``layers_held``, ``experts_held``, ``vocab_held``, ``max_positions``, as
+    ``AfmoeDecoder`` takes them). Every layer routes AHEAD of its attention
+    (``RouteAheadLayer``): a softmax router over ``moe_num_primary_experts``
+    (``moe_primary_router_apply_softmax``) reads the attention's normed
+    input, keeps the ``moe_num_active_primary_experts`` largest logits and
+    renormalises their weights over the chosen (``norm_topk_prob``); no
+    shared expert, no selection bias; ReGLU experts (``relu(gate) * up``) of
+    width ``moe_ffn_hidden_size`` on the post-attention stream. Attention is
+    grouped-query without gate, per-head norms or a norm after the block:
+    RoPE where ``rope_layout`` is 1 and no positions where it is 0, a ring
+    of ``sliding_window_size`` slots where ``sliding_window_layout`` is 1 and
+    full attention where it is 0. A norm before a block only; no embedding
+    scale; the head untied unless ``tie_word_embeddings``. The family's
+    "secondary experts" have no key in the configuration and are not built."""
+
+    def __init__(
+        self,
+        *,
+        hidden_size: int,
+        num_attention_heads: int,
+        num_key_value_heads: int,
+        head_dim: int,
+        moe_ffn_hidden_size: int,
+        moe_num_primary_experts: int,
+        moe_num_active_primary_experts: int,
+        moe_primary_router_apply_softmax: bool,
+        norm_topk_prob: bool,
+        num_hidden_layers: int,
+        rope_layout: Sequence[int],
+        sliding_window_layout: Sequence[int],
+        sliding_window_size: int,
+        rope_theta: float,
+        rope_scaling: Optional[dict],
+        rms_norm_eps: float,
+        tie_word_embeddings: bool,
+        vocab_size: int,
+        max_positions: int,
+        layers_held: Optional[Sequence[int]] = None,
+        experts_held: Optional[range] = None,
+        vocab_held: Optional[int] = None,
+    ):
+        if not moe_primary_router_apply_softmax:
+            raise ValueError("moe_primary_router_apply_softmax false: only the softmax router is implemented here")
+        if rope_scaling is not None:
+            raise ValueError("rope_scaling is not implemented")
+        count = int(num_hidden_layers)
+        for name, layout in (("rope_layout", rope_layout), ("sliding_window_layout", sliding_window_layout)):
+            if len(layout) != count or not set(layout) <= {0, 1}:
+                raise ValueError(f"{name} must give a 0 or a 1 for each of the {count} layers")
+        self.layers_held = tuple(range(count) if layers_held is None else layers_held)
+        if not all(0 <= index < count for index in self.layers_held):
+            raise ValueError(f"layers_held {self.layers_held!r} is not within {count} layers")
+        eps, max_positions = float(rms_norm_eps), int(max_positions)
+        layers = []
+        for index in self.layers_held:
+            attention = GatedAttention(
+                hidden_size,
+                num_attention_heads,
+                num_key_value_heads,
+                head_dim,
+                slots=min(int(sliding_window_size), max_positions) if sliding_window_layout[index] else max_positions,
+                rope_theta=rope_theta if rope_layout[index] else None,  # no positions where the layout says 0
+                eps=eps,
+                gate=False,
+                qk_norm=False,
+                post_norm=False,
+            )
+            experts = SparseExperts(
+                hidden_size,
+                moe_ffn_hidden_size,
+                moe_num_primary_experts,
+                moe_num_active_primary_experts,
+                experts_held=experts_held,
+                num_shared_experts=0,
+                route_norm=norm_topk_prob,
+                score_func="softmax",
+                eps=eps,
+                post_norm=False,
+                activation="relu",
+            )
+            layers.append(RouteAheadLayer(attention, experts))
         super().__init__(
             hidden_size=hidden_size,
             eps=eps,

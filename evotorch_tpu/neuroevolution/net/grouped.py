@@ -3,10 +3,12 @@
 ``decoder.py:SparseExperts`` sends every lane to ``top_k`` experts and holds
 some of them. What the held ones add is, per (lane, expert) pair,
 
-    w * down_e(silu(gate_e(y)) * up_e(y)),    m_e(x) = x @ W_e + ((x @ a_e) * z) @ b_e^T
+    w * down_e(act(gate_e(y)) * up_e(y)),    m_e(x) = x @ W_e + ((x @ a_e) * z) @ b_e^T
 
-with ``W_e`` the trunk's matrix of expert ``e`` and ``a_e``, ``b_e`` the
-rank-``k`` factors of the lanes' deltas (``net/lowrank.py``). The plain form
+with ``W_e`` the trunk's matrix of expert ``e``, ``a_e``, ``b_e`` the
+rank-``k`` factors of the lanes' deltas (``net/lowrank.py``) and ``act`` the
+family's activation, a static argument (``ACTIVATIONS``: ``silu`` for SwiGLU
+experts, ``relu`` for ReGLU ones). The plain form
 (``SparseExperts._experts_plain``) sorts the pairs by expert and hands XLA's
 ``ragged_dot`` the stacked matrices, nine times a layer, with one-hot matmuls
 around them; on the v5e each of them walks the experts' weights at a third of
@@ -25,7 +27,7 @@ into the resident float32 result by 0/1 matrices built from each lane's rank
 within the expert (a ``top_k`` picks an expert at most once per lane, so an
 expert's rows are a subset of the lanes, in lane order: a ``cumsum`` over a
 ``(held, lanes)`` mask takes the place of the sort); the rank-``k``
-companions, ``silu(gate) * up`` and the router's weight ride in the same
+companions, ``act(gate) * up`` and the router's weight ride in the same
 visit. Operands in the compute dtype, float32 accumulation on the MXU.
 
 Rounding, against the plain form in bfloat16: ``hidden`` is rounded once (it
@@ -41,12 +43,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ROW_TILE", "KERNEL_NAME", "fits", "held_experts"]
+__all__ = ["ROW_TILE", "KERNEL_NAME", "ACTIVATIONS", "fits", "held_experts", "held_experts_for"]
 
 #: rows of one visit: the MXU's height, so a thinner tile would cost the same
 ROW_TILE = 128
 #: the kernel's name in a compiled program's text
 KERNEL_NAME = "held_experts_grouped"
+#: the experts' activation, ``act(gate) * up``, by name
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 _LANES = 128
 #: bytes of one matrix's block in VMEM (three matrices, two buffers each)
@@ -83,7 +87,7 @@ def _width_tile(dim: int, width: int, itemsize: int) -> int:
 
 def _kernel(sizes_ref, rank_row_ref, rank_col_ref, weight_ref, y_ref, z_ref,
             gate_ref, up_ref, down_ref, gate_a_ref, gate_b_ref, up_a_ref, up_b_ref,
-            down_a_ref, down_b_ref, out_ref, *, k):  # fmt: skip
+            down_a_ref, down_b_ref, out_ref, *, k, activation):  # fmt: skip
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -115,7 +119,7 @@ def _kernel(sizes_ref, rank_row_ref, rank_col_ref, weight_ref, y_ref, z_ref,
 
         gate = product(x, gate_ref, gate_a_ref, gate_b_ref)
         up = product(x, up_ref, up_a_ref, up_b_ref)
-        hidden = (jax.nn.silu(gate) * up).astype(dtype)
+        hidden = (ACTIVATIONS[activation](gate) * up).astype(dtype)
         out = product(hidden, down_ref, down_a_ref, down_b_ref) * weight
         columns = jax.lax.broadcasted_iota(jnp.int32, (n, ROW_TILE), 1) + first
         scatter = (rank_col_ref[0] == columns).astype(dtype)  # (n, ROW_TILE)
@@ -126,7 +130,7 @@ def _kernel(sizes_ref, rank_row_ref, rank_col_ref, weight_ref, y_ref, z_ref,
     jax.lax.fori_loop(0, tiles, visit, None)
 
 
-def held_experts(center, factors, z, y, local, weights, *, interpret=False):
+def held_experts(center, factors, z, y, local, weights, *, activation="silu", interpret=False):
     """What the held experts add to every lane, the lanes on each of them,
     and the row tiles visited.
 
@@ -137,7 +141,7 @@ def held_experts(center, factors, z, y, local, weights, *, interpret=False):
     ``local`` ``(n, top_k)`` the chosen experts counted from the first held
     one (anything outside ``[0, held)`` is not held) and ``weights`` their
     float32 router weights. Returns ``(n, dim)`` in ``y``'s dtype, ``(held,)``
-    int32 and an int32 scalar."""
+    int32 and an int32 scalar. ``activation``: a name of ``ACTIVATIONS``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -180,7 +184,7 @@ def held_experts(center, factors, z, y, local, weights, *, interpret=False):
         (per_expert(k, dim), transposed("down", "b")),
     ]
     out = pl.pallas_call(
-        functools.partial(_kernel, k=k),
+        functools.partial(_kernel, k=k, activation=activation),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(held, width // tile),
@@ -196,3 +200,12 @@ def held_experts(center, factors, z, y, local, weights, *, interpret=False):
     )(sizes, *(array for _, array in operands))
     tiles = jnp.sum((sizes + ROW_TILE - 1) // ROW_TILE, dtype=jnp.int32)
     return out[:n].astype(dtype), sizes, tiles
+
+
+@functools.cache
+def held_experts_for(activation):
+    """``held_experts`` with its ``activation`` bound: ONE callable a name, so
+    that every layer's call is the same function to jax's caches (a fresh
+    ``partial`` a call is not, and lowers the program under other private
+    names)."""
+    return functools.partial(held_experts, activation=activation)

@@ -4,23 +4,28 @@ steps never fill the 2,048-slot ring), and the cell's comparison with a lower
 precision in the program's place (the control its bounds were set against).
 
     python scripts/lm_ring_wrap_check.py [--steps 2304] [--lanes 4] [--seed 1] [--cpu --tiny]
+    python scripts/lm_ring_wrap_check.py --cell smallthinker_ep4.decode128 [--steps 4352] [--lanes 4]
     python scripts/lm_ring_wrap_check.py --control int8,float8_e4m3,bfloat16 [--seed 1] [--cpu --tiny]
         [--cell glm47_flash_ep8.decode512] [--seeds 10]
     python scripts/lm_ring_wrap_check.py --cell granite4_h_micro_pp4.decode256 --control int8,bfloat16
     python scripts/lm_ring_wrap_check.py --cell kimi_linear_ep32.decode256 --control int8,bfloat16,no_correction,bf16_state
+    python scripts/lm_ring_wrap_check.py --cell smallthinker_ep4.decode128 --control int8,bfloat16,route_after,swiglu --seeds 6
 
-Default: ``trinity_mini_ep8``'s share of the model (``benchmark/configs/``), a
-seeded trunk and one generation's rank-4 factors; ``--lanes`` lanes are
-teacher-forced through a seeded id sequence of ``--steps`` tokens by the
-population-wide stepwise forward (``net/decoder.py:stepwise_logits``: the
-forward the rollout steps, cache as carried state, ``sliding_window`` 2,048 so
-the ring wraps at step 2,048), and the plain whole-sequence reference
-(``benchmark/reference/afmoe_decoder.py``) computes the same logits on each
-lane's weights, layer by layer, with the routes the system chose. Prints one
-JSON line: relative RMS error of all logits, of the logits at the positions
-past the wrap alone, and the share of top-k sets that differ; exits non-zero
-where the error past the wrap (of all positions, in a replay too short to
-wrap) exceeds the cell's bound (``drivers/oo_lm_searcher.py:LOGIT_RTOL``).
+Default: the share of the model of ``--cell`` (default
+``trinity_mini_ep8.decode256``; a cell whose driver names its window's key,
+``WINDOW_KEY``, and builds its network, ``network``, such as
+``smallthinker_ep4.decode128``), a seeded trunk and one generation's rank-4
+factors; ``--lanes`` lanes are teacher-forced through a seeded id sequence of
+``--steps`` tokens (default: the window and 256 more) by the population-wide
+stepwise forward (``net/decoder.py:stepwise_logits``: the forward the rollout
+steps, cache as carried state, the published window, 2,048 slots for Trinity
+and 4,096 for SmallThinker, so the ring wraps), and the cell's plain
+whole-sequence reference computes the same logits on each lane's weights,
+layer by layer, with the routes the system chose. Prints one JSON line:
+relative RMS error of all logits, of the logits at the positions past the
+wrap alone, and the share of top-k sets that differ; exits non-zero where the
+error past the wrap (of all positions, in a replay too short to wrap) exceeds
+the cell's bound (its driver's ``LOGIT_RTOL``).
 
 ``--control``: the cell's own session (``--cell``, default
 ``trinity_mini_ep8.decode256``: its driver, its lanes and steps; two
@@ -105,13 +110,13 @@ def control(args, files):
 
 def main(argv):
     parser = argparse.ArgumentParser()
-    parser.add_argument("--steps", type=int, default=2304)
+    parser.add_argument("--steps", type=int, default=None, help="default: the window and 256 more")
     parser.add_argument("--lanes", type=int, default=4)
     parser.add_argument("--seed", type=int, default=1)
     parser.add_argument("--cpu", action="store_true")
     parser.add_argument("--tiny", action="store_true")
     parser.add_argument("--control", default=None, help="precisions, comma-separated: int8,float8_e4m3,bfloat16")
-    parser.add_argument("--cell", default="trinity_mini_ep8.decode256", help="the cell --control runs")
+    parser.add_argument("--cell", default="trinity_mini_ep8.decode256", help="the cell whose model is checked")
     parser.add_argument("--seeds", type=int, default=1, help="--control: seeds the system's comparison is drawn with")
     args = parser.parse_args(argv)
     if args.cpu:
@@ -122,7 +127,11 @@ def main(argv):
     files = BenchmarkFiles(ROOT)
     if args.control:
         return control(args, files)
-    config = files.config("trinity_mini_ep8")
+    workload = files.workload(args.cell)
+    config = files.config(workload["config"])
+    driver = files.driver(workload["driver"])
+    generic = files.driver("oo_lm_searcher")  # the comparison's pieces
+    window_key = getattr(driver, "WINDOW_KEY", "sliding_window")
 
     import jax
     import jax.numpy as jnp
@@ -135,23 +144,29 @@ def main(argv):
     from evotorch_tpu.tools.lowrank import TrunkDeltaParamsBatch
 
     setup_backend(force_cpu=args.cpu)
-    driver = files.driver("oo_lm_searcher")
-    scale = {"kept_sparse_layers": 1, "vocab_held": 512} if args.tiny else {}
+    # the rehearsal's layers and rows, every width kept
+    scale = {k: v for k, v in config["rehearse"].items() if k.startswith("kept_") or k == "vocab_held"} if args.tiny else {}
     if args.tiny:
-        config = dict(config, sliding_window=16)
+        config = dict(config, **{window_key: 16})
+    if args.steps is None:
+        args.steps = int(config[window_key]) + 256
+    if args.tiny:
         args.steps = min(args.steps, 40)
     ref = files.module_at(config["reference"]["forward"])
     s = ref.sizes(config, scale)
-    first, past = config["experts_held"]
-    net = AfmoeDecoder(
-        **{key: config[key] for key in driver.MODEL_KEYS},
-        num_experts=int(config["published"]["num_experts"]),
-        vocab_size=int(config["published"]["vocab_size"]),
-        max_positions=args.steps,
-        layers_held=s["layers"],
-        experts_held=range(int(first), int(past)),
-        vocab_held=s["vocab"],
-    )
+    if hasattr(driver, "network"):
+        net = driver.network(config, s, args.steps)
+    else:
+        first, past = config["experts_held"]
+        net = AfmoeDecoder(
+            **{key: config[key] for key in driver.MODEL_KEYS},
+            num_experts=int(config["published"]["num_experts"]),
+            vocab_size=int(config["published"]["vocab_size"]),
+            max_positions=args.steps,
+            layers_held=s["layers"],
+            experts_held=range(int(first), int(past)),
+            vocab_held=s["vocab"],
+        )
     policy = FlatParamsPolicy(net)
     dtype = None if args.cpu else jnp.bfloat16
     k_center, k_factors, k_coeffs, k_ids = jax.random.split(jax.random.key(args.seed), 4)
@@ -175,8 +190,8 @@ def main(argv):
     logits, routes = system(batch, ids)
     window = s["window"]
     positions = np.broadcast_to(np.arange(args.steps), ids.shape)
-    found = driver.reference_comparison(
-        driver.LaneReference(ref, s, policy), batch, range(args.lanes), np.asarray(ids), positions,
+    found = generic.reference_comparison(
+        generic.LaneReference(ref, s, policy), batch, range(args.lanes), np.asarray(ids), positions,
         np.asarray(logits), np.asarray(routes), cuts=(0, window),
     )
     error = dict(zip(("all", "past_wrap"), found["relative_rms_error"]))

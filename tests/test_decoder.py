@@ -407,6 +407,13 @@ def test_absorbed_attention_equals_the_plain_form():
     assert int(state["read"]) == STEPS * (STEPS + 1) // 2
 
 
+def test_the_afmoe_constructor_refuses_a_router_without_its_bias():
+    """The family selects with ``expert_bias``; the expert layer has that
+    leaf under the sigmoid router alone."""
+    with pytest.raises(ValueError, match="score_func"):
+        AfmoeDecoder(**{**MODEL, "score_func": "softmax"}, num_experts=8, vocab_size=64, max_positions=STEPS)
+
+
 @pytest.mark.parametrize(
     "refused, match",
     [
